@@ -1,0 +1,570 @@
+"""chip_smoke.py — does the system still start on the chip?
+
+One process, no children: it drives the main path once through the
+entry points a user would call, at the full width of one model the repo
+supports (the `transformer_flash` geometry: GPT, hidden 1024, 16 heads,
+6 layers, vocab 32768, seq 2048, bf16, about 111M parameters; weights
+random from a seed), and checks what comes out by the repo's own means.
+
+Phases, in order; each prints its result and the compile seconds it paid:
+
+  device           platform must be "tpu", or the run ends non-zero
+  train_layer      models/train.py step with AdamW, flash kernels in its HLO
+  train_executor   static GPT through Executor.train_from_dataset with
+                   default flags (the AMP + fusion substitute)
+  serve            DecodeEngine with default slots / max_len under a
+                   burst of ragged greedy requests, nothing retried
+  kernels          every Pallas kernel, compiled, against its XLA
+                   composition at highest precision
+  four_chips       sharded layer runtime on {dp=2, tp=2}, executor on
+                   dp=4 and {dp=2, mp=2}; printed as skipped below 4 chips
+
+Any exception in any phase is fatal.  It prints set-up facts (compile
+seconds, step and token counts, errors against references) and no rate
+or utilization.  Nothing it reads comes from outside the repository, and
+it stays off the native reader (paddle_tpu/native builds a .so that git
+does not carry).
+
+    python chip_smoke.py          # exit 0 and a last line
+                                  # {"ok": true, "device": {...}} on a TPU
+
+The phase functions take their sizes as arguments so that
+tests/test_chip_smoke.py can run them tiny on the CPU; main() has no
+size switch and no CPU mode.
+"""
+
+import functools
+import json
+import sys
+import time
+
+# the package import applies the compile-cache rule (compile_cache.py)
+# before jax initialises a backend
+import paddle_tpu as fluid
+from paddle_tpu import compile_cache
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+GPT_FULL = dict(vocab_size=32768, hidden_size=1024, num_layers=6,
+                num_heads=16, max_seq_len=2048, dtype="bfloat16")
+STATIC_GPT_FULL = dict(t=512, d=768, heads=12, vocab=32768)
+MOSAIC_CALL = "tpu_custom_call"
+
+
+class CompileLog:
+    """What jax itself reports about compilation: every trip through
+    the compile path, the seconds the backend compile took (a
+    persistent-cache hit costs its retrieval), the seconds of tracing
+    and lowering before it (no cache saves those), and the cache hits."""
+
+    _TRACE_LOWER = ("/jax/core/compile/jaxpr_trace_duration",
+                    "/jax/core/compile/jaxpr_to_mlir_module_duration")
+    _BACKEND = "/jax/core/compile/backend_compile_duration"
+    _HIT = "/jax/compilation_cache/cache_hits"
+
+    def __init__(self):
+        self.compiles = 0
+        self.backend_s = 0.0
+        self.trace_lower_s = 0.0
+        self.cache_hits = 0
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_duration(self, event, secs, **_):
+        if event == self._BACKEND:
+            self.compiles += 1
+            self.backend_s += secs
+        elif event in self._TRACE_LOWER:
+            self.trace_lower_s += secs
+
+    def _on_event(self, event, **_):
+        if event == self._HIT:
+            self.cache_hits += 1
+
+    def mark(self):
+        return (self.compiles, self.backend_s, self.trace_lower_s,
+                self.cache_hits)
+
+    def since(self, mark):
+        return {"compiles": self.compiles - mark[0],
+                "backend_compile_s": round(self.backend_s - mark[1], 2),
+                "trace_and_lower_s": round(self.trace_lower_s - mark[2], 2),
+                "persistent_cache_hits": self.cache_hits - mark[3]}
+
+
+def _check(cond, what):
+    if not cond:
+        raise AssertionError(what)
+
+
+def _batch(vocab, batch, seq, seed=0):
+    rng = np.random.default_rng(seed)
+    x = rng.integers(0, vocab, (batch, seq)).astype(np.int32)
+    y = rng.integers(0, vocab, (batch, seq)).astype(np.int32)
+    return x, y
+
+
+# ---------------------------------------------------------------------------
+# device
+# ---------------------------------------------------------------------------
+
+def phase_device():
+    import importlib.metadata
+
+    import jaxlib
+
+    devs = jax.devices()
+    try:
+        libtpu = importlib.metadata.version("libtpu")
+    except importlib.metadata.PackageNotFoundError:
+        libtpu = None
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs), "jax": jax.__version__,
+            "jaxlib": jaxlib.__version__, "libtpu": libtpu,
+            "compile_cache_dir": compile_cache.cache_dir()}
+
+
+# ---------------------------------------------------------------------------
+# train: layer runtime
+# ---------------------------------------------------------------------------
+
+def phase_train_layer(gpt, batch, seq, steps, lr=1e-4):
+    from paddle_tpu import nn
+    from paddle_tpu.models.gpt import GPT, GPTConfig
+    from paddle_tpu.models.train import init_train_state, make_train_step
+    from paddle_tpu.optimizer.functional import AdamW
+
+    nn.seed(0)
+    model = GPT(GPTConfig(**gpt))
+    opt = AdamW(lr)
+    state = init_train_state(model, opt)
+    params = sum(int(np.prod(v.shape)) for v in state.params.values())
+    step = make_train_step(model, opt)
+    x, y = (jnp.asarray(a) for a in _batch(gpt["vocab_size"], batch, seq))
+    compiled = step.lower(state, x, y).compile()
+    hlo = compiled.as_text()
+    losses = []
+    for _ in range(steps):
+        state, loss = compiled(state, x, y)
+        losses.append(float(jax.block_until_ready(loss)))
+    _check(all(np.isfinite(losses)), f"non-finite loss: {losses}")
+    _check(losses[-1] < losses[0],
+           f"loss did not fall on a fixed batch: {losses}")
+    return {"params": params, "batch": [batch, seq], "steps": steps,
+            "losses": [round(v, 4) for v in losses],
+            "mosaic_calls_in_hlo": hlo.count(MOSAIC_CALL)}
+
+
+# ---------------------------------------------------------------------------
+# train: executor
+# ---------------------------------------------------------------------------
+
+def _static_gpt_feed(static, batch, seed=0):
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(0, static["vocab"], (batch, static["t"]))
+    tgt = rng.integers(0, static["vocab"], (batch, static["t"], 1))
+    return {"ids": ids.astype(np.int64), "targets": tgt.astype(np.int64)}
+
+
+def _run_static_gpt(static, batch, steps, wrap=None):
+    """Build the static GPT, run startup, train `steps` batches through
+    train_from_dataset (default flags: AMP + fusion).  Returns (model,
+    scope, first loss, last loss).  `wrap` turns the main program into
+    the CompiledProgram to run."""
+    from paddle_tpu.models import static_zoo
+
+    m = static_zoo.build_gpt(**static)
+    exe, scope = fluid.Executor(), fluid.Scope()
+    exe.run(m.startup, scope=scope)
+    prog = wrap(m) if wrap is not None else m.main
+    feed = _static_gpt_feed(static, batch)
+
+    def train(n):
+        out = exe.train_from_dataset(prog, [feed] * n, scope=scope,
+                                     fetch_list=[m.loss_name],
+                                     print_period=10 ** 9)
+        return float(np.mean(np.asarray(out[0])))
+
+    first = train(1)
+    last = train(steps - 1) if steps > 1 else first
+    return m, scope, first, last
+
+
+def phase_train_executor(static, batch, steps):
+    m, _, first, last = _run_static_gpt(static, batch, steps)
+    _check(np.isfinite(first) and np.isfinite(last),
+           f"non-finite loss: {first}, {last}")
+    _check(steps == 1 or last < first,
+           f"loss did not fall on a fixed batch: {first} -> {last}")
+    sub = next(iter(m.main._opt_cache.values()))
+    fused = sorted({op.type for op in sub.global_block().ops
+                    if op.type.startswith("fused_")})
+    want = {"fused_attention", "fused_bias_act", "fused_layer_norm"}
+    _check(want <= set(fused),
+           f"default train path is not the fused substitute: {fused}")
+    return {"model": "static_zoo.build_gpt, one layer deep (the widest "
+                     "call the static zoo accepts)",
+            "geometry": static, "batch": batch, "steps": steps,
+            "loss_first": round(first, 4), "loss_last": round(last, 4),
+            "fused_ops": fused}
+
+
+# ---------------------------------------------------------------------------
+# serve
+# ---------------------------------------------------------------------------
+
+def phase_serve(gpt, prompt_lens, new_tokens, buckets, log, slots=None,
+                max_len=None, exact_vs_generate=False):
+    """`prompt_lens[-1]` repeats the prompt of `prompt_lens[0]` (the
+    twins).  slots / max_len None = the flag defaults a user gets."""
+    from paddle_tpu import nn
+    from paddle_tpu.models import generate as G
+    from paddle_tpu.models.gpt import GPT, GPTConfig
+    from paddle_tpu.serving import DecodeConfig, DecodeEngine
+    from paddle_tpu.serving import decode as decode_mod
+
+    _check(prompt_lens[-1] == prompt_lens[0], "last prompt is the twin")
+    nn.seed(0)
+    model = GPT(GPTConfig(**gpt))
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(1, gpt["vocab_size"], n).astype(np.int32)
+               for n in prompt_lens[:-1]]
+    prompts.append(prompts[0].copy())
+
+    t0 = time.perf_counter()
+    eng = DecodeEngine(model, config=DecodeConfig(
+        slots=slots, max_len=max_len, buckets=buckets))
+    start_s = time.perf_counter() - t0
+    try:
+        cfg = eng.config
+        # which attention the decode step traced: lowering alone, from
+        # shapes, no second compile and no second cache
+        step_hlo = jax.jit(functools.partial(
+            decode_mod._decode_step_impl, cfg=eng.params.cfg)).lower(
+            jax.eval_shape(eng._fresh_state), eng._trees,
+            np.zeros(cfg.slots, bool)).as_text()
+        before = log.mark()
+        futs = [eng.submit(p, max_new_tokens=new_tokens) for p in prompts]
+        outs = [np.asarray(f.result(timeout=600)) for f in futs]
+        traffic = log.since(before)
+        summary = eng.summary()
+    finally:
+        eng.close()
+
+    for p, o in zip(prompts, outs):
+        _check(o.shape == (new_tokens,) and o.dtype == np.int32,
+               f"prompt {p.size}: tokens {o.shape} {o.dtype}")
+        _check(((o >= 0) & (o < gpt["vocab_size"])).all(),
+               f"prompt {p.size}: token out of vocabulary")
+    _check((outs[0] == outs[-1]).all(),
+           f"twin prompts disagree: {outs[0]} vs {outs[-1]}")
+    n = len(prompts)
+    _check(summary["requests"] == n
+           and summary["outcomes"].get("completed") == n
+           and summary["pending"] == 0, f"ledger: {summary}")
+    # the resilience tier must have had nothing to do: a retried or
+    # degraded dispatch would absorb a failure this run exists to see
+    breaker = summary["breaker"]
+    idle = {"dispatch_retries": summary["dispatch_retries"],
+            "watchdog_stalls": summary["watchdog_stalls"],
+            "degraded_batches": summary["degraded_batches"],
+            "stalled_in_flight": summary.get("stalled_in_flight", 0),
+            "breaker_transitions": len(breaker["transitions"]),
+            "breaker_failures": breaker["consecutive_failures"]}
+    _check(breaker["state"] == "closed" and not any(idle.values()),
+           f"resilience tier was busy: {breaker['state']} {idle}")
+    _check(traffic["compiles"] == 0,
+           f"prewarm missed a program: {traffic} under traffic")
+
+    # the cohort decoder on the shortest prompt: token-exact in float32
+    # (the CPU test); in bf16 the deep-cache kernel and the XLA path
+    # may break an argmax tie differently, so on the chip it is printed
+    short = int(np.argmin([p.size for p in prompts]))
+    ref = np.asarray(G.generate(model, prompts[short][None],
+                                max_new_tokens=new_tokens))[0]
+    same = int((ref == outs[short]).sum())
+    if exact_vs_generate:
+        _check(same == new_tokens,
+               f"engine {outs[short]} vs generate() {ref}")
+    dec = summary["decode"]
+    return {"slots": cfg.slots, "max_len": cfg.max_len,
+            "buckets": list(cfg.buckets), "programs_prewarmed":
+            eng.prewarmed, "engine_start_s": round(start_s, 2),
+            "requests": n, "prompt_lens": [int(p.size) for p in prompts],
+            "new_tokens_each": new_tokens,
+            "tokens_returned": int(sum(o.size for o in outs)),
+            "prefill_steps": dec["prefill_steps"],
+            "decode_steps": dec["decode_steps"],
+            "compiles_under_traffic": traffic["compiles"],
+            "resilience": idle, "twins_identical": True,
+            "tokens_equal_to_generate": f"{same}/{new_tokens}",
+            "mosaic_calls_in_decode_step": step_hlo.count(MOSAIC_CALL)}
+
+
+# ---------------------------------------------------------------------------
+# kernels
+# ---------------------------------------------------------------------------
+
+def _err(got, want):
+    """(max abs error, the same over the reference's max magnitude)."""
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    err = float(np.max(np.abs(got - want)))
+    return err, err / (float(np.max(np.abs(want))) + 1e-30)
+
+
+def _highest(fn):
+    def run(*args):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args)
+    return jax.jit(run)
+
+
+def phase_kernels(attn, decode, decode_lengths, ln, topk_n, dtype, tol):
+    """attn = (b, h, s, d); decode = (b, h, t, d); ln = (rows, d).
+    `tol` bounds each error relative to the reference's max magnitude;
+    the top-k histogram must be exact."""
+    from paddle_tpu.kernels import attention as A
+    from paddle_tpu.kernels.flash_attention import (flash_attention,
+                                                    flash_decode)
+    from paddle_tpu.kernels.layer_norm import layer_norm_pallas
+    from paddle_tpu.kernels.topk_threshold import (NUM_EDGES,
+                                                   count_ge_histogram,
+                                                   topk_threshold)
+
+    rng = np.random.default_rng(2)
+    f32 = jnp.float32
+
+    def rand(shape, scale=1.0):
+        return jnp.asarray(rng.standard_normal(shape) * scale, dtype)
+
+    out = {}
+
+    def record(name, got, want):
+        err, rel = _err(got, want)
+        out[name] = {"max_err": float(f"{err:.3g}"),
+                     "rel_to_max": float(f"{rel:.3g}")}
+        _check(np.isfinite(err) and rel <= tol,
+               f"{name}: error {err} ({rel} of max) over {tol}")
+
+    # flash attention fwd + bwd, causal; the reference runs one batch
+    # row at a time (its [h, s, s] scores are the memory flash avoids)
+    q, k, v, w = (rand(attn) for _ in range(4))
+    scale = 1.0 / np.sqrt(attn[-1])
+
+    @jax.jit
+    def flash(q, k, v, w):
+        o, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(q, k, v, causal=True), q, k, v)
+        return (o,) + vjp(w)
+
+    def ref_row(args):
+        q, k, v, w = (a[None].astype(f32) for a in args)
+        o, vjp = jax.vjp(lambda q, k, v: A._xla_attention(
+            q, k, v, None, scale, True, 0.0, False, None), q, k, v)
+        return tuple(a[0] for a in (o,) + vjp(w))
+
+    got = flash(q, k, v, w)
+    want = _highest(lambda *a: jax.lax.map(ref_row, a))(q, k, v, w)
+    for name, g, r in zip(("flash_fwd", "flash_bwd_dq", "flash_bwd_dk",
+                           "flash_bwd_dv"), got, want):
+        record(name, g, r)
+
+    # single-query decode over a ragged cache
+    b, h, t, d = decode
+    q1, kc, vc = rand((b, h, 1, d)), rand(decode), rand(decode)
+    lengths = jnp.asarray(decode_lengths, jnp.int32)
+    _check(lengths.shape == (b,), "one length per decode row")
+    got = jax.jit(flash_decode)(q1, kc, vc, lengths)
+    want = _highest(lambda q, k, v, n: A.decode_attention(
+        q.astype(f32), k.astype(f32), v.astype(f32), pos=n - 1,
+        use_flash=False))(q1, kc, vc, lengths)
+    record("flash_decode", got, want)
+
+    # layer norm fwd + bwd
+    x, dy = rand(ln), rand(ln)
+    gamma = jnp.asarray(1.0 + 0.1 * rng.standard_normal(ln[1]), dtype)
+    beta = jnp.asarray(0.1 * rng.standard_normal(ln[1]), dtype)
+
+    def ln_ref(x, g, b):
+        x = x.astype(f32)
+        xc = x - x.mean(-1, keepdims=True)
+        return xc * jax.lax.rsqrt((xc * xc).mean(-1, keepdims=True)
+                                  + 1e-5) * g.astype(f32) + b.astype(f32)
+
+    def with_grads(fn):
+        def run(x, g, b, dy):
+            y, vjp = jax.vjp(fn, x, g, b)
+            return (y,) + vjp(dy.astype(y.dtype))
+        return run
+
+    got = jax.jit(with_grads(layer_norm_pallas))(x, gamma, beta, dy)
+    want = _highest(with_grads(ln_ref))(x, gamma, beta, dy)
+    for name, g, r in zip(("layer_norm_fwd", "layer_norm_bwd_dx",
+                           "layer_norm_bwd_dgamma", "layer_norm_bwd_dbeta"),
+                          got, want):
+        record(name, g, r)
+
+    # DGC top-k threshold: the histogram against a broadcast compare,
+    # a chunk at a time (whole, it is the 64 MB intermediate the kernel
+    # was rewritten to avoid)
+    grad = jnp.asarray(rng.standard_normal(topk_n), f32)
+    k_keep = max(1, topk_n // 1000)
+    flat = jnp.abs(grad)
+    edges = jnp.linspace(0.0, 1.0, NUM_EDGES, dtype=f32) * jnp.max(flat)
+    got = count_ge_histogram(flat, edges)
+    pad = (-topk_n) % 4096
+    chunks = jnp.pad(flat, (0, pad), constant_values=-1.0).reshape(-1, 4096)
+    want = jax.jit(lambda c, e: jax.lax.map(
+        lambda row: (row[:, None] >= e[None, :]).sum(0).astype(jnp.int32),
+        c).sum(0))(chunks, edges)
+    diff = int(np.max(np.abs(np.asarray(got, np.int64)
+                             - np.asarray(want, np.int64))))
+    thr = float(topk_threshold(grad, k_keep))
+    kept = int((np.abs(np.asarray(grad)) >= thr).sum())
+    out["topk_threshold"] = {"histogram_max_count_diff": diff, "k": k_keep,
+                             "kept": kept}
+    _check(diff == 0, f"topk histogram off by {diff}")
+    _check(kept >= k_keep, f"threshold keeps {kept} < k = {k_keep}")
+    return out
+
+
+# ---------------------------------------------------------------------------
+# four chips
+# ---------------------------------------------------------------------------
+
+def _on_distinct_devices(arr, n):
+    return len({s.device for s in arr.addressable_shards}) == n
+
+
+def phase_four_chips(gpt, batch, seq, steps, one_chip_loss, static,
+                     static_batch, one_chip_static_loss, rtol, lr=1e-4):
+    """The two train runtimes again, sharded over four devices, on the
+    batches of the one-chip phases: first-step losses must agree."""
+    from paddle_tpu import nn
+    from paddle_tpu.distributed.mesh import build_mesh
+    from paddle_tpu.distributed.sharded import (gpt_rules,
+                                                make_sharded_train_step,
+                                                shard_batch)
+    from paddle_tpu.models.gpt import GPT, GPTConfig
+    from paddle_tpu.optimizer.functional import AdamW
+
+    out = {}
+    # layer runtime, {dp=2, tp=2}
+    nn.seed(0)
+    model = GPT(GPTConfig(**gpt))
+    mesh = build_mesh(dp=2, tp=2)
+    step, state = make_sharded_train_step(model, AdamW(lr), mesh,
+                                          rules=gpt_rules())
+    x, y = shard_batch(mesh, *_batch(gpt["vocab_size"], batch, seq))
+    losses = []
+    for _ in range(steps):
+        state, loss = step(state, x, y)
+        losses.append(float(jax.block_until_ready(loss)))
+    leaf = state.params["blocks.0.attn.q_proj.weight"]
+    shard_bytes = leaf.addressable_shards[0].data.nbytes
+    _check(all(np.isfinite(losses)), f"non-finite loss: {losses}")
+    _check(_on_distinct_devices(leaf, 4)
+           and _on_distinct_devices(state.params["norm_f.weight"], 4),
+           "parameter shards do not sit on four distinct devices")
+    _check(shard_bytes * 2 == leaf.nbytes,
+           f"tensor-parallel leaf holds {shard_bytes} of {leaf.nbytes} "
+           f"bytes a device, not half")
+    _check(abs(losses[0] - one_chip_loss) <= rtol * abs(one_chip_loss),
+           f"first-step loss {losses[0]} vs one chip {one_chip_loss}")
+    out["layer_dp2_tp2"] = {
+        "losses": [round(v, 4) for v in losses],
+        "one_chip_first_loss": round(one_chip_loss, 4),
+        "tp_leaf_bytes": [shard_bytes, leaf.nbytes],
+        "devices": sorted(d.id for d in mesh.devices.flat)}
+
+    # executor: dp=4, then the GSPMD tier on {dp=2, mp=2}
+    def dp4(m):
+        return fluid.CompiledProgram(m.main).with_data_parallel(
+            loss_name=m.loss_name, places=4)
+
+    def dp2_mp2(m):
+        return fluid.CompiledProgram(m.main).with_sharding_rules(
+            m.partition_rules(), execute=True)
+
+    for name, wrap, halved in (("executor_dp4", dp4, False),
+                               ("executor_dp2_mp2", dp2_mp2, True)):
+        _, scope, first, _ = _run_static_gpt(static, static_batch, 1,
+                                             wrap=wrap)
+        w = scope.vars["fc_0.w_0"]
+        _check(_on_distinct_devices(w, 4),
+               f"{name}: fc_0.w_0 is not on four distinct devices")
+        shard_bytes = w.addressable_shards[0].data.nbytes
+        _check(shard_bytes * (2 if halved else 1) == w.nbytes,
+               f"{name}: a device holds {shard_bytes} of fc_0.w_0's "
+               f"{w.nbytes} bytes")
+        _check(abs(first - one_chip_static_loss)
+               <= rtol * abs(one_chip_static_loss),
+               f"{name}: first-step loss {first} vs one chip "
+               f"{one_chip_static_loss}")
+        out[name] = {"loss_first": round(first, 4),
+                     "one_chip_first_loss": round(one_chip_static_loss, 4),
+                     "fc_0.w_0_bytes": [shard_bytes, w.nbytes]}
+    return out
+
+
+# ---------------------------------------------------------------------------
+# main
+# ---------------------------------------------------------------------------
+
+def main():
+    device = phase_device()
+    if device["platform"] != "tpu":
+        print(f"chip_smoke: no TPU: jax.devices() found platform "
+              f"{device['platform']!r} ({device['kind']}, "
+              f"{device['count']} device(s))", file=sys.stderr)
+        return 1
+    print(f"[device] {json.dumps(device)}", flush=True)
+    log = CompileLog()
+
+    def run(name, fn, *args, **kw):
+        mark, t0 = log.mark(), time.perf_counter()
+        result = fn(*args, **kw)
+        print(f"[{name}] {json.dumps(result)}", flush=True)
+        print(f"[{name}] {json.dumps(log.since(mark))} "
+              f"phase_s={time.perf_counter() - t0:.1f}", flush=True)
+        return result
+
+    layer = run("train_layer", phase_train_layer, GPT_FULL, batch=8,
+                seq=2048, steps=5)
+    _check(layer["mosaic_calls_in_hlo"] > 0,
+           "train step HLO has no Mosaic call: the flash kernels did not "
+           "run")
+    static_batch = 16
+    executor = run("train_executor", phase_train_executor, STATIC_GPT_FULL,
+                   batch=static_batch, steps=5)
+    # twelve requests, 20..1500 tokens, the last the twin of the first
+    lens = [int(n) for n in np.linspace(20, 1500, 11)] + [20]
+    serve = run("serve", phase_serve, GPT_FULL, lens, 32, (512, 2048), log)
+    _check(serve["mosaic_calls_in_decode_step"] > 0,
+           "decode step has no Mosaic call: flash_decode did not run")
+    run("kernels", phase_kernels, attn=(8, 16, 2048, 64),
+        decode=(8, 16, 2048, 64),
+        decode_lengths=[1, 40, 1024, 2048, 777, 128, 129, 2047],
+        ln=(16384, 1024), topk_n=1024 * 4096, dtype=jnp.bfloat16,
+        tol=5e-2)
+    if device["count"] >= 4:
+        run("four_chips", phase_four_chips, GPT_FULL, 8, 2048, 3,
+            layer["losses"][0], STATIC_GPT_FULL, static_batch,
+            executor["loss_first"], rtol=2e-2)
+    else:
+        print(f"[four_chips] skipped, {device['count']} device",
+              flush=True)
+    _check("paddle_tpu.native" not in sys.modules,
+           "the smoke touched the native reader")
+    print(json.dumps({"ok": True, "device": {
+        "platform": device["platform"], "kind": device["kind"],
+        "count": device["count"]}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -14,7 +14,10 @@ Top-level namespace mirrors the reference's `paddle.fluid` surface:
     exe.run(fluid.default_startup_program())
 """
 
-from . import _jax_compat  # noqa: F401  — must run before any jax use
+from . import compile_cache
+
+compile_cache.install()   # before anything below can compile
+
 from . import flags
 from .flags import set_flags, get_flags
 

@@ -23,11 +23,9 @@ class Place:
         """Resolve to a concrete jax.Device (None = jax default)."""
         if self._device_kind is None:
             return None
-        devs = [d for d in jax.devices() if d.platform == self._device_kind]
-        if not devs:
-            # Fall back to default backend (e.g. asking for TPU on a CPU-only
-            # test host): behave like the reference's CPU fallback kernels.
-            devs = jax.devices()
+        # raises RuntimeError on a host without that platform: a
+        # TPUPlace never quietly resolves to a CPU device
+        devs = jax.devices(self._device_kind)
         return devs[self.device_id % len(devs)]
 
     def __eq__(self, other):

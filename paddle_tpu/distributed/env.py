@@ -47,24 +47,6 @@ class ParallelEnv:
         return self._endpoints.split(",") if self._endpoints else []
 
 
-def _maybe_enable_cpu_collectives():
-    """Multi-process collectives on the CPU backend need the gloo
-    transport switched on BEFORE the backend initialises (without it
-    XLA:CPU fails every cross-process psum with "Multiprocess
-    computations aren't implemented on the CPU backend").  Only the
-    declared-platform config is consulted — calling
-    jax.default_backend() here would itself initialise the backend and
-    make the flag a no-op."""
-    platforms = (getattr(jax.config, "jax_platforms", None)
-                 or os.environ.get("JAX_PLATFORMS", ""))
-    if not platforms.split(",")[0].strip().lower() == "cpu":
-        return
-    try:
-        jax.config.update("jax_cpu_enable_gloo_collectives", True)
-    except Exception:  # pragma: no cover — jax without the gloo option
-        pass
-
-
 def init_parallel_env():
     """Multi-host init. On a single host this is a no-op (the mesh covers
     local devices); with PADDLE_TRAINER_ENDPOINTS set it performs the DCN
@@ -76,7 +58,6 @@ def init_parallel_env():
     env = ParallelEnv()
     if env.nranks > 1 and env.trainer_endpoints:
         coordinator = env.trainer_endpoints[0]
-        _maybe_enable_cpu_collectives()
         kwargs = {}
         # bounded rendezvous (reference launch.py aborts the pack when a
         # worker dies; an unbounded initialize would hang instead)
@@ -94,8 +75,8 @@ def init_parallel_env():
 
 
 def get_rank():
-    return getattr(jax, "process_index", lambda: 0)()
+    return jax.process_index()
 
 
 def get_world_size():
-    return getattr(jax, "process_count", lambda: 1)()
+    return jax.process_count()

@@ -252,17 +252,26 @@ def make_sharded_train_step(model, optimizer, mesh, rules=None,
     state = init_train_state(model, optimizer, rng_seed=rng_seed)
     state = shard_train_state(state, mesh, rules, zero1=zero1)
     if not zero1:
-        step = make_train_step(model, optimizer, loss_fn=loss_fn, jit=True,
-                               accum_steps=accum_steps)
-        return step, state
+        jitted = make_train_step(model, optimizer, loss_fn=loss_fn,
+                                 jit=True, accum_steps=accum_steps)
+    else:
+        inner = make_train_step(model, optimizer, loss_fn=loss_fn,
+                                jit=False, accum_steps=accum_steps)
+        state_sh = jax.tree.map(lambda a: a.sharding, state)
 
-    inner = make_train_step(model, optimizer, loss_fn=loss_fn, jit=False,
-                            accum_steps=accum_steps)
-    state_sh = jax.tree.map(lambda a: a.sharding, state)
+        def pinned(st, *batch):
+            st2, loss = inner(st, *batch)
+            st2 = jax.tree.map(jax.lax.with_sharding_constraint, st2,
+                               state_sh)
+            return st2, loss
+
+        jitted = jax.jit(pinned, donate_argnums=(0,))
 
     def step(st, *batch):
-        st2, loss = inner(st, *batch)
-        st2 = jax.tree.map(jax.lax.with_sharding_constraint, st2, state_sh)
-        return st2, loss
+        # traced under the mesh, so code that GSPMD cannot partition by
+        # itself (the Pallas kernels, kernels/attention.py) can find
+        # the axes to split over by hand
+        with jax.set_mesh(mesh):
+            return jitted(st, *batch)
 
-    return jax.jit(step, donate_argnums=(0,)), state
+    return step, state

@@ -2533,11 +2533,12 @@ class Executor:
                     # boundary concatenates the [dp] vector instead
                     out_fetch_specs = out_fetch_specs + [
                         P("dp") if spmd else P()]
-                # GSPMD tier: the model axes are AUTO — XLA propagates
-                # the state placements + body pins and inserts the mp
-                # collectives itself; the dp axis stays manual so the
-                # bucketed grad sync / skew probe machinery runs as-is
-                sm_kw = {"auto": model_axes} if spmd else {}
+                # GSPMD tier: only dp is a manual axis — the model axes
+                # stay automatic, so XLA propagates the state placements
+                # + body pins and inserts the mp collectives itself
+                # while the bucketed grad sync / skew probe machinery
+                # runs on dp as-is
+                sm_kw = {"axis_names": frozenset({"dp"})} if spmd else {}
                 fn = _mon().instrument_jit(
                     jax.jit(apply_precision_policy(shard_map(
                         dp_step_shaped, mesh=dp_mesh,

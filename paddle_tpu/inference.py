@@ -20,14 +20,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-# jax.export is a submodule that is NOT imported by `import jax` —
-# attribute access alone raises AttributeError on exactly the jax
-# versions that ship it; import it explicitly (and degrade to a clear
-# error on ancient jax without the module)
-try:
-    from jax import export as _jax_export
-except ImportError:  # pragma: no cover
-    _jax_export = None
+# jax.export is a submodule that `import jax` does not import
+from jax import export as _jax_export
 
 from . import flags
 from .core.dtype import to_jax_dtype
@@ -171,9 +165,6 @@ class Predictor:
                 examples[n] = jnp.zeros(tuple(shape), to_jax_dtype(dtype))
             else:
                 examples[n] = jnp.asarray(np.asarray(spec))
-        if _jax_export is None:  # pragma: no cover
-            raise RuntimeError("this jax has no jax.export; AOT "
-                               "artifact serialization is unavailable")
         exported = _jax_export.export(
             self._fn, platforms=platforms)(examples)
         blob = exported.serialize()
@@ -191,9 +182,6 @@ class CompiledPredictor:
     def __init__(self, path):
         if os.path.isdir(path):
             path = os.path.join(path, _COMPILED_FILE)
-        if _jax_export is None:  # pragma: no cover
-            raise RuntimeError("this jax has no jax.export; AOT "
-                               "artifact deserialization is unavailable")
         with open(path, "rb") as f:
             self._exported = _jax_export.deserialize(f.read())
         self._path = path

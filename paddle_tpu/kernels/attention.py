@@ -45,6 +45,43 @@ def _xla_attention(q, k, v, mask, scale, is_causal, dropout_p, training,
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
+def _flash_per_shard(q, k, v, causal, scale):
+    """The flash kernel, split by hand where GSPMD would have to.
+
+    XLA refuses to partition a Mosaic call ("Mosaic kernels cannot be
+    automatically partitioned"), so under a mesh with automatic axes (a
+    jit over sharded arrays: distributed/sharded.py sets the mesh) the
+    kernel runs inside a shard_map: each device takes its own
+    [batch, heads] shard — batch over "dp", heads over "tp", this
+    package's axis conventions (distributed/mesh.py) — with seq and
+    head_dim whole.  No mesh, or one whose axes are all manual already
+    (inside a shard_map), calls the kernel directly."""
+    from jax.sharding import AxisType, PartitionSpec as P
+
+    from .flash_attention import flash_attention
+
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = [n for n, t in zip(mesh.axis_names, mesh.axis_types)
+            if t == AxisType.Auto]
+    if not auto:
+        return flash_attention(q, k, v, causal=causal, sm_scale=scale)
+
+    def axis_for(name, dim):
+        return name if name in auto and dim % mesh.shape[name] == 0 \
+            else None
+
+    spec = P(axis_for("dp", q.shape[0]), axis_for("tp", q.shape[1]),
+             None, None)
+    # every automatic axis turns manual (Mosaic lowers only where no
+    # axis is left to the partitioner); axes the spec does not name
+    # hold replicas
+    return jax.shard_map(
+        lambda q, k, v: flash_attention(q, k, v, causal=causal,
+                                        sm_scale=scale),
+        in_specs=(spec, spec, spec), out_specs=spec,
+        axis_names=frozenset(auto), check_vma=False)(q, k, v)
+
+
 def decode_attention(q, k, v, pos=None, mask=None, scale=None,
                      use_flash=None):
     """Single-query decode attention: q [B, H, 1, D] against a KV-cache
@@ -153,8 +190,6 @@ def dot_product_attention(q, k, v, mask=None, dropout_p=0.0, is_causal=False,
             "self-attention, seq%128==0, head_dim in 64/128/256) — "
             "falling back to the XLA path", stacklevel=2)
     if use_flash and can_flash:
-        from .flash_attention import flash_attention
-
-        return flash_attention(q, k, v, causal=is_causal, sm_scale=scale)
+        return _flash_per_shard(q, k, v, is_causal, scale)
     return _xla_attention(q, k, v, mask, scale, is_causal, dropout_p,
                           training, rng_key)

@@ -29,14 +29,9 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-specific pieces; absent on CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from .backend import interpret
 
 # 1024x1024 tiles: measured fastest on v5e (r4 flash_block_ab2,
 # b8 h16 s2048 d64 fwd+bwd chained): 512x512 17.48ms, 1024x512 16.62,
@@ -69,28 +64,16 @@ def _env_blocks():
 
 
 def _vmem_spec(*args):
-    if _VMEM is None:
-        return pl.BlockSpec(*args)
-    return pl.BlockSpec(*args, memory_space=_VMEM)
+    return pl.BlockSpec(*args, memory_space=pltpu.VMEM)
 
 
 def _scratch(shape, dtype=jnp.float32):
-    if pltpu is not None:
-        return pltpu.VMEM(shape, dtype)
-    raise RuntimeError("pallas TPU backend unavailable")  # pragma: no cover
+    return pltpu.VMEM(shape, dtype)
 
 
 def _compiler_params():
-    if pltpu is None:  # pragma: no cover
-        return None
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
-
-
-def _interpret():
-    from .backend import is_tpu_backend
-
-    return not is_tpu_backend()
 
 
 def _causal_mask(s, qi, ki, block_q, block_k):
@@ -191,7 +174,7 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k):
             _scratch((block_q, _LANES)),
         ],
         compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        interpret=interpret(),
     )(q3, k3, v3)
     return out.reshape(b, h, s, d), lse.reshape(b, h, s)
 
@@ -326,7 +309,7 @@ def _bwd_core(sm_scale, causal, block_q, block_k, q, k, v, do, lse,
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         scratch_shapes=[_scratch((block_q, d))],
         compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        interpret=interpret(),
     )(q3, k3, v3, do3, lse3, delta3)
 
     grid_kv = (b * h, s // block_k, s // block_q)
@@ -352,7 +335,7 @@ def _bwd_core(sm_scale, causal, block_q, block_k, q, k, v, do, lse,
         ],
         scratch_shapes=[_scratch((block_k, d)), _scratch((block_k, d))],
         compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        interpret=interpret(),
     )(q3, k3, v3, do3, lse3, delta3)
 
     return (dq.reshape(b, h, s, d), dk.reshape(b, h, s, d),
@@ -363,21 +346,8 @@ def _bwd_core(sm_scale, causal, block_q, block_k, q, k, v, do, lse,
 # single-query decode forward (ISSUE 17)
 # --------------------------------------------------------------------------
 
-def _decode_compiler_params():
-    if pltpu is None:  # pragma: no cover
-        return None
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"))
-
-
-def _smem_spec(*args):
-    if pltpu is None:  # pragma: no cover
-        return pl.BlockSpec(*args)
-    return pl.BlockSpec(*args, memory_space=pltpu.SMEM)
-
-
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                   l_ref, *, sm_scale, block_k):
+                   l_ref, *, sm_scale, block_k, heads):
     ki = pl.program_id(1)
     num_k = pl.num_programs(1)
 
@@ -387,7 +357,9 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    length = len_ref[0, 0]
+    # per-row lengths ride scalar prefetch (SMEM holds the whole [B]
+    # vector; a (1, 1) SMEM block per grid step does not lower)
+    length = len_ref[pl.program_id(0) // heads]
 
     # k blocks entirely past the live prefix contribute nothing; skip
     # their DMA'd compute outright (the ragged-length win: a slot at
@@ -448,33 +420,36 @@ def flash_decode(q, k, v, lengths, sm_scale=None, block_k=None):
             f"cache depth {t} must be divisible by block_k {block_k}")
     lengths = jnp.broadcast_to(
         jnp.asarray(lengths, jnp.int32).reshape(-1), (b,))
-    len2 = jnp.repeat(lengths, h).reshape(b * h, 1)
     q3 = q.reshape(b * h, 1, d)
     k3 = k.reshape(b * h, t, d)
     v3 = v.reshape(b * h, t, d)
-    grid = (b * h, t // block_k)
     out = pl.pallas_call(
         functools.partial(_decode_kernel, sm_scale=float(sm_scale),
-                          block_k=block_k),
-        grid=grid,
-        in_specs=[
-            _smem_spec((1, 1), lambda bh, ki: (bh, 0)),
-            _vmem_spec((1, 1, d), lambda bh, ki: (bh, 0, 0)),
-            _vmem_spec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
-            _vmem_spec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
-        ],
-        out_specs=_vmem_spec((1, 1, d), lambda bh, ki: (bh, 0, 0)),
+                          block_k=block_k, heads=h),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * h, t // block_k),
+            in_specs=[
+                _vmem_spec((1, 1, d), lambda bh, ki, lens: (bh, 0, 0)),
+                _vmem_spec((1, block_k, d),
+                           lambda bh, ki, lens: (bh, ki, 0)),
+                _vmem_spec((1, block_k, d),
+                           lambda bh, ki, lens: (bh, ki, 0)),
+            ],
+            out_specs=_vmem_spec((1, 1, d),
+                                 lambda bh, ki, lens: (bh, 0, 0)),
+            scratch_shapes=[
+                # 8-row scratch (f32 sublane tile) though only row 0 is
+                # used: sub-tile scratch shapes are not portable on TPU
+                _scratch((8, d)),
+                _scratch((8, _LANES)),
+                _scratch((8, _LANES)),
+            ]),
         out_shape=jax.ShapeDtypeStruct((b * h, 1, d), q.dtype),
-        scratch_shapes=[
-            # 8-row scratch (f32 sublane tile) though only row 0 is
-            # used: sub-tile scratch shapes are not portable on TPU
-            _scratch((8, d)),
-            _scratch((8, _LANES)),
-            _scratch((8, _LANES)),
-        ],
-        compiler_params=_decode_compiler_params(),
-        interpret=_interpret(),
-    )(len2, q3, k3, v3)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret(),
+    )(lengths, q3, k3, v3)
     return out.reshape(b, h, 1, d)
 
 

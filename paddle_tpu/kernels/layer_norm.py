@@ -25,21 +25,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pragma: no cover - TPU-specific
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from .backend import interpret
 
 DEFAULT_BLOCK_ROWS = 256
-
-
-def _interpret():
-    from .backend import is_tpu_backend
-
-    return not is_tpu_backend()
 
 
 def _fwd_kernel(x_ref, g_ref, b_ref, y_ref, *, eps):
@@ -115,7 +103,7 @@ def _fwd(x, gamma, beta, eps, block_rows):
         ],
         out_specs=pl.BlockSpec((block, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
-        interpret=_interpret(),
+        interpret=interpret(),
     )(x, gamma, beta)
     return y
 
@@ -146,7 +134,7 @@ def _bwd(x, gamma, dy, eps, block_rows):
             jax.ShapeDtypeStruct((groups, d), jnp.float32),
             jax.ShapeDtypeStruct((groups, d), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=interpret(),
     )(x, gamma, dy)
     return dx, dg_acc.sum(axis=0), db_acc.sum(axis=0)
 

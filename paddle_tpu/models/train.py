@@ -21,12 +21,6 @@ from ..nn.layers import (
 )
 from ..nn.parameter import default_rng
 
-try:  # jax>=0.4.27
-    _register_dataclass = jax.tree_util.register_dataclass
-except AttributeError:  # pragma: no cover
-    _register_dataclass = None
-
-
 @dataclasses.dataclass
 class TrainState:
     params: Any
@@ -36,18 +30,11 @@ class TrainState:
     rng: Any
 
 
-if _register_dataclass is not None:
-    _register_dataclass(
-        TrainState,
-        data_fields=["params", "opt_state", "buffers", "step", "rng"],
-        meta_fields=[],
-    )
-else:  # pragma: no cover
-    jax.tree_util.register_pytree_node(
-        TrainState,
-        lambda s: ((s.params, s.opt_state, s.buffers, s.step, s.rng), None),
-        lambda _, c: TrainState(*c),
-    )
+jax.tree_util.register_dataclass(
+    TrainState,
+    data_fields=["params", "opt_state", "buffers", "step", "rng"],
+    meta_fields=[],
+)
 
 
 def init_train_state(model, optimizer, rng_seed=0):

@@ -424,7 +424,8 @@ def jsonl_path():
 
 def mfu(step_time_s=None, key=None, peak=None):
     """MFU from the compile ledger's cost analysis.  step_time_s
-    defaults to the session's mean recorded step time."""
+    defaults to the session's mean recorded step time.  None on a CPU
+    (no peak on record, see peak_flops) unless `peak` is passed."""
     if step_time_s is None:
         step_time_s = _session.mean_step_time()
     return _ledger.mfu(step_time_s, key=key, peak=peak)

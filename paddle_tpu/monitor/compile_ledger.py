@@ -12,9 +12,10 @@ The AOT path (`aot_compile`) uses jax's lower()/compile() split so the
 compile wall time is measured alone (trace time is separate) and the
 executable handle is available for analysis; `instrument_jit` wraps an
 implicitly-jitted callable with a per-signature memo of AOT-compiled
-executables, falling back to the plain jit call whenever AOT is
-unavailable for the callable (and then recording the first-call wall
-time, which includes trace+compile, with analysis fields absent).
+executables; a callable that is not a jit (no .lower) is called as
+it is, and its first-call wall time, which includes trace+compile, is
+recorded with the analysis fields absent.  A lower or compile error
+propagates from the first attempt.
 """
 
 import threading
@@ -23,28 +24,34 @@ import time
 __all__ = ["CompileLedger", "PEAK_FLOPS", "peak_flops",
            "parse_cost_analysis", "parse_memory_analysis", "live_bytes"]
 
-# Peak dense-matmul FLOPs per chip (bf16), by device-kind substring.
-# Longest match wins ("v5e" before "v5").  CPU gets a nominal 1e11 so
-# CPU-mesh smoke runs still produce a finite, obviously-synthetic MFU.
+# Peak dense-matmul FLOP/s of one chip in bf16, keyed by the
+# `device_kind` string jax reports.  A kind is added with its source
+# once its spelling has been read off a device.
 PEAK_FLOPS = {
-    "v2": 22.5e12, "v3": 61.0e12, "v4": 137.5e12,
-    "v5e": 197e12, "v5p": 459e12, "v6e": 918e12, "v6": 918e12,
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip
+    "TPU v5 lite": 197e12,
 }
 
 
 def peak_flops(device=None):
-    """Peak FLOPs of `device` (default: jax.devices()[0])."""
+    """Peak bf16 FLOP/s of `device` (default: jax.devices()[0]).
+
+    None for a CPU: the host has no published matmul peak, so nothing
+    derived from it (an MFU) is a number.  An accelerator that is not
+    in the table is an error, not a default."""
     if device is None:
         import jax
 
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower().replace(" ", "")
-    for k in sorted(PEAK_FLOPS, key=len, reverse=True):
-        if k in kind:
-            return PEAK_FLOPS[k]
     if device.platform == "cpu":
-        return 1e11
-    return 197e12
+        return None
+    kind = getattr(device, "device_kind", "")
+    if kind not in PEAK_FLOPS:
+        raise KeyError(
+            f"no peak FLOP/s on record for device kind {kind!r} "
+            f"(platform {device.platform!r}); add it to "
+            f"monitor.compile_ledger.PEAK_FLOPS with its source")
+    return PEAK_FLOPS[kind]
 
 
 def parse_cost_analysis(cost):
@@ -192,8 +199,10 @@ class CompileLedger:
     def aot_compile(self, jitfn, *args, key="jit", var_info=None):
         """lower+compile `jitfn` at `args`, recording one compile event
         (wall-clocked compile, cost_analysis, memory_analysis).  Returns
-        the compiled executable, or None when the callable does not
-        support AOT (caller falls back to the implicit-jit path).
+        the compiled executable, or None when the callable is not a
+        jit (no .lower; the caller then calls it directly).  A lower or
+        compile error propagates: a second attempt through the
+        implicit-jit path would only pay the refused compile twice.
 
         `var_info` ({"params": ..., "persist": ...} — the executor's
         param/persist var maps) feeds the mem-profile's variable-class
@@ -202,14 +211,11 @@ class CompileLedger:
         lower = getattr(jitfn, "lower", None)
         if lower is None:
             return None
-        try:
-            t0 = time.perf_counter()
-            lowered = lower(*args)
-            t1 = time.perf_counter()
-            compiled = lowered.compile()
-            t2 = time.perf_counter()
-        except Exception:
-            return None
+        t0 = time.perf_counter()
+        lowered = lower(*args)
+        t1 = time.perf_counter()
+        compiled = lowered.compile()
+        t2 = time.perf_counter()
         try:
             cost = parse_cost_analysis(compiled.cost_analysis())
         except Exception:
@@ -334,6 +340,8 @@ class CompileLedger:
             return None
         if peak is None:
             peak = peak_flops()
+        if peak is None:
+            return None
         return flops / step_time_s / peak
 
     def summary(self):

@@ -314,10 +314,8 @@ def compute_fractions(record):
 
 def _mfu():
     from paddle_tpu import monitor
-    try:
-        return monitor.mfu()
-    except Exception:
-        return None
+
+    return monitor.mfu()
 
 
 # -- module-global active ledger (the gate) -----------------------------

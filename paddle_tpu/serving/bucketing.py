@@ -30,10 +30,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax import export as _jax_export
-except ImportError:  # pragma: no cover
-    _jax_export = None
+from jax import export as _jax_export
 
 __all__ = ["default_buckets", "pick_bucket", "BucketDispatcher"]
 
@@ -195,27 +192,18 @@ class BucketDispatcher:
 
     def _compile(self, bucket, example, feat_sig):
         """Lower+compile the predictor's jitted fn at the bucket shape.
-        Routed through the monitor's AOT instrumentation so the compile
-        is wall-clocked and cost/memory-analyzed like an executor
-        compile; falls back to the implicit-jit callable when the jax
-        version cannot AOT."""
+        With telemetry on it goes through the monitor's AOT
+        instrumentation, so the compile is wall-clocked and
+        cost/memory-analyzed like an executor compile.  A compile error
+        propagates: prewarm fails at start-up, not at first traffic."""
         mon = _mon()
         key = self._key(bucket, feat_sig)
-        compiled = mon.aot_compile(
-            self.predictor._fn, example,
-            key=f"serving/{self.label}/b{bucket}") \
-            if mon.is_enabled() else None
-        if compiled is None:
-            lower = getattr(self.predictor._fn, "lower", None)
-            if lower is not None:
-                try:
-                    compiled = lower(example).compile()
-                except Exception:
-                    compiled = None
-        if compiled is None:
-            # ancient jax with no AOT: the implicit jit cache still
-            # pins one executable per bucket shape
-            compiled = self.predictor._fn
+        if mon.is_enabled():
+            compiled = mon.aot_compile(
+                self.predictor._fn, example,
+                key=f"serving/{self.label}/b{bucket}")
+        else:
+            compiled = self.predictor._fn.lower(example).compile()
         self._cache[key] = compiled
         if mon.is_enabled():
             mon.counter("serving.bucket_compile").add(1)
@@ -261,8 +249,8 @@ class BucketDispatcher:
         the same serialization path as Predictor.export_compiled.
         Returns the number of artifacts written (0 for a
         CompiledPredictor — it already IS the artifact — or when shapes
-        are dynamic / jax.export is unavailable)."""
-        if hasattr(self.predictor, "_exported") or _jax_export is None:
+        are dynamic)."""
+        if hasattr(self.predictor, "_exported"):
             return 0
         os.makedirs(dirname, exist_ok=True)
         n = 0
@@ -289,7 +277,7 @@ class BucketDispatcher:
         mismatch simply misses and falls through to a (ledgered)
         compile instead of serving a stale executable.  Returns the
         number of buckets imported."""
-        if hasattr(self.predictor, "_exported") or _jax_export is None:
+        if hasattr(self.predictor, "_exported"):
             return 0
         n = 0
         for bucket in self.buckets:

@@ -1,55 +1,52 @@
 """Unit tests for bench.py's config-selection logic (no chip needed).
 
 The measurement numbers themselves are chip-side; what IS testable here
-is the glue the round's evidence depends on: sweep-best adoption into
-the headline resnet config, and metric-name stability across
-success/skip/error rows (ADVICE r4).
+is the glue around them: the headline resnet config fixed in code, no
+row without a TPU, and metric-name stability across success/skip/error
+rows.
 """
 
 import bench
 
 
-def _fake_time_config(calls):
-    def fn(peak, batch=128, remat=False, iters=40, data_format="NHWC",
-           bn_stats_sample=0, fused=False):
-        calls.append({"batch": batch, "ss": bn_stats_sample,
-                      "fused": fused})
+def test_resnet_headline_ignores_persisted_sweep(monkeypatch):
+    """The headline config is fixed in code: a sweep row persisted by
+    an earlier capture must not steer today's batch size."""
+    stale = {"rows": {"resnet50_sweep": {"configs": [
+        {"batch": 192, "bn_stats_sample": 8, "mfu": 0.17}]}}}
+    calls = []
+
+    def fake_time_config(peak, batch=128, remat=False, iters=40,
+                         data_format="NHWC", bn_stats_sample=0):
+        calls.append({"batch": batch, "ss": bn_stats_sample})
         return {"batch": batch, "remat": remat, "step_ms": 10.0,
                 "samples_per_sec": 1.0, "mfu": 0.2}
-    return fn
 
-
-def test_resnet_headline_adopts_best_unfused_sweep_config(monkeypatch):
-    fake = {"rows": {"resnet50_sweep": {"configs": [
-        {"batch": 128, "bn_stats_sample": 16, "mfu": 0.15},
-        {"batch": 192, "bn_stats_sample": 16, "mfu": 0.17},
-        # a fused row winning the sweep must NOT block unfused adoption
-        {"batch": 128, "bn_stats_sample": 16, "mfu": 0.25, "fused": True},
-        {"batch": 128, "bn_stats_sample": 16, "mfu": 0.20, "remat": True},
-    ], "best": {"batch": 128, "mfu": 0.25, "fused": True}}}}
-    calls = []
-    monkeypatch.setattr(bench, "_load_bench_tpu", lambda: fake)
-    monkeypatch.setattr(bench, "resnet50_time_config",
-                        _fake_time_config(calls))
+    monkeypatch.setattr(bench, "_load_bench_tpu", lambda: stale)
+    monkeypatch.setattr(bench, "resnet50_time_config", fake_time_config)
     row = bench.bench_resnet50(True, 197e12)
-    assert calls[0] == {"batch": 192, "ss": 16, "fused": False}
-    assert row["batch"] == 192
+    assert calls == [{"batch": 128, "ss": 16}]
+    assert row["batch"] == 128
     assert row["metric"] == "resnet50_train_mfu"
 
 
-def test_resnet_headline_falls_back_without_sweep(monkeypatch):
-    calls = []
-    monkeypatch.setattr(bench, "_load_bench_tpu", lambda: {})
-    monkeypatch.setattr(bench, "resnet50_time_config",
-                        _fake_time_config(calls))
-    bench.bench_resnet50(True, 197e12)
-    assert calls[0] == {"batch": 128, "ss": 16, "fused": False}
+def test_main_needs_a_tpu_and_replays_nothing(capsys):
+    """`python bench.py` on a host with no TPU: non-zero exit naming
+    the platform found, and not one row on stdout (in particular none
+    out of BENCH_TPU.json)."""
+    import pytest
+
+    with pytest.raises(SystemExit) as ei:
+        bench.main()
+    assert ei.value.code not in (0, None)
+    assert "cpu" in str(ei.value.code)
+    assert capsys.readouterr().out == ""
 
 
 def test_error_rows_carry_real_metric_names():
     # the benches table must name each config's REAL metric so error
-    # rows can't flip keys vs success rows (ADVICE r4); this pins the
-    # pairs that previously drifted
+    # rows can't flip keys vs success rows; this pins the pairs that
+    # previously drifted
     src = open(bench.__file__).read()
     for key, metric in (
             ("decode", "gpt_decode_tokens_per_sec"),

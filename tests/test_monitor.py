@@ -354,7 +354,8 @@ def test_executor_feeds_monitor_automatically(tmp_path):
     assert snap["host_dispatch_us"]["mean"] > 0
     assert snap["examples"] == 16 * 4
     assert snap["feed_bytes"] > 0 and snap["fetch_bytes"] > 0
-    assert snap["mfu"] and snap["mfu"] > 0
+    assert snap["mfu"] is None                      # a CPU has no peak
+    assert monitor.mfu(peak=1e11) > 0               # the inputs are there
     # the two compile-paying runs are warmup-tagged, so the means above
     # are steady-state numbers
     records = monitor.step_records()
@@ -578,7 +579,8 @@ def test_bench_telemetry_smoke_row_passes():
     assert brief["counters"]["run_plan.miss"] > 0
     assert brief["compile"]["count"] >= 1
     assert brief["compile"]["memory"]["temp_bytes"] is not None
-    assert brief["mfu"] > 0
+    assert brief["compile"]["flops"] > 0
+    assert "mfu" not in brief                       # a CPU has no peak
     # the smoke row leaves the global monitor clean for the next config
     assert not monitor.is_enabled()
     assert monitor.snapshot()["steps"] == 0

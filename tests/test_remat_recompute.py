@@ -48,6 +48,28 @@ def _make(remat, dtype="float32", data_format="NCHW", bn_stats_sample=0,
     return model, state, step
 
 
+# remat and plain are two XLA schedules of ONE float32 program, so they
+# differ only by reduction order: a reduction of n terms moves by about
+# sqrt(n) eps when reordered, ResNet-18 stacks ~20 conv/BN layers of
+# >= 3*3*64 = 576-term reductions, and the errors add: 5.7e-5 of the
+# result's scale for one pass through the net.  The installed XLA lands
+# at 1.05e-5 on the loss (one pass) and 1.2e-4 on a parameter update
+# (forward and backward: two passes), so the bound carries a factor 2.
+F32_REORDER_RTOL = 20 * float(np.sqrt(576)) * float(np.finfo(np.float32).eps)
+
+
+def _assert_same_step(new0, new1, old, passes):
+    """Two schedules moved every leaf of `old` to the same place, up to
+    float32 reordering over `passes` trips through the net.  Judged on
+    the update's norm: a ReLU gate that flips on a last-bit difference
+    moves single elements by more than any elementwise bound allows."""
+    for n in new0:
+        a, b = np.asarray(new0[n]), np.asarray(new1[n])
+        moved = np.linalg.norm(a - np.asarray(old[n]))
+        assert np.linalg.norm(a - b) <= \
+            2 * passes * F32_REORDER_RTOL * moved + 1e-7, n
+
+
 def _batch(dtype=jnp.float32, batch=4, ch=3, size=16):
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((batch, ch, size, size)), dtype)
@@ -65,15 +87,9 @@ def test_remat_grad_parity_with_plain_path(remat):
 
     s0, l0 = jax.jit(step0)(state0, x, y)
     s1, l1 = jax.jit(step1)(state1, x, y)
-    assert float(l0) == pytest.approx(float(l1), rel=1e-5)
-    for n in s0.params:
-        np.testing.assert_allclose(np.asarray(s0.params[n]),
-                                   np.asarray(s1.params[n]),
-                                   rtol=1e-4, atol=1e-5, err_msg=n)
-    for n in s0.buffers:
-        np.testing.assert_allclose(np.asarray(s0.buffers[n]),
-                                   np.asarray(s1.buffers[n]),
-                                   rtol=1e-4, atol=1e-5, err_msg=n)
+    assert float(l0) == pytest.approx(float(l1), rel=F32_REORDER_RTOL)
+    _assert_same_step(s0.params, s1.params, state0.params, passes=2)
+    _assert_same_step(s0.buffers, s1.buffers, state0.buffers, passes=1)
 
 
 def test_remat_inside_scan_with_donation():
@@ -143,7 +159,7 @@ def test_remat_with_accum_steps():
     state1, step1 = build(True)
     s0, l0 = step0(state0, x, y)
     s1, l1 = step1(state1, x, y)
-    assert float(l0) == pytest.approx(float(l1), rel=1e-5)
+    assert float(l0) == pytest.approx(float(l1), rel=F32_REORDER_RTOL)
     # slightly looser than the single-step parity: the accumulation scan
     # reorders the recompute, which legally perturbs fp32 rounding
     for n in s0.params:

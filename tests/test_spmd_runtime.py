@@ -184,7 +184,17 @@ def test_executor_tp_run_shards_leaves_and_conforms():
                                     scope=scope)[0]))
               for _ in range(2)]
     assert all(np.isfinite(losses))
-    assert losses[1] < losses[0]          # it is actually training
+    # it is actually training: both steps track the unsharded program
+    # on the same feed (step 2 only matches if step 1's update landed)
+    # (a fresh executor: parameter init draws from its rng stream)
+    ref_exe, ref_scope = fluid.Executor(), Scope()
+    ref_exe.run(m.startup, scope=ref_scope)
+    ref = [float(np.mean(ref_exe.run(m.main, feed=feed,
+                                     fetch_list=[m.loss_name],
+                                     scope=ref_scope)[0]))
+           for _ in range(2)]
+    assert ref[1] != ref[0]
+    np.testing.assert_allclose(losses, ref, rtol=1e-5)
 
     # (a) placement per plan leaf: sharded specs land sharded, with
     # per-shard bytes strictly below the replicated size

@@ -87,11 +87,6 @@ def load_xspace(path):
     return xs
 
 
-# importer-compat alias: tools/r5_resnet_probe.py and tools/onchip_queue.py
-# do `from tools.parse_xplane import load`
-load = load_xspace
-
-
 def device_plane(xs):
     for p in xs.planes:
         if p.name.startswith("/device:TPU"):
