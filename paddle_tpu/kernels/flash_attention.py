@@ -151,7 +151,7 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k):
     q3 = q.reshape(b * h, s, d)
     k3 = k.reshape(b * h, s, d)
     v3 = v.reshape(b * h, s, d)
-    out, lse = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k),
         grid=grid,
@@ -175,7 +175,10 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k):
         ],
         compiler_params=_compiler_params(),
         interpret=interpret(),
-    )(q3, k3, v3)
+        name="flash_fwd",
+    )
+    with jax.named_scope("flash_fwd"):
+        out, lse = call(q3, k3, v3)
     return out.reshape(b, h, s, d), lse.reshape(b, h, s)
 
 
@@ -293,7 +296,7 @@ def _bwd_core(sm_scale, causal, block_q, block_k, q, k, v, do, lse,
     delta3 = delta.reshape(b * h, s, 1)
 
     grid_dq = (b * h, s // block_q, s // block_k)
-    dq = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k),
         grid=grid_dq,
@@ -310,10 +313,13 @@ def _bwd_core(sm_scale, causal, block_q, block_k, q, k, v, do, lse,
         scratch_shapes=[_scratch((block_q, d))],
         compiler_params=_compiler_params(),
         interpret=interpret(),
-    )(q3, k3, v3, do3, lse3, delta3)
+        name="flash_dq",
+    )
+    with jax.named_scope("flash_dq"):
+        dq = call(q3, k3, v3, do3, lse3, delta3)
 
     grid_kv = (b * h, s // block_k, s // block_q)
-    dk, dv = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k),
         grid=grid_kv,
@@ -336,7 +342,10 @@ def _bwd_core(sm_scale, causal, block_q, block_k, q, k, v, do, lse,
         scratch_shapes=[_scratch((block_k, d)), _scratch((block_k, d))],
         compiler_params=_compiler_params(),
         interpret=interpret(),
-    )(q3, k3, v3, do3, lse3, delta3)
+        name="flash_dkv",
+    )
+    with jax.named_scope("flash_dkv"):
+        dk, dv = call(q3, k3, v3, do3, lse3, delta3)
 
     return (dq.reshape(b, h, s, d), dk.reshape(b, h, s, d),
             dv.reshape(b, h, s, d))
@@ -423,7 +432,7 @@ def flash_decode(q, k, v, lengths, sm_scale=None, block_k=None):
     q3 = q.reshape(b * h, 1, d)
     k3 = k.reshape(b * h, t, d)
     v3 = v.reshape(b * h, t, d)
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_decode_kernel, sm_scale=float(sm_scale),
                           block_k=block_k, heads=h),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -449,7 +458,10 @@ def flash_decode(q, k, v, lengths, sm_scale=None, block_k=None):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret(),
-    )(lengths, q3, k3, v3)
+        name="flash_decode",
+    )
+    with jax.named_scope("flash_decode"):
+        out = call(lengths, q3, k3, v3)
     return out.reshape(b, h, 1, d)
 
 

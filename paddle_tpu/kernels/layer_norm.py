@@ -93,7 +93,7 @@ def _fwd(x, gamma, beta, eps, block_rows):
     rows, d = x.shape
     block = min(block_rows, rows)
     grid = (pl.cdiv(rows, block),)
-    y = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
         grid=grid,
         in_specs=[
@@ -104,7 +104,10 @@ def _fwd(x, gamma, beta, eps, block_rows):
         out_specs=pl.BlockSpec((block, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
         interpret=interpret(),
-    )(x, gamma, beta)
+        name="layer_norm_fwd",
+    )
+    with jax.named_scope("layer_norm_fwd"):
+        y = call(x, gamma, beta)
     return y
 
 
@@ -113,7 +116,7 @@ def _bwd(x, gamma, dy, eps, block_rows):
     block = min(block_rows, rows)
     nblocks = pl.cdiv(rows, block)
     groups = 8 if block % 8 == 0 else 1
-    dx, dg_acc, db_acc = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_bwd_kernel, rows=rows, block=block,
                           groups=groups, eps=eps),
         grid=(nblocks,),
@@ -135,7 +138,10 @@ def _bwd(x, gamma, dy, eps, block_rows):
             jax.ShapeDtypeStruct((groups, d), jnp.float32),
         ],
         interpret=interpret(),
-    )(x, gamma, dy)
+        name="layer_norm_bwd",
+    )
+    with jax.named_scope("layer_norm_bwd"):
+        dx, dg_acc, db_acc = call(x, gamma, dy)
     return dx, dg_acc.sum(axis=0), db_acc.sum(axis=0)
 
 

@@ -64,7 +64,7 @@ def count_ge_histogram(flat_abs, edges, block=DEFAULT_BLOCK):
     x = jnp.pad(flat_abs.astype(jnp.float32), (0, pad),
                 constant_values=-1.0)                    # pads count 0
     rows = block // _LANES
-    per_lane = pl.pallas_call(
+    call = pl.pallas_call(
         _count_ge_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -77,7 +77,10 @@ def count_ge_histogram(flat_abs, edges, block=DEFAULT_BLOCK):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret(),
-    )(edges.astype(jnp.float32), x.reshape(-1, _LANES))
+        name="topk_threshold",
+    )
+    with jax.named_scope("topk_threshold"):
+        per_lane = call(edges.astype(jnp.float32), x.reshape(-1, _LANES))
     # a lane holds at most N/128 hits, exact in f32 far past any
     # gradient size; the cross-lane total is summed as integers
     return per_lane.astype(jnp.int32).sum(axis=1)

@@ -33,7 +33,8 @@ def host_span_events(events):
     return [
         {"name": e["name"], "ph": "X", "ts": e["ts"], "dur": e["dur"],
          "pid": _HOST_PID, "tid": e.get("tid", e.get("depth", 0)),
-         "cat": "host", "args": {"depth": e.get("depth", 0)}}
+         "cat": "host",
+         "args": {"depth": e.get("depth", 0), **e.get("attrs", {})}}
         for e in events
     ]
 

@@ -13,6 +13,7 @@ aggregate-table + chrome-trace-export shape.
 import contextlib
 import json
 import os
+import statistics
 import threading
 import time
 
@@ -22,7 +23,14 @@ from . import flags
 
 __all__ = ["profiler", "start_profiler", "stop_profiler", "RecordEvent",
            "cuda_profiler", "reset_profiler", "is_profiling",
-           "export_chrome_tracing", "add_span"]
+           "export_chrome_tracing", "add_span", "spans",
+           "trace_clock_offset_ns", "TRACE_PREFIX"]
+
+# A span recorded while a jax.profiler session runs is also written
+# into that session's trace under this prefix, on the device events'
+# clock, with its own perf_counter_ns start as the stat `pc_ns`.
+TRACE_PREFIX = "paddle_tpu:"
+_trace_enabled = jax.profiler.TraceAnnotation.is_enabled
 
 # Span storage: the nesting STACK is per-thread (spans nest within one
 # thread), but the recorded events are aggregated across threads —
@@ -68,55 +76,105 @@ def _clear_events():
         del evs[:]    # in place: each thread keeps its registered list
 
 
+def _event(name, start_ns, end_ns, depth, attrs=None):
+    event = {
+        "name": name,
+        "ts": start_ns / 1000.0,
+        "dur": (end_ns - start_ns) / 1000.0,
+        "depth": depth,
+        "tid": threading.get_ident(),
+        "start_ns": start_ns,
+        "end_ns": end_ns,
+    }
+    if attrs:
+        event["attrs"] = attrs
+    return event
+
+
 class RecordEvent:
-    """RAII host-side span (platform/profiler.h:124 parity).
+    """RAII host-side span (platform/profiler.h:124 parity), recorded
+    on two clocks.
 
-    Zero-cost while no profiling session is active: `__enter__` checks
-    `is_profiling()` ITSELF (not just the executor call sites), so a
-    RecordEvent sprinkled through user code costs steady-state training
-    one boolean check and records nothing.  A span that straddles
-    `reset_profiler` (entered before, exited after) is dropped rather
-    than resurrected: its start predates the reset, so appending it
-    would re-populate the just-cleared table with a stale event — the
-    session `epoch` stamp catches exactly that."""
+    On while a `start_profiler` session is active or a `jax.profiler`
+    session is (`TraceAnnotation.is_enabled()`: an operator's own
+    `start_trace`, the benchmark's traced stretch); off otherwise, at
+    the cost of that one check, so a RecordEvent sprinkled through the
+    hot path records nothing in steady state and needs no flag.  On, the
+    same two `perf_counter_ns` readings go to the event store
+    (`_all_events()`, `spans()`) and, while jax traces, a
+    `TraceAnnotation(TRACE_PREFIX + name, pc_ns=start, **attrs)` stays
+    open for the span's length: the span lies in the xplane trace beside
+    the device events, and `pc_ns` ties the two clocks
+    (`trace_clock_offset_ns`).
 
-    def __init__(self, name):
+    A span that straddles `reset_profiler` or the start of a session
+    (entered before, exited after) is dropped rather than resurrected:
+    its start predates the clear, so appending it would re-populate the
+    just-cleared table with a stale event — the `epoch` stamp catches
+    exactly that."""
+
+    __slots__ = ("name", "attrs", "start", "_epoch", "_annotation")
+
+    def __init__(self, name, **attrs):
         self.name = name
+        self.attrs = attrs
         self.start = None
         self._epoch = None
+        self._annotation = None
 
     def __enter__(self):
-        if not _active["on"]:
+        tracing = _note_trace_session(_trace_enabled())
+        if not (tracing or _active["on"]):
             self.start = None      # armed-off: __exit__ is a no-op
             return self
         _events()
         self._epoch = _active["epoch"]
         self.start = time.perf_counter_ns()
         _state.stack.append(self.name)
+        if tracing:
+            self._annotation = jax.profiler.TraceAnnotation(
+                TRACE_PREFIX + self.name, pc_ns=self.start, **self.attrs)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc):
         if self.start is None:
             return False
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
         end = time.perf_counter_ns()
         _state.stack.pop()
         if self._epoch != _active["epoch"]:
-            # reset_profiler (or a new start_profiler) cleared the event
-            # store while this span was open: discard, don't resurrect
+            # reset_profiler (or a new session) cleared the event store
+            # while this span was open: discard, don't resurrect
             return False
-        _events().append({
-            "name": self.name,
-            "ts": self.start / 1000.0,
-            "dur": (end - self.start) / 1000.0,
-            "depth": len(_state.stack),
-            "tid": threading.get_ident(),
-        })
+        _events().append(_event(self.name, self.start, end,
+                                len(_state.stack), self.attrs))
         return False
 
 
 # `epoch` counts event-store clears (reset_profiler / start_profiler);
 # an in-flight RecordEvent compares its entry epoch before appending.
-_active = {"on": False, "jax_trace": False, "dir": None, "epoch": 0}
+# `tracing` is what the span sites last saw of the jax.profiler session.
+_active = {"on": False, "jax_trace": False, "dir": None, "epoch": 0,
+           "tracing": False}
+
+
+def _note_trace_session(enabled):
+    """Keep `_active["tracing"]` in step with the jax.profiler session
+    and return `enabled`.  A session begins when a span site (or a
+    reader) first sees the profiler on: the store is cleared for it,
+    unless a `start_profiler` session, which cleared it itself, is on.
+    Its events outlive `stop_trace`, until the next session or
+    `reset_profiler()`."""
+    if enabled != _active["tracing"]:
+        with _registry_lock:
+            began = enabled and not _active["tracing"]
+            _active["tracing"] = enabled
+        if began and not _active["on"]:
+            reset_profiler()
+    return enabled
 
 
 def add_span(name, start_ns, end_ns, depth=0):
@@ -126,13 +184,37 @@ def add_span(name, start_ns, end_ns, depth=0):
     No-op outside a profiling session, same contract as RecordEvent."""
     if not _active["on"]:
         return
-    _events().append({
-        "name": name,
-        "ts": start_ns / 1000.0,
-        "dur": (end_ns - start_ns) / 1000.0,
-        "depth": depth,
-        "tid": threading.get_ident(),
-    })
+    _events().append(_event(name, start_ns, end_ns, depth))
+
+
+def spans(prefix=None):
+    """The newest session's spans as `(name, start_ns, end_ns, attrs)`
+    on `perf_counter_ns`, in order of their starts; those whose name
+    starts with `prefix`, if one is given.  They outlive the session
+    (`stop_trace`, `stop_profiler`) until the next one begins or
+    `reset_profiler()`."""
+    _note_trace_session(_trace_enabled())
+    return [(e["name"], e["start_ns"], e["end_ns"], e.get("attrs", {}))
+            for e in _all_events()
+            if prefix is None or e["name"].startswith(prefix)]
+
+
+def trace_clock_offset_ns(xplane_events):
+    """What to add to a `perf_counter_ns` reading to lay it on the
+    clock of a jax.profiler trace: the median of `start_ns - pc_ns`
+    over the `TRACE_PREFIX` events among `xplane_events` (events of a
+    `jax.profiler.ProfileData` line: `.name`, `.start_ns`, `.stats`).
+    The two clocks run at one rate and the offset is the session's, so
+    every span stamped with `perf_counter_ns` (request traces, goodput,
+    the merged chrome trace) can be laid against the device's events.
+    None where the trace holds no such event."""
+    offsets = []
+    for e in xplane_events:
+        if e.name.startswith(TRACE_PREFIX):
+            pc_ns = dict(e.stats).get("pc_ns")
+            if pc_ns is not None:
+                offsets.append(e.start_ns - int(pc_ns))
+    return statistics.median(offsets) if offsets else None
 
 
 def is_profiling():

@@ -204,14 +204,23 @@ def device_prefetch(batches, size=2, device=None):
         return jax.tree_util.tree_map(put_leaf, item)
 
     from .. import monitor
+    from ..profiler import RecordEvent
 
     depth = monitor.gauge("reader.prefetch_depth")
     it = iter(batches)
     queue = collections.deque()
+    exhausted = object()
 
     def fill(n):
-        for item in itertools.islice(it, n):
-            queue.append(put(item))
+        # under a profiler session: the source's time to make a batch
+        # and the host's time to start its transfer, apart
+        for _ in range(n):
+            with RecordEvent("reader.source"):
+                item = next(it, exhausted)
+            if item is exhausted:
+                return
+            with RecordEvent("reader.device_put"):
+                queue.append(put(item))
 
     fill(size)
     while queue:

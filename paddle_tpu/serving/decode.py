@@ -50,7 +50,6 @@ then admit again) — the bench's control arm, isolating iteration-level
 scheduling as the measured lever.
 """
 
-import functools
 import threading
 import time
 from collections import deque
@@ -58,6 +57,7 @@ from collections import deque
 import numpy as np
 
 from .. import flags
+from ..profiler import RecordEvent
 from ..resilience import faultinject
 from ..resilience.breaker import CircuitBreaker
 from ..resilience.retry import RetryPolicy, call_with_retry
@@ -394,19 +394,29 @@ class DecodeEngine:
         mon = _mon()
         cfg = self.config
         dec_cfg = self.params.cfg
-        step = jax.jit(functools.partial(_decode_step_impl, cfg=dec_cfg),
-                       donate_argnums=(0,))
+
+        # jitted from named functions, so that a device trace reads
+        # `jit_decode_step` and `jit_prefill_b<bucket>` (a jitted
+        # functools.partial reads `jit__unknown`)
+        def named(name, impl):
+            def fn(*args):
+                return impl(*args, cfg=dec_cfg)
+
+            fn.__name__ = fn.__qualname__ = name
+            return jax.jit(fn, donate_argnums=(0,))
+
         self._step_fn = mon.instrument_jit(
-            step, key=f"{cfg.label}.decode_step")
+            named("decode_step", _decode_step_impl),
+            key=f"{cfg.label}.decode_step")
         self._prefill_fns = {}
-        pre = jax.jit(functools.partial(_prefill_impl, cfg=dec_cfg),
-                      donate_argnums=(0,))
         for b in cfg.buckets:
-            # one instrumented wrapper per bucket: the ledger wrappers
-            # are signature-pinned, and per-bucket keys make the
-            # "1 prefill compile per bucket" assertion a ledger query
+            # one program and one instrumented wrapper per bucket: the
+            # ledger wrappers are signature-pinned, and per-bucket keys
+            # make the "1 prefill compile per bucket" assertion a
+            # ledger query
             self._prefill_fns[b] = mon.instrument_jit(
-                pre, key=f"{cfg.label}.prefill_b{b}")
+                named(f"prefill_b{b}", _prefill_impl),
+                key=f"{cfg.label}.prefill_b{b}")
 
     def _fresh_state(self):
         import jax.numpy as jnp
@@ -735,13 +745,30 @@ class DecodeEngine:
     def step(self):
         """One engine iteration: sweep budgets, refill free slots via
         prefill, then run one full-width decode step.  Returns the
-        number of device dispatches made (0 = idle)."""
-        cfg = self.config
-        self.sweep_expired()
+        number of device dispatches made (0 = idle).
+
+        While a profiler session runs, an iteration with work is one
+        `engine.step` span cut into its phases, in this order:
+        `engine.sweep`, `engine.admit`; for each admitted request
+        `engine.prefill_host`, `engine.prefill_wait`,
+        `engine.prefill_book`; then `engine.decode_host`,
+        `engine.decode_wait`, `engine.emit` and, every 64th step,
+        `engine.telemetry`.  The `*_wait` spans are the host blocked on
+        the device's answer; all the others are the host's own work."""
         with self._lock:
-            if self._broken:
-                return 0
-            picks = self._admit_locked()
+            if not self._queue and not any(self._slot_req):
+                return 0                   # nothing to do: no span
+        with RecordEvent("engine.step"):
+            return self._step()
+
+    def _step(self):
+        with RecordEvent("engine.sweep"):
+            self.sweep_expired()
+        with RecordEvent("engine.admit"):
+            with self._lock:
+                if self._broken:
+                    return 0
+                picks = self._admit_locked()
         dispatched = 0
         for idx, (slot, req) in enumerate(picks):
             if not self.breaker.allow():
@@ -765,48 +792,55 @@ class DecodeEngine:
                     self._resolve_error(r, err, "cancelled")
                 return dispatched + 1      # engine broken
             dispatched += 1
-        with self._lock:
-            slot_reqs = list(self._slot_req)
-        want_step = any(
-            r is not None and (r.kill or not r.future.done())
-            for r in slot_reqs)
-        if want_step and self.breaker.allow():
-            self._decode_once(slot_reqs)
-            dispatched += 1
-        return dispatched
+        return dispatched + self._decode_once()
 
     def _prefill(self, slot, req):
         cfg = self.config
-        bucket = req.bucket
-        prompt = np.zeros((1, bucket), np.int32)
-        prompt[0, :req.prompt.size] = req.prompt
-        true_len = req.prompt.size
-        stop = true_len + req.max_new - 1   # position of the last token
-        meta = {"op": "prefill", "bucket": bucket, "slot": slot,
-                "rid": req.rid}
-        pspan = None
-        if req.trace is not None:
-            meta["trace_id"] = req.trace.trace_id
-            req.trace.end(req.qspan)
-            pspan = req.trace.child(f"prefill/b{bucket}", "prefill",
-                                    attrs={"bucket": bucket,
-                                           "slot": slot})
-        fn = self._prefill_fns[bucket]
-        state = self._state
+        # the engine turns to this request: its wait in the queue ends
+        # here and its prefill's turnaround begins
+        admit_t = cfg.clock()
+        with RecordEvent("engine.prefill_host"):
+            bucket = req.bucket
+            prompt = np.zeros((1, bucket), np.int32)
+            prompt[0, :req.prompt.size] = req.prompt
+            true_len = req.prompt.size
+            stop = true_len + req.max_new - 1   # position of the last token
+            meta = {"op": "prefill", "bucket": bucket, "slot": slot,
+                    "rid": req.rid}
+            pspan = None
+            if req.trace is not None:
+                meta["trace_id"] = req.trace.trace_id
+                req.trace.end(req.qspan)
+                pspan = req.trace.child(f"prefill/b{bucket}", "prefill",
+                                        attrs={"bucket": bucket,
+                                               "slot": slot})
+            fn = self._prefill_fns[bucket]
+            state = self._state
 
-        def call():
-            return fn(state, self._trees, prompt, np.int32(true_len),
-                      np.int32(slot), np.int32(stop),
-                      np.int32(-1 if req.eos_id is None else req.eos_id),
-                      np.float32(req.temperature), req.key)
+            def call():
+                return fn(state, self._trees, prompt, np.int32(true_len),
+                          np.int32(slot), np.int32(stop),
+                          np.int32(-1 if req.eos_id is None
+                                   else req.eos_id),
+                          np.float32(req.temperature), req.key)
 
-        out = self._dispatch(call, meta, [req])
+            out = self._dispatch(call, meta, [req])
         if out is None:
             return False
         self._state, first, active = out
+        # the first token's time, as the stats and the budgets have it:
+        # the program is launched, its answer not yet on the host
         now = cfg.clock()
-        first = int(first)
-        active = bool(active)
+        with RecordEvent("engine.prefill_wait", bucket=bucket, slot=slot,
+                         rid=req.rid, queue_wait_s=admit_t - req.enqueue_t,
+                         turnaround_s=now - admit_t):
+            first = int(first)
+            active = bool(active)
+        with RecordEvent("engine.prefill_book"):
+            self._prefill_book(slot, req, first, active, pspan, now)
+        return True
+
+    def _prefill_book(self, slot, req, first, active, pspan, now):
         req.first_token_t = req.last_token_t = now
         if req.trace is not None:
             req.trace.annotate(pspan, "first_token")
@@ -816,7 +850,7 @@ class DecodeEngine:
             req.kill = True
             with self._lock:
                 self._slot_req[slot] = req if active else None
-            return True
+            return
         self.stats.note_prefill(ttft_s=now - req.enqueue_t, now=now)
         req.tokens.append(first)
         req.slot = slot
@@ -832,37 +866,57 @@ class DecodeEngine:
                                             attrs={"slot": slot})
             with self._lock:
                 self._slot_req[slot] = req
-        return True
 
-    def _decode_once(self, slot_reqs):
+    def _decode_once(self):
+        """One full-width decode step if a slot needs one and the
+        breaker allows it.  Returns the dispatches made (0 or 1)."""
         cfg = self.config
-        kill = np.array([r is not None and r.kill for r in slot_reqs],
-                        bool)
-        rids = [r.rid for r in slot_reqs if r is not None]
-        meta = {"op": "decode", "active": int(sum(
-            r is not None and not r.kill for r in slot_reqs)),
-            "request_ids": rids}
-        tids = [r.trace.trace_id for r in slot_reqs
-                if r is not None and r.trace is not None]
-        if tids:
-            # a wedged decode step's stall dump names every resident
-            # request's trace
-            meta["trace_ids"] = tids
-        state = self._state
+        with RecordEvent("engine.decode_host"):
+            with self._lock:
+                slot_reqs = list(self._slot_req)
+            want_step = any(
+                r is not None and (r.kill or not r.future.done())
+                for r in slot_reqs)
+            if not (want_step and self.breaker.allow()):
+                return 0
+            kill = np.array([r is not None and r.kill for r in slot_reqs],
+                            bool)
+            rids = [r.rid for r in slot_reqs if r is not None]
+            meta = {"op": "decode", "active": int(sum(
+                r is not None and not r.kill for r in slot_reqs)),
+                "request_ids": rids}
+            tids = [r.trace.trace_id for r in slot_reqs
+                    if r is not None and r.trace is not None]
+            if tids:
+                # a wedged decode step's stall dump names every resident
+                # request's trace
+                meta["trace_ids"] = tids
+            state = self._state
 
-        def call():
-            return self._step_fn(state, self._trees, kill)
+            def call():
+                return self._step_fn(state, self._trees, kill)
 
-        waiting = [r for r in slot_reqs
-                   if r is not None and not r.future.done()]
-        out = self._dispatch(call, meta, waiting)
+            waiting = [r for r in slot_reqs
+                       if r is not None and not r.future.done()]
+            out = self._dispatch(call, meta, waiting)
         if out is None:
-            return False
+            return 1
         self._state, tokens, was_active, still = out
         now = cfg.clock()
-        tokens = np.asarray(tokens)
-        was_active = np.asarray(was_active)
-        still = np.asarray(still)
+        with RecordEvent("engine.decode_wait", active=meta["active"]):
+            tokens = np.asarray(tokens)
+            was_active = np.asarray(was_active)
+            still = np.asarray(still)
+        with RecordEvent("engine.emit"):
+            self._emit(slot_reqs, tokens, was_active, still, now)
+        if self.stats.decode_steps % 64 == 0:
+            with RecordEvent("engine.telemetry"):
+                self.emit_telemetry()
+        return 1
+
+    def _emit(self, slot_reqs, tokens, was_active, still, now):
+        """Hand the step's tokens to their requests, resolve the
+        finished ones and release their slots."""
         emitted = 0
         for i, req in enumerate(slot_reqs):
             if req is None:
@@ -893,9 +947,6 @@ class DecodeEngine:
                         self._slot_req[i] = None
         self.stats.note_decode_step(int(was_active.sum()), emitted,
                                     now=now)
-        if self.stats.decode_steps % 64 == 0:
-            self.emit_telemetry()
-        return True
 
     def _loop(self):
         while True:
@@ -926,6 +977,10 @@ class DecodeEngine:
         telemetry JSONL stream (no-op while telemetry is off).  With
         request tracing on, the record carries the label's
         attribution/SLO summary."""
+        if not _mon().is_enabled():
+            # to_record() sorts three rings of 8,192 samples: not on the
+            # engine's thread for a record that would be thrown away
+            return None
         rec = self.stats.to_record()
         store = _tracing().get()
         if store.enabled:

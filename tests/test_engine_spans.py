@@ -1,0 +1,428 @@
+"""Spans inside the decode engine and the reader, on two clocks (ISSUE
+27): `profiler.RecordEvent` follows the jax.profiler session with no
+flag, the engine's iteration is cut into phases, a request's way to its
+first token is split, the spans lie in the xplane trace with the
+`perf_counter_ns` reading that ties the clocks, and the programs and
+kernels carry names.
+
+Structure only: no test asserts a duration on a shared CPU.  Engines are
+driven by `step()` (`auto_start=False`), so what ran is exact."""
+
+import glob
+import importlib.util
+import os
+import statistics
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu import monitor, profiler
+from paddle_tpu.models.gpt import GPT, GPTConfig
+from paddle_tpu.reader import device_prefetch
+from paddle_tpu.serving.decode import DecodeConfig, DecodeEngine
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PHASES = ["engine.sweep", "engine.admit", "engine.prefill_host",
+          "engine.prefill_wait", "engine.prefill_book",
+          "engine.decode_host", "engine.decode_wait", "engine.emit",
+          "engine.telemetry"]
+
+
+@pytest.fixture(autouse=True)
+def _clean_state():
+    monitor.disable()
+    monitor.reset()
+    profiler.reset_profiler()
+    yield
+    monitor.disable()
+    monitor.reset()
+    profiler.reset_profiler()
+
+
+@pytest.fixture(scope="module")
+def model():
+    np.random.seed(27)
+    return GPT(GPTConfig(vocab_size=97, hidden_size=48, num_layers=2,
+                         num_heads=4, max_seq_len=32, dropout=0.0))
+
+
+def _engine(model, **kw):
+    kw.setdefault("slots", 3)
+    kw.setdefault("max_len", 32)
+    kw.setdefault("buckets", (8, 16))
+    kw.setdefault("watchdog_stall_s", 30.0)
+    kw.setdefault("label", f"span_test_{len(kw)}_{id(kw) % 10000}")
+    return DecodeEngine(model, config=DecodeConfig(**kw), auto_start=False)
+
+
+def _serve(eng, n=4, max_new=5):
+    """Submit `n` requests (more than slots, so one waits) and step the
+    engine until all have resolved."""
+    rng = np.random.default_rng(3)
+    futs = [eng.submit(rng.integers(0, 97, size=4 + i), max_new + i)
+            for i in range(n)]
+    for _ in range(200):
+        if all(f.done() for f in futs):
+            return futs
+        eng.step()
+    raise AssertionError("engine did not drain")
+
+
+@pytest.fixture
+def traced(tmp_path):
+    """Run `body()` under a jax.profiler session; returns the events of
+    the xplane file's host plane whose names carry the prefix."""
+    def run(body):
+        tdir = str(tmp_path / f"trace{len(os.listdir(tmp_path))}")
+        jax.profiler.start_trace(tdir)
+        try:
+            body()
+        finally:
+            jax.profiler.stop_trace()
+        files = glob.glob(os.path.join(tdir, "**", "*.xplane.pb"),
+                          recursive=True)
+        assert files, "the profiler left no trace"
+        data = jax.profiler.ProfileData.from_file(files[0])
+        return [e for plane in data.planes if plane.name == "/host:CPU"
+                for line in plane.lines for e in line.events
+                if e.name.startswith(profiler.TRACE_PREFIX)]
+    return run
+
+
+# ---------------------------------------------------------------------
+# off: nothing recorded, nothing opened
+# ---------------------------------------------------------------------
+
+def test_off_the_engine_and_the_reader_record_nothing(model, monkeypatch):
+    class Refused:
+        def __init__(self, *a, **kw):
+            raise AssertionError("an annotation was opened with no "
+                                 "profiler session running")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Refused)
+    eng = _engine(model)
+    futs = _serve(eng)
+    list(device_prefetch(iter([np.zeros(3)] * 3)))
+    eng.close()
+    assert all(f.result(timeout=0).size for f in futs)
+    assert profiler.spans() == []
+    assert profiler._all_events() == []
+
+
+# ---------------------------------------------------------------------
+# on: the iteration's phases
+# ---------------------------------------------------------------------
+
+def test_every_iteration_holds_its_phases_in_order(model, traced):
+    eng = _engine(model)
+    steps_run = []
+
+    def body():
+        _serve(eng)
+        steps_run.append(eng.stats.decode_steps)
+        assert eng.step() == 0         # idle: has to leave no span
+
+    traced(body)
+    eng.close()
+    spans = profiler.spans("engine.")
+    steps = [(s, e) for n, s, e, _ in spans if n == "engine.step"]
+    phases = [(n, s, e) for n, s, e, _ in spans if n != "engine.step"]
+    assert set(n for n, _, _ in phases) <= set(PHASES)
+    assert len(steps) >= steps_run[0] > 0
+    seen = 0
+    for lo, hi in steps:
+        inside = [(n, s, e) for n, s, e in phases if lo <= s and e <= hi]
+        seen += len(inside)
+        names = [n for n, _, _ in inside]
+        # in the order of the list above (a prefill's three once for
+        # each admitted request), disjoint, nothing twice but prefills
+        assert names[:2] == ["engine.sweep", "engine.admit"]
+        assert [PHASES.index(n) for n in names if "prefill" not in n] == \
+            sorted(PHASES.index(n) for n in names if "prefill" not in n)
+        pre = [n for n in names if "prefill" in n]
+        assert pre == PHASES[2:5] * (len(pre) // 3)
+        last_prefill = max([i for i, n in enumerate(names)
+                            if "prefill" in n], default=1)
+        assert all("prefill" in n for n in names[2:last_prefill + 1])
+        for (_, _, e0), (_, s1, _) in zip(inside, inside[1:]):
+            assert e0 <= s1
+        if "engine.decode_wait" in names:
+            assert names[names.index("engine.decode_wait") - 1:][:3] == [
+                "engine.decode_host", "engine.decode_wait", "engine.emit"]
+    assert seen == len(phases)         # no phase outside an iteration
+    waits = [a for n, _, _, a in spans if n == "engine.decode_wait"]
+    assert len(waits) == steps_run[0]
+    assert all(1 <= a["active"] <= 3 for a in waits)
+
+
+def test_first_token_split_adds_up_to_ttft(model, traced):
+    """Under a clock that ticks on every reading, `queue_wait_s` +
+    `turnaround_s` is exactly the `ttft_s` that `note_prefill` got."""
+    ticks = iter(np.arange(100.0, 1e6, 0.125))
+    eng = _engine(model, clock=lambda: float(next(ticks)), slots=1,
+                  buckets=(8,))
+    got = []
+    note = eng.stats.note_prefill
+
+    def note_prefill(ttft_s=None, now=None):
+        got.append(ttft_s)
+        return note(ttft_s=ttft_s, now=now)
+
+    eng.stats.note_prefill = note_prefill
+    traced(lambda: _serve(eng, n=3, max_new=2))
+    eng.close()
+    attrs = [a for n, _, _, a in profiler.spans("engine.prefill_wait")]
+    assert len(attrs) == len(got) == 3
+    assert sorted(a["rid"] for a in attrs) == [1, 2, 3]
+    for a, ttft_s in zip(attrs, got):
+        assert a["bucket"] == 8 and a["slot"] == 0
+        assert a["queue_wait_s"] > 0 and a["turnaround_s"] > 0
+        assert a["queue_wait_s"] + a["turnaround_s"] == ttft_s
+    # one slot: the later requests waited for the earlier ones
+    assert attrs[2]["queue_wait_s"] > attrs[0]["queue_wait_s"]
+
+
+# ---------------------------------------------------------------------
+# the same spans in the xplane trace, and the two clocks tied
+# ---------------------------------------------------------------------
+
+def test_xplane_holds_the_spans_with_their_clock_reading(model, traced):
+    eng = _engine(model)
+
+    def body():
+        _serve(eng)
+        list(device_prefetch(iter([np.zeros(3)] * 4)))
+
+    events = traced(body)
+    eng.close()
+    spans = profiler.spans()
+    assert {n.split(".")[0] for n, _, _, _ in spans} == {"engine", "reader"}
+    in_trace = sorted((e.name[len(profiler.TRACE_PREFIX):],
+                       int(dict(e.stats)["pc_ns"])) for e in events)
+    assert in_trace == sorted((n, s) for n, s, _, _ in spans)
+    stats = {e.name: dict(e.stats) for e in events}
+    assert {"bucket", "slot", "rid", "queue_wait_s", "turnaround_s"} <= \
+        set(stats[profiler.TRACE_PREFIX + "engine.prefill_wait"])
+    assert "active" in stats[profiler.TRACE_PREFIX + "engine.decode_wait"]
+    # one offset for the session, to well under a decode step
+    offsets = [profiler.trace_clock_offset_ns([e]) for e in events]
+    assert max(offsets) - min(offsets) < 2e6
+    assert profiler.trace_clock_offset_ns(events) == \
+        statistics.median(offsets)
+    assert profiler.trace_clock_offset_ns([]) is None
+    # the span store's perf_counter_ns times land on the trace's clock
+    offset = profiler.trace_clock_offset_ns(events)
+    by_start = {int(dict(e.stats)["pc_ns"]): e for e in events}
+    for n, s, e, _ in spans:
+        assert abs(by_start[s].start_ns - (s + offset)) < 2e6
+
+
+def test_a_second_session_does_not_return_the_first_ones_spans(model,
+                                                               traced):
+    eng = _engine(model)
+    traced(lambda: _serve(eng, n=2))
+    first = profiler.spans()
+    assert first and profiler.spans() == first   # they outlive stop_trace
+    traced(lambda: list(device_prefetch(iter([np.zeros(3)] * 2))))
+    eng.close()
+    second = profiler.spans()
+    assert second and {n.split(".")[0] for n, _, _, _ in second} == \
+        {"reader"}
+    assert profiler.spans("engine.") == []
+    profiler.reset_profiler()
+    assert profiler.spans() == []
+
+
+def test_start_profiler_sessions_still_record(model):
+    """The older switch: `start_profiler` without a jax trace records to
+    the store, attrs and all, and opens no annotation."""
+    profiler.start_profiler(state="CPU")
+    try:
+        with profiler.RecordEvent("outer", k=1):
+            with profiler.RecordEvent("inner"):
+                pass
+    finally:
+        table = profiler.stop_profiler(profile_path=None)
+    assert set(table) == {"outer", "inner"}
+    (n0, s0, e0, a0), (n1, s1, e1, a1) = profiler.spans()
+    assert (n0, a0, n1, a1) == ("outer", {"k": 1}, "inner", {})
+    assert s0 <= s1 <= e1 <= e0
+    from paddle_tpu.monitor.trace import host_span_events
+
+    rows = host_span_events(profiler._all_events())
+    assert rows[0]["args"] == {"depth": 0, "k": 1}
+
+
+# ---------------------------------------------------------------------
+# the reader
+# ---------------------------------------------------------------------
+
+def test_reader_spans_one_pair_a_batch(traced):
+    batches = [{"x": np.full((2, 3), i, np.float32)} for i in range(5)]
+    out = []
+    traced(lambda: out.extend(device_prefetch(iter(batches), size=2)))
+    assert [int(b["x"][0, 0]) for b in out] == list(range(5))
+    names = [n for n, _, _, _ in profiler.spans("reader.")]
+    assert names.count("reader.device_put") == 5
+    # and the looks at the source that found it exhausted
+    assert names.count("reader.source") == 5 + 2
+    spans = profiler.spans("reader.")
+    for (_, _, e0, _), (_, s1, _, _) in zip(spans, spans[1:]):
+        assert e0 <= s1
+
+
+# ---------------------------------------------------------------------
+# what the benchmark leans on
+# ---------------------------------------------------------------------
+
+def _load(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_the_benchmarks_recording_stats_still_graft(model):
+    driver = _load(os.path.join(ROOT, "benchmarks", "drivers",
+                                "serve_closed.py"), "serve_closed_driver")
+    t = iter(np.arange(0.0, 1e6, 0.5))
+    clock = lambda: float(next(t))  # noqa: E731
+    eng = _engine(model, clock=clock)
+    eng.stats.__class__ = driver.recording_stats(type(eng.stats))
+    eng.stats.start_recording(eng, clock)
+    futs = _serve(eng)
+    eng.close()
+    stats = eng.stats
+    tokens = sum(f.result(timeout=0).size for f in futs)
+    assert len(stats.first_tokens) == len(futs)
+    assert len(stats.gaps) == tokens - len(futs)
+    assert sum(s[2] for s in stats.decode_steps_at) == len(stats.gaps)
+    assert len(stats.resolved_at) == len(futs)
+    assert all(now is not None for now, _ in stats.first_tokens)
+
+
+def test_no_telemetry_record_is_built_while_telemetry_is_off(model):
+    eng = _engine(model)
+    built = []
+    to_record = eng.stats.to_record
+
+    def counting():
+        built.append(1)
+        return to_record()
+
+    eng.stats.to_record = counting
+    futs = [eng.submit(np.arange(4), 24) for _ in range(9)]
+    while not all(f.done() for f in futs):
+        eng.step()
+    assert eng.stats.decode_steps >= 64    # past the 64th step's record
+    assert eng.emit_telemetry() is None
+    assert built == []
+    monitor.enable()
+    try:
+        rec = eng.emit_telemetry()
+    finally:
+        monitor.disable()
+    assert built == [1] and rec["kind"] == "serving"
+    eng.close()
+
+
+# ---------------------------------------------------------------------
+# names on the device
+# ---------------------------------------------------------------------
+
+def test_the_engines_programs_are_jitted_from_named_functions(
+        model, monkeypatch):
+    names = []
+    jit = jax.jit
+
+    def recording_jit(fn, *a, **kw):
+        names.append(fn.__name__)
+        return jit(fn, *a, **kw)
+
+    monkeypatch.setattr(jax, "jit", recording_jit)
+    eng = _engine(model, prewarm=False)
+    monkeypatch.setattr(jax, "jit", jit)
+    eng.close()
+    assert names == ["decode_step", "prefill_b8", "prefill_b16"]
+    # jax names a program after the function it was jitted from
+    step = jit(_named("decode_step"))
+    assert "module @jit_decode_step" in step.lower(1.0).as_text()
+
+
+def _named(name):
+    def fn(x):
+        return x
+
+    fn.__name__ = name
+    return fn
+
+
+def _kernel_jaxprs():
+    from paddle_tpu.kernels.flash_attention import (flash_attention,
+                                                    flash_decode)
+    from paddle_tpu.kernels.layer_norm import layer_norm_pallas
+    from paddle_tpu.kernels.topk_threshold import dgc_topk_mask_pallas
+
+    f32 = jnp.float32
+    q = jax.ShapeDtypeStruct((1, 2, 128, 64), f32)
+
+    def total(fn):
+        return lambda *a: jnp.sum(fn(*a).astype(f32))
+
+    def causal(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    ln = [jax.ShapeDtypeStruct(s, f32) for s in ((64, 128), (128,), (128,))]
+    return {
+        "flash": str(jax.make_jaxpr(jax.grad(total(causal), (0, 1, 2)))(
+            q, q, q)),
+        "decode": str(jax.make_jaxpr(flash_decode)(
+            jax.ShapeDtypeStruct((2, 2, 1, 64), f32),
+            jax.ShapeDtypeStruct((2, 2, 128, 64), f32),
+            jax.ShapeDtypeStruct((2, 2, 128, 64), f32),
+            jax.ShapeDtypeStruct((2,), jnp.int32))),
+        "layer_norm": str(jax.make_jaxpr(jax.grad(
+            total(layer_norm_pallas), (0, 1, 2)))(*ln)),
+        "topk": str(jax.make_jaxpr(
+            lambda g: dgc_topk_mask_pallas(g, 0.99))(
+            jax.ShapeDtypeStruct((64, 128), f32))),
+    }
+
+
+@pytest.fixture(scope="module")
+def kernel_jaxprs():
+    return _kernel_jaxprs()
+
+
+@pytest.mark.parametrize("where,kernel", [
+    ("flash", "flash_fwd"), ("flash", "flash_dq"), ("flash", "flash_dkv"),
+    ("decode", "flash_decode"), ("layer_norm", "layer_norm_fwd"),
+    ("layer_norm", "layer_norm_bwd"), ("topk", "topk_threshold")])
+def test_every_pallas_call_has_its_name(kernel_jaxprs, where, kernel):
+    assert f"name={kernel}\n" in kernel_jaxprs[where] \
+        or f"name={kernel} " in kernel_jaxprs[where]
+
+
+# ---------------------------------------------------------------------
+# the tool that lays the spans against the device's idle gaps
+# ---------------------------------------------------------------------
+
+def test_idle_split_attributes_gaps_to_phases():
+    tool = _load(os.path.join(ROOT, "tools", "engine_idle_split.py"),
+                 "engine_idle_split")
+    # device busy 10-40 and 60-90 of a window 0-100 (ns): idle 0-10,
+    # 40-60, 90-100
+    ops = [(10, 40), (60, 90)]
+    spans = [("engine.step", 35, 95), ("engine.decode_wait", 35, 45),
+             ("engine.emit", 45, 55), ("engine.decode_host", 57, 62),
+             ("engine.decode_wait", 62, 95)]
+    table, idle_s, outside_s = tool.split(spans, ops, (0, 100))
+    rows = {n: (c, round(h * 1e9), round(i * 1e9)) for n, c, h, i in table}
+    assert round(idle_s * 1e9) == 40
+    assert rows["engine.emit"] == (1, 10, 10)
+    assert rows["engine.decode_wait"] == (2, 43, 10)
+    assert rows["engine.decode_host"] == (1, 5, 3)
+    assert rows["engine.step outside its phases"] == (1, 2, 2)
+    assert round(outside_s * 1e9) == 15   # 0-10 and 95-100

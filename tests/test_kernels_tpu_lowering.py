@@ -6,6 +6,7 @@ A kernel that can be selected on the chip and cannot compile there must
 fail here first."""
 
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +60,17 @@ def _cases():
 
 CASES = _cases()
 IDS = [c[0] for c in CASES]
+# the names the compiled program's Mosaic calls carry, which is what a
+# device trace lists them under
+KERNELS = {"flash_fwd": ["flash_fwd"],
+           "flash_fwd_bwd": ["flash_fwd", "flash_dq", "flash_dkv"],
+           "flash_with_lse_fwd_bwd": ["flash_fwd", "flash_dq", "flash_dkv"],
+           "flash_decode": ["flash_decode"],
+           "flash_decode_f32_d128": ["flash_decode"],
+           "layer_norm_fwd": ["layer_norm_fwd"],
+           # the gradient of a sum needs no forward output
+           "layer_norm_fwd_bwd": ["layer_norm_bwd"],
+           "topk_threshold": ["topk_threshold"]}
 
 
 @pytest.fixture
@@ -95,7 +107,11 @@ def test_kernel_compiles_for_v5e(compiled_kernels, v5e, name, fn, args):
     avals = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in args]
     compiled = jax.jit(fn).trace(*avals).lower(
         lowering_platforms=("tpu",)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    calls = re.findall(r"%([\w-]+?)(?:\.\d+)? = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    assert sorted(set(calls)) == sorted(KERNELS[name])
 
 
 def _attn_grads(q, k, v):

@@ -1,0 +1,219 @@
+"""Lay the decode engine's own spans against the device's idle gaps.
+
+Runs one traced run of a serving cell of BENCHMARK.json in this process
+(`benchmarks.run`, unchanged), reads the run's xplane file before the
+benchmark removes it, and prints, for each `paddle_tpu:engine.*` phase:
+how often it ran, the host time it took, and how much of the device's
+idle time (the gaps between the device's ops inside the benchmark's
+window) lay under it.  Both are events of one trace, so they share a
+clock.  What the benchmark folds into the one label `engine_step`
+(`breakdown.idle_gaps`) is split by phase here.
+
+Also printed: the spread of `start_ns - pc_ns` over the spans (how well
+`profiler.trace_clock_offset_ns` ties `perf_counter_ns` to the trace's
+clock), and `engine_host_ms_per_step.serve` x decode steps over the
+seconds of `engine_step` in the same run's `idle_gaps`.
+
+    python tools/engine_idle_split.py [--workload W] [--seed N]
+        [--seconds S] [--rehearse] [--out FILE.json]
+
+Needs the chip, as the benchmark does; `--rehearse` runs the cell's tiny
+sizes on the CPU, where the trace has no device plane and only the
+spans' own table is printed.
+"""
+
+import argparse
+import bisect
+import contextlib
+import io
+import json
+import os
+import statistics
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmarks import run as bench_run  # noqa: E402
+from benchmarks import trace_reduce  # noqa: E402
+
+STEP = "engine.step"
+
+
+def read_trace(path):
+    """(program spans [(name, start, end)] without their prefix, clock
+    offsets `start_ns - pc_ns`, device op intervals of the first chip,
+    the benchmark's window or None, the runs of each program on that
+    chip by name), all on the trace's clock in ns."""
+    from jax.profiler import ProfileData
+
+    from paddle_tpu import profiler
+
+    spans, offsets, ops, window, programs = [], [], [], None, {}
+    chips = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == trace_reduce.HOST_PLANE:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name == trace_reduce.WINDOW_SPAN:
+                        window = (e.start_ns, e.start_ns + e.duration_ns)
+                    elif e.name.startswith(profiler.TRACE_PREFIX):
+                        spans.append((e.name[len(profiler.TRACE_PREFIX):],
+                                      e.start_ns,
+                                      e.start_ns + e.duration_ns))
+                        offsets.append(
+                            profiler.trace_clock_offset_ns([e]))
+        elif trace_reduce.DEVICE_PLANE.match(plane.name):
+            chips.append(plane)
+    if chips:
+        first = min(chips, key=lambda p: int(
+            trace_reduce.DEVICE_PLANE.match(p.name).group(1)))
+        for line in first.lines:
+            if line.name == trace_reduce.OPS_LINE:
+                ops = [(e.start_ns, e.start_ns + e.duration_ns)
+                       for e in line.events]
+            elif line.name == trace_reduce.MODULES_LINE:
+                for e in line.events:
+                    name = trace_reduce.program_name(e.name)
+                    programs[name] = programs.get(name, 0) + 1
+    return (spans, [o for o in offsets if o is not None], ops, window,
+            programs)
+
+
+def idle_under(span, gaps, starts):
+    """Length of `gaps` (sorted, disjoint; `starts` their starts) that
+    lies inside `span`."""
+    s, e = span
+    i = max(bisect.bisect_right(starts, s) - 1, 0)
+    total = 0.0
+    while i < len(gaps) and gaps[i][0] < e:
+        total += max(0.0, min(e, gaps[i][1]) - max(s, gaps[i][0]))
+        i += 1
+    return total
+
+
+def split(spans, ops, window):
+    """Rows [phase, count, host seconds, idle seconds under it], the
+    device's idle seconds in the window, and the part of them under no
+    `engine.step` at all."""
+    lo, hi = window
+    clipped = [(max(s, lo), min(e, hi)) for s, e in ops
+               if min(e, hi) > max(s, lo)]
+    _, gaps = trace_reduce.busy_union(clipped)
+    if clipped:
+        gaps = [(lo, min(s for s, _ in clipped))] + gaps + \
+               [(max(e for _, e in clipped), hi)]
+    else:
+        gaps = [(lo, hi)]
+    gaps = [g for g in gaps if g[1] > g[0]]
+    idle = sum(e - s for s, e in gaps)
+    starts = [g[0] for g in gaps]
+
+    # a phase of an iteration begun before the session has no step span
+    # around it: its share goes to "outside any engine.step"
+    whole = [(s, e) for name, s, e in spans if name == STEP]
+    rows = {}
+    for name, s, e in spans:
+        if name != STEP and not any(lo <= s and e <= hi for lo, hi in whole):
+            continue
+        row = rows.setdefault(name, [0, 0.0, 0.0])
+        row[0] += 1
+        row[1] += e - s
+        row[2] += idle_under((s, e), gaps, starts)
+    steps = rows.pop(STEP, [0, 0.0, 0.0])
+    phases = sorted(rows.items(), key=lambda kv: -kv[1][2])
+    # an iteration's time outside its phases: loop control, the breaker
+    glue = [STEP + " outside its phases", steps[0],
+            steps[1] - sum(r[1] for _, r in phases),
+            steps[2] - sum(r[2] for _, r in phases)]
+    table = [[n, c, h / 1e9, i / 1e9] for n, (c, h, i) in phases]
+    table.append([glue[0], glue[1], glue[2] / 1e9, glue[3] / 1e9])
+    return table, idle / 1e9, (idle - steps[2]) / 1e9
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", default="gpt2-medium.serve-closed-c64")
+    ap.add_argument("--seed", type=int, default=2700000001)
+    ap.add_argument("--seconds", type=float, default=50.0)
+    ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--out", default=None, help="also write the numbers "
+                    "to this JSON file")
+    args = ap.parse_args(argv)
+
+    captured = {}
+    load = trace_reduce.load_xplane
+
+    def load_and_keep(path, *a, **kw):
+        captured["trace"] = read_trace(path)
+        return load(path, *a, **kw)
+
+    trace_reduce.load_xplane = load_and_keep
+    cmd = ["--workload", args.workload, "--seed", str(args.seed),
+           "--seconds", str(args.seconds), "--trace", "1"]
+    if args.rehearse:
+        cmd.append("--rehearse")
+    out = io.StringIO()
+    try:
+        with contextlib.redirect_stdout(out):
+            code = bench_run.main(cmd)
+    finally:
+        trace_reduce.load_xplane = load
+    if code or "trace" not in captured:
+        print(out.getvalue(), file=sys.stderr)
+        return code or 1
+    line = json.loads(out.getvalue().strip().splitlines()[-1])
+    spans, offsets, ops, window, programs = captured["trace"]
+    engine = [s for s in spans if s[0].startswith("engine.")]
+
+    report = {"workload": args.workload, "seed": args.seed,
+              "device": line["device"], "metrics": line["metrics"],
+              "breakdown": line.get("breakdown", {}),
+              "programs": programs, "spans_in_trace": len(spans)}
+    if programs:
+        print("programs run (XLA Modules): " + ", ".join(
+            f"{n} x{c}" for n, c in sorted(programs.items())))
+    if offsets:
+        report["clock_offset_ns"] = {
+            "spans": len(offsets), "min": min(offsets),
+            "median": statistics.median(offsets),
+            "max": max(offsets), "spread_ns": max(offsets) - min(offsets)}
+        print(f"clock: start_ns - pc_ns over {len(offsets)} spans spreads "
+              f"by {(max(offsets) - min(offsets)) / 1e3:.1f} us")
+    if ops and window:
+        table, idle_s, outside_s = split(engine, ops, window)
+        report.update(idle_s=idle_s, idle_outside_engine_step_s=outside_s,
+                      window_s=(window[1] - window[0]) / 1e9, phases=table)
+        print(f"window {report['window_s']:.3f} s, device idle "
+              f"{idle_s:.4f} s, of it outside any engine.step "
+              f"{outside_s:.4f} s")
+        print(f"{'phase':<34}{'count':>7}{'host s':>10}{'idle s':>10}")
+        for name, count, host_s, under_s in table:
+            print(f"{name:<34}{count:>7}{host_s:>10.4f}{under_s:>10.4f}")
+        host = line["metrics"].get("engine_host_ms_per_step.serve")
+        waits = sum(c for n, c, _, _ in table if n == "engine.decode_wait")
+        labelled = dict(map(tuple, report["breakdown"].get(
+            "idle_gaps", []))).get("engine_step")
+        if host and labelled:
+            report["host_over_engine_step_gap"] = \
+                host["value"] / 1e3 * waits / labelled
+            print(f"engine_host_ms_per_step.serve {host['value']:.3f} ms x "
+                  f"{waits} decode steps / idle_gaps engine_step "
+                  f"{labelled:.4f} s = "
+                  f"{report['host_over_engine_step_gap']:.2f}")
+    else:
+        print("no device plane or window in the trace: spans only")
+        for name in sorted({s[0] for s in spans}):
+            durs = [e - s for n, s, e in spans if n == name]
+            print(f"{name:<34}{len(durs):>7}{sum(durs) / 1e9:>10.4f}")
+    print(json.dumps(line["metrics"]))
+    if args.out:
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)),
+                    exist_ok=True)
+        with open(args.out, "w") as f:
+            json.dump(report, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
