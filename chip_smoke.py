@@ -329,7 +329,9 @@ def phase_kernels(attn, decode, decode_lengths, ln, topk_n, dtype, tol):
     the top-k histogram must be exact."""
     from paddle_tpu.kernels import attention as A
     from paddle_tpu.kernels.flash_attention import (flash_attention,
-                                                    flash_decode)
+                                                    flash_decode,
+                                                    flash_decode_resident,
+                                                    kv_append)
     from paddle_tpu.kernels.layer_norm import layer_norm_pallas
     from paddle_tpu.kernels.topk_threshold import (NUM_EDGES,
                                                    count_ge_histogram,
@@ -383,6 +385,32 @@ def phase_kernels(attn, decode, decode_lengths, ln, topk_n, dtype, tol):
         q.astype(f32), k.astype(f32), v.astype(f32), pos=n - 1,
         use_flash=False))(q1, kc, vc, lengths)
     record("flash_decode", got, want)
+
+    # the decode engine's pair on its resident layout [L, S, H, D, T]:
+    # the new column of layer 1 appended in place (exact, and nothing
+    # else touched), then that layer attended where it lies
+    kT, vT = (jnp.stack([rand((b, h, d, t)), jnp.swapaxes(c, -1, -2)])
+              for c in (kc, vc))
+    k_new, v_new = rand((b, h, d)), rand((b, h, d))
+    cols = lengths - 1
+    got_k, got_v = jax.jit(kv_append, donate_argnums=(0, 1))(
+        kT, vT, k_new, v_new, jnp.int32(1), cols)
+    rows = np.arange(b)
+    for name, got, c, new in (("k", got_k, kc, k_new),
+                              ("v", got_v, vc, v_new)):
+        want = np.asarray(jnp.swapaxes(c, -1, -2)).copy()
+        want[rows, :, :, np.asarray(cols)] = np.asarray(new)
+        _check(np.array_equal(np.asarray(got[1]), want),
+               f"kv_append: {name} of layer 1 is not its input with the "
+               f"new columns")
+    out["kv_append"] = {"exact": True}
+    got = jax.jit(flash_decode_resident)(q1, got_k, got_v, jnp.int32(1),
+                                         lengths)
+    want = _highest(lambda q, k, v, n: A.decode_attention(
+        q.astype(f32), jnp.swapaxes(k[1], -1, -2).astype(f32),
+        jnp.swapaxes(v[1], -1, -2).astype(f32), pos=n - 1,
+        use_flash=False))(q1, got_k, got_v, lengths)
+    record("flash_decode_resident", got, want)
 
     # layer norm fwd + bwd
     x, dy = rand(ln), rand(ln)

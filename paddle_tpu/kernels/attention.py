@@ -8,6 +8,7 @@ sequence lengths, and an XLA-fused softmax(QK^T)V composition otherwise.
 """
 
 import math
+import os
 import warnings
 
 import jax
@@ -82,6 +83,25 @@ def _flash_per_shard(q, k, v, causal, scale):
         axis_names=frozenset(auto), check_vma=False)(q, k, v)
 
 
+def _decode_takes_kernel(t, head_dim, use_flash=None):
+    """The one predicate that picks the Pallas decode kernels over the
+    XLA mathematics, for `decode_attention` and for the engine's
+    `resident_decode_attention` alike: shapes the kernel tiles (cache
+    depth a multiple of 128, head_dim 64/128/256) and, unless
+    `use_flash` or PADDLE_TPU_FORCE_FLASH_DECODE says otherwise, a TPU
+    and a cache at least 1024 deep."""
+    if t % 128 or head_dim not in (64, 128, 256):
+        return False
+    if use_flash is not None:
+        return bool(use_flash)
+    env = os.environ.get("PADDLE_TPU_FORCE_FLASH_DECODE", "")
+    if env:
+        return env.lower() in ("1", "true", "yes")
+    from .backend import is_tpu_backend
+
+    return is_tpu_backend() and t >= 1024
+
+
 def decode_attention(q, k, v, pos=None, mask=None, scale=None,
                      use_flash=None):
     """Single-query decode attention: q [B, H, 1, D] against a KV-cache
@@ -102,8 +122,6 @@ def decode_attention(q, k, v, pos=None, mask=None, scale=None,
     flash path (TPU, deep caches) is the online-softmax Pallas kernel in
     flash_attention.py: same math re-associated, allclose not bitwise,
     so the serving engine pins one path per process."""
-    import os
-
     head_dim = q.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(head_dim)
     t = k.shape[-2]
@@ -112,17 +130,8 @@ def decode_attention(q, k, v, pos=None, mask=None, scale=None,
     if pos is None and mask is None:
         pos = t - 1
 
-    can_flash = (mask is None and q.shape[-2] == 1 and t % 128 == 0
-                 and head_dim in (64, 128, 256))
-    if use_flash is None:
-        from .backend import is_tpu_backend
-
-        env = os.environ.get("PADDLE_TPU_FORCE_FLASH_DECODE", "")
-        if env:
-            use_flash = env.lower() in ("1", "true", "yes")
-        else:
-            use_flash = is_tpu_backend() and t >= 1024
-    if use_flash and can_flash:
+    if mask is None and q.shape[-2] == 1 \
+            and _decode_takes_kernel(t, head_dim, use_flash):
         from .flash_attention import flash_decode
 
         return flash_decode(q, k, v, jnp.asarray(pos, jnp.int32) + 1,
@@ -144,6 +153,48 @@ def decode_attention(q, k, v, pos=None, mask=None, scale=None,
         s = s.astype(jnp.float32) + mask
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(q.dtype))
+
+
+def resident_decode_attention(q, k_new, v_new, k_cache, v_cache, layer,
+                              pos, scale=None):
+    """One layer of the decode engine's step on the cache it owns
+    (serving/decode.py): write each slot's new column, then attend.
+
+    q, k_new, v_new: [S, H, 1, D], this step's projections; k_cache,
+    v_cache: the engine's stacked caches in their resident layout
+    [L, S, H, D, T] (K and V transposed, T minor); layer: int32 scalar,
+    traced (the loop's counter); pos: int32 [S], each slot's current
+    position.  A stale pos >= T (an inactive slot) is clamped for the
+    write into the slot's own last column.  Returns (o [S, H, 1, D],
+    k_cache, v_cache).
+
+    Reader and writer both take the stacked cache as it lies, so the
+    caller can carry it through its layer loop and the compiled step
+    holds one copy of it.  Where `_decode_takes_kernel` holds these are
+    the Pallas calls `kv_append` and `flash_decode`; elsewhere the same
+    mathematics in XLA, through `decode_attention`'s own code (which is
+    what keeps the engine token-exact against generate())."""
+    t = k_cache.shape[-1]
+    pos = jnp.asarray(pos, jnp.int32)
+    posw = jnp.minimum(pos, t - 1)
+    k_col, v_col = k_new[:, :, 0, :], v_new[:, :, 0, :]       # [S, H, D]
+    if _decode_takes_kernel(t, q.shape[-1]):
+        from .flash_attention import flash_decode_resident, kv_append
+
+        k_cache, v_cache = kv_append(k_cache, v_cache, k_col, v_col,
+                                     layer, posw)
+        o = flash_decode_resident(q, k_cache, v_cache, layer, pos + 1,
+                                  sm_scale=scale)
+        return o, k_cache, v_cache
+    slots = jnp.arange(q.shape[0])
+    k_cache = k_cache.at[layer, slots, :, :, posw].set(
+        k_col.astype(k_cache.dtype))
+    v_cache = v_cache.at[layer, slots, :, :, posw].set(
+        v_col.astype(v_cache.dtype))
+    o = decode_attention(q, jnp.swapaxes(k_cache[layer], -1, -2),
+                         jnp.swapaxes(v_cache[layer], -1, -2), pos=pos,
+                         scale=scale, use_flash=False)
+    return o, k_cache, v_cache
 
 
 def dot_product_attention(q, k, v, mask=None, dropout_p=0.0, is_causal=False,
@@ -173,8 +224,6 @@ def dot_product_attention(q, k, v, mask=None, dropout_p=0.0, is_causal=False,
         # crossover on v5e: 512 -> XLA, 2048 -> flash by ~20%).
         # PADDLE_TPU_FORCE_FLASH=0/1 overrides the heuristic for
         # on-chip A/B runs (same role as PADDLE_TPU_FLASH_BLOCK).
-        import os
-
         from .backend import is_tpu_backend
 
         env = os.environ.get("PADDLE_TPU_FORCE_FLASH", "")

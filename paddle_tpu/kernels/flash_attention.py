@@ -352,11 +352,34 @@ def _bwd_core(sm_scale, causal, block_q, block_k, q, k, v, do, lse,
 
 
 # --------------------------------------------------------------------------
-# single-query decode forward (ISSUE 17)
+# single-query decode forward
 # --------------------------------------------------------------------------
+#
+# Two callers, one online-softmax body (`_decode_kernel`):
+#
+# - `flash_decode` takes K and V as arrays `[B, H, T, D]` (the cohort
+#   decoder of models/generate.py, ops/fused_ops.py): a tile is
+#   `[block_k, D]`, scores contract D of q with D of the tile.
+# - `flash_decode_resident` reads the decode engine's stacked cache
+#   `[L, S, H, D, T]` (serving/decode.py) where it lies: the layer index
+#   rides scalar prefetch beside the lengths and picks the layer in the
+#   BlockSpec, so no layer is sliced out; a tile is `[D, block_k]`, with
+#   T on the lanes, and the output contracts p with the V tile over
+#   block_k (the q @ k^T form of the forward kernel above).  T minor is
+#   the order the device holds such an array in anyway (a minor
+#   dimension of 64 would be padded to 128 lanes), so neither XLA nor the
+#   Mosaic call has a reason to re-lay the cache.
+#
+# `kv_append` is the engine's only writer inside the decode step: per
+# slot, the 128-lane tile that holds column `pos[s]` comes in, the new
+# column is merged under an iota mask, the same tile goes out, on the
+# cache aliased to the call's output.  (An XLA scatter or
+# dynamic_update_slice of one column re-lays the whole stacked cache
+# around the write: the update's minor dimension of 1 pulls the operand's
+# layout with it.)
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                   l_ref, *, sm_scale, block_k, heads):
+                   l_ref, *, sm_scale, block_k, heads, t_minor):
     ki = pl.program_id(1)
     num_k = pl.num_programs(1)
 
@@ -369,17 +392,20 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
     # per-row lengths ride scalar prefetch (SMEM holds the whole [B]
     # vector; a (1, 1) SMEM block per grid step does not lower)
     length = len_ref[pl.program_id(0) // heads]
+    # which axis of a K tile holds head_dim, and of a V tile the columns:
+    # tiles are [block_k, D], or [D, block_k] from a cache kept T minor
+    k_d, v_t = (0, 1) if t_minor else (1, 0)
 
     # k blocks entirely past the live prefix contribute nothing; skip
     # their DMA'd compute outright (the ragged-length win: a slot at
     # pos 40 in a 2048-deep cache touches 1 block, not 16)
     @pl.when(ki * block_k < length)
     def _tile():
-        q = q_ref[0]                                      # [1, d]
-        k_blk = k_ref[0]                                  # [bk, d]
-        v_blk = v_ref[0]
+        q = q_ref[...]                                    # [1, d]
+        k_blk = k_ref[...]
+        v_blk = v_ref[...]
         s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
+            q, k_blk, (((1,), (k_d,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale  # [1, bk] f32
         k_pos = ki * block_k + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
@@ -391,7 +417,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
         p = jnp.exp(s - m_cur)
         l_ref[:] = jnp.broadcast_to(l_prev * alpha + p.sum(), l_ref.shape)
         acc_ref[0:1] = acc_ref[0:1] * alpha + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+            p.astype(v_blk.dtype), v_blk, (((1,), (v_t,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[:] = jnp.broadcast_to(m_cur, m_ref.shape)
 
@@ -399,7 +425,47 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
     def _finalize():
         l = l_ref[0, 0]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[0:1] / l_safe).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[0:1] / l_safe).astype(o_ref.dtype)
+
+
+def _decode_block_k(t, block_k):
+    if block_k is None:
+        cand = 512
+        while cand > 64 and (cand > t or t % cand):
+            cand //= 2
+        block_k = cand if (cand <= t and t % cand == 0) else t
+    if t % block_k:
+        raise ValueError(
+            f"cache depth {t} must be divisible by block_k {block_k}")
+    return block_k
+
+
+def _decode_call(kernel, rows, heads, t, d, block_k, dtype, n_prefetch,
+                 kv_spec):
+    """The pallas_call both decode entry points share: grid
+    (rows * heads, T // block_k), one [1, D] query and output a row,
+    `kv_spec` the BlockSpec of a K (and V) tile."""
+    q_spec = _vmem_spec((None, 1, d), lambda bh, ki, *_: (bh, 0, 0))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n_prefetch,
+            grid=(rows * heads, t // block_k),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=[
+                # 8-row scratch (f32 sublane tile) though only row 0 is
+                # used: sub-tile scratch shapes are not portable on TPU
+                _scratch((8, d)),
+                _scratch((8, _LANES)),
+                _scratch((8, _LANES)),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((rows * heads, 1, d), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret(),
+        name="flash_decode",
+    )
 
 
 def flash_decode(q, k, v, lengths, sm_scale=None, block_k=None):
@@ -419,50 +485,107 @@ def flash_decode(q, k, v, lengths, sm_scale=None, block_k=None):
     t = k.shape[-2]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    if block_k is None:
-        cand = 512
-        while cand > 64 and (cand > t or t % cand):
-            cand //= 2
-        block_k = cand if (cand <= t and t % cand == 0) else t
-    if t % block_k:
-        raise ValueError(
-            f"cache depth {t} must be divisible by block_k {block_k}")
+    block_k = _decode_block_k(t, block_k)
     lengths = jnp.broadcast_to(
         jnp.asarray(lengths, jnp.int32).reshape(-1), (b,))
-    q3 = q.reshape(b * h, 1, d)
-    k3 = k.reshape(b * h, t, d)
-    v3 = v.reshape(b * h, t, d)
-    call = pl.pallas_call(
+    call = _decode_call(
         functools.partial(_decode_kernel, sm_scale=float(sm_scale),
-                          block_k=block_k, heads=h),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b * h, t // block_k),
-            in_specs=[
-                _vmem_spec((1, 1, d), lambda bh, ki, lens: (bh, 0, 0)),
-                _vmem_spec((1, block_k, d),
-                           lambda bh, ki, lens: (bh, ki, 0)),
-                _vmem_spec((1, block_k, d),
-                           lambda bh, ki, lens: (bh, ki, 0)),
-            ],
-            out_specs=_vmem_spec((1, 1, d),
-                                 lambda bh, ki, lens: (bh, 0, 0)),
-            scratch_shapes=[
-                # 8-row scratch (f32 sublane tile) though only row 0 is
-                # used: sub-tile scratch shapes are not portable on TPU
-                _scratch((8, d)),
-                _scratch((8, _LANES)),
-                _scratch((8, _LANES)),
-            ]),
-        out_shape=jax.ShapeDtypeStruct((b * h, 1, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret(),
-        name="flash_decode",
-    )
+                          block_k=block_k, heads=h, t_minor=False),
+        b, h, t, d, block_k, q.dtype, 1,
+        _vmem_spec((None, block_k, d), lambda bh, ki, lens: (bh, ki, 0)))
     with jax.named_scope("flash_decode"):
-        out = call(lengths, q3, k3, v3)
+        out = call(lengths, q.reshape(b * h, 1, d),
+                   k.reshape(b * h, t, d), v.reshape(b * h, t, d))
     return out.reshape(b, h, 1, d)
+
+
+def flash_decode_resident(q, k_cache, v_cache, layer, lengths,
+                          sm_scale=None):
+    """`flash_decode` over one layer of the decode engine's resident
+    cache, read where it lies.
+
+    q: [S, H, 1, D]; k_cache/v_cache: the stacked caches [L, S, H, D, T]
+    (K and V stored transposed, T minor); layer: int32 scalar, traced;
+    lengths: int32 [S].  Same grid, same online softmax and the same
+    pruning as `flash_decode`; a tile is [D, block_k]."""
+    s, h, q_len, d = q.shape
+    if q_len != 1:
+        raise ValueError(f"flash_decode needs q_len == 1, got {q_len}")
+    t = k_cache.shape[-1]
+    if k_cache.shape[1:] != (s, h, d, t) or v_cache.shape != k_cache.shape:
+        raise ValueError(
+            f"resident caches must be [L, {s}, {h}, {d}, T], got "
+            f"{k_cache.shape} and {v_cache.shape}")
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d)
+    block_k = _decode_block_k(t, None)
+    lengths = jnp.asarray(lengths, jnp.int32).reshape(s)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    kernel = functools.partial(_decode_kernel, sm_scale=float(sm_scale),
+                               block_k=block_k, heads=h, t_minor=True)
+    call = _decode_call(
+        lambda len_ref, layer_ref, *refs: kernel(len_ref, *refs),
+        s, h, t, d, block_k, q.dtype, 2,
+        _vmem_spec((None, None, None, d, block_k),
+                   lambda bh, ki, lens, layer: (layer[0], bh // h, bh % h,
+                                                0, ki)))
+    with jax.named_scope("flash_decode"):
+        out = call(lengths, layer, q.reshape(s * h, 1, d), k_cache, v_cache)
+    return out.reshape(s, h, 1, d)
+
+
+def _append_kernel(pos_ref, layer_ref, kc_ref, vc_ref, kn_ref, vn_ref,
+                   ko_ref, vo_ref):
+    # one slot a grid step: tiles [H, D, 128], new columns [D, H]
+    col = pos_ref[pl.program_id(0)] % _LANES
+    hit = jax.lax.broadcasted_iota(jnp.int32, kc_ref.shape[1:], 1) == col
+    for new_ref, in_ref, out_ref in ((kn_ref, kc_ref, ko_ref),
+                                     (vn_ref, vc_ref, vo_ref)):
+        new = new_ref[...]
+        for h in range(in_ref.shape[0]):
+            out_ref[h] = jnp.where(hit, new[:, h:h + 1], in_ref[h])
+
+
+def kv_append(k_cache, v_cache, k_new, v_new, layer, pos):
+    """Write one new column per slot into one layer of the resident
+    caches, in place.
+
+    k_cache/v_cache: [L, S, H, D, T] with T a multiple of 128;
+    k_new/v_new: [S, H, D]; layer: int32 scalar, traced; pos: int32 [S],
+    each inside [0, T).  Slot s gets column pos[s] of `layer`; nothing
+    else is touched: the caches are aliased to the outputs and only the
+    128-lane tile around each column passes through VMEM."""
+    n_layers, s, h, d, t = k_cache.shape
+    if t % _LANES:
+        raise ValueError(f"cache depth {t} must be a multiple of {_LANES}")
+    pos = jnp.asarray(pos, jnp.int32).reshape(s)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    tile = _vmem_spec(
+        (None, None, h, d, _LANES),
+        lambda i, pos, layer: (layer[0], i, 0, 0, pos[i] // _LANES))
+    # the new columns arrive [S, D, H]: head_dim on the sublanes, as in
+    # the tile, so a head's column is one lane of the block, broadcast
+    column = _vmem_spec((None, d, h), lambda i, pos, layer: (i, 0, 0))
+    call = pl.pallas_call(
+        _append_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(s,),
+            in_specs=[tile, tile, column, column],
+            out_specs=[tile, tile]),
+        out_shape=[jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
+                   jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)],
+        # operand numbers count the two scalar-prefetch arguments
+        input_output_aliases={2: 0, 3: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret(),
+        name="kv_append",
+    )
+    with jax.named_scope("kv_append"):
+        return call(pos, layer, k_cache, v_cache,
+                    jnp.swapaxes(k_new, 1, 2).astype(k_cache.dtype),
+                    jnp.swapaxes(v_new, 1, 2).astype(v_cache.dtype))
 
 
 # --------------------------------------------------------------------------

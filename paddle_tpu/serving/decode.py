@@ -7,10 +7,26 @@ iteration-level scheduling of Orca (OSDI '22) and the slot-resident
 KV cache of vLLM (SOSP '23):
 
 - ONE compiled decode step owns the whole serving state: a fixed
-  `[layers, slots, heads, max_len, head_dim]` ring-buffer KV cache plus
-  per-slot `pos/active/token/stop/eos/temp/key` vectors, passed as
-  **donated** executor state (the PR-16 donation idiom — the cache
-  never copies, the step updates it in place on device).
+  ring-buffer KV cache plus per-slot `pos/active/token/stop/eos/temp/
+  key` vectors, passed as **donated** executor state (the PR-16
+  donation idiom).
+- The cache has ONE resident layout, `[layers, slots, heads, head_dim,
+  max_len]`: K and V are stored transposed, cache depth minor.  That is
+  the order the TPU keeps such an array in whatever its logical shape (a
+  minor dimension of 64 would be padded to 128 lanes), and every reader
+  and writer takes it as it lies: the decode step carries both stacked
+  caches through its layer loop (never as a scan's `xs`/`ys`, which
+  slices a layer out and stacks it back), `kv_append` writes the new
+  column into the donated buffer through a Pallas call aliased to its
+  input, `flash_decode` reads layer `l` of the stack through its
+  BlockSpec, and a prefill drops its transposed K/V into the slot's
+  region with one `dynamic_update_slice`.  The compiled step holds one
+  copy of the cache and no copy, slice or re-layout of a layer of it
+  (asserted on the compiled program by
+  tests/test_kernels_tpu_lowering.py).  An XLA write of one column
+  into this layout is not an option: a scatter or a per-slot
+  `dynamic_update_slice` re-lays the whole stacked cache around the
+  write.
 - Requests **join and leave mid-decode**: a finished slot is released
   and refilled by the next queued request's prefill WITHOUT retracing —
   prefill runs at the PR-8 bucket shapes (prompt padded to a
@@ -24,8 +40,9 @@ KV cache of vLLM (SOSP '23):
   slot's region and are overwritten by the next tenant's prefill or by
   the step that first attends the position — see _decode_step_impl).
 
-Token-exactness: decode attention is the SAME code generate() uses
-(kernels/attention.py decode_attention), prefill is the same layer math
+Token-exactness: off the kernel path decode attention is the SAME code
+generate() uses (kernels/attention.py decode_attention, reached through
+resident_decode_attention), prefill is the same layer math
 at bucket shape with MoE routed drop-free (cap = cohort size), and
 padded/causally-dead columns underflow to exact f32 zeros — so a
 request decoded through slots, including one that joins mid-stream
@@ -211,39 +228,37 @@ def _decode_step_impl(state, trees, kill, cfg):
     import jax
     import jax.numpy as jnp
 
-    from ..kernels.attention import decode_attention
+    from ..kernels.attention import resident_decode_attention
     from ..models import generate as G
     from ..nn import functional as F
 
     params = G.DecodeParams(*trees, cfg)
-    n_slots = state["pos"].shape[0]
-    max_len = state["k"].shape[3]
     scale = 1.0 / (cfg.hidden_size // cfg.num_heads) ** 0.5
     active = jnp.logical_and(state["active"], jnp.logical_not(kill))
     pos = state["pos"]
     tok = state["token"]
     x = jnp.take(params.emb["wte.weight"], tok[:, None], axis=0) \
         + jnp.take(params.emb["wpe.weight"], pos, axis=0)[:, None, :]
-    posw = jnp.minimum(pos, max_len - 1)
-    sl = jnp.arange(n_slots)
 
-    def layer(x, xs):
-        bp, k_cache, v_cache = xs          # caches [S, H, T, D]
+    def layer(carry, xs):
+        # the stacked caches [L, S, H, D, T] ride the carry whole: the
+        # layer's reader and writer address layer `l` inside them
+        x, k_cache, v_cache = carry
+        bp, l = xs
         hn = F.layer_norm(x, [cfg.hidden_size], bp["norm1.weight"],
                           bp["norm1.bias"])
         q, k, v = G._qkv(hn, bp, cfg.num_heads)      # [S, H, 1, D]
-        k_cache = k_cache.at[sl, :, posw, :].set(
-            k[:, :, 0, :].astype(k_cache.dtype))
-        v_cache = v_cache.at[sl, :, posw, :].set(
-            v[:, :, 0, :].astype(v_cache.dtype))
-        # per-slot ragged positions through the SAME single-query
-        # kernel generate() decodes with — the token-exactness hinge
-        o = decode_attention(q, k_cache, v_cache, pos=pos, scale=scale)
-        return G._block_tail(x, G._merge_heads(o), bp, cfg,
-                             decode=True), (k_cache, v_cache)
+        # per-slot ragged positions; off the kernel path the SAME
+        # single-query math generate() decodes with — the
+        # token-exactness hinge
+        o, k_cache, v_cache = resident_decode_attention(
+            q, k, v, k_cache, v_cache, l, pos, scale=scale)
+        x = G._block_tail(x, G._merge_heads(o), bp, cfg, decode=True)
+        return (x, k_cache, v_cache), None
 
-    x, (ks, vs) = jax.lax.scan(
-        layer, x, (params.blocks, state["k"], state["v"]))
+    (x, ks, vs), _ = jax.lax.scan(
+        layer, (x, state["k"], state["v"]),
+        (params.blocks, jnp.arange(state["k"].shape[0], dtype=jnp.int32)))
     x = F.layer_norm(x, [cfg.hidden_size], params.head["norm_f.weight"],
                      params.head["norm_f.bias"])
     logits = jnp.einsum("bh,vh->bv", x[:, -1],
@@ -304,11 +319,20 @@ def _prefill_impl(state, trees, prompt, true_len, slot, stop, eos,
                              decode=True), (k, v)
 
     x, (ks, vs) = jax.lax.scan(layer, x, params.blocks)
-    # ks: [L, 1, H, bucket, D] -> this slot's cache region [:, slot]
-    k_cache = jax.lax.dynamic_update_slice(
-        state["k"], ks.astype(state["k"].dtype), (0, slot, 0, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(
-        state["v"], vs.astype(state["v"].dtype), (0, slot, 0, 0, 0))
+    # ks: [L, 1, H, bucket, D], transposed into the resident layout
+    # [L, 1, H, D, bucket] (on the device the scan's output is held
+    # bucket minor already, so the transpose moves nothing) and dropped
+    # into columns [0, bucket) of this slot's region [:, slot] of the
+    # donated cache.  Columns from `bucket` on keep the last tenant's
+    # values: none is attended before the decode step that writes it
+    # (see _decode_step_impl)
+    def into_slot(cache, new):
+        return jax.lax.dynamic_update_slice(
+            cache, jnp.swapaxes(new, -1, -2).astype(cache.dtype),
+            (0, slot, 0, 0, 0))
+
+    k_cache = into_slot(state["k"], ks)
+    v_cache = into_slot(state["v"], vs)
     x = F.layer_norm(x, [cfg.hidden_size], params.head["norm_f.weight"],
                      params.head["norm_f.bias"])
     # logits at the TRUE last prompt position (LN is per-position, so
@@ -424,8 +448,9 @@ class DecodeEngine:
         cfg = self.config
         dec = self.params.cfg
         head_dim = dec.hidden_size // dec.num_heads
-        kv = (dec.num_layers, cfg.slots, dec.num_heads, cfg.max_len,
-              head_dim)
+        # the resident layout: K and V transposed, cache depth minor
+        kv = (dec.num_layers, cfg.slots, dec.num_heads, head_dim,
+              cfg.max_len)
         return {
             "k": jnp.zeros(kv, dec.dtype),
             "v": jnp.zeros(kv, dec.dtype),
@@ -441,8 +466,11 @@ class DecodeEngine:
     def _prewarm(self):
         """Compile every program this engine will ever run (1 decode
         step + 1 prefill per bucket) against throwaway state, then
-        rebuild the state zeros — donation consumed the warm buffers,
-        and serving must start from an empty cache anyway."""
+        rebuild the state zeros — serving must start from an empty
+        cache.  The warm state is released first, so the device never
+        holds two caches."""
+        import jax
+
         cfg = self.config
         n = 0
         for b in cfg.buckets:
@@ -452,8 +480,14 @@ class DecodeEngine:
                 np.int32(1), np.int32(-1), np.float32(0.0),
                 np.zeros(2, np.uint32))
             n += 1
-        self._state, _, _, _ = self._step_fn(
+        warm, _, _, _ = self._step_fn(
             self._state, self._trees, np.zeros(cfg.slots, bool))
+        # a buffer that a running program writes cannot be freed: wait
+        # for the step, free the warm state, and only then build the
+        # fresh one
+        self._state = None
+        for leaf in jax.tree.leaves(jax.block_until_ready(warm)):
+            leaf.delete()
         self._state = self._fresh_state()
         return n + 1
 

@@ -63,7 +63,8 @@ def test_kernels_phase_interpreted():
         decode_lengths=[1, 40, 128, 256], ln=(64, 128), topk_n=5000,
         dtype=jnp.float32, tol=1e-4)
     assert set(r) >= {"flash_fwd", "flash_bwd_dq", "flash_bwd_dk",
-                      "flash_bwd_dv", "flash_decode", "layer_norm_fwd",
+                      "flash_bwd_dv", "flash_decode", "kv_append",
+                      "flash_decode_resident", "layer_norm_fwd",
                       "layer_norm_bwd_dx", "topk_threshold"}
     assert r["topk_threshold"]["histogram_max_count_diff"] == 0
 

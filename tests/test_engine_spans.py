@@ -361,7 +361,9 @@ def _named(name):
 
 def _kernel_jaxprs():
     from paddle_tpu.kernels.flash_attention import (flash_attention,
-                                                    flash_decode)
+                                                    flash_decode,
+                                                    flash_decode_resident,
+                                                    kv_append)
     from paddle_tpu.kernels.layer_norm import layer_norm_pallas
     from paddle_tpu.kernels.topk_threshold import dgc_topk_mask_pallas
 
@@ -375,7 +377,21 @@ def _kernel_jaxprs():
         return flash_attention(q, k, v, causal=True)
 
     ln = [jax.ShapeDtypeStruct(s, f32) for s in ((64, 128), (128,), (128,))]
+    cache = jax.ShapeDtypeStruct((2, 2, 2, 64, 128), f32)
+    new = jax.ShapeDtypeStruct((2, 2, 64), f32)
+    layer = jax.ShapeDtypeStruct((), jnp.int32)
+    per_slot = jax.ShapeDtypeStruct((2,), jnp.int32)
+
+    # the decode engine's pair on its resident cache
+    def engine_layer(q, k_cache, v_cache, k_new, v_new, layer, pos):
+        k_cache, v_cache = kv_append(k_cache, v_cache, k_new, v_new,
+                                     layer, pos)
+        return flash_decode_resident(q, k_cache, v_cache, layer, pos + 1)
+
     return {
+        "engine": str(jax.make_jaxpr(engine_layer)(
+            jax.ShapeDtypeStruct((2, 2, 1, 64), f32), cache, cache, new,
+            new, layer, per_slot)),
         "flash": str(jax.make_jaxpr(jax.grad(total(causal), (0, 1, 2)))(
             q, q, q)),
         "decode": str(jax.make_jaxpr(flash_decode)(
@@ -398,7 +414,8 @@ def kernel_jaxprs():
 
 @pytest.mark.parametrize("where,kernel", [
     ("flash", "flash_fwd"), ("flash", "flash_dq"), ("flash", "flash_dkv"),
-    ("decode", "flash_decode"), ("layer_norm", "layer_norm_fwd"),
+    ("decode", "flash_decode"), ("engine", "kv_append"),
+    ("engine", "flash_decode"), ("layer_norm", "layer_norm_fwd"),
     ("layer_norm", "layer_norm_bwd"), ("topk", "topk_threshold")])
 def test_every_pallas_call_has_its_name(kernel_jaxprs, where, kernel):
     assert f"name={kernel}\n" in kernel_jaxprs[where] \

@@ -17,7 +17,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from paddle_tpu.kernels import attention, backend
 from paddle_tpu.kernels.flash_attention import (flash_attention,
                                                 flash_attention_with_lse,
-                                                flash_decode)
+                                                flash_decode,
+                                                flash_decode_resident,
+                                                kv_append)
 from paddle_tpu.kernels.layer_norm import layer_norm_pallas
 from paddle_tpu.kernels.topk_threshold import dgc_topk_mask_pallas
 
@@ -39,6 +41,10 @@ def _cases():
     ln = [((8192, 768), BF16), ((768,), BF16), ((768,), BF16)]
     dec = [((8, 12, 1, 64), BF16), ((8, 12, 2048, 64), BF16),
            ((8, 12, 2048, 64), BF16), ((8,), jnp.int32)]
+    # the decode engine's resident cache [L, S, H, D, T] at the serve
+    # cell's widths (BENCHMARK.json), two layers of it
+    cache = ((2, 64, 16, 64, 1024), BF16)
+    scalar, per_slot = ((), jnp.int32), ((64,), jnp.int32)
     return [
         ("flash_fwd", causal, att),
         ("flash_fwd_bwd", jax.grad(_sum32(causal), argnums=(0, 1, 2)), att),
@@ -50,6 +56,14 @@ def _cases():
         ("flash_decode_f32_d128", flash_decode,
          [((3, 4, 1, 128), F32), ((3, 4, 1280, 128), F32),
           ((3, 4, 1280, 128), F32), ((3,), jnp.int32)]),
+        ("flash_decode_resident", flash_decode_resident,
+         [((64, 16, 1, 64), BF16), cache, cache, scalar, per_slot]),
+        ("flash_decode_resident_f32_d128", flash_decode_resident,
+         [((3, 4, 1, 128), F32), ((2, 3, 4, 128, 1280), F32),
+          ((2, 3, 4, 128, 1280), F32), scalar, ((3,), jnp.int32)]),
+        ("kv_append", lambda *a: kv_append(*a)[0],
+         [cache, cache, ((64, 16, 64), BF16), ((64, 16, 64), BF16),
+          scalar, per_slot]),
         ("layer_norm_fwd", layer_norm_pallas, ln),
         ("layer_norm_fwd_bwd",
          jax.grad(_sum32(layer_norm_pallas), argnums=(0, 1, 2)), ln),
@@ -67,6 +81,9 @@ KERNELS = {"flash_fwd": ["flash_fwd"],
            "flash_with_lse_fwd_bwd": ["flash_fwd", "flash_dq", "flash_dkv"],
            "flash_decode": ["flash_decode"],
            "flash_decode_f32_d128": ["flash_decode"],
+           "flash_decode_resident": ["flash_decode"],
+           "flash_decode_resident_f32_d128": ["flash_decode"],
+           "kv_append": ["kv_append"],
            "layer_norm_fwd": ["layer_norm_fwd"],
            # the gradient of a sum needs no forward output
            "layer_norm_fwd_bwd": ["layer_norm_bwd"],
@@ -109,9 +126,137 @@ def test_kernel_compiles_for_v5e(compiled_kernels, v5e, name, fn, args):
         lowering_platforms=("tpu",)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    calls = re.findall(r"%([\w-]+?)(?:\.\d+)? = [^\n]*"
-                       r'custom_call_target="tpu_custom_call"', text)
-    assert sorted(set(calls)) == sorted(KERNELS[name])
+    assert sorted(set(_mosaic_calls(text))) == sorted(KERNELS[name])
+
+
+def _mosaic_calls(text):
+    return re.findall(r"%([\w-]+?)(?:\.\d+)? = [^\n]*"
+                      r'custom_call_target="tpu_custom_call"', text)
+
+
+# ---------------------------------------------------------------------
+# the decode engine's programs, compiled whole: the cache is held once
+# and no instruction copies, slices or re-lays a layer of it
+# ---------------------------------------------------------------------
+
+SLOTS, HEADS, HEAD_DIM, DEPTH, LAYERS = 64, 16, 64, 1024, 4
+LAYER_ELEMS = SLOTS * HEADS * DEPTH * HEAD_DIM
+CACHE_BYTES = 2 * LAYERS * LAYER_ELEMS * 2          # K and V, bfloat16
+# results that may be as large as a layer's cache: the donated buffers
+# handed along, and the Mosaic calls that read and write them in place
+PASSES_ALONG = {"parameter", "get-tuple-element", "tuple", "bitcast",
+                "while", "tpu_custom_call"}
+_INSTR = re.compile(r"^\s*(?:ROOT )?%[\w.-]+ = (.*?) ([a-z][\w-]*)\(")
+
+
+def _large_results(text):
+    """[(opcode, line)] of every instruction of the compiled program
+    whose result, or an element of whose tuple result, holds a layer's
+    cache or more."""
+    out = []
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        sizes = [math.prod(int(n) for n in dims.split(",") if n)
+                 for dims in re.findall(r"[a-z]\w*\[([\d,]*)\]", m.group(1))]
+        if max(sizes, default=0) >= LAYER_ELEMS:
+            op = m.group(2)
+            if op == "custom-call" and "tpu_custom_call" in line:
+                op = "tpu_custom_call"
+            out.append((op, line.strip()[:160]))
+    return out
+
+
+@pytest.fixture(scope="module")
+def engine_programs(v5e):
+    """(decode step, prefill at bucket 64) of `DecodeEngine`, at the
+    serve cell's widths and four layers, compiled for the described
+    v5e with the state donated, as the engine jits them."""
+    import functools
+
+    from paddle_tpu.models import generate as G
+    from paddle_tpu.models.gpt import GPT, GPTConfig
+    from paddle_tpu.nn.parameter import default_rng
+    from paddle_tpu.serving import decode as D
+
+    one = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    box = {}
+
+    def trees():
+        # shapes only; the layers draw their initial values from a key
+        # of this trace's own, not from the process's generator
+        with default_rng.key_context(jax.random.PRNGKey(0)):
+            p = G.build_decode_params(GPT(GPTConfig(
+                vocab_size=50257, hidden_size=HEADS * HEAD_DIM,
+                num_layers=LAYERS, num_heads=HEADS, max_seq_len=DEPTH,
+                dropout=0.0, dtype="bfloat16")))
+        box["cfg"] = p.cfg
+        return p.emb, p.blocks, p.head
+
+    trees = jax.tree.map(lambda a: aval(a.shape, a.dtype),
+                         jax.eval_shape(trees))
+    kv = aval((LAYERS, SLOTS, HEADS, HEAD_DIM, DEPTH), BF16)
+    i32, f32 = jnp.int32, jnp.float32
+    state = {"k": kv, "v": kv, "pos": aval((SLOTS,), i32),
+             "active": aval((SLOTS,), bool), "token": aval((SLOTS,), i32),
+             "stop": aval((SLOTS,), i32), "eos": aval((SLOTS,), i32),
+             "temp": aval((SLOTS,), f32),
+             "key": aval((SLOTS, 2), jnp.uint32)}
+
+    def compiled(impl, *args):
+        return jax.jit(functools.partial(impl, cfg=box["cfg"]),
+                       donate_argnums=(0,)).trace(
+            state, trees, *args).lower(
+            lowering_platforms=("tpu",)).compile()
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(backend, "is_tpu_backend", lambda: True)
+        return {
+            "decode_step": compiled(D._decode_step_impl,
+                                    aval((SLOTS,), bool)),
+            "prefill_b64": compiled(
+                D._prefill_impl, aval((1, 64), i32), aval((), i32),
+                aval((), i32), aval((), i32), aval((), i32),
+                aval((), f32), aval((2,), jnp.uint32)),
+        }
+
+
+@pytest.mark.parametrize("program,in_place", [
+    ("decode_step", set()),
+    # the prefill's one write into the slot's region, in place on the
+    # donated cache, alone or as the root of its fusion
+    ("prefill_b64", {"dynamic-update-slice", "fusion"})])
+def test_engine_program_moves_no_layer_of_the_cache(engine_programs,
+                                                    program, in_place):
+    text = engine_programs[program].as_text()
+    large = _large_results(text)
+    assert large, "the cache is not in the program at all"
+    odd = [(op, line) for op, line in large
+           if op not in PASSES_ALONG | in_place]
+    assert not odd, odd
+    for op, line in large:
+        if op == "fusion":
+            assert "dynamic-update-slice_fusion" in line, line
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_b64"])
+def test_engine_program_holds_one_copy_of_the_cache(engine_programs,
+                                                    program):
+    mem = engine_programs[program].memory_analysis()
+    assert mem.temp_size_in_bytes < 0.05 * CACHE_BYTES
+    # every byte of K and V goes out in the buffer it came in
+    assert mem.alias_size_in_bytes >= CACHE_BYTES
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 1 << 20
+
+
+def test_decode_step_names_its_mosaic_calls(engine_programs):
+    calls = _mosaic_calls(engine_programs["decode_step"].as_text())
+    assert sorted(calls) == ["flash_decode", "kv_append"]
 
 
 def _attn_grads(q, k, v):
