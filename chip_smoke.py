@@ -35,6 +35,7 @@ size switch and no CPU mode.
 
 import functools
 import json
+import os
 import sys
 import time
 
@@ -461,6 +462,102 @@ def phase_kernels(attn, decode, decode_lengths, ln, topk_n, dtype, tol):
 
 
 # ---------------------------------------------------------------------------
+# Kimi-K2 behind the decode engine
+# ---------------------------------------------------------------------------
+
+def phase_k2(hf, slots, max_len, buckets, prompt_lens, new_tokens,
+             decode_lengths, tol, gap_tol):
+    """`hf`: the model's sizes under its config.json keys.  (1)
+    `latent_append` + `mla_decode` at the model's widths against their
+    XLA mathematics, at ragged lengths, and the routed experts' grouped
+    product (`moe_grouped_mm`) against each group's rows times its
+    expert, in numpy; (2) the engine's first `new_tokens` tokens of each
+    prompt against the unbatched forward pass (`kimi_k2.full_logits`
+    over prompt and served tokens, the published form of attention, no
+    cache): the widest gap by which a served token's logit lies under
+    that pass's best."""
+    from paddle_tpu.distributed.moe import grouped_product
+    from paddle_tpu.kernels.attention import resident_mla_attention
+    from paddle_tpu.models import kimi_k2
+    from paddle_tpu.serving import DecodeConfig, DecodeEngine
+
+    cfg = kimi_k2.K2Cfg.from_hf(hf, max_seq_len=max_len)
+    dtype = jnp.dtype(cfg.dtype)
+    rng = np.random.default_rng(3)
+    s, width = len(decode_lengths), cfg.latent_width
+
+    def rand(shape):
+        return jnp.asarray(rng.standard_normal(shape), dtype)
+
+    args = (rand((s, cfg.num_heads, cfg.kv_lora_rank)),
+            rand((s, cfg.num_heads, cfg.qk_rope_head_dim)), rand((s, width)),
+            rand((2, s, width, max_len)))
+    pos = jnp.asarray(decode_lengths, jnp.int32) - 1
+    outs = {}
+    for use_kernel in (True, False):
+        fn = functools.partial(resident_mla_attention, layer=1, pos=pos,
+                               scale=cfg.softmax_scale,
+                               use_kernel=use_kernel)
+        outs[use_kernel] = (_highest(fn) if not use_kernel
+                            else jax.jit(fn))(*args)
+    _check(bool((outs[True][1] == outs[False][1]).all()),
+           "latent_append differs from the XLA write")
+    err, rel = _err(outs[True][0], outs[False][0])
+    _check(rel <= tol, f"mla_decode: error {err:.3g} is {rel:.3g} of the "
+                       f"reference's max, over {tol}")
+
+    # a decode step's rows over the held experts: uneven groups, one
+    # empty, one over a row-tile boundary
+    counts = np.resize([5, 0, 130, 1, 9, 3], cfg.experts_held)
+    rows = rand((256 * cfg.num_experts_per_tok, cfg.hidden_size))
+    experts = rand((cfg.experts_held, cfg.hidden_size,
+                    2 * cfg.moe_intermediate_size)) * 0.02
+    got = jax.jit(grouped_product)(rows, experts, counts)
+    ends = np.cumsum(counts)
+    want = np.concatenate([
+        np.asarray(rows[e - c:e], np.float32) @ np.asarray(w, np.float32)
+        for w, c, e in zip(experts, counts, ends)])
+    got = got[:ends[-1]]
+    gerr, grel = _err(got, want)
+    _check(grel <= tol, f"moe_grouped_mm: error {gerr:.3g} is {grel:.3g} "
+                        f"of the reference's max, over {tol}")
+
+    params = kimi_k2.K2Params.from_flat(
+        cfg, kimi_k2.init_params(cfg, jax.random.PRNGKey(29)))
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in prompt_lens]
+    eng = DecodeEngine(params, config=DecodeConfig(
+        slots=slots, max_len=max_len, buckets=buckets))
+    try:
+        futs = [eng.submit(p, max_new_tokens=new_tokens) for p in prompts]
+        served = [np.asarray(f.result(timeout=900)) for f in futs]
+        summary = eng.summary()
+    finally:
+        eng.close()
+    forward = jax.jit(functools.partial(kimi_k2.full_logits, cfg))
+    widest, same = 0.0, 0
+    for p, out in zip(prompts, served):
+        _check(out.shape == (new_tokens,), f"prompt {p.size}: {out.shape}")
+        logits = np.asarray(forward(
+            params.trees, np.concatenate([p, out])), np.float32)
+        rows = logits[p.size - 1:p.size - 1 + new_tokens]
+        widest = max(widest, float((rows.max(axis=1) - rows[
+            np.arange(new_tokens), out]).max()))
+        same += int((rows.argmax(axis=1) == out).sum())
+    _check(widest <= gap_tol, f"engine tokens lie up to {widest:.3g} under "
+                              f"the forward pass's best, over {gap_tol}")
+    dec = summary["decode"]
+    _check(dec["experts"]["tokens_total"] > 0, f"no expert counts: {dec}")
+    return {"mla_decode": {"max_abs_err": err, "rel_to_max": rel},
+            "latent_append": "exact",
+            "moe_grouped_mm": {"max_abs_err": gerr, "rel_to_max": grel},
+            "requests": len(prompts),
+            "widest_logit_gap": widest,
+            "tokens_equal_to_forward": f"{same}/{len(prompts) * new_tokens}",
+            "cache": dec["cache"], "experts": dec["experts"]}
+
+
+# ---------------------------------------------------------------------------
 # four chips
 # ---------------------------------------------------------------------------
 
@@ -579,6 +676,13 @@ def main():
         decode_lengths=[1, 40, 1024, 2048, 777, 128, 129, 2047],
         ln=(16384, 1024), topk_n=1024 * 4096, dtype=jnp.bfloat16,
         tol=5e-2)
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmarks", "configs", "kimi-k2.6.json")) as f:
+        k2 = json.load(f)
+    run("k2", phase_k2, k2, slots=8, max_len=2048, buckets=(256, 1024),
+        prompt_lens=[40, 200, 900, 513, 700, 40, 255, 1000, 333, 90],
+        new_tokens=32, decode_lengths=[1, 40, 1024, 2048, 777, 128, 129,
+                                       2047], tol=5e-2, gap_tol=0.25)
     if device["count"] >= 4:
         run("four_chips", phase_four_chips, GPT_FULL, 8, 2048, 3,
             layer["losses"][0], STATIC_GPT_FULL, static_batch,

@@ -14,6 +14,10 @@ Forms:
 - `moe_ffn`: dense (single-device or auto-sharded under jit) MoE FFN.
 - `sharded_moe_ffn`: the same computation with explicit sharding
   constraints so pjit lowers dispatch/combine to all_to_all over "ep".
+- `sigmoid_top_k` / `routed_experts`: the DeepSeek-V3 family's layer as
+  one chip of an expert-parallel deployment runs it: sigmoid scores and
+  a selection bias over ALL routed experts, no capacity and no drop, and
+  a grouped product over the experts this chip is told it holds.
 """
 
 import math
@@ -182,3 +186,85 @@ def moe_ffn_shardmap(params, x, axis="ep", k=2, capacity_factor=1.25,
     y = jnp.einsum("ecd,nec->nd", out.reshape(ep * e_loc, cap, d),
                    combine)
     return y.reshape(*lead, d).astype(x.dtype), aux
+
+
+def sigmoid_top_k(h, router, bias, top_k, scale):
+    """DeepSeek-V3's `noaux_tc` router with one group: scores
+    sigmoid(h @ router) in float32 over all routed experts; the `top_k`
+    experts of a token are the largest of score + bias (the bias chooses
+    and does not weigh); weights are the chosen scores over their sum,
+    times `scale`.  h [N, D], router [D, E], bias [E] ->
+    (experts int32 [N, k], weights float32 [N, k])."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        h.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    chosen = jnp.take_along_axis(scores, experts, axis=1)
+    return experts.astype(jnp.int32), \
+        scale * chosen / chosen.sum(axis=1, keepdims=True)
+
+
+def grouped_product(rows, weights, counts, use_kernel=None):
+    """rows [M, K] sorted by group, group g owning the next counts[g];
+    weights [G, K, N] -> float32 [M, N], row r of group g being
+    `rows[r] @ weights[g]`; rows past the groups' hold anything.  On the
+    TPU, at shapes it tiles, the Pallas call `moe_grouped_mm`
+    (kernels/grouped_mm.py); elsewhere `jax.lax.ragged_dot`.
+    `use_kernel` forces the choice (the tests' interpreter runs)."""
+    from ..kernels import grouped_mm
+    from ..kernels.backend import is_tpu_backend
+
+    tiles = grouped_mm.takes_kernel(rows.shape[0], rows.shape[1],
+                                    weights.shape[2])
+    if tiles and (is_tpu_backend() if use_kernel is None else use_kernel):
+        return grouped_mm.moe_grouped_mm(rows, weights, counts)
+    return jax.lax.ragged_dot(rows, weights, counts,
+                              preferred_element_type=jnp.float32)
+
+
+def routed_experts(h, router, bias, experts_held, first_expert, n_routed,
+                   top_k, scale, valid=None, use_kernel=None):
+    """The routed part of an expert layer on a chip that holds experts
+    [first_expert, first_expert + held) of `n_routed`.
+
+    h [N, D]; router [D, n_routed]; bias [n_routed]; experts_held =
+    (gate_up [held, D, 2F], down [held, F, D]), SwiGLU experts with gate
+    and up side by side; valid: bool [N] or None, tokens that are
+    padding make no assignment.  Every token is routed over all
+    `n_routed` (`sigmoid_top_k`, the weights normalised over all its
+    chosen experts, held here or not); the assignments that fall on the
+    experts held here are sorted by expert and go through one grouped
+    product (`grouped_product`: the Pallas call `moe_grouped_mm` on the
+    TPU) over static N * top_k rows, so nothing is dropped at any
+    imbalance; what the absent experts would have added is left out.
+    Returns (y [N, D] in h's type, assignments on each held expert
+    int32 [held]); the caller adds the shared expert."""
+    gate_up, down = experts_held
+    held = gate_up.shape[0]
+    if router.shape[1] != n_routed or not \
+            0 <= first_expert <= n_routed - held:
+        raise ValueError(
+            f"experts [{first_expert}, {first_expert + held}) do not lie "
+            f"in a router over {router.shape[1]} (n_routed {n_routed})")
+    n, d = h.shape
+    experts, weights = sigmoid_top_k(h, router, bias, top_k, scale)
+    local = experts - first_expert
+    here = (local >= 0) & (local < held)
+    if valid is not None:
+        here = here & valid[:, None]
+    # assignments sorted by held expert, the others (group `held`) last
+    group = jnp.where(here, local, held).reshape(-1)
+    order = jnp.argsort(group, stable=True)
+    counts = jnp.sum(group[:, None] == jnp.arange(held)[None, :],
+                     axis=0, dtype=jnp.int32)
+    rows = jnp.take(h, order // top_k, axis=0)
+    gu = grouped_product(rows, gate_up, counts, use_kernel)
+    f = gu.shape[1] // 2
+    act = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(h.dtype)
+    out = grouped_product(act, down, counts, use_kernel)
+    # back into the tokens' order; rows past the held assignments hold
+    # whatever the grouped product left there
+    back = jnp.take(out, jnp.argsort(order), axis=0).reshape(n, top_k, d)
+    y = jnp.sum(jnp.where(here[..., None],
+                          back * weights[..., None], 0.0), axis=1)
+    return y.astype(h.dtype), counts

@@ -197,6 +197,46 @@ def resident_decode_attention(q, k_new, v_new, k_cache, v_cache, layer,
     return o, k_cache, v_cache
 
 
+def resident_mla_attention(q_latent, q_rope, new, latent, layer, pos,
+                           scale, use_kernel=None):
+    """One layer of the decode engine's step on a latent (MLA) cache it
+    owns: write each slot's new latent column, then attend.
+
+    q_latent [S, H, rank], q_rope [S, H, rope]: this step's queries, the
+    first absorbed into the latent space; new [S, rank + rope]: this
+    step's `[c_kv ; k_rope]`; latent: the resident cache
+    [L, S, rank + rope, T]; layer: a Python int; pos: int32 [S].  A
+    stale pos >= T (an inactive slot) is clamped for the write into the
+    slot's own last column.  Returns (softmax(scores) . c_kv
+    [S, H, rank], latent).
+
+    Under the same predicate as `resident_decode_attention`
+    (`_decode_takes_kernel`, on the rotary width, and a rank of whole
+    lanes; `use_kernel` as its `use_flash`) these are the Pallas calls
+    `latent_append` and `mla_decode` (kernels/mla.py); elsewhere the
+    same mathematics in XLA."""
+    rank, t = q_latent.shape[-1], latent.shape[-1]
+    pos = jnp.asarray(pos, jnp.int32)
+    posw = jnp.minimum(pos, t - 1)
+    if rank % 128 == 0 and _decode_takes_kernel(t, q_rope.shape[-1],
+                                                use_kernel):
+        from .mla import latent_append, mla_decode
+
+        latent = latent_append(latent, new, layer, posw)
+        return mla_decode(q_latent, q_rope, latent, layer, posw + 1,
+                          scale), latent
+    latent = latent.at[layer, jnp.arange(pos.shape[0]), :, posw].set(
+        new.astype(latent.dtype))
+    c = latent[layer].astype(q_latent.dtype)              # [S, R+r, T]
+    s = (jnp.einsum("shc,sct->sht", q_latent, c[:, :rank])
+         + jnp.einsum("shr,srt->sht", q_rope.astype(q_latent.dtype),
+                      c[:, rank:])) * scale
+    live = jnp.arange(t, dtype=jnp.int32)[None, :] <= pos[:, None]
+    s = jnp.where(live[:, None, :], s.astype(jnp.float32), DECODE_NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(q_latent.dtype)
+    return jnp.einsum("sht,sct->shc", p, c[:, :rank]), latent
+
+
 def dot_product_attention(q, k, v, mask=None, dropout_p=0.0, is_causal=False,
                           scale=None, training=True, rng_key=None,
                           use_flash=None):

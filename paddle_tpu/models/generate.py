@@ -53,6 +53,26 @@ class DecCfg(NamedTuple):
                    cfg.max_seq_len, cfg.dtype, cfg.moe_top_k,
                    cfg.moe_capacity_factor)
 
+    # -- the decode engine's seam (serving/decode.py, "The seam") ------
+    cache_kind = "kv [layers, slots, heads, head_dim, max_len]"
+
+    def cache_arrays(self, slots, max_len):
+        """K and V in the resident layout: transposed, depth minor."""
+        kv = (self.num_layers, slots, self.num_heads,
+              self.hidden_size // self.num_heads, max_len)
+        return {"k": jnp.zeros(kv, self.dtype),
+                "v": jnp.zeros(kv, self.dtype)}
+
+    def prefill(self, trees, cache, prompt, true_len, slot):
+        return _slot_prefill(DecodeParams(*trees, self), cache, prompt,
+                             true_len, slot)
+
+    def decode(self, trees, cache, token, pos):
+        return _slot_decode(DecodeParams(*trees, self), cache, token, pos)
+
+    def head(self, trees, hidden):
+        return jnp.einsum("bh,vh->bv", hidden, trees[0]["wte.weight"])
+
 
 class DecodeParams(NamedTuple):
     """Stacked decode-ready parameters: emb/head plain dicts, blocks
@@ -62,6 +82,10 @@ class DecodeParams(NamedTuple):
     blocks: dict
     head: dict
     cfg: DecCfg
+
+    @property
+    def trees(self):
+        return self.emb, self.blocks, self.head
 
 
 def build_decode_params(model):
@@ -140,6 +164,91 @@ def _qkv(hn, bp, num_heads):
 def _merge_heads(o):
     b, h, s, d = o.shape
     return jnp.transpose(o, (0, 2, 1, 3)).reshape(b, s, h * d)
+
+
+def _slot_prefill(params, cache, prompt, true_len, slot):
+    """The decode engine's prefill of one request into one slot, at a
+    static bucket shape (`DecCfg.prefill`).
+
+    `prompt` is [1, bucket] zero-padded; causal masking makes the pad
+    columns exactly inert for the real positions (masked scores
+    underflow to f32 zero), and MoE routes DROP-FREE (cap = cohort
+    size) so pad tokens cannot displace real ones — the first emitted
+    token is bitwise what generate()'s unpadded prefill emits.  Returns
+    (cache, the final hidden state at the true last position [1, H],
+    no counters)."""
+    cfg = params.cfg
+    bucket = prompt.shape[1]
+    pos = jnp.arange(bucket, dtype=jnp.int32)[None, :]
+    x = jnp.take(params.emb["wte.weight"], prompt, axis=0) \
+        + jnp.take(params.emb["wpe.weight"], pos, axis=0)
+
+    def layer(x, bp):
+        hn = F.layer_norm(x, [cfg.hidden_size], bp["norm1.weight"],
+                          bp["norm1.bias"])
+        q, k, v = _qkv(hn, bp, cfg.num_heads)
+        o = F.scaled_dot_product_attention(q, k, v, is_causal=True,
+                                           training=False)
+        return _block_tail(x, _merge_heads(o), bp, cfg,
+                           decode=True), (k, v)
+
+    x, (ks, vs) = jax.lax.scan(layer, x, params.blocks)
+    # ks: [L, 1, H, bucket, D], transposed into the resident layout
+    # [L, 1, H, D, bucket] (on the device the scan's output is held
+    # bucket minor already, so the transpose moves nothing) and dropped
+    # into columns [0, bucket) of this slot's region [:, slot] of the
+    # donated cache.  Columns from `bucket` on keep the last tenant's
+    # values: none is attended before the decode step that writes it
+    # (see serving/decode.py _decode_step_impl)
+    def into_slot(cache, new):
+        return jax.lax.dynamic_update_slice(
+            cache, jnp.swapaxes(new, -1, -2).astype(cache.dtype),
+            (0, slot, 0, 0, 0))
+
+    cache = {"k": into_slot(cache["k"], ks),
+             "v": into_slot(cache["v"], vs)}
+    x = F.layer_norm(x, [cfg.hidden_size], params.head["norm_f.weight"],
+                     params.head["norm_f.bias"])
+    # the TRUE last prompt position (LN is per-position, so slicing
+    # before the head matches generate()'s slice-after bitwise)
+    h = jax.lax.dynamic_slice(
+        x, (0, true_len - 1, 0), (1, 1, cfg.hidden_size))[:, 0]
+    return cache, h, {}
+
+
+def _slot_decode(params, cache, token, pos):
+    """One full-width step of the decode engine over every slot
+    (`DecCfg.decode`): token [S] at per-slot positions pos [S] ->
+    (cache, final hidden states [S, H], no counters)."""
+    from ..kernels.attention import resident_decode_attention
+
+    cfg = params.cfg
+    scale = 1.0 / (cfg.hidden_size // cfg.num_heads) ** 0.5
+    x = jnp.take(params.emb["wte.weight"], token[:, None], axis=0) \
+        + jnp.take(params.emb["wpe.weight"], pos, axis=0)[:, None, :]
+
+    def layer(carry, xs):
+        # the stacked caches [L, S, H, D, T] ride the carry whole: the
+        # layer's reader and writer address layer `l` inside them
+        x, k_cache, v_cache = carry
+        bp, l = xs
+        hn = F.layer_norm(x, [cfg.hidden_size], bp["norm1.weight"],
+                          bp["norm1.bias"])
+        q, k, v = _qkv(hn, bp, cfg.num_heads)      # [S, H, 1, D]
+        # per-slot ragged positions; off the kernel path the SAME
+        # single-query math generate() decodes with — the
+        # token-exactness hinge
+        o, k_cache, v_cache = resident_decode_attention(
+            q, k, v, k_cache, v_cache, l, pos, scale=scale)
+        x = _block_tail(x, _merge_heads(o), bp, cfg, decode=True)
+        return (x, k_cache, v_cache), None
+
+    (x, ks, vs), _ = jax.lax.scan(
+        layer, (x, cache["k"], cache["v"]),
+        (params.blocks, jnp.arange(cache["k"].shape[0], dtype=jnp.int32)))
+    x = F.layer_norm(x, [cfg.hidden_size], params.head["norm_f.weight"],
+                     params.head["norm_f.bias"])
+    return {"k": ks, "v": vs}, x[:, -1], {}
 
 
 def prefill(params: DecodeParams, input_ids, cache, cfg=None):

@@ -1,10 +1,35 @@
-"""Continuous-batching decode serving — slot-based KV-cache engine
-(ISSUE 17 tentpole).
+"""Continuous-batching decode serving — slot-based cache engine
+(ISSUE 17 tentpole; the model behind a seam since ISSUE 29).
 
-The PR-8 runtime batches single-shot predictors; this engine serves
-`models/generate.py`'s GPT family autoregressively, with the
-iteration-level scheduling of Orca (OSDI '22) and the slot-resident
-KV cache of vLLM (SOSP '23):
+The PR-8 runtime batches single-shot predictors; this engine serves a
+causal decoder autoregressively, with the iteration-level scheduling of
+Orca (OSDI '22) and the slot-resident cache of vLLM (SOSP '23).
+
+**The seam.**  The engine does not know its model.  It is given
+`params` with `.trees` (the arrays, a pytree) and `.cfg` (hashable,
+static under jit), and `cfg` gives:
+
+- `cache_arrays(slots, max_len)` -> {name: array}, the model's cache
+  (`cache_kind` says what it is), which the engine keeps in its donated
+  state beside its own per-slot vectors;
+- `prefill(trees, cache, prompt[1, bucket], true_len, slot)` ->
+  (cache, hidden [1, H] at the true last position, counters);
+- `decode(trees, cache, token[S], pos[S])` -> (cache, hidden [S, H],
+  counters), one step of every slot at its own position;
+- `head(trees, hidden)` -> logits; and `max_seq_len`.
+
+Everything after the hidden state (head, greedy or sampled token,
+`pos/active/stop/eos/temp/key`) and everything on the host (admission,
+slots, budgets, spans, stats, hardening) is the engine's and shared.
+`models/generate.py`'s `DecCfg` (the GPT family; a `models/gpt.py` GPT
+is accepted as it is) and `models/kimi_k2.py`'s `K2Cfg` implement it.
+`counters` is a dict of small arrays; `expert_counts` (assignments on
+each expert held here, summed over the expert layers), where a model
+gives it, is one more result of the program after the parent's (a model
+without it answers with exactly what the engine always fetched) and
+becomes the `expert_tokens` / `expert_load_max` attributes of
+`engine.*_wait` and `DecodeStats`' running totals.  What follows
+describes the engine with the GPT family's cache:
 
 - ONE compiled decode step owns the whole serving state: a fixed
   ring-buffer KV cache plus per-slot `pos/active/token/stop/eos/temp/
@@ -216,53 +241,36 @@ class EngineBrokenError(RuntimeError):
 # device programs (module-level so each engine jits exactly two shapes)
 # ---------------------------------------------------------------------------
 
+# what the engine keeps for each slot beside the model's cache
+_SLOT_KEYS = ("pos", "active", "token", "stop", "eos", "temp", "key")
+
+
+def _cache_of(state):
+    return {k: v for k, v in state.items() if k not in _SLOT_KEYS}
+
+
 def _decode_step_impl(state, trees, kill, cfg):
-    """One full-width decode step over every slot.
+    """One full-width decode step over every slot.  `cfg` is the model's
+    side of the seam: `cfg.decode` runs the layers and writes the cache,
+    `cfg.head` gives the logits; the rest is the engine's.
 
     Inactive (or host-killed) slots still flow through the math — their
     writes land at their stale position CLAMPED inside their own slot's
     cache region, which is safe: a position is only ever attended on or
     after the step that first writes it (the live mask is `col <= pos`
     and the write at `pos` happens before the attend), and a refilling
-    prefill overwrites the prompt region wholesale."""
+    prefill overwrites the prompt region wholesale.
+
+    Returns (state, tokens [S], was active [S], still active [S], the
+    model's counters)."""
     import jax
     import jax.numpy as jnp
 
-    from ..kernels.attention import resident_decode_attention
-    from ..models import generate as G
-    from ..nn import functional as F
-
-    params = G.DecodeParams(*trees, cfg)
-    scale = 1.0 / (cfg.hidden_size // cfg.num_heads) ** 0.5
     active = jnp.logical_and(state["active"], jnp.logical_not(kill))
     pos = state["pos"]
     tok = state["token"]
-    x = jnp.take(params.emb["wte.weight"], tok[:, None], axis=0) \
-        + jnp.take(params.emb["wpe.weight"], pos, axis=0)[:, None, :]
-
-    def layer(carry, xs):
-        # the stacked caches [L, S, H, D, T] ride the carry whole: the
-        # layer's reader and writer address layer `l` inside them
-        x, k_cache, v_cache = carry
-        bp, l = xs
-        hn = F.layer_norm(x, [cfg.hidden_size], bp["norm1.weight"],
-                          bp["norm1.bias"])
-        q, k, v = G._qkv(hn, bp, cfg.num_heads)      # [S, H, 1, D]
-        # per-slot ragged positions; off the kernel path the SAME
-        # single-query math generate() decodes with — the
-        # token-exactness hinge
-        o, k_cache, v_cache = resident_decode_attention(
-            q, k, v, k_cache, v_cache, l, pos, scale=scale)
-        x = G._block_tail(x, G._merge_heads(o), bp, cfg, decode=True)
-        return (x, k_cache, v_cache), None
-
-    (x, ks, vs), _ = jax.lax.scan(
-        layer, (x, state["k"], state["v"]),
-        (params.blocks, jnp.arange(state["k"].shape[0], dtype=jnp.int32)))
-    x = F.layer_norm(x, [cfg.hidden_size], params.head["norm_f.weight"],
-                     params.head["norm_f.bias"])
-    logits = jnp.einsum("bh,vh->bv", x[:, -1],
-                        params.emb["wte.weight"])
+    cache, hidden, counters = cfg.decode(trees, _cache_of(state), tok, pos)
+    logits = cfg.head(trees, hidden)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     temp = state["temp"]
     scaled = logits.astype(jnp.float32) \
@@ -278,68 +286,29 @@ def _decode_step_impl(state, trees, kill, cfg):
         new_pos >= state["stop"])
     still = jnp.logical_and(active, jnp.logical_not(done))
     out = dict(state)
+    out.update(cache)
     out.update(
-        k=ks, v=vs,
         pos=jnp.where(active, new_pos, pos),
         token=jnp.where(active, nxt, tok),
         active=still)
-    return out, nxt, active, still
+    return out, nxt, active, still, counters
 
 
 def _prefill_impl(state, trees, prompt, true_len, slot, stop, eos,
                   temp, key, cfg):
-    """Prefill one request into one slot at a static bucket shape.
-
-    `prompt` is [1, bucket] zero-padded; causal masking makes the pad
-    columns exactly inert for the real positions (masked scores
-    underflow to f32 zero), and MoE routes DROP-FREE (cap = cohort
-    size) so pad tokens cannot displace real ones — the first emitted
-    token is bitwise what generate()'s unpadded prefill emits.
+    """Prefill one request into one slot at a static bucket shape:
+    `cfg.prefill` runs the prompt [1, bucket] (zero-padded; the model
+    keeps the padding inert) and writes the slot's region of the cache.
     true_len/slot/stop are traced scalars: refilling any slot with any
-    prompt length inside the bucket reuses this one program."""
+    prompt length inside the bucket reuses this one program.
+
+    Returns (state, first token, active, the model's counters)."""
     import jax
     import jax.numpy as jnp
 
-    from ..models import generate as G
-    from ..nn import functional as F
-
-    params = G.DecodeParams(*trees, cfg)
-    bucket = prompt.shape[1]
-    pos = jnp.arange(bucket, dtype=jnp.int32)[None, :]
-    x = jnp.take(params.emb["wte.weight"], prompt, axis=0) \
-        + jnp.take(params.emb["wpe.weight"], pos, axis=0)
-
-    def layer(x, bp):
-        hn = F.layer_norm(x, [cfg.hidden_size], bp["norm1.weight"],
-                          bp["norm1.bias"])
-        q, k, v = G._qkv(hn, bp, cfg.num_heads)
-        o = F.scaled_dot_product_attention(q, k, v, is_causal=True,
-                                           training=False)
-        return G._block_tail(x, G._merge_heads(o), bp, cfg,
-                             decode=True), (k, v)
-
-    x, (ks, vs) = jax.lax.scan(layer, x, params.blocks)
-    # ks: [L, 1, H, bucket, D], transposed into the resident layout
-    # [L, 1, H, D, bucket] (on the device the scan's output is held
-    # bucket minor already, so the transpose moves nothing) and dropped
-    # into columns [0, bucket) of this slot's region [:, slot] of the
-    # donated cache.  Columns from `bucket` on keep the last tenant's
-    # values: none is attended before the decode step that writes it
-    # (see _decode_step_impl)
-    def into_slot(cache, new):
-        return jax.lax.dynamic_update_slice(
-            cache, jnp.swapaxes(new, -1, -2).astype(cache.dtype),
-            (0, slot, 0, 0, 0))
-
-    k_cache = into_slot(state["k"], ks)
-    v_cache = into_slot(state["v"], vs)
-    x = F.layer_norm(x, [cfg.hidden_size], params.head["norm_f.weight"],
-                     params.head["norm_f.bias"])
-    # logits at the TRUE last prompt position (LN is per-position, so
-    # slicing before the head matches generate()'s slice-after bitwise)
-    h = jax.lax.dynamic_slice(
-        x, (0, true_len - 1, 0), (1, 1, cfg.hidden_size))[:, 0]
-    logits = jnp.einsum("bh,vh->bv", h, params.emb["wte.weight"])
+    cache, hidden, counters = cfg.prefill(trees, _cache_of(state), prompt,
+                                          true_len, slot)
+    logits = cfg.head(trees, hidden)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     scaled = logits.astype(jnp.float32) / jnp.maximum(temp, 1e-6)
     sampled = jax.random.categorical(key, scaled,
@@ -349,8 +318,8 @@ def _prefill_impl(state, trees, prompt, true_len, slot, stop, eos,
         true_len < stop,
         jnp.logical_not(jnp.logical_and(eos >= 0, first == eos)))
     out = dict(state)
+    out.update(cache)
     out.update(
-        k=k_cache, v=v_cache,
         pos=state["pos"].at[slot].set(true_len),
         token=state["token"].at[slot].set(first),
         active=state["active"].at[slot].set(active),
@@ -358,7 +327,31 @@ def _prefill_impl(state, trees, prompt, true_len, slot, stop, eos,
         eos=state["eos"].at[slot].set(eos),
         temp=state["temp"].at[slot].set(temp),
         key=state["key"].at[slot].set(key))
-    return out, first, active
+    return out, first, active, counters
+
+
+def _with_counts(impl, cfg):
+    """`impl` as a program: its results as they are, and after them the
+    model's `expert_counts` where it gives them."""
+    def fn(*args):
+        *results, counters = impl(*args, cfg=cfg)
+        if "expert_counts" in counters:
+            results.append(counters["expert_counts"])
+        return tuple(results)
+
+    return fn
+
+
+def _expert_load(counts):
+    """The span attributes of a program's expert counters (`counts`: what
+    the program answered after the engine's own results): assignments
+    that fell on the experts held here, and the fullest one's.  A model
+    without experts gives none."""
+    if not counts:
+        return {}
+    counts = np.asarray(counts[0])
+    return {"expert_tokens": int(counts.sum()),
+            "expert_load_max": int(counts.max())}
 
 
 # ---------------------------------------------------------------------------
@@ -371,21 +364,22 @@ class DecodeEngine:
 
     def __init__(self, model_or_params, config=None, auto_start=True,
                  **kw):
-        from ..models import generate as G
-
         self.config = cfg = config or DecodeConfig(**kw)
         if config is not None and kw:
             raise TypeError("pass either config= or keyword knobs, "
                             "not both")
-        params = (model_or_params
-                  if isinstance(model_or_params, G.DecodeParams)
-                  else G.build_decode_params(model_or_params))
+        params = model_or_params
+        if not hasattr(params, "trees"):
+            # a models/gpt.py GPT layer: the seam's first implementation
+            from ..models import generate as G
+
+            params = G.build_decode_params(params)
         self.params = params
         if cfg.max_len > params.cfg.max_seq_len:
             raise ValueError(
                 f"max_len {cfg.max_len} exceeds the model's "
                 f"max_seq_len {params.cfg.max_seq_len}")
-        self._trees = (params.emb, params.blocks, params.head)
+        self._trees = params.trees
         self.stats = DecodeStats(cfg.label, slots=cfg.slots)
         self.breaker = CircuitBreaker(
             failure_threshold=cfg.breaker_threshold,
@@ -423,9 +417,7 @@ class DecodeEngine:
         # `jit_decode_step` and `jit_prefill_b<bucket>` (a jitted
         # functools.partial reads `jit__unknown`)
         def named(name, impl):
-            def fn(*args):
-                return impl(*args, cfg=dec_cfg)
-
+            fn = _with_counts(impl, dec_cfg)
             fn.__name__ = fn.__qualname__ = name
             return jax.jit(fn, donate_argnums=(0,))
 
@@ -446,14 +438,17 @@ class DecodeEngine:
         import jax.numpy as jnp
 
         cfg = self.config
-        dec = self.params.cfg
-        head_dim = dec.hidden_size // dec.num_heads
-        # the resident layout: K and V transposed, cache depth minor
-        kv = (dec.num_layers, cfg.slots, dec.num_heads, head_dim,
-              cfg.max_len)
+        cache = self.params.cfg.cache_arrays(cfg.slots, cfg.max_len)
+        taken = sorted(set(cache) & set(_SLOT_KEYS))
+        if taken:
+            raise ValueError(
+                f"the model's cache arrays {taken} carry names the engine "
+                f"keeps for its own per-slot vectors {_SLOT_KEYS}")
+        self.stats.note_cache(
+            self.params.cfg.cache_kind,
+            sum(a.size * a.dtype.itemsize for a in cache.values()))
         return {
-            "k": jnp.zeros(kv, dec.dtype),
-            "v": jnp.zeros(kv, dec.dtype),
+            **cache,
             "pos": jnp.zeros(cfg.slots, jnp.int32),
             "active": jnp.zeros(cfg.slots, bool),
             "token": jnp.zeros(cfg.slots, jnp.int32),
@@ -474,13 +469,13 @@ class DecodeEngine:
         cfg = self.config
         n = 0
         for b in cfg.buckets:
-            self._state, _, _ = self._prefill_fns[b](
+            self._state, *_ = self._prefill_fns[b](
                 self._state, self._trees,
                 np.zeros((1, b), np.int32), np.int32(1), np.int32(0),
                 np.int32(1), np.int32(-1), np.float32(0.0),
                 np.zeros(2, np.uint32))
             n += 1
-        warm, _, _, _ = self._step_fn(
+        warm, *_ = self._step_fn(
             self._state, self._trees, np.zeros(cfg.slots, bool))
         # a buffer that a running program writes cannot be freed: wait
         # for the step, free the warm state, and only then build the
@@ -788,7 +783,10 @@ class DecodeEngine:
         `engine.prefill_book`; then `engine.decode_host`,
         `engine.decode_wait`, `engine.emit` and, every 64th step,
         `engine.telemetry`.  The `*_wait` spans are the host blocked on
-        the device's answer; all the others are the host's own work."""
+        the device's answer; all the others are the host's own work.  Where the model counts
+        expert assignments, the `*_wait` spans gain `expert_tokens` and
+        `expert_load_max` once the answer is in (in `spans()`; the
+        trace's copy of the span was opened before they were known)."""
         with self._lock:
             if not self._queue and not any(self._slot_req):
                 return 0                   # nothing to do: no span
@@ -861,15 +859,18 @@ class DecodeEngine:
             out = self._dispatch(call, meta, [req])
         if out is None:
             return False
-        self._state, first, active = out
+        self._state, first, active, *counts = out
         # the first token's time, as the stats and the budgets have it:
         # the program is launched, its answer not yet on the host
         now = cfg.clock()
         with RecordEvent("engine.prefill_wait", bucket=bucket, slot=slot,
                          rid=req.rid, queue_wait_s=admit_t - req.enqueue_t,
-                         turnaround_s=now - admit_t):
+                         turnaround_s=now - admit_t) as span:
             first = int(first)
             active = bool(active)
+            load = _expert_load(counts)
+            span.attrs.update(load)
+        self.stats.note_experts(**load)
         with RecordEvent("engine.prefill_book"):
             self._prefill_book(slot, req, first, active, pspan, now)
         return True
@@ -935,12 +936,16 @@ class DecodeEngine:
             out = self._dispatch(call, meta, waiting)
         if out is None:
             return 1
-        self._state, tokens, was_active, still = out
+        self._state, tokens, was_active, still, *counts = out
         now = cfg.clock()
-        with RecordEvent("engine.decode_wait", active=meta["active"]):
+        with RecordEvent("engine.decode_wait",
+                         active=meta["active"]) as span:
             tokens = np.asarray(tokens)
             was_active = np.asarray(was_active)
             still = np.asarray(still)
+            load = _expert_load(counts)
+            span.attrs.update(load)
+        self.stats.note_experts(**load)
         with RecordEvent("engine.emit"):
             self._emit(slot_reqs, tokens, was_active, still, now)
         if self.stats.decode_steps % 64 == 0:

@@ -277,6 +277,13 @@ class DecodeStats(ServingStats):
         self.tok_lat_dropped = 0
         self._first_t = None           # first/last token wall-clock
         self._last_t = None            # (engine clock) for tokens/s
+        self.cache = None              # {"kind", "bytes"}: note_cache
+        # programs that routed over experts held here, their assignments
+        # on those experts, and the fullest single expert's count of
+        # one program
+        self.expert_programs = 0
+        self.expert_tokens_total = 0
+        self.expert_load_max = 0
 
     # -- recording ------------------------------------------------------
     def note_prefill(self, ttft_s=None, now=None):
@@ -314,6 +321,21 @@ class DecodeStats(ServingStats):
             mon.counter("serving.decode_tokens").add(int(emitted))
             if self.slots:
                 mon.gauge("serving.decode_active_slots").set(active)
+
+    def note_cache(self, kind, nbytes):
+        """What the engine's cache is and holds, as its model says."""
+        self.cache = {"kind": kind, "bytes": int(nbytes)}
+
+    def note_experts(self, expert_tokens=None, expert_load_max=0):
+        """One program's (prefill or decode step) assignments on the
+        experts held here; a model without experts notes nothing."""
+        if expert_tokens is None:
+            return
+        with self._lock:
+            self.expert_programs += 1
+            self.expert_tokens_total += expert_tokens
+            self.expert_load_max = max(self.expert_load_max,
+                                       expert_load_max)
 
     def note_token_latency(self, latency_s):
         with self._lock:
@@ -357,6 +379,13 @@ class DecodeStats(ServingStats):
                     round(self._occupancy_sum / steps, 4) if steps
                     and self.slots else None),
             }
+            if self.cache is not None:
+                out["cache"] = dict(self.cache)
+            if self.expert_programs:
+                out["experts"] = {
+                    "programs": self.expert_programs,
+                    "tokens_total": self.expert_tokens_total,
+                    "load_max": self.expert_load_max}
             span = (self._last_t - self._first_t
                     if self._first_t is not None
                     and self._last_t is not None else None)

@@ -69,6 +69,25 @@ def test_kernels_phase_interpreted():
     assert r["topk_threshold"]["histogram_max_count_diff"] == 0
 
 
+def test_k2_phase_at_the_rehearsal_size():
+    import json
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "kimi-k2.6.json")) as f:
+        hf = json.load(f)
+    # the rehearsal's toy widths, but a latent the kernels tile
+    hf.update(hf["rehearse"])
+    hf.update(dtype="float32", kv_lora_rank=128, qk_rope_head_dim=64)
+    r = chip_smoke.phase_k2(
+        hf, slots=2, max_len=128, buckets=(16, 64),
+        prompt_lens=[5, 40, 17, 5], new_tokens=6,
+        decode_lengths=[1, 40, 128], tol=1e-4, gap_tol=1e-4)
+    assert r["requests"] == 4
+    assert r["tokens_equal_to_forward"] == "24/24"
+    assert r["cache"]["kind"].startswith("latent")
+    assert r["experts"]["tokens_total"] > 0
+
+
 def test_four_chips_phase_on_the_cpu_mesh():
     """The CPU-mesh twin of the four-chip phase: shards on four distinct
     devices, half a tensor-parallel leaf on each, first-step losses

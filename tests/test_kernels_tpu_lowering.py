@@ -149,7 +149,7 @@ PASSES_ALONG = {"parameter", "get-tuple-element", "tuple", "bitcast",
 _INSTR = re.compile(r"^\s*(?:ROOT )?%[\w.-]+ = (.*?) ([a-z][\w-]*)\(")
 
 
-def _large_results(text):
+def _large_results(text, layer_elems=LAYER_ELEMS):
     """[(opcode, line)] of every instruction of the compiled program
     whose result, or an element of whose tuple result, holds a layer's
     cache or more."""
@@ -160,7 +160,7 @@ def _large_results(text):
             continue
         sizes = [math.prod(int(n) for n in dims.split(",") if n)
                  for dims in re.findall(r"[a-z]\w*\[([\d,]*)\]", m.group(1))]
-        if max(sizes, default=0) >= LAYER_ELEMS:
+        if max(sizes, default=0) >= layer_elems:
             op = m.group(2)
             if op == "custom-call" and "tpu_custom_call" in line:
                 op = "tpu_custom_call"
@@ -309,3 +309,149 @@ def test_decode_dispatch_picks_the_kernel_on_default_serving_depth(
         q, k, v, pos=p, scale=1.0 / math.sqrt(64))).trace(
         q, kv, kv, pos).lower(lowering_platforms=("tpu",)).as_text()
     assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------
+# Kimi-K2 behind the same engine (ISSUE 29): the latent kernels and the
+# grouped product at the published widths, and the decode step and a
+# prefill compiled whole over the latent cache
+# ---------------------------------------------------------------------
+
+K2_SLOTS, K2_DEPTH, K2_LAYERS = 64, 1024, 2          # 1 dense + 1 expert
+K2_LAYER_ELEMS = K2_SLOTS * 576 * K2_DEPTH
+K2_CACHE_BYTES = K2_LAYERS * K2_LAYER_ELEMS * 2
+
+
+def _k2_cases():
+    from paddle_tpu.distributed.moe import routed_experts
+    from paddle_tpu.kernels.mla import latent_append, mla_decode
+
+    latent = ((2, 64, 576, 1024), BF16)
+    per_slot = ((64,), jnp.int32)
+    return {
+        "mla_decode": (
+            lambda ql, qr, c, n: mla_decode(ql, qr, c, 1, n, 0.1),
+            [((64, 64, 512), BF16), ((64, 64, 64), BF16), latent, per_slot],
+            ["mla_decode"]),
+        "latent_append": (
+            lambda c, new, pos: latent_append(c, new, 1, pos),
+            [latent, ((64, 576), BF16), per_slot], ["latent_append"]),
+        # a decode step's tokens over 12 of 384 experts of width 2048
+        "routed_experts": (
+            lambda h, r, b, gu, d: routed_experts(
+                h, r, b, (gu, d), 0, 384, 8, 2.827)[0],
+            [((256, 7168), BF16), ((7168, 384), BF16), ((384,), F32),
+             ((12, 7168, 4096), BF16), ((12, 2048, 7168), BF16)],
+            ["moe_grouped_mm"]),
+    }
+
+
+@pytest.mark.parametrize("name", ["mla_decode", "latent_append",
+                                  "routed_experts"])
+def test_k2_kernel_compiles_for_v5e(compiled_kernels, v5e, name):
+    fn, args, calls = _k2_cases()[name]
+    one = jax.sharding.SingleDeviceSharding(v5e[0])
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in args]
+    text = jax.jit(fn).trace(*avals).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert sorted(set(_mosaic_calls(text))) == calls
+
+
+@pytest.fixture(scope="module")
+def k2_programs(v5e):
+    """(decode step, prefill at bucket 256) of `DecodeEngine` over
+    `models/kimi_k2.py` at the published widths, one dense and one
+    expert layer, compiled for the described v5e with the state
+    donated, as the engine jits them."""
+    import functools
+    import json
+    import os
+
+    from paddle_tpu.models import kimi_k2
+    from paddle_tpu.serving import decode as D
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "kimi-k2.6.json")) as f:
+        cfg = dict(json.load(f), num_hidden_layers=K2_LAYERS)
+    kcfg = kimi_k2.K2Cfg.from_hf(cfg, max_seq_len=K2_DEPTH)
+    one = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    trees = kimi_k2.K2Params.from_flat(kcfg, {
+        n: aval(s, F32 if kind == "bias" else BF16)
+        for n, (s, kind) in kimi_k2.param_shapes(kcfg).items()}).trees
+    i32 = jnp.int32
+    state = {"latent": aval((K2_LAYERS, K2_SLOTS, 576, K2_DEPTH), BF16),
+             "pos": aval((K2_SLOTS,), i32),
+             "active": aval((K2_SLOTS,), bool),
+             "token": aval((K2_SLOTS,), i32), "stop": aval((K2_SLOTS,), i32),
+             "eos": aval((K2_SLOTS,), i32), "temp": aval((K2_SLOTS,), F32),
+             "key": aval((K2_SLOTS, 2), jnp.uint32)}
+
+    def compiled(impl, *args):
+        return jax.jit(functools.partial(impl, cfg=kcfg),
+                       donate_argnums=(0,)).trace(
+            state, trees, *args).lower(
+            lowering_platforms=("tpu",)).compile()
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(backend, "is_tpu_backend", lambda: True)
+        return {
+            "decode_step": compiled(D._decode_step_impl,
+                                    aval((K2_SLOTS,), bool)),
+            "prefill_b256": compiled(
+                D._prefill_impl, aval((1, 256), i32), aval((), i32),
+                aval((), i32), aval((), i32), aval((), i32),
+                aval((), F32), aval((2,), jnp.uint32)),
+        }
+
+
+@pytest.mark.parametrize("program,in_place", [
+    ("decode_step", set()),
+    ("prefill_b256", {"dynamic-update-slice", "fusion"})])
+def test_k2_program_moves_no_layer_of_the_latent_cache(k2_programs, program,
+                                                       in_place):
+    text = k2_programs[program].as_text()
+    large = [(op, line) for op, line in _large_results(text, K2_LAYER_ELEMS)
+             # weights are larger than a layer of this small cache: only
+             # results with the cache's own dimensions are judged
+             if f"[{K2_LAYERS},{K2_SLOTS},576,{K2_DEPTH}]" in line]
+    assert large, "the cache is not in the program at all"
+    odd = [(op, line) for op, line in large
+           if op not in PASSES_ALONG | in_place]
+    assert not odd, odd
+    for op, line in large:
+        if op == "fusion":
+            # the prefill's one write, in place: the fusion's root is a
+            # dynamic-update-slice of the donated cache itself (the
+            # stacking of the layers' latents may be fused into it)
+            name = line.split(" = ")[0].lstrip("ROOT %")
+            called = re.search(
+                rf"%{re.escape(name)} = [^\n]*calls=%([\w.-]+)", text)
+            body = text[text.index(f"%{called.group(1)} ("):]
+            root = next(ln for ln in body.splitlines() if "ROOT" in ln)
+            assert re.search(r"dynamic-update-slice\(%param_0[.\d]*,",
+                             root), root
+    # no slice of it either: nothing holds one layer, or one slot's part
+    for dims in (f"[{K2_SLOTS},576,{K2_DEPTH}]",
+                 f"[1,{K2_SLOTS},576,{K2_DEPTH}]"):
+        assert dims not in text, dims
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_b256"])
+def test_k2_program_holds_one_copy_of_the_latent_cache(k2_programs,
+                                                       program):
+    mem = k2_programs[program].memory_analysis()
+    assert mem.alias_size_in_bytes >= K2_CACHE_BYTES
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 1 << 20
+    # temporaries: activations of 64 tokens (256 in the prefill) at these
+    # widths, never a second cache
+    assert mem.temp_size_in_bytes < 0.75 * K2_CACHE_BYTES
+
+
+def test_k2_decode_step_names_its_calls(k2_programs):
+    calls = set(_mosaic_calls(k2_programs["decode_step"].as_text()))
+    assert calls == {"latent_append", "mla_decode", "moe_grouped_mm"}

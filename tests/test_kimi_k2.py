@@ -1,0 +1,533 @@
+"""Kimi-K2 (DeepSeek-V3's layer) behind the decode engine's seam (ISSUE
+29), on the CPU at toy widths with seeded random weights, each test
+against the plain reference of `benchmarks/configs/kimi-k2.6.py` (which
+imports nothing of paddle_tpu) or a few lines of numpy.
+
+The toy model is float32, so what separates program and reference is
+re-association only: the absorbed attention (`q_nope W_uk^T` against the
+latent) against K and V built per head, the grouped product against
+every expert over every token, XLA's fusions.  Logits of size one agree
+to a few 1e-6; the tolerances below leave a hundred times that."""
+
+import glob
+import importlib.util
+import json
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu import monitor, profiler
+from paddle_tpu.distributed.moe import routed_experts, sigmoid_top_k
+from paddle_tpu.kernels import grouped_mm, mla
+from paddle_tpu.kernels.attention import resident_mla_attention
+from paddle_tpu.models import kimi_k2
+from paddle_tpu.models.gpt import GPT, GPTConfig
+from paddle_tpu.serving.decode import DecodeConfig, DecodeEngine
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CONFIG = os.path.join(ROOT, "benchmarks", "configs", "kimi-k2.6")
+TOL = 2e-4
+
+
+def _module():
+    spec = importlib.util.spec_from_file_location("kimi_k2_6_config",
+                                                  CONFIG + ".py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _toy_cfg(**over):
+    with open(CONFIG + ".json") as f:
+        cfg = json.load(f)
+    cfg.update(cfg["rehearse"])
+    cfg.update(dtype="float32", **over)
+    return cfg
+
+
+class Toy:
+    def __init__(self, seed=29, max_len=64, **over):
+        self.M = _module()
+        self.cfg = _toy_cfg(**over)
+        self.kcfg = kimi_k2.K2Cfg.from_hf(self.cfg, max_seq_len=max_len)
+        with jax.default_matmul_precision("highest"):
+            self.flat = self.M.init_params(self.cfg, seed)
+        self.params = kimi_k2.K2Params.from_flat(self.kcfg, self.flat)
+        self.ref = self.M.ReferenceLM(self.cfg, seed, max_len,
+                                      params=self.flat)
+        self.ref.PAD_TO = 16
+
+    def engine(self, **kw):
+        kw.setdefault("slots", 2)
+        kw.setdefault("max_len", self.kcfg.max_seq_len)
+        kw.setdefault("buckets", (16, 32))
+        kw.setdefault("watchdog_stall_s", 60.0)
+        kw.setdefault("label", f"k2_{time.time_ns() % 1000000}")
+        return DecodeEngine(self.params, config=DecodeConfig(**kw),
+                            auto_start=False)
+
+
+@pytest.fixture(scope="module")
+def toy():
+    return Toy()
+
+
+@pytest.fixture(autouse=True)
+def _clean_state():
+    monitor.disable()
+    monitor.reset()
+    profiler.reset_profiler()
+    yield
+    monitor.disable()
+    monitor.reset()
+    profiler.reset_profiler()
+
+
+def _drain(eng, futs, max_steps=600):
+    for _ in range(max_steps):
+        if all(f.done() for f in futs):
+            return
+        eng.step()
+    raise AssertionError("engine did not drain")
+
+
+# ---------------------------------------------------------------------
+# the model's side of the seam against the reference
+# ---------------------------------------------------------------------
+
+def test_param_shapes_are_the_benchmarks(toy):
+    want = {n: tuple(s) for n, s, _ in toy.M.param_specs(toy.cfg)}
+    have = {n: tuple(s) for n, (s, _) in
+            kimi_k2.param_shapes(toy.kcfg).items()}
+    assert want == have
+    mine = kimi_k2.init_params(toy.kcfg, jax.random.PRNGKey(0))
+    assert {n: v.shape for n, v in mine.items()} == want
+    assert mine["layers.1.router_bias"].dtype == jnp.float32
+    assert float(jnp.abs(mine["layers.1.router_bias"]).max()) > 0
+
+
+def test_yarn_frequencies_are_the_references(toy):
+    got = kimi_k2.yarn_inv_freq(toy.kcfg.qk_rope_head_dim,
+                                toy.kcfg.rope_theta, toy.kcfg.yarn)
+    np.testing.assert_allclose(got, toy.M.yarn_frequencies(toy.cfg),
+                               rtol=1e-6)
+    # the published sizes: the fastest lanes keep their frequency, the
+    # slowest are divided by the factor
+    with open(CONFIG + ".json") as f:
+        full = kimi_k2.K2Cfg.from_hf(json.load(f))
+    inv = kimi_k2.yarn_inv_freq(64, full.rope_theta, full.yarn)
+    plain = full.rope_theta ** (-np.arange(0, 64, 2) / 64)
+    np.testing.assert_allclose(inv[0], plain[0], rtol=1e-6)
+    np.testing.assert_allclose(inv[-1], plain[-1] / 64, rtol=1e-6)
+    assert full.softmax_scale == pytest.approx(
+        192 ** -0.5 * (0.1 * np.log(64) + 1) ** 2)
+
+
+def test_absorbed_decode_through_the_latent_cache_equals_the_full_forward(
+        toy):
+    """Prefill (first form of attention, latent written into the slot),
+    then twelve steps of the absorbed form through the cache, fed the
+    reference's own tokens: every step's logits against the reference's
+    full forward pass over the whole sequence."""
+    k, trees = toy.kcfg, toy.params.trees
+    rng = np.random.default_rng(0)
+    ids = rng.integers(0, k.vocab_size, 11 + 12).astype(np.int32)
+    want = np.asarray(toy.ref.logits(np.pad(ids, (0, 32 - ids.size))))
+    cache = k.cache_arrays(3, 64)
+    prompt = np.zeros((1, 16), np.int32)
+    prompt[0, :11] = ids[:11]
+    with jax.default_matmul_precision("highest"):
+        cache, hidden, counters = jax.jit(k.prefill)(
+            trees, cache, prompt, np.int32(11), np.int32(1))
+        got = [np.asarray(k.head(trees, hidden))[0]]
+        assert int(counters["expert_counts"].sum()) > 0
+        step = jax.jit(k.decode)
+        for i in range(11, ids.size - 1):
+            token = np.zeros(3, np.int32)
+            token[1] = ids[i]
+            pos = np.array([0, i, 0], np.int32)
+            cache, hidden, _ = step(trees, cache, token, pos)
+            got.append(np.asarray(k.head(trees, hidden))[1])
+    np.testing.assert_allclose(np.stack(got), want[10:ids.size - 1],
+                               atol=TOL, rtol=0)
+    # the slot holds [c_kv ; k_rope] of its positions, depth minor, and
+    # the other slots' prompt regions were left alone
+    latent = np.asarray(cache["latent"])
+    assert latent.shape == (3, 3, 32 + 8, 64)
+    assert np.abs(latent[:, 1, :, :ids.size - 1]).min(axis=1).max() > 0
+    assert not latent[:, 0, :, 1:].any() and not latent[:, 2, :, 1:].any()
+
+
+def test_engine_serves_what_the_reference_computes(toy):
+    """Five requests through two slots, so three of them join a slot
+    that another has released, mid-stream: every served token is the
+    reference's first choice over prompt and served tokens together."""
+    eng = toy.engine()
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, toy.kcfg.vocab_size, n).astype(np.int32)
+               for n in (5, 20, 9, 30, 12)]
+    try:
+        futs = [eng.submit(p, 10 + 3 * i) for i, p in enumerate(prompts)]
+        _drain(eng, futs)
+        summary = eng.summary()
+    finally:
+        eng.close()
+    for p, f in zip(prompts, futs):
+        served = f.result(timeout=0)
+        assert toy.ref.token_gaps(p, served).max() <= TOL
+    assert summary["decode"]["prefill_steps"] == 5
+    assert summary["decode"]["cache"] == {
+        "kind": toy.kcfg.cache_kind, "bytes": 3 * 2 * 40 * 64 * 4}
+
+
+def test_gpt_runs_through_the_same_seam():
+    np.random.seed(29)
+    model = GPT(GPTConfig(vocab_size=97, hidden_size=48, num_layers=2,
+                          num_heads=4, max_seq_len=32, dropout=0.0))
+    eng = DecodeEngine(model, config=DecodeConfig(
+        slots=2, max_len=32, buckets=(8,), label="k2_gpt_seam"),
+        auto_start=False)
+    try:
+        fut = eng.submit(np.arange(5), 4)
+        _drain(eng, [fut])
+        decode = eng.summary()["decode"]
+    finally:
+        eng.close()
+    # the model's cache beside the engine's own per-slot vectors
+    assert set(eng._state) == {"k", "v", "pos", "active", "token", "stop",
+                               "eos", "temp", "key"}
+    assert fut.result(timeout=0).size == 4
+    assert decode["cache"]["kind"].startswith("kv [")
+    assert decode["cache"]["bytes"] == 2 * 2 * 2 * 48 * 32 * 4
+    assert "experts" not in decode
+
+
+def test_a_cache_array_may_not_take_one_of_the_engines_names(toy):
+    """The engine keeps the model's cache arrays beside its own per-slot
+    vectors in one donated dict: a model whose cache is called `pos`
+    would silently lose it."""
+    class Clash(kimi_k2.K2Cfg):
+        def cache_arrays(self, slots, max_len):
+            return {"pos": super().cache_arrays(slots, max_len)["latent"]}
+
+    params = kimi_k2.K2Params(toy.params.trees, Clash(*toy.kcfg))
+    with pytest.raises(ValueError, match="names the engine keeps"):
+        DecodeEngine(params, config=DecodeConfig(
+            slots=2, max_len=64, buckets=(16,), prewarm=False,
+            label="k2_clash"), auto_start=False)
+
+
+@pytest.mark.parametrize("mscale, all_dim", [(0.707, 1.0), (1.0, 0.5)])
+def test_rotary_scale_is_the_published_ratio(mscale, all_dim):
+    """cos and sin are scaled by yarn_get_mscale(factor, mscale) /
+    yarn_get_mscale(factor, mscale_all_dim), as the reference has it; K2's
+    own two are equal, so only a configuration where they differ shows a
+    wrong formula."""
+    base = _toy_cfg()["rope_scaling"]
+    t = Toy(rope_scaling=dict(base, mscale=mscale, mscale_all_dim=all_dim))
+    ids = np.random.default_rng(4).integers(
+        0, t.kcfg.vocab_size, 16).astype(np.int32)
+    want = np.asarray(t.ref.logits(ids))[12]
+    with jax.default_matmul_precision("highest"):
+        _, hidden, _ = jax.jit(t.kcfg.prefill)(
+            t.params.trees, t.kcfg.cache_arrays(2, 64), ids[None],
+            np.int32(13), np.int32(0))
+        got = np.asarray(t.kcfg.head(t.params.trees, hidden))[0]
+    np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+
+
+# ---------------------------------------------------------------------
+# the router and the expert layer that is told its experts
+# ---------------------------------------------------------------------
+
+def test_router_chooses_by_score_plus_bias_and_weighs_by_score():
+    h = jnp.eye(3, dtype=jnp.float32)
+    logits = np.array([[2.0, 1.0, 0.0, -1.0, -2.0],
+                       [0.0, 0.0, 0.0, 3.0, -3.0],
+                       [1.0, 1.1, 1.2, 1.3, 1.4]], np.float32)
+    bias = np.array([-0.5, 0.0, 0.3, 0.0, 0.0], np.float32)
+    experts, weights = sigmoid_top_k(h, jnp.asarray(logits),
+                                     jnp.asarray(bias), 2, 2.827)
+    sig = 1.0 / (1.0 + np.exp(-logits))
+    for row in range(3):
+        want = np.argsort(-(sig[row] + bias))[:2]
+        assert sorted(np.asarray(experts[row])) == sorted(want)
+        chosen = sig[row][np.asarray(experts[row])]
+        np.testing.assert_allclose(weights[row],
+                                   2.827 * chosen / chosen.sum(), rtol=1e-6)
+    # row 0: the bias changed the choice (by score alone: experts 0, 1),
+    # and the weights are of the scores, not of score + bias
+    assert sorted(np.asarray(experts[0])) == [1, 2]
+    np.testing.assert_allclose(np.asarray(weights).sum(axis=1), 2.827,
+                               rtol=1e-6)
+
+
+def _dense_moe(h, router, bias, gate_up, down, top_k, scale, first=0,
+               n_held=None):
+    """Every token through its chosen experts, one at a time, in numpy
+    float64: what `routed_experts` must equal."""
+    h, router, gate_up, down = (np.asarray(a, np.float64)
+                                for a in (h, router, gate_up, down))
+    sig = 1.0 / (1.0 + np.exp(-(h @ router)))
+    n_held = gate_up.shape[0] if n_held is None else n_held
+    y = np.zeros_like(h)
+    counts = np.zeros(n_held, int)
+    for t in range(h.shape[0]):
+        chosen = np.argsort(-(sig[t] + np.asarray(bias)),
+                            kind="stable")[:top_k]
+        total = sig[t][chosen].sum()
+        for e in chosen:
+            if first <= e < first + n_held:
+                gu = h[t] @ gate_up[e - first]
+                f = gu.size // 2
+                act = gu[:f] / (1.0 + np.exp(-gu[:f])) * gu[f:]
+                y[t] += scale * sig[t][e] / total * (act @ down[e - first])
+                counts[e - first] += 1
+    return y, counts
+
+
+def _layer_weights(rng, d=24, f=16, experts=16):
+    return dict(
+        router=jnp.asarray(rng.standard_normal((d, experts)), jnp.float32),
+        bias=jnp.asarray(0.3 * rng.standard_normal(experts), jnp.float32),
+        gate_up=jnp.asarray(rng.standard_normal((experts, d, 2 * f))
+                            / np.sqrt(d), jnp.float32),
+        down=jnp.asarray(rng.standard_normal((experts, f, d))
+                         / np.sqrt(f), jnp.float32))
+
+
+def test_routed_experts_computes_its_own_experts_part_only():
+    rng = np.random.default_rng(3)
+    w = _layer_weights(rng)
+    h = jnp.asarray(rng.standard_normal((13, 24)), jnp.float32)
+    valid = jnp.arange(13) < 11
+    with jax.default_matmul_precision("highest"):
+        y, counts = jax.jit(
+            lambda h, valid: routed_experts(
+                h, w["router"], w["bias"], (w["gate_up"][4:8],
+                                            w["down"][4:8]),
+                4, 16, 3, 2.5, valid=valid))(h, valid)
+    want, want_counts = _dense_moe(h[:11], w["router"], w["bias"],
+                                   w["gate_up"][4:8], w["down"][4:8],
+                                   3, 2.5, first=4)
+    np.testing.assert_allclose(np.asarray(y)[:11], want, atol=1e-5)
+    assert not np.asarray(y)[11:].any()       # padding makes no assignment
+    assert list(np.asarray(counts)) == list(want_counts)
+    with pytest.raises(ValueError):
+        routed_experts(h, w["router"], w["bias"],
+                       (w["gate_up"][:4], w["down"][:4]), 14, 16, 3, 2.5)
+
+
+def test_no_token_is_dropped_at_a_planted_imbalance():
+    """The bias sends every token to held expert 2 first: GShard's
+    capacity would cut all but a few; here each one is computed."""
+    rng = np.random.default_rng(4)
+    w = _layer_weights(rng)
+    bias = w["bias"].at[2].set(10.0)
+    h = jnp.asarray(rng.standard_normal((64, 24)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        y, counts = routed_experts(h, w["router"], bias,
+                                   (w["gate_up"][:4], w["down"][:4]),
+                                   0, 16, 2, 2.827)
+    want, want_counts = _dense_moe(h, w["router"], bias, w["gate_up"][:4],
+                                   w["down"][:4], 2, 2.827)
+    assert int(counts[2]) == 64 and list(np.asarray(counts)) == \
+        list(want_counts)
+    np.testing.assert_allclose(np.asarray(y), want, atol=1e-5)
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """16 routed experts in 4 shares of 4: the four chips' routed parts,
+    with the shared expert counted once, are the reference's whole
+    layer (the reference holding all 16)."""
+    M = _module()
+    cfg = _toy_cfg(n_routed_experts=16, n_routed_experts_deployment=16)
+    rng = np.random.default_rng(5)
+    w = _layer_weights(rng, d=cfg["hidden_size"],
+                       f=cfg["moe_intermediate_size"])
+    f = cfg["moe_intermediate_size"]
+    shared_gu = jnp.asarray(rng.standard_normal((cfg["hidden_size"], 2 * f))
+                            / 8, jnp.float32)
+    shared_down = jnp.asarray(rng.standard_normal((f, cfg["hidden_size"]))
+                              / 4, jnp.float32)
+    h = jnp.asarray(rng.standard_normal((37, cfg["hidden_size"])),
+                    jnp.float32)
+    mm = M._product("float32")
+    whole, _ = M._moe(cfg, {"router": w["router"], "router_bias": w["bias"],
+                            "experts_gate_up": w["gate_up"],
+                            "experts_down": w["down"],
+                            "shared_gate_up": shared_gu,
+                            "shared_down": shared_down}, h, mm, None)
+    with jax.default_matmul_precision("highest"):
+        parts, counts = zip(*[routed_experts(
+            h, w["router"], w["bias"],
+            (w["gate_up"][s:s + 4], w["down"][s:s + 4]), s, 16,
+            cfg["num_experts_per_tok"], cfg["routed_scaling_factor"])
+            for s in (0, 4, 8, 12)])
+        shared = kimi_k2._swiglu(h, shared_gu, shared_down)
+    np.testing.assert_allclose(np.asarray(sum(parts) + shared),
+                               np.asarray(whole), atol=1e-5)
+    # every assignment fell on exactly one share
+    assert sum(int(c.sum()) for c in counts) == \
+        37 * cfg["num_experts_per_tok"]
+    # and one share alone is the reference's share
+    one, flips = M._moe(
+        dict(cfg, n_routed_experts=4, first_expert=8),
+        {"router": w["router"], "router_bias": w["bias"],
+         "experts_gate_up": w["gate_up"][8:12],
+         "experts_down": w["down"][8:12],
+         "shared_gate_up": shared_gu, "shared_down": shared_down},
+        h, mm, "no_shared_expert")
+    np.testing.assert_allclose(np.asarray(parts[2]), np.asarray(one),
+                               atol=1e-5)
+    # the reference's count of routings that a bfloat16 rounding of the
+    # router's input changes: a token's held experts change only where
+    # its chosen set does
+    flips = np.asarray(flips)
+    assert flips.shape == (37, 2) and not (flips[:, 1] & ~flips[:, 0]).any()
+
+
+# ---------------------------------------------------------------------
+# the kernels, interpreted, against the XLA mathematics
+# ---------------------------------------------------------------------
+
+def test_mla_kernels_equal_their_xla_mathematics():
+    rng = np.random.default_rng(6)
+    s, heads, rank, rope, t, layers = 3, 8, 128, 64, 384, 2
+    ql = jnp.asarray(rng.standard_normal((s, heads, rank)), jnp.float32)
+    qr = jnp.asarray(rng.standard_normal((s, heads, rope)), jnp.float32)
+    latent = jnp.asarray(rng.standard_normal((layers, s, rank + rope, t)),
+                         jnp.float32)
+    new = jnp.asarray(rng.standard_normal((s, rank + rope)), jnp.float32)
+    pos = jnp.asarray([5, 130, 383], jnp.int32)
+    want_o, want_latent = resident_mla_attention(ql, qr, new, latent, 1,
+                                                 pos, 0.07)
+    got_latent = mla.latent_append(latent, new, 1, pos)
+    np.testing.assert_array_equal(np.asarray(got_latent),
+                                  np.asarray(want_latent))
+    changed = np.asarray(got_latent != latent)
+    assert changed.sum() == s * (rank + rope) and not changed[0].any()
+    for block_k in (128, 384):
+        got_o = mla.mla_decode(ql, qr, got_latent, 1, pos + 1, 0.07,
+                               block_k=block_k)
+        np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o),
+                                   atol=2e-5)
+    with pytest.raises(ValueError):
+        mla.mla_decode(ql, qr, got_latent, 1, pos + 1, 0.07, block_k=256)
+
+
+@pytest.mark.parametrize("counts", [
+    [5, 0, 130, 1, 0, 7],         # a group over a row-tile boundary
+    [100, 28, 1, 1, 126, 0],      # a row tile shared by five groups
+    [256, 0, 0, 0, 0, 0],         # every row on one group
+    [0, 0, 0, 0, 0, 0]])          # no assignment: no visit, no write
+def test_moe_grouped_mm_equals_ragged_dot(counts):
+    rng = np.random.default_rng(8)
+    m, k, n = 256, 256, 384
+    rows = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
+    weights = jnp.asarray(rng.standard_normal((len(counts), k, n)),
+                          jnp.float32)
+    c = jnp.asarray(counts, jnp.int32)
+    got = jax.jit(grouped_mm.moe_grouped_mm)(rows, weights, c)
+    with jax.default_matmul_precision("highest"):
+        want = jax.lax.ragged_dot(rows, weights, c)
+    live = sum(counts)
+    # float32 sums over 256 terms of size one, in another order
+    np.testing.assert_allclose(np.asarray(got)[:live],
+                               np.asarray(want)[:live], atol=2e-4)
+    with pytest.raises(ValueError):
+        grouped_mm.moe_grouped_mm(rows[:100], weights, c)
+
+
+def test_routed_experts_through_the_kernel_equals_the_dense_layer():
+    """64 tokens x top 2 = 128 rows, one row tile, widths of whole
+    lanes: `routed_experts` on `moe_grouped_mm` (interpreted), at a
+    planted imbalance too."""
+    rng = np.random.default_rng(9)
+    w = _layer_weights(rng, d=128, f=128)
+    h = jnp.asarray(rng.standard_normal((64, 128)), jnp.float32)
+    for bias in (w["bias"], w["bias"].at[5].set(10.0)):
+        fn = jax.jit(lambda h, bias: routed_experts(
+            h, w["router"], bias, (w["gate_up"][4:8], w["down"][4:8]),
+            4, 16, 2, 2.827, use_kernel=True))
+        assert "name=moe_grouped_mm" in str(jax.make_jaxpr(fn)(h, bias))
+        y, counts = fn(h, bias)
+        want, want_counts = _dense_moe(h, w["router"], bias,
+                                       w["gate_up"][4:8], w["down"][4:8],
+                                       2, 2.827, first=4)
+        assert list(np.asarray(counts)) == list(want_counts)
+        np.testing.assert_allclose(np.asarray(y), want, atol=2e-4)
+
+
+def test_engine_through_the_kernels_serves_the_references_tokens(
+        monkeypatch):
+    """The engine's decode step on `latent_append` and `mla_decode`
+    (interpreted), at a latent the kernels tile (rank 128, rope 64,
+    depth 128): still the reference's tokens, also in a refilled slot."""
+    monkeypatch.setenv("PADDLE_TPU_FORCE_FLASH_DECODE", "1")
+    toy = Toy(max_len=128, kv_lora_rank=128, qk_rope_head_dim=64,
+              num_hidden_layers=2, max_position_embeddings=128)
+    eng = toy.engine(slots=1, buckets=(16,))
+    step = str(jax.make_jaxpr(toy.kcfg.decode)(
+        toy.params.trees, jax.eval_shape(
+            lambda: toy.kcfg.cache_arrays(1, 128)),
+        np.zeros(1, np.int32), np.zeros(1, np.int32)))
+    assert "name=latent_append" in step and "name=mla_decode" in step
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(0, toy.kcfg.vocab_size, n).astype(np.int32)
+               for n in (12, 5)]
+    try:
+        futs = [eng.submit(p, 6) for p in prompts]
+        _drain(eng, futs)
+    finally:
+        eng.close()
+    for p, f in zip(prompts, futs):
+        assert toy.ref.token_gaps(p, f.result(timeout=0)).max() <= TOL
+
+
+# ---------------------------------------------------------------------
+# tracing: the expert counts on the engine's spans and in its stats
+# ---------------------------------------------------------------------
+
+def test_wait_spans_and_summary_carry_the_expert_counts(toy, tmp_path):
+    eng = toy.engine()
+    rng = np.random.default_rng(8)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        futs = [eng.submit(rng.integers(0, 211, size=6 + i), 5)
+                for i in range(3)]
+        _drain(eng, futs)
+    finally:
+        jax.profiler.stop_trace()
+    summary = eng.summary()["decode"]
+    eng.close()
+    assert glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                     recursive=True)
+    spans = profiler.spans("engine.")
+    held, k = toy.kcfg.experts_held, toy.kcfg.num_experts_per_tok
+    expert_layers = toy.kcfg.num_layers - toy.kcfg.first_k_dense
+    waits = {name: [a for n, _, _, a in spans if n == name]
+             for name in ("engine.decode_wait", "engine.prefill_wait")}
+    assert len(waits["engine.prefill_wait"]) == 3
+    assert len(waits["engine.decode_wait"]) == summary["decode_steps"]
+    for name, attrs in waits.items():
+        for a in attrs:
+            assert 0 <= a["expert_load_max"] <= a["expert_tokens"]
+            assert a["expert_tokens"] <= held * a["expert_load_max"]
+    for a in waits["engine.decode_wait"]:
+        # every slot routes, live or not: at most slots x top_k x layers
+        assert a["expert_tokens"] <= 2 * k * expert_layers
+        assert "active" in a
+    for a, fut in zip(waits["engine.prefill_wait"], futs):
+        assert {"bucket", "slot", "rid", "queue_wait_s"} <= set(a)
+    total = sum(a["expert_tokens"] for attrs in waits.values()
+                for a in attrs)
+    assert summary["experts"] == {
+        "programs": 3 + summary["decode_steps"], "tokens_total": total,
+        "load_max": max(a["expert_load_max"] for attrs in waits.values()
+                        for a in attrs)}
+    assert total > 0
