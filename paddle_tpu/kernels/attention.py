@@ -263,7 +263,8 @@ def dot_product_attention(q, k, v, mask=None, dropout_p=0.0, is_causal=False,
         # once the S^2 materialisation starts thrashing HBM (measured
         # crossover on v5e: 512 -> XLA, 2048 -> flash by ~20%).
         # PADDLE_TPU_FORCE_FLASH=0/1 overrides the heuristic for
-        # on-chip A/B runs (same role as PADDLE_TPU_FLASH_BLOCK).
+        # on-chip A/B runs.  (The kernel's tiling is not a switch: it
+        # follows from the shapes, flash_attention.flash_tiling.)
         from .backend import is_tpu_backend
 
         env = os.environ.get("PADDLE_TPU_FORCE_FLASH", "")

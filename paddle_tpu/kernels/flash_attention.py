@@ -2,22 +2,53 @@
 
 The native-kernel tier of the attention stack: replaces the reference's
 hand-fused CUDA attention (/root/reference/paddle/fluid/operators/fused/
-multihead_matmul_op.cu, operators/math/bert_encoder_functor.cu) with an
-online-softmax tiled kernel that never materialises the [S, S] score
-matrix in HBM.
+multihead_matmul_op.cu, operators/math/bert_encoder_functor.cu) with
+tiled kernels that never materialise the [S, S] score matrix in HBM.
 
-Structure (canonical TPU pipelining shape): the grid is
-(batch*heads, q blocks, k blocks) with the k axis innermost and marked
-"arbitrary" so Mosaic double-buffers the k/v block DMAs against compute.
-Softmax statistics (running max m, running sum l) and the output
-accumulator live in VMEM scratch that persists across the k steps of one
-q block; the causal triangle prunes dead (qi, ki) tiles with pl.when.
-Matmuls run in the input dtype (bf16 → full-rate MXU) accumulating f32
-via preferred_element_type.
+Structure.  `flash_tiling` decides everything from (seq, head_dim,
+causal).  A grid step of `flash_fwd` and `flash_dq` is one block of
+queries (`block_q` rows) of one head against the keys a grid step holds
+(`block_major` rows of K and V): the whole sequence where a block's
+strip of scores against it fits VMEM, which at the sizes this package
+trains and serves it does, else blocks of it streamed through the
+innermost, "arbitrary" grid axis, the running max, sum and accumulator
+passing through VMEM scratch once a grid step.  `flash_dkv` mirrors
+it: a grid step is one block of keys (`block_k`) against the resident
+q and dO.
 
-Backward recomputes scores blockwise from the saved logsumexp (no S×S
-residual): one kernel for dq (grid k-innermost) and one for dk/dv (grid
-q-innermost) — the flash-attention-2 decomposition.
+Inside a step nothing loops and every shape is static.  With
+causal=True the step branches once on where the diagonal lies
+(`_strips`) and computes the live part only (`_key_strips`,
+`_query_strips`): the keys before the diagonal tile in one product for
+all the block's rows, and inside the diagonal tile, chunk of keys by
+chunk of keys, the rows from that chunk's own on.  So a chunk of K is
+loaded into the MXU once for every query that meets it (a product with
+few rows a load is bound by the loads), the tiles above the diagonal
+are never computed, and only the strips inside the diagonal tile pay
+the mask's select (in the forward, only the chunk x chunk tiles the
+diagonal crosses), against a comparison formed once a step.  The forward's softmax runs
+between its two rounds of products, chunk of rows by chunk of rows over
+whatever strips reach it: a row's maximum and sum are formed once, with
+no rescaling of an accumulator (a column of per-row numbers costs the
+vector unit as much as a 128-wide tile of scores, every time it is
+touched).  `flash_dkv` computes the scores transposed ([keys, queries]),
+so that its products need no transposed operand and the statistics
+broadcast down the sublanes as they lie.  Matmuls run in the input
+dtype (bf16 -> full-rate MXU) accumulating f32 via
+preferred_element_type; exp, max and sum are f32; masked scores are
+NEG_INF.
+
+Row statistics (the saved logsumexp, and delta = rowsum(dO * O)) cross
+the kernel boundary as [batch*heads, 1, seq] float32: the sequence is
+the minor dimension, held in 128-lane tiles, a block of them is
+block_q * 4 bytes.  (A trailing axis of 1 is padded to 128 lanes on the
+chip: 512 KB a 1024-row block, and a copy on XLA's side for each.)
+`flash_fwd` turns its columns of row sums into rows on the way out,
+`flash_dq` turns the rows back once a step.
+
+Backward recomputes scores from the saved logsumexp (no SxS residual):
+one kernel for dq and one for dk/dv, the flash-attention-2
+decomposition.
 
 On non-TPU backends the same kernels run in interpret mode, which is how
 tests/test_flash_attention.py checks numerics vs the XLA composition.
@@ -25,6 +56,7 @@ tests/test_flash_attention.py checks numerics vs the XLA composition.
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -33,34 +65,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .backend import interpret
 
-# 1024x1024 tiles: measured fastest on v5e (r4 flash_block_ab2,
-# b8 h16 s2048 d64 fwd+bwd chained): 512x512 17.48ms, 1024x512 16.62,
-# 2048x512 17.07, 1024x1024 14.80 (2048x1024 fails to compile).  The
-# f32 score block is 4 MB — fits Mosaic's default 16MB scoped budget
-# (this file sets no vmem_limit_bytes, unlike fused_bottleneck); shorter
-# k loops beat the extra DMA overlap the 512 tiling bought.  Override
-# per-call via flash_attention(block_q=..., block_k=...) or globally
-# via PADDLE_TPU_FLASH_BLOCK=<q>x<k> for on-chip A/B runs.
-DEFAULT_BLOCK_Q = 1024
-DEFAULT_BLOCK_K = 1024
 _LANES = 128
 NEG_INF = -1e30
 
-
-def _env_blocks():
-    import os
-
-    v = os.environ.get("PADDLE_TPU_FLASH_BLOCK")
-    if not v:
-        return None
-    try:
-        bq, _, bk = v.partition("x")
-        return int(bq), int(bk or bq)
-    except ValueError:
-        raise ValueError(
-            f"PADDLE_TPU_FLASH_BLOCK={v!r} is malformed; expected "
-            f"'<block_q>x<block_k>' (e.g. 512x512) or a single size"
-        ) from None
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
 
 
 def _vmem_spec(*args):
@@ -76,110 +85,286 @@ def _compiler_params():
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _causal_mask(s, qi, ki, block_q, block_k):
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(q_pos >= k_pos, s, NEG_INF)
+# --------------------------------------------------------------------------
+# tiling
+# --------------------------------------------------------------------------
+
+class FlashTiling(NamedTuple):
+    """How the three training kernels walk one head's score matrix."""
+    block_q: int        # q rows of a grid step of `flash_fwd`, `flash_dq`
+    block_k: int        # k rows of a grid step of `flash_dkv`
+    chunk_q: int        # edge of the tiles the diagonal is followed in,
+    chunk_k: int        # by `flash_fwd`/`flash_dq` and by `flash_dkv`
+    block_major: int    # rows of the other operand a grid step holds
+    tiles_visited: int  # chunk_q x chunk_q tiles the forward pass computes
+    tiles_total: int    # ... of the whole square
+
+
+# What one operand of a grid step may take of VMEM: a block's strip of
+# scores against the resident operand ([block, block_major] float32),
+# and that operand itself ([block_major, head_dim], counted at 4 bytes
+# an element so that the tiling does not depend on the dtype).  With K
+# and V double-buffered that fits Mosaic's default 16 MiB of scoped
+# VMEM (this file sets no vmem_limit_bytes).
+_STRIP_BYTES = 4 << 20
+
+
+def _fit(want, seq):
+    """The largest power-of-two block <= `want` and >= 64 that divides
+    `seq`; the whole of `seq` where none does (short or oddly sized
+    sequences, which `dot_product_attention` keeps off the chip's
+    kernel path)."""
+    cand = want
+    while cand >= 64:
+        if cand <= seq and seq % cand == 0:
+            return cand
+        cand //= 2
+    return seq
+
+
+def flash_tiling(seq, head_dim, causal, block_q=None, block_k=None):
+    """The tiling of `flash_fwd`, `flash_dq` and `flash_dkv` for one
+    (seq, head_dim, causal), and the count that says the causal pruning
+    engages: `tiles_visited` of `tiles_total` tiles of chunk_q x
+    chunk_q, those on or under the diagonal when causal and all of them
+    when not.  `block_q` / `block_k` override the blocks (tests, the
+    interpreter).
+
+    Measured on the v5e at the train cell's [128, 1024, 64], causal
+    (PERF.md section 6, PR 30).  Rows of a grid step: 1024 beat 512 and
+    256 in all three kernels (the more rows a loaded chunk of K meets,
+    the better the MXU is used); 512 where the strip of 1024 rows
+    against the whole sequence would not fit, so that K and V stay
+    resident (seq 2048, head_dim 256: 0.88 ms against 1.23).  Chunks:
+    256 for `flash_fwd` and `flash_dq` (0.284 and 0.401 ms a call
+    against 0.362 and 0.414 at 128), 128 for `flash_dkv` (0.486 against
+    0.564 at 256)."""
+    rows = 1024 if seq * 1024 * 4 <= _STRIP_BYTES else 512
+    block_q = min(block_q, seq) if block_q else _fit(rows, seq)
+    block_k = min(block_k, seq) if block_k else _fit(rows, seq)
+    if seq % block_q or seq % block_k:
+        raise ValueError(
+            f"seq {seq} must be divisible by block sizes ({block_q},{block_k})")
+    chunk_q, chunk_k = _fit(256, block_q), _fit(128, block_k)
+    # the other operand whole where it and the strip fit, else the
+    # largest power-of-two share of it that both blocks divide
+    step = block_q * block_k // math.gcd(block_q, block_k)
+    widest = max(head_dim, block_q, block_k)
+    block_major = seq
+    while (block_major * widest * 4 > _STRIP_BYTES
+           and block_major % (2 * step) == 0):
+        block_major //= 2
+    n = seq // chunk_q
+    visited = n * (n + 1) // 2 if causal else n * n
+    return FlashTiling(block_q, block_k, chunk_q, chunk_k, block_major,
+                       visited, n * n)
+
+
+def _strips(causal, offset, block, n_major, run, mirrored=False):
+    """Call `run(diag)` for the live part of one grid step's strip of
+    scores, every shape in it static: `diag` is the place, among the
+    resident operand's `n_major` blocks, of the one block x block tile
+    the diagonal crosses, or None where the whole operand is live and
+    no mask is needed.
+
+    `offset` (traced) is where the step's own block starts within the
+    resident operand.  At block `c` of it, that tile is the diagonal
+    one; the blocks before it are live too (`flash_fwd`, `flash_dq`:
+    keys before the queries) or, `mirrored`, the blocks after it
+    (`flash_dkv`: queries after the keys).  Past the operand's far end
+    (before its start, `mirrored`) all of it is live; on the other side
+    nothing is, and nothing runs.  One branch per place of the diagonal,
+    of which one runs."""
+    if not causal:
+        run(None)
+        return
+    for c in range(n_major):
+        pl.when(offset == c * block)(functools.partial(run, c))
+    whole = offset < 0 if mirrored else offset >= n_major * block
+    pl.when(whole)(functools.partial(run, None))
+
+
+def _key_strips(diag, block, chunk, block_major):
+    """Static strips (row0, col0, width, masked) of a q block's scores
+    against the resident keys: all its rows against the keys before the
+    diagonal tile; then inside that tile, key chunk by key chunk, the
+    rows from the chunk's own on.  One product a strip: a chunk of keys
+    is loaded into the MXU once for every query that meets it."""
+    if diag is None:
+        return ((0, 0, block_major, False),)
+    plain = ((0, 0, diag * block, False),) if diag else ()
+    return plain + tuple((j * chunk, diag * block + j * chunk, chunk, True)
+                         for j in range(block // chunk))
+
+
+def _query_strips(diag, block, chunk, block_major):
+    """... (rows, col0, width, masked) of a k block's transposed scores
+    against the resident queries: inside the diagonal tile, query chunk
+    by query chunk, the first `rows` keys, up to the chunk's own; then
+    all its keys against the queries after that tile."""
+    if diag is None:
+        return ((block, 0, block_major, False),)
+    after = (diag + 1) * block
+    tri = tuple(((i + 1) * chunk, diag * block + i * chunk, chunk, True)
+                for i in range(block // chunk))
+    return tri + (((block, after, block_major - after, False),)
+                  if after < block_major else ())
+
+
+def _seen(rows, chunk, keys_on_rows=False):
+    """[rows, chunk] bool, the causal mask of a strip inside the diagonal
+    tile: queries on the rows, counted from the first that meets the
+    chunk of keys on the columns; or, `keys_on_rows` (`flash_dkv`'s
+    transposed scores), keys on the rows, counted so that the last
+    `chunk` of them face the chunk of queries on the columns."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, chunk), 1)
+    return col + (rows - chunk) >= row if keys_on_rows else row >= col
 
 
 # --------------------------------------------------------------------------
 # forward
 # --------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, sm_scale, causal, block_q, block_k):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
+                sm_scale, causal, block_q, chunk):
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    num_k = pl.num_programs(2)
+    kb = pl.program_id(2)
+    block_major = k_ref.shape[0]
+    n_rows = block_q // chunk
+    streamed = bool(scratch)        # K and V arrive in several blocks
 
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    if streamed:
+        acc_ref, m_ref, l_ref = scratch
 
-    def _tile(masked):
-        q = q_ref[0]                                      # [bq, d] native
-        k_blk = k_ref[0]                                  # [bk, d]
-        v_blk = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # [bq, bk] f32
-        if masked:
-            s = _causal_mask(s, qi, ki, block_q, block_k)
-        m_prev = m_ref[:, 0]                              # [bq]
-        l_prev = l_ref[:, 0]
-        m_cur = jnp.maximum(m_prev, s.max(axis=-1))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
-        l_cur = l_prev * alpha + p.sum(axis=-1)
-        acc_ref[:] = acc_ref[:] * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_cur[:, None], m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_cur[:, None], l_ref.shape)
+        @pl.when(kb == 0)
+        def _init():
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    if causal:
-        # only tiles straddling the diagonal pay the iota/mask passes;
-        # tiles fully below it run the unmasked fast path
-        live = (qi + 1) * block_q > ki * block_k
-        full = qi * block_q >= (ki + 1) * block_k
-
-        @pl.when(live & full)
-        def _fast():
-            _tile(masked=False)
-
-        @pl.when(live & jnp.logical_not(full))
-        def _diag():
-            _tile(masked=True)
-    else:
-        _tile(masked=False)
-
-    @pl.when(ki == num_k - 1)
-    def _finalize():
-        l = l_ref[:, 0]
+    def _finalize(rows, m, l, acc):
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[:] / l_safe[:, None]).astype(o_ref.dtype)
-        # stats get a trailing singleton axis: TPU block shapes need the
-        # last two dims (8,128)-aligned or equal to the array dims
-        lse_ref[0] = (m_ref[:, 0] + jnp.log(l_safe))[:, None]
+        o_ref[rows, :] = (acc / l_safe).astype(o_ref.dtype)
+        # the column of row statistics leaves as a row: sequence minor
+        lse = jnp.broadcast_to(m + jnp.log(l_safe), (acc.shape[0], _LANES))
+        lse_ref[:, rows] = lse.T[:1]
+
+    def _run(diag):
+        """Softmax over the live keys of this step.  The products run
+        strip by strip (`_key_strips`), the softmax between them chunk
+        of rows by chunk of rows over whatever strips reach it, so that
+        a row's maximum and sum are formed once."""
+        strips = _key_strips(diag, block_q, chunk, block_major)
+        scores = [jax.lax.dot_general(
+            q_ref[row0:, :], k_ref[col0:col0 + width, :], _NT,
+            preferred_element_type=jnp.float32)
+            for row0, col0, width, _ in strips]
+        seen = _seen(chunk, chunk)
+
+        def _tiles(of, i):
+            """Rows [i*chunk, (i+1)*chunk) of every strip that has them:
+            (strip, tile, whether the diagonal crosses the tile)."""
+            for si, (row0, _, _, masked) in enumerate(strips):
+                r = i * chunk - row0
+                if r >= 0:
+                    yield si, of[si][r:r + chunk], masked and r == 0
+
+        probs = [[] for _ in strips]    # per strip, p by chunk of rows
+        stats = []
+        for i in range(n_rows):
+            rows = slice(i * chunk, (i + 1) * chunk)
+            tiles = [(si, jnp.where(seen, t * sm_scale, NEG_INF) if crossed
+                      else t * sm_scale) for si, t, crossed in _tiles(scores, i)]
+            m = functools.reduce(jnp.maximum, [
+                t.max(axis=-1, keepdims=True) for _, t in tiles])
+            if streamed:
+                m = jnp.maximum(m_ref[rows, :], m)
+            l = 0.0
+            for si, t in tiles:
+                p = jnp.exp(t - m)
+                l = l + p.sum(axis=-1, keepdims=True)
+                probs[si].append(p.astype(v_ref.dtype))
+            stats.append((m, l))
+        outs = [jax.lax.dot_general(
+            ps[0] if len(ps) == 1 else jnp.concatenate(ps, axis=0),
+            v_ref[col0:col0 + width, :], _NN,
+            preferred_element_type=jnp.float32)
+            for ps, (_, col0, width, _) in zip(probs, strips)]
+        for i, (m, l) in enumerate(stats):
+            rows = slice(i * chunk, (i + 1) * chunk)
+            acc = functools.reduce(
+                jnp.add, [t for _, t, _ in _tiles(outs, i)])
+            if streamed:
+                alpha = jnp.exp(m_ref[rows, :] - m)
+                m_ref[rows, :] = m
+                l_ref[rows, :] = l_ref[rows, :] * alpha + l
+                acc_ref[rows, :] = acc_ref[rows, :] * alpha + acc
+            else:
+                _finalize(rows, m, l, acc)
+
+    _strips(causal, qi * block_q - kb * block_major, block_q,
+            block_major // block_q, _run)
+
+    if streamed:
+        @pl.when(kb == pl.num_programs(2) - 1)
+        def _last():
+            _finalize(slice(None), m_ref[...], l_ref[...], acc_ref[...])
 
 
-def _fwd(q, k, v, sm_scale, causal, block_q, block_k):
+def _major_index(causal, block, block_major):
+    """Index map of the streamed operand of `flash_fwd` / `flash_dq`:
+    K/V block `kb` for q block `qi`.  Causal, a block wholly past the
+    diagonal names the last live one again, which Pallas does not fetch
+    twice."""
+    def index(bh, qi, kb):
+        last_live = (qi * block) // block_major
+        return bh, (jnp.minimum(kb, last_live) if causal else kb), 0
+    return index
+
+
+def _fwd(q, k, v, sm_scale, causal, tiling):
+    """-> out [b, h, s, d], lse [b*h, 1, s] (the kernels' layout)."""
+    return _fwd_call(q, k, v, sm_scale, causal, tiling, interpret())
+
+
+# jitted, so that a model's layers trace and lower each kernel once and
+# not once a layer (the kernels' bodies are unrolled, static code);
+# `interpreted` is an argument so that it is part of the cache's key
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6), inline=True)
+def _fwd_call(q, k, v, sm_scale, causal, tiling, interpreted):
     b, h, s, d = q.shape
-    grid = (b * h, s // block_q, s // block_k)
-    q3 = q.reshape(b * h, s, d)
-    k3 = k.reshape(b * h, s, d)
-    v3 = v.reshape(b * h, s, d)
+    block_q, chunk, block_major = (tiling.block_q, tiling.chunk_q,
+                                   tiling.block_major)
+    q3, k3, v3 = (x.reshape(b * h, s, d) for x in (q, k, v))
+    q_spec = _vmem_spec((None, block_q, d), lambda bh, qi, kb: (bh, qi, 0))
+    kv_spec = _vmem_spec((None, block_major, d),
+                         _major_index(causal, block_q, block_major))
     call = pl.pallas_call(
         functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
-        grid=grid,
-        in_specs=[
-            _vmem_spec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            _vmem_spec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-            _vmem_spec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-        ],
+                          block_q=block_q, chunk=chunk),
+        grid=(b * h, s // block_q, s // block_major),
+        in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[
-            _vmem_spec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            _vmem_spec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)),
+            q_spec,
+            _vmem_spec((None, 1, block_q), lambda bh, qi, kb: (bh, 0, qi)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, s, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, 1, s), jnp.float32),
         ],
         scratch_shapes=[
             _scratch((block_q, d)),
-            _scratch((block_q, _LANES)),
-            _scratch((block_q, _LANES)),
-        ],
+            _scratch((block_q, 1)),
+            _scratch((block_q, 1)),
+        ] if block_major < s else [],
         compiler_params=_compiler_params(),
-        interpret=interpret(),
+        interpret=interpreted,
         name="flash_fwd",
     )
     with jax.named_scope("flash_fwd"):
         out, lse = call(q3, k3, v3)
-    return out.reshape(b, h, s, d), lse.reshape(b, h, s)
+    return out.reshape(b, h, s, d), lse
 
 
 # --------------------------------------------------------------------------
@@ -187,165 +372,177 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k):
 # --------------------------------------------------------------------------
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_acc_ref, *, sm_scale, causal, block_q, block_k):
+                   dq_acc_ref, *, sm_scale, causal, block_q, chunk):
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    num_k = pl.num_programs(2)
+    kb = pl.program_id(2)
+    block_major = k_ref.shape[0]
 
-    @pl.when(ki == 0)
+    @pl.when(kb == 0)
     def _init():
-        dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
+        dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
 
-    live = ((qi + 1) * block_q > ki * block_k) if causal else True
+    def _run(diag):
+        # the rows of statistics, turned into columns once a q block
+        lse = lse_ref[0][:, None]                         # [bq, 1]
+        delta = delta_ref[0][:, None]
+        seen = _seen(block_q, chunk)
+        for row0, col0, width, masked in _key_strips(
+                diag, block_q, chunk, block_major):
+            k_blk = k_ref[col0:col0 + width, :]
+            s = sm_scale * jax.lax.dot_general(
+                q_ref[row0:, :], k_blk, _NT,
+                preferred_element_type=jnp.float32)
+            if masked:
+                s = jnp.where(seen[:block_q - row0], s, NEG_INF)
+            p = jnp.exp(s - lse[row0:])                   # [rows, width]
+            dp = jax.lax.dot_general(
+                do_ref[row0:, :], v_ref[col0:col0 + width, :], _NT,
+                preferred_element_type=jnp.float32)
+            ds = p * (dp - delta[row0:]) * sm_scale
+            dq_acc_ref[row0:, :] += jax.lax.dot_general(
+                ds.astype(k_blk.dtype), k_blk, _NN,
+                preferred_element_type=jnp.float32)
 
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0]                                      # [bq, d]
-        do = do_ref[0]
-        lse = lse_ref[0][:, 0]                            # [bq]
-        delta = delta_ref[0][:, 0]
-        k_blk = k_ref[0]
-        v_blk = v_ref[0]
-        s = sm_scale * jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k)
-        p = jnp.exp(s - lse[:, None])                     # [bq, bk]
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * sm_scale
-        dq_acc_ref[:] = dq_acc_ref[:] + jax.lax.dot_general(
-            ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _strips(causal, qi * block_q - kb * block_major, block_q,
+            block_major // block_q, _run)
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(kb == pl.num_programs(2) - 1)
     def _finalize():
-        dq_ref[0] = dq_acc_ref[:].astype(dq_ref.dtype)
+        dq_ref[...] = dq_acc_ref[...].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc_ref, dv_acc_ref,
-                    *, sm_scale, causal, block_q, block_k):
+                    *, sm_scale, causal, block_k, chunk):
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    num_q = pl.num_programs(2)
+    qb = pl.program_id(2)
+    block_major = q_ref.shape[0]
 
-    @pl.when(qi == 0)
+    @pl.when(qb == 0)
     def _init():
-        dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
-        dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
+        dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
+        dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
-    live = ((qi + 1) * block_q > ki * block_k) if causal else True
+    def _run(diag):
+        # scores transposed, keys on the sublanes: the statistics
+        # broadcast down them as they lie, and p^T, ds^T are the left
+        # operands of plain products
+        seen = _seen(block_k, chunk, keys_on_rows=True)
+        for rows, col0, width, masked in _query_strips(
+                diag, block_k, chunk, block_major):
+            q = q_ref[col0:col0 + width, :]               # [width, d]
+            do = do_ref[col0:col0 + width, :]
+            lse = lse_ref[:, col0:col0 + width]           # [1, width]
+            delta = delta_ref[:, col0:col0 + width]
+            s = sm_scale * jax.lax.dot_general(
+                k_ref[:rows, :], q, _NT,
+                preferred_element_type=jnp.float32)       # [rows, width]
+            if masked:
+                s = jnp.where(seen[block_k - rows:], s, NEG_INF)
+            p = jnp.exp(s - lse)
+            dv_acc_ref[:rows, :] += jax.lax.dot_general(
+                p.astype(do.dtype), do, _NN,
+                preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(
+                v_ref[:rows, :], do, _NT, preferred_element_type=jnp.float32)
+            ds = p * (dp - delta) * sm_scale
+            dk_acc_ref[:rows, :] += jax.lax.dot_general(
+                ds.astype(q.dtype), q, _NN,
+                preferred_element_type=jnp.float32)
 
-    @pl.when(live)
-    def _compute():
-        k_blk = k_ref[0]                                  # [bk, d]
-        v_blk = v_ref[0]
-        q = q_ref[0]                                      # [bq, d]
-        do = do_ref[0]
-        lse = lse_ref[0][:, 0]
-        delta = delta_ref[0][:, 0]
-        s = sm_scale * jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [bq, bk]
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k)
-        p = jnp.exp(s - lse[:, None])
-        dv_acc_ref[:] = dv_acc_ref[:] + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * sm_scale
-        dk_acc_ref[:] = dk_acc_ref[:] + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _strips(causal, ki * block_k - qb * block_major, block_k,
+            block_major // block_k, _run, mirrored=True)
 
-    @pl.when(qi == num_q - 1)
+    @pl.when(qb == pl.num_programs(2) - 1)
     def _finalize():
-        dk_ref[0] = dk_acc_ref[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc_ref[:].astype(dv_ref.dtype)
+        dk_ref[...] = dk_acc_ref[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc_ref[...].astype(dv_ref.dtype)
 
 
-def _bwd(sm_scale, causal, block_q, block_k, res, g):
+def _stats_rows(x):
+    """Row statistics [batch, heads, seq] in the kernels' layout,
+    [batch*heads, 1, seq]."""
+    b, h, s = x.shape
+    return x.reshape(b * h, 1, s)
+
+
+def _delta(do, out):
+    # rowsum(dO * O) — plain XLA, fuses into one pass
+    return _stats_rows(jnp.sum(
+        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1))
+
+
+def _bwd(sm_scale, causal, tiling, res, g):
     q, k, v, out, lse = res
-    do = g
-    # delta = rowsum(dO * O), [b,h,s] — plain XLA, fuses into one pass
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)
-    return _bwd_core(sm_scale, causal, block_q, block_k, q, k, v, do,
-                     lse, delta)
+    return _bwd_core(sm_scale, causal, tiling, q, k, v, g, lse,
+                     _delta(g, out))
 
 
-def _bwd_core(sm_scale, causal, block_q, block_k, q, k, v, do, lse,
-              delta):
-    """Shared FA-2 backward given a precomputed delta row vector.
+def _bwd_core(sm_scale, causal, tiling, q, k, v, do, lse, delta):
+    """Shared FA-2 backward given the row statistics `lse` and `delta`
+    in the kernels' layout (`_stats_rows`).
 
     The (out, lse)-output variant folds its lse cotangent in here:
     ds = p*(dp - delta + dlse) = p*(dp - (delta - dlse)), so the caller
     just passes delta - dlse and the kernels stay byte-identical."""
-    b, h, s, d = q.shape
-    q3 = q.reshape(b * h, s, d)
-    k3 = k.reshape(b * h, s, d)
-    v3 = v.reshape(b * h, s, d)
-    do3 = do.reshape(b * h, s, d)
-    lse3 = lse.reshape(b * h, s, 1)
-    delta3 = delta.reshape(b * h, s, 1)
+    return _bwd_call(sm_scale, causal, tiling, interpret(), q, k, v, do,
+                     lse, delta)
 
-    grid_dq = (b * h, s // block_q, s // block_k)
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3), inline=True)
+def _bwd_call(sm_scale, causal, tiling, interpreted, q, k, v, do, lse,
+              delta):
+    b, h, s, d = q.shape
+    block_q, block_k, block_major = (tiling.block_q, tiling.block_k,
+                                     tiling.block_major)
+    q3, k3, v3, do3 = (x.reshape(b * h, s, d) for x in (q, k, v, do))
+
+    q_spec = _vmem_spec((None, block_q, d), lambda bh, qi, kb: (bh, qi, 0))
+    kv_spec = _vmem_spec((None, block_major, d),
+                         _major_index(causal, block_q, block_major))
+    row_spec = _vmem_spec((None, 1, block_q), lambda bh, qi, kb: (bh, 0, qi))
     call = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
-        grid=grid_dq,
-        in_specs=[
-            _vmem_spec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            _vmem_spec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-            _vmem_spec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-            _vmem_spec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            _vmem_spec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)),
-            _vmem_spec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)),
-        ],
-        out_specs=_vmem_spec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
+                          block_q=block_q, chunk=tiling.chunk_q),
+        grid=(b * h, s // block_q, s // block_major),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         scratch_shapes=[_scratch((block_q, d))],
         compiler_params=_compiler_params(),
-        interpret=interpret(),
+        interpret=interpreted,
         name="flash_dq",
     )
     with jax.named_scope("flash_dq"):
-        dq = call(q3, k3, v3, do3, lse3, delta3)
+        dq = call(q3, k3, v3, do3, lse, delta)
 
-    grid_kv = (b * h, s // block_k, s // block_q)
+    # q, dO and their statistics are the streamed operands here; causal,
+    # a block wholly before the k block names the first live one again
+    def major(ki, qb):
+        first_live = (ki * block_k) // block_major
+        return jnp.maximum(qb, first_live) if causal else qb
+    k_spec = _vmem_spec((None, block_k, d), lambda bh, ki, qb: (bh, ki, 0))
+    qm_spec = _vmem_spec((None, block_major, d),
+                         lambda bh, ki, qb: (bh, major(ki, qb), 0))
+    rows_spec = _vmem_spec((None, 1, block_major),
+                           lambda bh, ki, qb: (bh, 0, major(ki, qb)))
     call = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
-        grid=grid_kv,
-        in_specs=[
-            _vmem_spec((1, block_q, d), lambda bh, ki, qi: (bh, qi, 0)),
-            _vmem_spec((1, block_k, d), lambda bh, ki, qi: (bh, ki, 0)),
-            _vmem_spec((1, block_k, d), lambda bh, ki, qi: (bh, ki, 0)),
-            _vmem_spec((1, block_q, d), lambda bh, ki, qi: (bh, qi, 0)),
-            _vmem_spec((1, block_q, 1), lambda bh, ki, qi: (bh, qi, 0)),
-            _vmem_spec((1, block_q, 1), lambda bh, ki, qi: (bh, qi, 0)),
-        ],
-        out_specs=[
-            _vmem_spec((1, block_k, d), lambda bh, ki, qi: (bh, ki, 0)),
-            _vmem_spec((1, block_k, d), lambda bh, ki, qi: (bh, ki, 0)),
-        ],
+                          block_k=block_k, chunk=tiling.chunk_k),
+        grid=(b * h, s // block_k, s // block_major),
+        in_specs=[qm_spec, k_spec, k_spec, qm_spec, rows_spec, rows_spec],
+        out_specs=[k_spec, k_spec],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, s, d), k.dtype),
             jax.ShapeDtypeStruct((b * h, s, d), v.dtype),
         ],
         scratch_shapes=[_scratch((block_k, d)), _scratch((block_k, d))],
         compiler_params=_compiler_params(),
-        interpret=interpret(),
+        interpret=interpreted,
         name="flash_dkv",
     )
     with jax.named_scope("flash_dkv"):
-        dk, dv = call(q3, k3, v3, do3, lse3, delta3)
+        dk, dv = call(q3, k3, v3, do3, lse, delta)
 
     return (dq.reshape(b, h, s, d), dk.reshape(b, h, s, d),
             dv.reshape(b, h, s, d))
@@ -592,85 +789,62 @@ def kv_append(k_cache, v_cache, k_new, v_new, layer, pos):
 # public API
 # --------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, sm_scale, causal, block_q, block_k):
-    out, _ = _fwd(q, k, v, sm_scale, causal, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, sm_scale, causal, tiling):
+    out, _ = _fwd(q, k, v, sm_scale, causal, tiling)
     return out
 
 
-def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k):
-    out, lse = _fwd(q, k, v, sm_scale, causal, block_q, block_k)
+def _flash_fwd(q, k, v, sm_scale, causal, tiling):
+    out, lse = _fwd(q, k, v, sm_scale, causal, tiling)
     return out, (q, k, v, out, lse)
 
 
 _flash.defvjp(_flash_fwd, _bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_lse(q, k, v, sm_scale, causal, block_q, block_k):
-    return _fwd(q, k, v, sm_scale, causal, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_lse(q, k, v, sm_scale, causal, tiling):
+    out, lse = _fwd(q, k, v, sm_scale, causal, tiling)
+    return out, lse.reshape(q.shape[:3])
 
 
-def _flash_lse_fwd(q, k, v, sm_scale, causal, block_q, block_k):
-    out, lse = _fwd(q, k, v, sm_scale, causal, block_q, block_k)
-    return (out, lse), (q, k, v, out, lse)
+def _flash_lse_fwd(q, k, v, sm_scale, causal, tiling):
+    out, lse = _fwd(q, k, v, sm_scale, causal, tiling)
+    return (out, lse.reshape(q.shape[:3])), (q, k, v, out, lse)
 
 
-def _flash_lse_bwd(sm_scale, causal, block_q, block_k, res, g):
+def _flash_lse_bwd(sm_scale, causal, tiling, res, g):
     q, k, v, out, lse = res
     do, dlse = g
     # dlse rides the same kernels: ds gains +p*dlse, i.e. delta -> delta
     # - dlse (see _bwd_core)
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1) - dlse.astype(jnp.float32)
-    return _bwd_core(sm_scale, causal, block_q, block_k, q, k, v, do,
-                     lse, delta)
+    delta = _delta(do, out) - _stats_rows(dlse.astype(jnp.float32))
+    return _bwd_core(sm_scale, causal, tiling, q, k, v, do, lse, delta)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
-def _resolve(q, sm_scale, block_q, block_k):
+def _resolve(q, sm_scale, causal, block_q, block_k):
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    s = q.shape[-2]
-
-    def _auto_block(default):
-        # largest power-of-two tile <= default that divides seq, so any
-        # 128-multiple seq (1920, 2176, ...) gets a valid tiling; the
-        # ladder always descends to 64 regardless of where the default
-        # starts (raising the default to 1024 must not lift the floor —
-        # a seq divisible by 64 but not 128 would otherwise fall back
-        # to one full-seq tile and blow the score block's VMEM)
-        cand = default
-        while cand >= 64:
-            if cand <= s and s % cand == 0:
-                return cand
-            cand //= 2
-        return s
-
-    env = _env_blocks()
-    dq, dk = env if env else (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)
-    block_q = block_q or _auto_block(dq)
-    block_k = block_k or _auto_block(dk)
-    block_q, block_k = min(block_q, s), min(block_k, s)
-    if s % block_q or s % block_k:
-        raise ValueError(
-            f"seq {s} must be divisible by block sizes ({block_q},{block_k})")
-    return float(sm_scale), block_q, block_k
+    return float(sm_scale), bool(causal), flash_tiling(
+        q.shape[-2], q.shape[-1], bool(causal), block_q, block_k)
 
 
 def flash_attention(q, k, v, causal=False, sm_scale=None,
                     block_q=None, block_k=None):
-    """Tiled attention over [batch, heads, seq, head_dim] inputs.
+    """Tiled attention over [batch, heads, seq, head_dim] inputs
+    (self-attention: q, k and v of one shape).
 
-    seq must be a multiple of the block sizes (default DEFAULT_BLOCK_Q/
-    DEFAULT_BLOCK_K = 1024, auto-shrunk to a power-of-two divisor of
-    seq); head_dim should be an MXU-friendly 64/128/256. Returns the same
-    shape/dtype as q.
+    The tiling comes from `flash_tiling(seq, head_dim, causal)`; with
+    causal=True only the tiles on or under the diagonal are computed.
+    `block_q` / `block_k` override the compute tile and must divide seq
+    (tests, the interpreter).  head_dim should be an MXU-friendly
+    64/128/256.  Returns the same shape/dtype as q.
     """
-    sm_scale, block_q, block_k = _resolve(q, sm_scale, block_q, block_k)
-    return _flash(q, k, v, sm_scale, bool(causal), block_q, block_k)
+    return _flash(q, k, v, *_resolve(q, sm_scale, causal, block_q, block_k))
 
 
 def flash_attention_with_lse(q, k, v, causal=False, sm_scale=None,
@@ -680,6 +854,6 @@ def flash_attention_with_lse(q, k, v, causal=False, sm_scale=None,
     outputs — the building block for ring attention's (out, lse) block
     combine (distributed/ring_attention.py): partial attentions over kv
     shards merge exactly via softmax-weighted averaging of normalized
-    outputs."""
-    sm_scale, block_q, block_k = _resolve(q, sm_scale, block_q, block_k)
-    return _flash_lse(q, k, v, sm_scale, bool(causal), block_q, block_k)
+    outputs.  Tiled as `flash_attention` is."""
+    return _flash_lse(q, k, v,
+                      *_resolve(q, sm_scale, causal, block_q, block_k))

@@ -38,6 +38,11 @@ def _cases():
     def causal(q, k, v):
         return flash_attention(q, k, v, causal=True)
 
+    # the benchmark's own shapes (BENCHMARK.json): the train cell's 8 x
+    # 16 heads at seq 1024, the K2 cell's widest prefill bucket
+    train = [((8, 16, 1024, 64), BF16)] * 3
+    k2_prefill = [((1, 64, 2048, 256), BF16)] * 3
+
     ln = [((8192, 768), BF16), ((768,), BF16), ((768,), BF16)]
     dec = [((8, 12, 1, 64), BF16), ((8, 12, 2048, 64), BF16),
            ((8, 12, 2048, 64), BF16), ((8,), jnp.int32)]
@@ -48,6 +53,9 @@ def _cases():
     return [
         ("flash_fwd", causal, att),
         ("flash_fwd_bwd", jax.grad(_sum32(causal), argnums=(0, 1, 2)), att),
+        ("flash_train_cell", jax.grad(_sum32(causal), argnums=(0, 1, 2)),
+         train),
+        ("flash_k2_prefill", causal, k2_prefill),
         ("flash_with_lse_fwd_bwd", jax.grad(
             lambda q, k, v: sum(jnp.sum(o.astype(F32)) for o in
                                 flash_attention_with_lse(q, k, v)),
@@ -78,6 +86,8 @@ IDS = [c[0] for c in CASES]
 # device trace lists them under
 KERNELS = {"flash_fwd": ["flash_fwd"],
            "flash_fwd_bwd": ["flash_fwd", "flash_dq", "flash_dkv"],
+           "flash_train_cell": ["flash_fwd", "flash_dq", "flash_dkv"],
+           "flash_k2_prefill": ["flash_fwd"],
            "flash_with_lse_fwd_bwd": ["flash_fwd", "flash_dq", "flash_dkv"],
            "flash_decode": ["flash_decode"],
            "flash_decode_f32_d128": ["flash_decode"],
@@ -132,6 +142,23 @@ def test_kernel_compiles_for_v5e(compiled_kernels, v5e, name, fn, args):
 def _mosaic_calls(text):
     return re.findall(r"%([\w-]+?)(?:\.\d+)? = [^\n]*"
                       r'custom_call_target="tpu_custom_call"', text)
+
+
+def test_flash_row_statistics_stay_lane_dense(compiled_kernels, v5e):
+    """The saved logsumexp and delta cross the kernels' boundary with
+    the sequence minor: at the train cell's shape no instruction of the
+    compiled forward + backward has a result with a minor dimension of
+    1 (which the chip pads to 128 lanes, and XLA then copies)."""
+    name, fn, args = next(c for c in CASES if c[0] == "flash_train_cell")
+    one = jax.sharding.SingleDeviceSharding(v5e[0])
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in args]
+    text = jax.jit(fn).trace(*avals).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert re.search(r"f32\[128,1,1024\]\{2,1,0:T\(1,128\)", text), \
+        "the statistics are not laid out [heads, 1, seq] in 128-lane tiles"
+    padded = [line.strip()[:120] for line in text.splitlines()
+              if re.search(r"= \(?f32\[[\d,]*,1\]", line)]
+    assert not padded, padded
 
 
 # ---------------------------------------------------------------------
