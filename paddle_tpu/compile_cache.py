@@ -1,9 +1,9 @@
 """Where XLA's persistent compilation cache lives — the one rule.
 
 Every entry point (`import paddle_tpu`, `python -m
-paddle_tpu.serving.replica`, a `distributed/launch.py` worker, bench.py,
-chip_smoke.py) imports this package before it compiles anything, so
-the rule is applied here, once, at package import:
+paddle_tpu.serving.replica`, a `distributed/launch.py` worker,
+`benchmarks.run`, chip_smoke.py) imports this package before it compiles
+anything, so the rule is applied here, once, at package import:
 
 - `JAX_COMPILATION_CACHE_DIR` set by the caller: that directory, and
   nothing in code names another;

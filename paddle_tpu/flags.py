@@ -14,6 +14,7 @@ debug modes).
 """
 
 import os
+import tempfile
 
 _REGISTRY = {}
 
@@ -101,8 +102,11 @@ declare_flag("executor_log_ops", False, "Log each op executed.")
 # for parity with the reference's fp16 AMP lists).
 declare_flag("amp_dtype", "bfloat16", "Low-precision dtype used by AMP.")
 
-# Benchmark / profiler output directory.
-declare_flag("profiler_dir", "/tmp/paddle_tpu_profile", "Profiler trace dir.")
+# Profiler output directory (under TMPDIR, so two checkouts run with
+# their own TMPDIR keep their traces apart).
+declare_flag("profiler_dir",
+             os.path.join(tempfile.gettempdir(), "paddle_tpu_profile"),
+             "Profiler trace dir.")
 
 declare_flag("use_pallas_layer_norm", False,
              "Route last-axis layer_norm through the Pallas fused kernel "
@@ -133,7 +137,8 @@ declare_flag("flight_recorder", True,
              "Keep the always-on post-mortem ring buffer recording.")
 declare_flag("flight_recorder_steps", 256,
              "How many recent step records the flight recorder keeps.")
-declare_flag("flight_recorder_dir", "/tmp/paddle_tpu_flight",
+declare_flag("flight_recorder_dir",
+             os.path.join(tempfile.gettempdir(), "paddle_tpu_flight"),
              "Directory flight-recorder post-mortem dumps land in.")
 
 # Static Program verifier (paddle_tpu.analysis): lint every program

@@ -3,7 +3,7 @@
 Parity targets: the reference's book-model fixtures and distributed test
 models (/root/reference/python/paddle/fluid/tests/book/,
 tests/unittests/dist_mnist.py, dist_se_resnext.py, dist_transformer.py,
-dist_ctr.py) plus BASELINE.md's headline configs (MNIST-LeNet, ResNet-50,
+dist_ctr.py) plus the reference's headline configs (MNIST-LeNet, ResNet-50,
 BERT-base, fused-attention transformer, Wide&Deep sparse).
 
 All models are `nn.Layer`s; use `nn.layers.functional_call` /

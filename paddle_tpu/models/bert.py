@@ -2,7 +2,7 @@
 
 Parity targets: the reference's collective-training BERT path (SURVEY.md
 §3.3 — the "BERT/ResNet cluster path") and the fused-attention transformer
-benchmark config from BASELINE.md; fused attention replaces
+benchmark config; fused attention replaces
 /root/reference/paddle/fluid/operators/fused/multihead_matmul_op.cu and
 math/bert_encoder_functor.cu with the Pallas flash-attention kernel
 (paddle_tpu/kernels/flash_attention.py).

@@ -31,10 +31,10 @@ class GPTConfig:
     # one extra logits matmul pass for ~2x less logits HBM traffic;
     # worthwhile at 32k+ vocabs on HBM-bound configs.
     # streaming vocab-chunked CE: a MEMORY lever (keeps the [B,S,V]
-    # logits out of the residual set), NOT a speed knob — the on-chip
-    # A/B (BENCH_TPU.json bert_chunked_ce: 0.4345 vs 0.4808 plain at
-    # seq 512 / 32k vocab) showed XLA's fused full-logit CE wins when
-    # the logits fit; engage only for long-seq x huge-vocab configs
+    # logits out of the residual set), NOT a speed lever: it pays one
+    # more logits matmul, so where the logits fit, the plain fused CE
+    # is the one to use; engage only for long-seq x huge-vocab configs.
+    # No cell of the benchmark sets it (ROADMAP.md, Design).
     ce_vocab_chunk: int = 0
     # MoE (0 = dense FFN): experts shard over the mesh's "ep" axis via
     # distributed.sharded.gpt_rules; router aux loss folds into .loss()

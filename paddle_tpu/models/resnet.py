@@ -1,6 +1,6 @@
 """ResNet family + SE-ResNeXt.
 
-Parity targets: the reference's ResNet DP benchmark config (BASELINE.md)
+Parity targets: the reference's ResNet DP benchmark config
 and the dist_se_resnext.py distributed fixture
 (/root/reference/python/paddle/fluid/tests/unittests/dist_se_resnext.py).
 

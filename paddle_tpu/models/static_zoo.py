@@ -35,8 +35,8 @@ class StaticModel:
         """This family's default partition-rule document (the
         ``--sharding-rules`` file format): ``{"mesh", "rules",
         "data_axis"}``.  Every default set is PT3xx-clean on its own
-        mesh — the property ``bench.py sharding_lint_smoke`` and the
-        zoo sweep tests pin."""
+        mesh (``tests/test_sharding.py::
+        test_zoo_model_pt3xx_clean_under_default_rules``)."""
         return DEFAULT_SHARDING_RULES.get(
             self.name, DEFAULT_SHARDING_RULES["_default"])
 

@@ -78,15 +78,11 @@ def make_train_step(model, optimizer, loss_fn=None, jit=True, donate=True,
     remat="conv_outs" saves ONLY conv outputs (the checkpoint_name tags
     the conv2d kernel emits) and recomputes the elementwise tail
     (BN affine / relu / residual add) during backward.  This is a
-    MEMORY knob, not a speed knob: measured on-chip r4 mid-round
-    (ResNet-50 bf16 NHWC b128) the step went 49.0ms -> 56.0ms because
-    the recompute re-materializes the elementwise outputs in HBM
-    during backward — XLA's default residual selection is already
-    traffic-optimal there; full remat=True was worse still (67ms,
-    re-runs the convs).  The HEAD-sha remat timing lives in
-    BENCH_TPU.json rows["resnet50_sweep"] (the (128, remat=True)
-    config) — trust that row over these dated numbers.  Use remat when
-    activations don't fit, not to go faster.
+    MEMORY lever, not a speed lever: the recompute re-materializes the
+    elementwise outputs in HBM during backward, where XLA's default
+    residual selection already keeps that traffic low, and full
+    remat=True re-runs the convs as well.  Use remat when activations
+    don't fit, not to go faster.
     jax.checkpoint must wrap the PURE params->loss function — wrapping a
     stateful `model(...)` call would leak buffer-update tracers across
     the re-trace and die with UnexpectedTracerError.  Belt-and-braces

@@ -14,6 +14,7 @@ import contextlib
 import json
 import os
 import statistics
+import sys
 import threading
 import time
 
@@ -129,12 +130,15 @@ class RecordEvent:
             return self
         _events()
         self._epoch = _active["epoch"]
-        self.start = time.perf_counter_ns()
-        _state.stack.append(self.name)
+        start = time.perf_counter_ns()
         if tracing:
             self._annotation = jax.profiler.TraceAnnotation(
-                TRACE_PREFIX + self.name, pc_ns=self.start, **self.attrs)
+                TRACE_PREFIX + self.name, pc_ns=start, **self.attrs)
             self._annotation.__enter__()
+        # armed only now: an annotation that failed to open leaves no
+        # entry on the stack for later spans to count as their depth
+        self.start = start
+        _state.stack.append(self.name)
         return self
 
     def __exit__(self, *exc):
@@ -157,8 +161,10 @@ class RecordEvent:
 # `epoch` counts event-store clears (reset_profiler / start_profiler);
 # an in-flight RecordEvent compares its entry epoch before appending.
 # `tracing` is what the span sites last saw of the jax.profiler session.
-_active = {"on": False, "jax_trace": False, "dir": None, "epoch": 0,
-           "tracing": False}
+# `owner` is where the open `start_profiler` session was started: a
+# jax.profiler session is process-wide, so a second start names it.
+_active = {"on": False, "jax_trace": False, "epoch": 0,
+           "tracing": False, "owner": None}
 
 
 def _note_trace_session(enabled):
@@ -224,20 +230,39 @@ def is_profiling():
     return _active["on"]
 
 
+def _caller():
+    """`file:line in function` of the nearest frame outside this module
+    and contextlib (the `profiler()` context's own frames)."""
+    frame = sys._getframe(1)
+    while frame.f_back is not None and frame.f_code.co_filename in (
+            __file__, contextlib.__file__):
+        frame = frame.f_back
+    code = frame.f_code
+    return f"{code.co_filename}:{frame.f_lineno} in {code.co_name}"
+
+
 def start_profiler(state="All", tracer_option="Default"):
-    _events()            # register this thread before clearing
-    _clear_events()
-    _active["epoch"] += 1
-    _active["on"] = True
+    """Open the process's one profiling session; `stop_profiler` ends
+    it.  A session that is already open is an error, not a no-op: with
+    "All" (or "GPU"/"TPU") the session holds the process-wide
+    `jax.profiler` trace, and a second one would run without it.  The
+    error of `jax.profiler.start_trace` itself (someone else's
+    `start_trace` is open) passes through, and leaves no session."""
+    if _active["on"]:
+        raise RuntimeError(
+            "a profiler session is already open (started at "
+            f"{_active['owner']}); call stop_profiler() before starting "
+            "another")
     if state in ("All", "GPU", "TPU"):
         trace_dir = flags.flag("profiler_dir")
         os.makedirs(trace_dir, exist_ok=True)
-        try:
-            jax.profiler.start_trace(trace_dir)
-            _active["jax_trace"] = True
-            _active["dir"] = trace_dir
-        except Exception:
-            _active["jax_trace"] = False
+        jax.profiler.start_trace(trace_dir)
+        _active["jax_trace"] = True
+    _events()            # register this thread before clearing
+    _clear_events()
+    _active["epoch"] += 1
+    _active["owner"] = _caller()
+    _active["on"] = True
 
 
 # Fluid-parity sort keys (profiler.py:196): each maps to the table
@@ -253,8 +278,13 @@ def stop_profiler(sorted_key=None, profile_path="/tmp/profile"):
     "total" | "max" | "min" | "ave" | "calls", reference parity), plus
     — when the monitor has per-op attribution data (a compiled step's
     static split and/or a sampling run) — the Fluid per-op table with
-    device-time, FLOPs, bytes, and %-of-step columns."""
+    device-time, FLOPs, bytes, and %-of-step columns.  Safe to call
+    with no session open; called outside the calling thread's spans,
+    whose nesting depth starts again at 0."""
     _active["on"] = False
+    _active["owner"] = None
+    _events()            # this thread has a stack
+    del _state.stack[:]  # its next session's spans start at depth 0
     if _active["jax_trace"]:
         try:
             jax.profiler.stop_trace()

@@ -165,8 +165,8 @@ def device_prefetch(batches, size=2, device=None):
     `jax.device_put` is async dispatch, so batch N+1's host->device copy
     is issued before the consumer has finished step N — the copy rides
     the DMA while the step occupies the compute units, which is the
-    entire win (measured as the prefetch lever of bench.py's
-    resnet50_sweep).  size=2 is the classic double buffer; larger only
+    entire win (the benchmark's train cell feeds its steps through
+    it).  size=2 is the classic double buffer; larger only
     helps if the producer is burstier than the consumer.
 
     Each array leaf of every yielded batch is a FRESH device buffer that

@@ -16,8 +16,7 @@ Four pillars (ISSUE 4 tentpole):
    restores the latest checkpoint and skips consumed batches.
 4. **Deterministic fault injection** (`faultinject.py`) — NaN feeds at
    step N, synthetic transient errors, kill-between-array-write-and-
-   marker during checkpoint saves; drives tests and the
-   `bench.py fault_tolerance_smoke` CI chaos row.
+   marker during checkpoint saves; drives `tests/test_resilience.py`.
 
 Plus the fleet-level pillar (ISSUE 11): the **elastic runtime**
 (`elastic.py`) — topology-change resharding
@@ -26,7 +25,8 @@ Plus the fleet-level pillar (ISSUE 11): the **elastic runtime**
 sync, leave/join intents, shrink/grow transitions gated into
 /healthz), and skew-driven policies (`ElasticPolicy`:
 warn | rebalance | evict off `monitor.fleet_skew()`), exercised by the
-`bench.py elastic_fleet_smoke` kill/reshard/rejoin chaos row.
+kill/reshard/rejoin arc of `tests/test_elastic.py` (`kill_and_rejoin`,
+two real processes).
 
 All recovery events land as `resilience.*` monitor counters/gauges
 (visible in `monitor.snapshot()` and the merged Chrome trace), and

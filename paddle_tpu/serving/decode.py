@@ -85,11 +85,6 @@ call, so the engine marks itself broken rather than pretend the cache
 survived), and DecodeStats publishing tokens/s, TTFT and inter-token
 percentiles (exact nearest-rank), slot occupancy and the
 prefill/decode split to /metrics and the telemetry stream.
-
-`continuous=False` turns the SAME engine into the pad-to-bucket
-baseline (admit a cohort, decode until every member finishes, only
-then admit again) — the bench's control arm, isolating iteration-level
-scheduling as the measured lever.
 """
 
 import threading
@@ -151,7 +146,7 @@ class DecodeConfig:
                  max_queue_depth=None, default_token_budget_s=None,
                  retry_policy=_DEFAULT_RETRY, breaker_threshold=5,
                  breaker_cooldown_s=5.0, watchdog_stall_s=None,
-                 watchdog_poll_s=None, continuous=True, prewarm=True,
+                 watchdog_poll_s=None, prewarm=True,
                  label="decode", clock=time.monotonic):
         self.slots = int(slots if slots is not None
                          else flags.flag("decode_slots"))
@@ -183,7 +178,6 @@ class DecodeConfig:
             watchdog_stall_s if watchdog_stall_s is not None
             else flags.flag("serving_watchdog_stall_s"))
         self.watchdog_poll_s = watchdog_poll_s
-        self.continuous = bool(continuous)
         self.prewarm = bool(prewarm)
         self.label = label
         self.clock = clock
@@ -751,16 +745,10 @@ class DecodeEngine:
         return [i for i, r in enumerate(self._slot_req) if r is None]
 
     def _admit_locked(self):
-        """Pick (slot, request) pairs to prefill this iteration.
-        Continuous mode refills any free slot the moment the queue has
-        work; static (baseline) mode only admits a fresh cohort once
-        EVERY slot is free — the pad-to-bucket re-prefill scheduling
-        the bench row compares against."""
+        """Pick (slot, request) pairs to prefill this iteration: any
+        free slot is refilled the moment the queue has work."""
         free = self._free_slots_locked()
         if not free or not self._queue:
-            return []
-        if not self.config.continuous \
-                and len(free) != self.config.slots:
             return []
         picks = []
         while free and self._queue:

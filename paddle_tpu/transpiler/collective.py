@@ -18,7 +18,8 @@ synchronization** (the PyTorch-DDP design, Li et al. VLDB 2020):
   latency-hiding scheduler overlaps it with the remaining backward
   compute;
 - psum is elementwise, so the bucketed sync is BITWISE identical to
-  the per-gradient sync (the property bench.py graph_opt_sweep pins);
+  the per-gradient sync (tests/test_passes.py
+  ::test_dp_bucketed_training_bitwise pins it);
 - gradients that are not plain dense arrays (SelectedRows-style
   lookup-table grads, custom pytree nodes) fall back to the unbucketed
   per-leaf sync, counted on ``passes.bucket_fallbacks`` — never a
@@ -34,8 +35,7 @@ from .. import flags
 from ..distributed.strategies import LocalSGDTrainStep  # noqa: F401
 
 # trace-time stats of the most recent sync_gradients emission: what
-# bench.py graph_opt_sweep and the tests read to assert the collective
-# count without parsing HLO
+# the tests read to assert the collective count without parsing HLO
 _LAST_SYNC = {}
 
 
@@ -95,7 +95,7 @@ def implied_collective_plan(entries, axes=("dp",), bucket_bytes=None):
     the SAME planner with the SAME flag default, the analyzer's
     predicted collective count/bytes and the executed
     ``last_sync_stats`` agree exactly — the conformance property
-    ``bench.py sharding_lint_smoke`` pins.
+    ``tests/test_sharding.py``'s ``dp_conformance`` pins.
 
     ``bucket_bytes=None`` reads ``FLAGS_dp_bucket_bytes``; 0 plans the
     legacy one-all-reduce-per-gradient sync."""
@@ -276,8 +276,8 @@ def note_model_sync(records, key=None):
     there is no trace-time hook to count them.  The executor therefore
     notes the ``ShardingPlan``'s own implied-collective records here
     after dispatch: the records ARE the analyzer's, so the predicted
-    table and the executed stats agree exactly by construction (the
-    conformance property ``bench.py tp_runtime_smoke`` pins)."""
+    table and the executed stats agree exactly by construction (pinned by
+    ``tests/test_spmd_runtime.py``)."""
     records = [dict(r) for r in records]
     axes = sorted({a for r in records for a in r.get("axes", ())})
     _LAST_SYNC["model"] = {

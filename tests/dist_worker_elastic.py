@@ -1,6 +1,7 @@
 """Elastic-fleet chaos worker (ISSUE 11).
 
-Companion script for ``bench.py elastic_fleet_smoke``, run by
+Companion script of ``tests/test_elastic.py``'s
+``test_a_killed_rank_is_resharded_around_and_rejoins``, run by
 ``distributed.launch.start_procs`` under the PADDLE_* env contract.
 One script, five phases — the CHAOS run exercises the recovery path,
 the CLEAN run produces the uninterrupted reference with the SAME
@@ -34,7 +35,7 @@ healthz probes, and (final phases) the trained parameters; telemetry
 JSONL streams land rank-tagged in ``<out_dir>/telemetry`` so the
 parent can merge the topology history with telemetry_report --fleet.
 
-argv: config.json path (see bench.py elastic_fleet_smoke).
+argv: config.json path (see that test's ``run_phase``).
 """
 
 import json

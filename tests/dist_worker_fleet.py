@@ -1,6 +1,7 @@
 """2-process fleet-observability smoke worker (ISSUE 10).
 
-Companion script for ``bench.py fleet_obs_smoke`` (and the dist test),
+Companion script of ``tests/test_fleet.py``'s
+``test_two_processes_name_their_straggler``,
 run by distributed.launch.start_procs under the PADDLE_* env contract.
 Each rank drives the PUBLIC Executor dp path over a REAL 2-process CPU
 mesh; rank 1 is slowed by ``faultinject.stall_point("executor.step")``

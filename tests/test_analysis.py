@@ -866,7 +866,7 @@ def test_telemetry_report_lint_section(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# satellites: did-you-mean, CLI, bench row
+# satellites: did-you-mean, CLI, the zoo in one pass
 # ---------------------------------------------------------------------------
 
 def test_block_var_did_you_mean():
@@ -911,20 +911,50 @@ def test_program_lint_cli_all_models_and_json_roundtrip(tmp_path):
     assert "PT101" in r2.stdout
 
 
-def test_bench_program_lint_smoke_row_passes():
-    import bench
+@pytest.fixture(scope="module")
+def zoo_lint():
+    """The whole bundled zoo through the verifier in one pass (main and
+    startup, keyed `<model>/<section>`), and one program seeded with
+    four different bugs through it once; each statement about the pass
+    is a case below."""
+    results, ops = {}, {}
+    for name, model in sorted(static_zoo.build_all().items()):
+        for section, prog, fetches in (("main", model.main, model.fetches),
+                                       ("startup", model.startup, [])):
+            key = f"{name}/{section}"
+            results[key] = analysis.check_program(
+                prog, fetch_names=fetches, program_key=key)
+            ops[key] = sum(len(b.ops) for b in prog.blocks)
 
-    row = bench.bench_program_lint_smoke(False, 1.0)
-    assert row["value"] == 1, row
-    assert row["models"] == len(static_zoo.BUILDERS)
-    assert row["lint_wall_ms"] > 0
-    assert all(v == 0 for v in row["zoo_errors"].values())
+    def build(main):
+        a = fluid.data("a", [2, 3])
+        b = fluid.data("b", [5, 4])
+        block = main.global_block()
+        block.append_op("mul", inputs={"X": a, "Y": b},
+                        outputs={"Out": block.create_var(name="o")})
+        block.append_op("relu", inputs={"X": "ghost"},
+                        outputs={"Out": block.create_var(name="r")})
+        block.append_op("no_such_op", inputs={"X": a},
+                        outputs={"Out": block.create_var(name="n")})
+        L.sigmoid(a)                      # never fetched, never read
+
+    seeded, _, _ = _fresh_program(build)
+    codes = set(_codes(analysis.check_program(
+        seeded, fetch_names=["o", "r", "n"])))
+    return {
+        "zoo_covered": {k.split("/")[0] for k in results}
+        == set(static_zoo.BUILDERS) and len(results) == 2 * len(
+            static_zoo.BUILDERS),
+        "zoo_zero_errors": all(not r.errors for r in results.values()),
+        "every_linted_program_has_ops": all(n > 0 for n in ops.values()),
+        # the verifier does not stop at a program's first error
+        "one_pass_reports_every_seeded_bug":
+            {"PT101", "PT103", "PT105", "PT201"} <= codes,
+    }
 
 
-def test_program_lint_smoke_in_suite_and_standalone():
-    import bench
-
-    src = open(bench.__file__).read()
-    assert '"program_lint_smoke",\n         bench_program_lint_smoke' \
-        in src or '("program_lint_smoke", "program_lint_smoke"' in src
-    assert 'if "program_lint_smoke" in sys.argv[1:]:' in src
+@pytest.mark.parametrize("check", [
+    "zoo_covered", "zoo_zero_errors", "every_linted_program_has_ops",
+    "one_pass_reports_every_seeded_bug"])
+def test_one_lint_pass_over_the_zoo(zoo_lint, check):
+    assert zoo_lint[check], zoo_lint

@@ -3,8 +3,11 @@ parity: fluid/debugger.py, net_drawer.py, fluid/profiler.py +
 tools/timeline.py."""
 
 import json
+import pathlib
 
+import jax
 import numpy as np
+import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import debugger, profiler
@@ -165,3 +168,109 @@ def test_nested_spans_survive_reset_without_stack_corruption():
             pass
     table = profiler.stop_profiler(profile_path=None)
     assert table["a"]["calls"] == 1 and table["b"]["calls"] == 1
+
+
+# ---------------------------------------------------------------------
+# a session has an owner (a jax.profiler session is process-wide)
+# ---------------------------------------------------------------------
+def test_a_second_start_raises_and_names_the_first():
+    profiler.start_profiler(state="CPU")
+    try:
+        with pytest.raises(RuntimeError, match="already open") as err:
+            profiler.start_profiler(state="CPU")
+        assert "test_a_second_start_raises_and_names_the_first" in \
+            str(err.value)
+        assert profiler.is_profiling()     # the first one is untouched
+        with profiler.RecordEvent("still_recording"):
+            pass
+    finally:
+        table = profiler.stop_profiler(profile_path=None)
+    assert table["still_recording"]["calls"] == 1
+    assert profiler.stop_profiler(profile_path=None) is not None  # safe
+
+
+def test_start_under_a_foreign_trace_raises_and_opens_nothing(tmp_path):
+    """`start_profiler("All")` needs the process's jax.profiler session;
+    where someone else holds it, jax's error passes through and no
+    half-session (host spans without a device trace) is left."""
+    from paddle_tpu import flags
+
+    old = flags.flag("profiler_dir")
+    flags.set_flags({"FLAGS_profiler_dir": str(tmp_path / "ours")})
+    jax.profiler.start_trace(str(tmp_path / "theirs"))
+    try:
+        with pytest.raises(RuntimeError, match="already been started"):
+            profiler.start_profiler("All")
+        assert not profiler.is_profiling()
+    finally:
+        jax.profiler.stop_trace()
+        flags.set_flags({"FLAGS_profiler_dir": old})
+
+
+def test_stop_profiler_resets_the_nesting_depth():
+    """A span that never exits (its generator was dropped) must not
+    deepen the next session's spans."""
+    profiler.start_profiler(state="CPU")
+    profiler.RecordEvent("never_exits").__enter__()
+    profiler.stop_profiler(profile_path=None)
+    profiler.start_profiler(state="CPU")
+    with profiler.RecordEvent("top"):
+        pass
+    profiler.stop_profiler(profile_path=None)
+    assert [e["depth"] for e in profiler._all_events()] == [0]
+
+
+def test_a_failed_annotation_leaves_no_depth_behind(tmp_path, monkeypatch):
+    def refuse(*a, **k):
+        raise OSError("no annotation today")
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with monkeypatch.context() as m:
+            m.setattr(jax.profiler, "TraceAnnotation", refuse)
+            with pytest.raises(OSError):
+                profiler.RecordEvent("refused").__enter__()
+        with profiler.RecordEvent("after"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    assert [(e["name"], e["depth"]) for e in profiler._all_events()] == \
+        [("after", 0)]
+
+
+_LEAKY = """
+import jax
+from paddle_tpu import profiler
+
+def test_leaves_a_session_open():
+    profiler.start_profiler(state="CPU")
+
+def test_leaves_a_jax_trace_open(tmp_path):
+    jax.profiler.start_trace(str(tmp_path))
+
+def test_neighbour_runs_clean(tmp_path):
+    assert not profiler.is_profiling()
+    with profiler.profiler("All", profile_path=None):
+        pass
+"""
+
+
+def test_a_leaked_session_fails_its_own_test_not_a_neighbour(pytester):
+    """tests/conftest.py's autouse fixture, in a pytest run of its own:
+    each leak is an error of the test that left it, and the test after
+    them opens a device-trace session without trouble."""
+    pytester.makeconftest(
+        pathlib.Path(__file__).with_name("conftest.py").read_text())
+    pytester.makepyfile(test_leaky=_LEAKY)
+    result = pytester.runpytest_inprocess(
+        "-p", "no:xdist", "-p", "no:randomly", "-p", "no:cacheprovider",
+        "-rE")
+    result.assert_outcomes(passed=3, errors=2)
+    result.stdout.fnmatch_lines([
+        "*ERROR at teardown of test_leaves_a_session_open*",
+        "*start_profiler session started at*test_leaky.py:*"
+        " in test_leaves_a_session_open",
+        "*ERROR at teardown of test_leaves_a_jax_trace_open*",
+        "*jax.profiler trace",
+    ])
+    assert not profiler.is_profiling()

@@ -254,6 +254,37 @@ def test_budget_expired_midstream_releases_slot(dense_model):
     eng.close()
 
 
+def test_a_slow_step_expires_only_the_request_with_a_budget(dense_model):
+    """Two slot-resident requests share one decode step that takes
+    longer than the inter-token budget of one of them: that one is
+    resolved 'expired', its budget-less neighbour rides the same step
+    to a token-exact completion, and nothing ends unclassified."""
+    clk = FakeClock()
+    eng = _engine(dense_model, clock=clk, slots=2, buckets=(8,))
+    rng = np.random.default_rng(19)
+    p_free, p_tight = (rng.integers(0, 97, size=5),
+                       rng.integers(0, 97, size=6))
+    f_free = eng.submit(p_free, 8)
+    f_tight = eng.submit(p_tight, 8, token_budget_s=0.5)
+    eng.step()                     # both prefilled
+    eng.step()                     # one shared decode token
+    assert not f_free.done() and not f_tight.done()
+    clk.advance(1.0)               # the slow step
+    assert eng.sweep_expired() == 1
+    assert isinstance(f_tight.exception(timeout=0), DeadlineExceeded)
+    _drain(eng, [f_free])
+    ref = np.asarray(G.generate(dense_model, p_free[None, :],
+                                max_new_tokens=8))[0]
+    assert np.array_equal(f_free.result(timeout=0), ref)
+    s = eng.summary()
+    assert s["outcomes"]["expired"] == 1
+    assert s["outcomes"]["completed"] == 1
+    assert s["outcomes"]["failed"] == s["outcomes"]["stalled"] == 0
+    assert s["requests"] == sum(s["outcomes"].values())
+    assert s["pending"] == 0
+    eng.close()
+
+
 def test_queue_full_rejected(dense_model):
     eng = _engine(dense_model, slots=1, max_queue_depth=2,
                   buckets=(8,))
@@ -462,28 +493,3 @@ def test_fuse_tags_decode_shape_and_matches():
                   scope=Scope())
     assert np.allclose(np.asarray(ref[0]), np.asarray(out[0]),
                        rtol=1e-5, atol=1e-6)
-
-
-def test_static_baseline_mode_waits_for_cohort(dense_model):
-    """continuous=False is the pad-to-bucket baseline: no admission
-    while ANY slot is occupied — the straggler holds the whole cohort."""
-    eng = _engine(dense_model, slots=2, continuous=False,
-                  buckets=(8,))
-    rng = np.random.default_rng(18)
-    f_long = eng.submit(rng.integers(0, 97, size=4), 8)
-    f_short = eng.submit(rng.integers(0, 97, size=4), 2)
-    eng.step()                    # admits BOTH (all slots free)
-    _drain(eng, [f_short])
-    f_next = eng.submit(rng.integers(0, 97, size=4), 2)
-    eng.step()
-    assert not f_next.done() or f_long.done()
-    with eng._lock:
-        occupied = [r is not None for r in eng._slot_req]
-    if not f_long.done():
-        # the freed slot must NOT have been refilled while the
-        # straggler decodes
-        assert sum(occupied) == 1
-    _drain(eng, [f_long, f_next])
-    for f, n in ((f_long, 8), (f_short, 2), (f_next, 2)):
-        assert len(f.result(timeout=0)) == n
-    eng.close()

@@ -337,3 +337,84 @@ def test_dispatch_spans_only_recorded_while_profiling(tmp_path):
     names = {e["name"] for e in profiler._all_events()}
     assert {"executor.run.prepare", "executor.run.dispatch",
             "executor.run.fetch"} <= names
+
+
+# ---------------------------------------------------------------------------
+# end to end: the dispatch modes of one train program, by what they count
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def dispatch_scenario():
+    """One small train program through the PUBLIC Executor.run on
+    device-resident feeds, in the three steady states an operator can
+    be in: the run plan rebuilt every call (dropped by hand between
+    calls), both caches hot with fetches left on the device, and both
+    hot with every fetch brought to the host.  A CPU run says nothing
+    of their times; it does say which cache each call hit and where
+    each fetch lives, from the monitor's counters."""
+    from paddle_tpu import monitor
+
+    steps = 12
+    main, startup, loss = _train_program()
+    exe = fluid.Executor()
+    scope = fluid.Scope()
+    exe.run(startup, scope=scope)
+    feed = {k: jax.device_put(v) for k, v in _batches(n=1)[0].items()}
+
+    def run_once(return_numpy=False):
+        return exe.run(main, feed=feed, fetch_list=[loss], scope=scope,
+                       return_numpy=return_numpy)[0]
+
+    def counted(prep=None, return_numpy=False):
+        before = dict(monitor.snapshot().get("counters", {}))
+        outs = []
+        for _ in range(steps):
+            if prep is not None:
+                prep()
+            outs.append(run_once(return_numpy))
+        after = monitor.snapshot().get("counters", {})
+        return outs, {k: after.get(k, 0) - before.get(k, 0)
+                      for k in ("run_plan.hit", "run_plan.miss",
+                                "compiled_step.hit", "compiled_step.miss")}
+
+    def drop_plan():
+        main._run_plan_cache = None
+
+    monitor.reset()
+    monitor.enable()
+    try:
+        first = float(np.asarray(run_once()))       # trace + compile
+        dropped, n_dropped = counted(prep=drop_plan)
+        hot, n_hot = counted()
+        blocking, n_blocking = counted(return_numpy=True)
+        losses = [first] + [float(np.asarray(v))
+                            for v in dropped + hot + blocking]
+        return {
+            "a_dropped_plan_is_rebuilt_and_the_step_is_not_recompiled":
+                n_dropped == {"run_plan.hit": 0, "run_plan.miss": steps,
+                              "compiled_step.hit": steps,
+                              "compiled_step.miss": 0},
+            "hot_caches_miss_nothing":
+                n_hot["run_plan.hit"] == steps
+                and n_hot["run_plan.miss"] == 0
+                and n_hot["compiled_step.miss"] == 0
+                and n_blocking["run_plan.miss"] == 0
+                and n_blocking["compiled_step.miss"] == 0,
+            "fetches_stay_on_the_device_unless_asked_for":
+                all(isinstance(v, jax.Array) for v in dropped + hot)
+                and all(isinstance(v, np.ndarray) for v in blocking),
+            "one_loss_trajectory_across_the_modes":
+                all(np.isfinite(losses)) and losses[-1] < losses[0],
+        }
+    finally:
+        monitor.disable()
+        monitor.reset()
+
+
+@pytest.mark.parametrize("check", [
+    "a_dropped_plan_is_rebuilt_and_the_step_is_not_recompiled",
+    "hot_caches_miss_nothing",
+    "fetches_stay_on_the_device_unless_asked_for",
+    "one_loss_trajectory_across_the_modes"])
+def test_dispatch_modes_of_one_train_program(dispatch_scenario, check):
+    assert dispatch_scenario[check], dispatch_scenario

@@ -8,12 +8,13 @@ bounded-timeout death detection, leave/join intents, drain signal,
 transition window -> /healthz + /metrics), the skew policy ladder
 (warn → rebalance → evict with hysteresis and share quantization), the
 taxonomy/retry agreement on "a rank died", and the executor's
-elastic= hook.  The REAL multi-process kill/reshard/rejoin arc runs in
-``python bench.py elastic_fleet_smoke``.
+elastic= hook.  The REAL multi-process kill/reshard/rejoin arc
+(tests/dist_worker_elastic.py) runs at the end of this file.
 """
 
 import json
 import os
+import sys
 import threading
 import time
 import urllib.error
@@ -899,3 +900,161 @@ def test_checkpointless_preempt_warning_names_the_flags():
     msg = "".join(str(w.message) for w in rec)
     assert "checkpoint=" in msg
     assert "SIGUSR1" in msg
+
+
+# ---------------------------------------------------------------------------
+# two real processes: kill one, reshard, let it rejoin
+# ---------------------------------------------------------------------------
+
+def test_a_killed_rank_is_resharded_around_and_rejoins(tmp_path):
+    """A REAL 2-process CPU-mesh dp train (tests/dist_worker_elastic.py)
+    in which rank 1 is KILLED at a step boundary (InjectedCrash at
+    `elastic.step_boundary`), the survivor reshards 2->1 in process and
+    trains on, a join intent grows the fleet 1->2 through a relaunch,
+    and the pair finishes; beside it an uninterrupted reference with the
+    SAME topology schedule (2 procs, 1 proc, 2 procs at the same
+    boundaries, no kill, no elastic machinery), since dp arithmetic
+    depends on the shard count.  One test for the whole arc: the
+    processes are real and share the CPU with the other workers, so a
+    run that goes wrong costs one failure, which names every statement
+    about the recovery that did not hold."""
+    from paddle_tpu.distributed.launch import start_procs
+    from paddle_tpu.resilience.elastic import request_join
+
+    total, kill_at, grow_at, batch = 12, 4, 8, 8
+    tmp = str(tmp_path)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    worker = os.path.join(repo, "tests", "dist_worker_elastic.py")
+
+    def run_phase(run, phase, nproc, start, end, elastic_on,
+                  expect_rc=None):
+        out_dir = os.path.join(tmp, run)
+        cfg = {"phase": phase, "ckpt_dir": os.path.join(tmp, f"ck_{run}"),
+               "out_dir": out_dir, "total_steps": total,
+               "kill_at": kill_at, "grow_at": grow_at, "batch": batch,
+               "start_step": start, "end_step": end,
+               "elastic": elastic_on, "peer_timeout_s": 8.0,
+               "report": os.path.join(out_dir, "report")}
+        os.makedirs(out_dir, exist_ok=True)
+        cpath = os.path.join(out_dir, f"cfg_{phase}.json")
+        with open(cpath, "w") as f:
+            json.dump(cfg, f)
+        procs, logs = start_procs(
+            node_ips=["127.0.0.1"], node_ip="127.0.0.1",
+            nproc_per_node=nproc, training_script=worker,
+            script_args=(cpath,),
+            log_dir=os.path.join(out_dir, f"logs_{phase}"),
+            env_extra={"PYTHONPATH": repo + os.pathsep
+                       + os.environ.get("PYTHONPATH", ""),
+                       "PADDLE_RENDEZVOUS_TIMEOUT": "60"})
+        deadline = time.time() + 180
+        while time.time() < deadline and any(
+                p.poll() is None for p in procs):
+            time.sleep(0.3)
+        for p in procs:
+            if p.poll() is None:
+                p.kill()
+                p.wait()
+        for f in logs:
+            f.close()
+        rcs = [p.poll() for p in procs]
+        want = expect_rc if expect_rc is not None else [0] * nproc
+        ok = all((r == 0) == (w == 0) for r, w in zip(rcs, want))
+        reports = {}
+        for r in range(nproc):
+            rp = f"{cfg['report']}.{phase}.r{r}"
+            if os.path.isfile(rp):
+                with open(rp) as f:
+                    reports[r] = json.load(f)
+        return ok, rcs, reports
+
+    checks = {}
+
+    # ---- chaos run: kill at kill_at, rejoin at grow_at ---------------
+    request_join(os.path.join(tmp, "ck_chaos"), 1, after_step=grow_at)
+    ok_a, rcs_a, rep_a = run_phase("chaos", "chaos_a", 2, 0, total, True,
+                                   expect_rc=[0, 1])
+    r0a = rep_a.get(0) or {}
+    checks["chaos_a_procs"] = ok_a and 0 in rep_a
+    checks["kill_fired"] = rcs_a[1] not in (0, None) and 1 not in rep_a
+    events = r0a.get("events") or []
+    death = next((e for e in events if e["kind"] == "rank_death"), None)
+    checks["rank_death_named"] = (death is not None
+                                  and death["ranks"] == [1]
+                                  and death["step"] == kill_at)
+    checks["shrunk_at_kill"] = r0a.get("shrunk_at") == kill_at
+    health = r0a.get("health") or {}
+    checks["healthz_503_during_transition"] = (
+        (health.get("during") or {}).get("status") == 503
+        and (health.get("during") or {}).get("reason")
+        == "elastic_transition")
+    checks["healthz_ok_after_commit"] = (
+        (health.get("after") or {}).get("status") == 200
+        and (health.get("after") or {}).get("ok") is True)
+    checks["grow_relaunch"] = (r0a.get("exit_action") == "relaunch"
+                               and r0a.get("steps_done") == grow_at
+                               and r0a.get("ckpt_latest") == grow_at)
+    counters = r0a.get("counters") or {}
+    checks["elastic_counters"] = (
+        counters.get("resilience.elastic_transitions") == 2
+        and counters.get("resilience.elastic_shrinks") == 1
+        and counters.get("resilience.elastic_grows") == 1
+        and counters.get("resilience.elastic_rank_deaths", 0) >= 1
+        and counters.get("resilience.elastic_reshards") == 1
+        and counters.get("resilience.elastic_rank_joins") == 1)
+    checks["process_count_gauge"] = (
+        (r0a.get("gauges") or {}).get("fleet.process_count") == 2)
+
+    ok_b, _, rep_b = run_phase("chaos", "chaos_b", 2, grow_at, total, True)
+    r0b = rep_b.get(0) or {}
+    checks["chaos_b_procs"] = ok_b and 0 in rep_b
+    checks["rejoin_resumed"] = (
+        r0b.get("restored_step") == grow_at
+        and (r0b.get("counters") or {})
+        .get("resilience.elastic_resumes") == 1
+        and r0b.get("steps_done") == total)
+    checks["topology_provenance"] = (
+        (r0b.get("restored_topology") or {}).get("world") == 1)
+
+    # ---- clean reference: same topology schedule, no kill ------------
+    ok_c1, _, rep_c1 = run_phase("clean", "clean_a", 2, 0, kill_at, False)
+    ok_c2, _, rep_c2 = run_phase("clean", "clean_b", 1, kill_at, grow_at,
+                                 False)
+    ok_c3, _, rep_c3 = run_phase("clean", "clean_c", 2, grow_at, total,
+                                 False)
+    checks["clean_reference_ran"] = ok_c1 and ok_c2 and ok_c3
+    final_chaos = r0b.get("final_params")
+    final_clean = (rep_c3.get(0) or {}).get("final_params")
+    checks["params_bitwise_identical"] = (
+        final_chaos is not None and final_clean is not None
+        and set(final_chaos) == set(final_clean)
+        and all(np.array_equal(np.asarray(final_chaos[n]),
+                               np.asarray(final_clean[n]))
+                for n in final_chaos))
+    # the loss streams line up leg by leg too (same batches, same
+    # worlds): A(0..kill) + shrunken(kill..grow) + B(grow..end)
+    chaos_losses = (r0a.get("losses") or []) + (r0b.get("losses") or [])
+    clean_losses = [x for rep in (rep_c1, rep_c2, rep_c3)
+                    for x in (rep.get(0) or {}).get("losses") or []]
+    checks["loss_stream_identical"] = (
+        len(chaos_losses) == total and chaos_losses == clean_losses)
+
+    # ---- topology history in the merged fleet report -----------------
+    sys.path.insert(0, repo)
+    try:
+        from tools.telemetry_report import fleet_merge, summarize_fleet
+    finally:
+        sys.path.pop(0)
+    tdir = os.path.join(tmp, "chaos", "telemetry")
+    by_rank, merged = fleet_merge(sorted(
+        os.path.join(tdir, p) for p in os.listdir(tdir)
+        if p.endswith(".jsonl")))
+    trans = (summarize_fleet(by_rank, merged).get("elastic_topology")
+             or {}).get("transitions") or []
+    checks["topology_history_reported"] = (
+        len(trans) == 2
+        and trans[0].get("transition") == "shrink"
+        and trans[0].get("to_world") == 1
+        and trans[1].get("transition") == "grow"
+        and trans[1].get("to_world") == 2)
+    assert not [name for name, held in checks.items() if not held], checks

@@ -8,12 +8,15 @@ mesh), and the live /metrics + /healthz exporter (Prometheus text
 round-trip, scrape == snapshot, serving outcome-ledger identity on the
 scrape itself, breaker-driven health) — plus the flight-recorder rank
 tagging satellite.  The REAL 2-process wiring is covered by
-tests/test_dist_collective.py (rank-stream merge) and
-`python bench.py fleet_obs_smoke` (injected straggler).
+tests/test_dist_collective.py (rank-stream merge) and, at the end of
+this file, by a 2-process run with an injected straggler
+(tests/dist_worker_fleet.py).
 """
 
 import json
 import os
+import sys
+import time
 import urllib.request
 
 import numpy as np
@@ -584,3 +587,111 @@ def test_telemetry_report_fleet_merge(tmp_path):
     assert s["step_time_straggler"]["rank"] == "hostX:p1"
     # ...and the probe's own table, riding the merged stream
     assert s["fleet_skew"]["straggler"]["process_index"] == 1
+
+
+# ---------------------------------------------------------------------------
+# two real processes, one of them slow
+# ---------------------------------------------------------------------------
+
+def test_two_processes_name_their_straggler(tmp_path):
+    """A REAL 2-process CPU-mesh dp train through the public Executor
+    path (tests/dist_worker_fleet.py), rank 1 stalled on every step by
+    `faultinject.stall_point("executor.step")`.  One test for the whole
+    run: a failure names every thing the two ranks wrote down that did
+    not hold."""
+    from paddle_tpu.distributed.launch import _wait, start_procs
+
+    steps = 12
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = str(tmp_path / "out.json")
+    log_dir = str(tmp_path / "logs")
+    procs, logs = start_procs(
+        node_ips=["127.0.0.1"], node_ip="127.0.0.1", nproc_per_node=2,
+        training_script=os.path.join(repo, "tests",
+                                     "dist_worker_fleet.py"),
+        script_args=(out, "0.08", str(steps)), log_dir=log_dir,
+        env_extra={"PYTHONPATH": repo + os.pathsep
+                   + os.environ.get("PYTHONPATH", ""),
+                   "PADDLE_RENDEZVOUS_TIMEOUT": "60"})
+    deadline = time.time() + 240
+    while time.time() < deadline and any(p.poll() is None for p in procs):
+        time.sleep(0.5)
+    for p in procs:
+        if p.poll() is None:
+            p.kill()
+    rc = _wait(procs, logs)
+    assert rc == 0, "\n".join(
+        f"--- {name}:\n" + open(os.path.join(log_dir, name)).read()[-2000:]
+        for name in sorted(os.listdir(log_dir)))
+    results = {}
+    for r in (0, 1):
+        with open(f"{out}.r{r}") as f:
+            results[r] = json.load(f)
+    r0 = results[0]
+    checks = {}
+
+    # the straggler is named, on BOTH ranks' own tables
+    for r in (0, 1):
+        st = (results[r]["table"] or {}).get("straggler") or {}
+        checks[f"straggler_named_r{r}"] = (
+            st.get("dp_index") == 1 and st.get("process_index") == 1)
+
+    # the rolling table recomputes EXACTLY from the raw per-step wait
+    # vectors, with the formulas and rounding monitor.fleet uses
+    rows = (r0.get("rows") or [])
+    checks["rows_complete"] = len(rows) == steps
+    rows = rows[-r0["window"]:]
+    times = [r["step_time_s"] for r in rows
+             if (r.get("step_time_s") or 0) > 0]
+    mean_step_us = sum(times) / len(times) * 1e6 if times else None
+    recomputed = []
+    for i in range(2):
+        waits = [r["waits_us"][i] for r in rows]
+        behind = [max(r["waits_us"]) - r["waits_us"][i] for r in rows]
+        row = {"wait_us_mean": round(sum(waits) / len(waits), 1),
+               "behind_us_mean": round(sum(behind) / len(behind), 1)}
+        if mean_step_us:
+            row["wait_frac"] = round(
+                sum(waits) / len(waits) / mean_step_us, 4)
+            row["straggler_score"] = round(
+                sum(behind) / len(behind) / mean_step_us, 4)
+        recomputed.append(row)
+    table_ranks = (r0.get("table") or {}).get("ranks") or []
+    checks["wait_frac_recomputed_exactly"] = (
+        len(table_ranks) == 2 and all(
+            all(trow.get(k) == rrow[k] for k in rrow)
+            for trow, rrow in zip(table_ranks, recomputed)))
+
+    # rank 0's live /metrics scrape against its own snapshot()
+    parsed = (r0.get("metrics") or {}).get("parsed") or {}
+    checks["metrics_scrape_parses"] = len(parsed) > 0
+
+    def prom(name, kind=None):
+        return exporter.metric_key(exporter.exported_name(name, kind))
+
+    counters = r0.get("snapshot_counters") or {}
+    checks["scrape_matches_snapshot"] = bool(counters) and all(
+        parsed.get(prom(n, "counter")) == float(v)
+        for n, v in counters.items()) and all(
+        parsed.get(prom(n)) == float(v)
+        for n, v in (r0.get("snapshot_gauges") or {}).items())
+    health = (r0.get("metrics") or {}).get("health") or {}
+    checks["healthz_ok"] = (health.get("ok") is True
+                            and health.get("status") == 200)
+
+    # the rank-tagged streams merge with the right attribution
+    sys.path.insert(0, repo)
+    try:
+        from tools.telemetry_report import fleet_merge, summarize_fleet
+    finally:
+        sys.path.pop(0)
+    tdir = str(tmp_path / "telemetry")
+    by_rank, merged = fleet_merge(sorted(
+        os.path.join(tdir, p) for p in os.listdir(tdir)
+        if p.endswith(".jsonl")))
+    fsum = summarize_fleet(by_rank, merged)
+    checks["fleet_merge_two_ranks"] = fsum.get("ranks") == 2
+    checks["fleet_merge_names_straggler"] = (
+        ((fsum.get("fleet_skew") or {}).get("straggler") or {})
+        .get("process_index") == 1)
+    assert not [name for name, held in checks.items() if not held], checks

@@ -8,7 +8,7 @@ replica-kill chaos primitive, and exporter/report surfaces.
 Determinism strategy: replicas run IN-PROCESS (ReplicaServer on
 ephemeral loopback ports) so death is a closed socket the test
 controls; the REAL process kill (os._exit) is exercised once through a
-subprocess and at fleet scale by `bench.py fleet_serving_smoke`."""
+subprocess."""
 
 import http.client
 import json
@@ -520,10 +520,41 @@ def test_router_failover_absorbs_replica_death(registry):
         dead = [r for r in router.replicas if r.name == "r0"][0]
         assert not dead.healthy
         assert router.attempts_started == router.attempts_resolved
+        for _ in range(4):             # declared dead, not just stale
+            router.poll_once()
+        assert dead.dead
     finally:
         router.close(emit=False)
         for s in reps:
             s.close()
+
+
+def test_one_trace_id_spans_the_router_hop_and_the_replica(registry):
+    """With request tracing on, the router's tree (its `route:` span)
+    and the tree of the replica that served the request carry ONE
+    trace id: the router sends its context along, the replica joins
+    it."""
+    from paddle_tpu.monitor import tracing
+
+    old = fluid.get_flags(["FLAGS_request_tracing", "FLAGS_trace_sample"])
+    fluid.set_flags({"FLAGS_request_tracing": True,
+                     "FLAGS_trace_sample": 1.0})
+    router, reps = _mk_fleet(registry)
+    try:
+        router.run(_feed(1))
+        label = router.label
+        trees = tracing.get().retained_trees()
+        router_trees = [t for t in trees if t["label"] == label]
+        replica_ids = {t["trace_id"] for t in trees if t["label"] != label}
+        joined = [t for t in router_trees if t["trace_id"] in replica_ids]
+        assert joined
+        assert any("route:" in (s.get("name") or "")
+                   for t in joined for s in t["spans"])
+    finally:
+        router.close(emit=False)
+        for s in reps:
+            s.close()
+        fluid.set_flags(old)
 
 
 def test_router_rejects_when_no_replica_routable(registry):

@@ -360,6 +360,29 @@ def test_train_loop_substitutes_and_bare_run_does_not():
         monitor.disable()
 
 
+def test_fused_amp_program_is_attributed_op_by_op():
+    """A multi-op fused kernel is ONE scope of the op profile, not a
+    hole in it: under AMP + fusion the FLOPs that no scope owns stay
+    under 1% of bert's step, casts and fused ops included."""
+    fluid.set_flags({"FLAGS_amp": "on", "FLAGS_graph_opt_fuse": "on"})
+    m = _build("bert")
+    monitor.reset()
+    monitor.enable()
+    try:
+        prog = fluid.CompiledProgram(m.main).with_telemetry("fused_amp")
+        losses, _ = _train(m, prog)
+        split = monitor.op_profile_split(key="fused_amp")
+    finally:
+        monitor.disable()
+        monitor.reset()
+    assert np.all(np.isfinite(losses))
+    sub = next(iter(m.main._opt_cache.values()))
+    assert "fused_attention" in _fused_types(sub)
+    assert any(op.type == "cast" for op in sub.global_block().ops)
+    assert any("fused" in scope for scope in split["scopes"])
+    assert split["unattributed"]["flops_pct"] <= 1.0, split["unattributed"]
+
+
 def test_flag_on_extends_to_bare_run_and_off_is_clean():
     fluid.set_flags({"FLAGS_amp": "on", "FLAGS_graph_opt_fuse": "on"})
     m = _build("mlp")

@@ -325,6 +325,77 @@ def test_train_from_dataset_emits_exact_record():
     assert goodput.active() is None                # slot released
 
 
+@pytest.fixture(scope="module")
+def injected_badput(tmp_path_factory):
+    """One ledgered `train_from_dataset` on the dp mesh with badput of
+    known length injected: a data stall at `reader.prepare`
+    (prefetch=False, so it lands on the consumer thread), one transient
+    under a fixed jitter-free backoff, and a stall inside the
+    checkpoint save.  Run once; a case below for each place a delay
+    must land.  A sleep lasts at least as long as asked, so each bucket
+    is held to its injection from below; how much genuine work shares
+    it is not a CPU run's to say."""
+    import jax
+
+    from paddle_tpu.checkpoint import CheckpointManager
+
+    steps, batch = 8, 16
+    stall_s, backoff_s, ck_stall_s = 0.06, 0.04, 0.10
+    old_flag = fluid.get_flags("FLAGS_goodput")
+    monitor.reset()
+    monitor.enable()
+    fluid.set_flags({"FLAGS_goodput": True})
+    try:
+        main, startup, loss = _mlp(seed_dim=16)
+        prog = fluid.CompiledProgram(main).with_data_parallel(
+            loss_name=loss.name, places=len(jax.devices()))
+        mgr = CheckpointManager(
+            str(tmp_path_factory.mktemp("goodput_ckpt")),
+            save_interval_steps=6)
+        exe = fluid.Executor()
+        sc = fluid.Scope()
+        exe.run(startup, scope=sc)
+        resilience.enable_retry(resilience.RetryPolicy(
+            max_retries=3, base_delay=backoff_s, jitter=0.0, seed=0))
+        with resilience.plan_scope(
+                transient_at_step=5, transient_times=1,
+                stall_points={"reader.prepare": (3, stall_s),
+                              "checkpoint.save": ck_stall_s}):
+            exe.train_from_dataset(
+                prog, _batches(steps, rows=batch, dim=16), scope=sc,
+                fetch_list=[loss], checkpoint=mgr,
+                print_period=10 ** 6, prefetch=False)
+            fired = dict(resilience.faultinject.active_plan().fired)
+        rec = monitor.goodput_records()[-1]
+        cats, wall = rec["categories"], rec["wall_ns"]
+        return {
+            "injections_fired": fired.get("transient") == 1
+            and fired.get("stall") == 2,
+            "sum_exact": wall > 0 and sum(cats.values()) == wall,
+            "unattributed_le_1pct": cats["unattributed"] <= 0.01 * wall,
+            "data_stall_in_data_wait": cats["data_wait"] >= stall_s * 1e9,
+            "retry_backoff_in_recovery":
+                cats["recovery"] >= backoff_s * 1e9,
+            "checkpoint_stall_in_checkpoint_save":
+                cats["checkpoint_save"] >= ck_stall_s * 1e9,
+            "steps_counted": rec["steps"] == steps,
+        }
+    finally:
+        resilience.disable_retry()
+        resilience.faultinject.disarm()
+        fluid.set_flags(old_flag)
+        monitor.disable()
+        monitor.reset()
+
+
+@pytest.mark.parametrize("check", [
+    "injections_fired", "sum_exact", "unattributed_le_1pct",
+    "data_stall_in_data_wait", "retry_backoff_in_recovery",
+    "checkpoint_stall_in_checkpoint_save", "steps_counted"])
+def test_injected_badput_lands_in_its_own_category(injected_badput, check):
+    assert injected_badput[check], injected_badput
+
+
 def test_flag_off_is_byte_for_byte_never_ledgered():
     """The FLAGS_goodput=off pin (FLAGS_static_check=off style): the
     off path creates NO ledger, emits NO record, and its numerics are

@@ -1,6 +1,6 @@
 """NHWC (channels-last, TPU-native) vs NCHW numeric parity.
 
-The ResNet-50 A/B grid's layout lever (bench.py resnet50_sweep) is only
+The train step's layout lever (README, "Train-step levers") is only
 trustworthy if the two layouts compute the same math — this pins forward
 AND backward (gradient) parity in fp32 on the CPU mesh at tolerance
 <= 1e-3, for both the dygraph model path (models/resnet.py data_format=)

@@ -27,21 +27,25 @@ def _clean_monitor():
     monitor.reset()
 
 
-def _toy_train_program():
+_TOOLS = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "tools")
+
+
+def _toy_train_program(width=8):
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):
-        x = fluid.data("x", [None, 8])
+        x = fluid.data("x", [None, width])
         y = fluid.data("y", [None, 1])
-        h = fluid.layers.fc(x, 8, act="relu")
+        h = fluid.layers.fc(x, width, act="relu")
         pred = fluid.layers.fc(h, 1)
         loss = fluid.layers.mean(fluid.layers.square_error_cost(pred, y))
         fluid.optimizer.SGD(0.1).minimize(loss)
     return main, startup, loss
 
 
-def _feed(batch=16):
+def _feed(batch=16, width=8):
     rng = np.random.default_rng(0)
-    return {"x": rng.standard_normal((batch, 8)).astype(np.float32),
+    return {"x": rng.standard_normal((batch, width)).astype(np.float32),
             "y": rng.standard_normal((batch, 1)).astype(np.float32)}
 
 
@@ -448,13 +452,13 @@ def test_registry_reset_clears_gauge_series():
 
 @pytest.fixture
 def _flight_dir(tmp_path):
+    old = fluid.get_flags("FLAGS_flight_recorder_dir")
     fluid.set_flags({"FLAGS_flight_recorder_dir": str(tmp_path)})
     fr = flight_recorder.get()
     fr.clear()
     yield str(tmp_path)
     fr.clear()
-    fluid.set_flags(
-        {"FLAGS_flight_recorder_dir": "/tmp/paddle_tpu_flight"})
+    fluid.set_flags(old)
 
 
 def test_flight_recorder_dump_after_injected_crash(_flight_dir):
@@ -551,14 +555,13 @@ def test_flight_recorder_shares_session_records(_flight_dir):
 
 
 # ---------------------------------------------------------------------------
-# tools + bench wiring
+# tools
 # ---------------------------------------------------------------------------
 
 def test_telemetry_report_op_and_resilience_sections(tmp_path):
     import subprocess
     import sys
 
-    import bench
 
     jsonl = str(tmp_path / "t.jsonl")
     with fluid.unique_name.guard():
@@ -570,8 +573,7 @@ def test_telemetry_report_op_and_resilience_sections(tmp_path):
     monitor.counter("resilience.retries").add(2)
     exe.run(main, feed=_feed(), fetch_list=[loss], scope=scope)
     monitor.disable()
-    tool = bench.os.path.join(bench.os.path.dirname(bench.__file__),
-                              "tools", "telemetry_report.py")
+    tool = os.path.join(_TOOLS, "telemetry_report.py")
     r = subprocess.run([sys.executable, tool, jsonl],
                        capture_output=True, text=True, timeout=120)
     assert r.returncode == 0, r.stderr
@@ -583,7 +585,6 @@ def test_parse_xplane_groups_sampled_trace_by_scope(tmp_path):
     import subprocess
     import sys
 
-    import bench
 
     with fluid.unique_name.guard():
         main, startup, loss = _toy_train_program()
@@ -595,10 +596,76 @@ def test_parse_xplane_groups_sampled_trace_by_scope(tmp_path):
         exe.run(main, feed=_feed(), fetch_list=[loss], scope=scope)
     path = str(tmp_path / "prof") + ".json"
     profiler.stop_profiler(profile_path=str(tmp_path / "prof"))
-    tool = bench.os.path.join(bench.os.path.dirname(bench.__file__),
-                              "tools", "parse_xplane.py")
+    tool = os.path.join(_TOOLS, "parse_xplane.py")
     r = subprocess.run([sys.executable, tool, path],
                        capture_output=True, text=True, timeout=120)
     assert r.returncode == 0, r.stderr
     assert "per-op attribution" in r.stdout
     assert "fwd0/" in r.stdout
+
+
+# ---------------------------------------------------------------------------
+# end to end: attribution of a data-parallel train loop
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def attribution_scenario():
+    """Six steps of a small fc train program through the PUBLIC
+    Executor.run, data-parallel over the test mesh, telemetry on; run
+    once, each attribution invariant is a case below."""
+    import jax
+
+    monitor.reset()
+    monitor.enable()
+    try:
+        with fluid.unique_name.guard():
+            main, startup, loss = _toy_train_program(width=64)
+        prog = fluid.CompiledProgram(main).with_data_parallel(
+            loss_name=loss.name, places=len(jax.devices())
+        ).with_telemetry("attribution_scenario")
+        exe = fluid.Executor()
+        scope = fluid.Scope()
+        exe.run(startup, scope=scope)
+        feed = _feed(64, width=64)
+        for _ in range(6):
+            exe.run(prog, feed=feed, fetch_list=[loss], scope=scope,
+                    return_numpy=False)
+        split = monitor.op_profile_split()
+        snap = monitor.snapshot()
+        expected = {s for s, _ in op_scope_names(prog, [loss.name])}
+        checks = {"split_present": split is not None}
+        if split is None:
+            return checks
+        scopes = split["scopes"]
+        flops_sum = sum(d["flops"] for d in scopes.values()) \
+            + split["unattributed"]["flops"]
+        bytes_sum = sum(d["bytes_accessed"] for d in scopes.values()) \
+            + split["unattributed"]["bytes_accessed"]
+        checks.update({
+            # exact: split_by_scope assigns the float remainder, so
+            # == (not approx) is the contract under test
+            "flops_sum_exact": flops_sum == split["totals"]["flops"]
+            and split["totals"]["flops"] > 0,
+            "bytes_sum_exact":
+                bytes_sum == split["totals"]["bytes_accessed"],
+            # every ProgramDesc op of the compiled section under its own
+            # scope name; framework-inserted dp-sync collectives carry
+            # scopes of their own on top
+            "all_ops_scoped": expected <= set(scopes),
+            "residual_under_1pct":
+                split["unattributed"]["flops_pct"] <= 1.0,
+            "snapshot_rows": bool(snap.get("op_profile"))
+            and json.dumps(snap["op_profile"]) is not None,
+        })
+        return checks
+    finally:
+        monitor.disable()
+        monitor.reset()
+
+
+@pytest.mark.parametrize("check", [
+    "split_present", "flops_sum_exact", "bytes_sum_exact",
+    "all_ops_scoped", "residual_under_1pct", "snapshot_rows"])
+def test_attribution_of_a_data_parallel_train_loop(attribution_scenario,
+                                                   check):
+    assert attribution_scenario.get(check), attribution_scenario

@@ -578,6 +578,53 @@ def test_zoo_optimize_execute_parity(name):
     np.testing.assert_allclose(got[0], ref[0], rtol=0, atol=0)
 
 
+@pytest.fixture(scope="module")
+def zoo_fold_sweep():
+    """Every zoo model's inference clone through `fold_inference` with
+    its real startup-initialized parameter values (so conv+BN folding
+    is live), run beside the unoptimized program on one feed: per model
+    the op-count reduction and whether the outputs agree."""
+    out = {}
+    for name in sorted(static_zoo.BUILDERS):
+        with fluid.unique_name.guard():
+            m = static_zoo.build(name)
+        exe = fluid.Executor()
+        scope = Scope()
+        exe.run(m.startup, scope=scope)
+        test = m.main.clone(for_test=True)
+        params = {n: np.asarray(v) for n, v in scope.vars.items()
+                  if v is not None}
+        opt, opt_params, rep = passes.fold_inference(
+            test, params, fetch_names=[m.loss_name], record=False)
+        feed = m.smoke_feed(batch=8)
+        ref = exe.run(test, feed=feed, fetch_list=[m.loss_name],
+                      scope=scope)
+        opt_scope = Scope()
+        for n, v in opt_params.items():
+            opt_scope.set_var(n, jnp.asarray(v))
+        got = exe.run(opt, feed=feed, fetch_list=[m.loss_name],
+                      scope=opt_scope)
+        out[name] = {
+            "allclose": all(np.allclose(a, b, rtol=1e-4, atol=1e-5)
+                            for a, b in zip(ref, got)),
+            "reduction": (rep["before_ops"] - rep["after_ops"])
+            / rep["before_ops"],
+        }
+    return out
+
+
+@pytest.mark.parametrize("name", sorted(static_zoo.BUILDERS))
+def test_zoo_fold_inference_keeps_the_outputs(zoo_fold_sweep, name):
+    assert zoo_fold_sweep[name]["allclose"], zoo_fold_sweep
+
+
+def test_zoo_fold_inference_drops_a_tenth_of_the_ops_on_3_models(
+        zoo_fold_sweep):
+    reduced = [n for n, r in zoo_fold_sweep.items()
+               if r["reduction"] >= 0.10]
+    assert len(reduced) >= 3, zoo_fold_sweep
+
+
 def test_pass_pipeline_record_emitted():
     monitor.reset()
     monitor.enable()
@@ -752,6 +799,7 @@ def test_dp_bucketed_training_bitwise():
     assert s1["mode"] == "bucketed"
     assert 0 < s1["psums"] <= -(-s1["total_bytes"] // 256)
     assert s2["mode"] == "bucketed" and s2["psums"] == 1
+    assert s1["fallbacks"] == 0 and s2["fallbacks"] == 0
     for name, params_k in (("tiny", tiny), ("big", big)):
         assert set(params_k) == set(base)
         for n in base:

@@ -9,7 +9,7 @@ make_train_step passes params, buffers, rng, and the batch explicitly —
 and these tests pin that property on the CPU mesh:
 
 - a recompute-wrapped ResNet block trains under jit (fwd+bwd) without a
-  tracer leak, inside the exact jit(scan(donate)) harness bench.py times;
+  tracer leak, inside a jit(scan(donate)) harness;
 - gradients match the unrecomputed path (remat changes scheduling, not
   math);
 - the bf16 / NHWC / ghost-BN-stats combination of the on-chip sweep

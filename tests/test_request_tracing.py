@@ -17,6 +17,7 @@ keep-rule — no wall-clock guesses anywhere."""
 import collections
 import glob
 import json
+import threading
 import time
 
 import numpy as np
@@ -236,6 +237,35 @@ def test_runtime_traces_reconcile_with_ledger(served_model):
         assert "queue" in names
         assert any(n.startswith("dispatch/b") for n in names)
     assert store.active_traces(label) == []            # all closed
+
+
+def test_a_watchdog_stall_is_charged_to_the_victims_tree(served_model):
+    """A dispatch that hangs past the watchdog is abandoned and the
+    request re-dispatched: it is served, and its tree charges the time
+    lost to the hang to `stall`, with the components still summing
+    exactly to the total."""
+    _, pred = served_model
+    _tracing_on()
+    hang = threading.Event()
+    # prewarmed: the re-dispatch must not meet a compile under the
+    # watchdog
+    rt = _mk(pred, prewarm=True, watchdog_stall_s=0.1,
+             watchdog_poll_s=0.02, watchdog_policy="cancel_retry")
+    try:
+        faultinject.arm(stall_points={"serving.dispatch": hang})
+        rt.run(_feed(2), timeout=30)   # stall -> abandon -> re-dispatch
+        assert rt.stats.watchdog_stalls >= 1
+        assert rt.stats.summary()["outcomes"]["completed"] == 1
+    finally:
+        hang.set()
+        rt.close()
+        faultinject.disarm()
+    (tree,) = tracing.get().retained_trees(rt.config.label)
+    assert tree["outcome"] == "completed"
+    assert tree["components_ns"].get("stall", 0) > 0
+    assert tree_problems(tree) == []
+    assert components_of(tree) == tree["components_ns"]
+    assert sum(tree["components_ns"].values()) == tree["total_ns"]
 
 
 def test_runtime_joins_external_traceparent(served_model):

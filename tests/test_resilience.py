@@ -462,6 +462,9 @@ def test_preempt_then_auto_resume_bitwise_identical(mon, tmp_path):
     assert c.get("resilience.preempt_checkpoint") == 1
     assert c.get("resilience.auto_resume") == 1
     assert c.get("resilience.batches_skipped") == 5
+    # the forced save left its duration on the gauge the trace plots
+    assert monitor.snapshot()["gauges"].get(
+        "resilience.last_save_s") is not None
 
 
 def test_sigterm_requests_preemption():

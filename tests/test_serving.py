@@ -708,17 +708,16 @@ def test_request_spans_in_profiler(served_model):
 
     _, pred = served_model
     rt = _mk(pred, auto_start=False)
-    profiler.start_profiler("All")
     try:
-        fut = rt.submit(_feed(1))
-        rt.process_once()
-        fut.result(timeout=5)
-        names = [e["name"] for e in profiler._all_events()]
+        with profiler.profiler("All", profile_path=None):
+            fut = rt.submit(_feed(1))
+            rt.process_once()
+            fut.result(timeout=5)
+            names = [name for name, _, _, _ in profiler.spans()]
         assert any(n.startswith("serving.request/") for n in names)
         assert any(n.startswith("serving.dispatch/") for n in names)
     finally:
         profiler.reset_profiler()
-        profiler._active["on"] = False
         rt.close()
 
 

@@ -7,10 +7,9 @@ collective table the executor notes verbatim), the shared
 compiled-step cache rekeying on (rule fingerprint, mesh device
 identity), a REAL ``{dp=2, mp=2}`` train run with verifiably sharded
 leaves and predicted==executed model collectives, and the
-``program_lint --lower`` CLI.  The dp-vs-tp loss conformance and the
-memory/elasticity pillars run end-to-end (with a dp reference compile)
-in ``python bench.py tp_runtime_smoke`` — re-running that second
-compile here would double CI cost for no new signal.
+``program_lint --lower`` CLI, and the same run against a pure-dp
+reference from the same init and feed (loss, peak memory, and the TP
+checkpoint resharded onto a {dp=4} mesh).
 """
 
 import json
@@ -163,7 +162,7 @@ def test_executor_tp_run_shards_leaves_and_conforms():
     """Acceptance (in-process half): a real {dp=2, mp=2} bert train
     step has (a) per-leaf sharded params/biases/moments exactly as the
     plan placed them, and (b) executed model collectives EQUAL to the
-    plan's prediction.  Loss-vs-dp and memory run in the bench row."""
+    plan's prediction.  Loss-vs-dp and memory: `tp_against_dp` below."""
     if len(jax.devices()) < 4:
         pytest.skip("needs 4 devices for {dp=2, mp=2}")
     m = _bert()
@@ -221,6 +220,87 @@ def test_executor_tp_run_shards_leaves_and_conforms():
     assert model.get("psums") == pred["count"] == 3
     assert model.get("total_bytes") == pred["bytes"] == 24576
     assert model.get("axes") == ["mp"]
+
+
+@pytest.fixture(scope="module")
+def tp_against_dp(tmp_path_factory):
+    """bert trained three steps on a REAL {dp=2, mp=2} mesh under its
+    default Megatron rules, against a pure-dp {dp=2} run from the SAME
+    init and feed (same local batch, so the memory delta isolates the
+    mp sharding), telemetry on; then the TP state saved with the npz
+    writer and restored onto {dp=4}.  Run once; each comparison is a
+    case below."""
+    from paddle_tpu import checkpoint as ckpt
+    from paddle_tpu import monitor
+    from paddle_tpu.distributed.mesh import build_rule_mesh
+
+    m = _bert()
+    feed = m.smoke_feed(batch=8, seed=11)
+    feed_shapes = {n: tuple(v.shape) for n, v in feed.items()}
+    plan_rec = sh.lower(m.main, m.partition_rules(),
+                        fetch_names=[m.loss_name],
+                        feed_names=sorted(feed_shapes),
+                        feed_shapes=feed_shapes).to_record()
+    exe = fluid.Executor()
+    init_scope = Scope()
+    exe.run(m.startup, scope=init_scope)
+    init_state = {n: np.asarray(v) for n, v in init_scope.vars.items()
+                  if v is not None}
+
+    def train(rules, key):
+        scope = Scope()
+        for n, v in init_state.items():
+            scope.set_var(n, v)
+        prog = fluid.CompiledProgram(m.main).with_sharding_rules(
+            rules, execute=True).with_telemetry(key)
+        losses = [float(np.mean(exe.run(
+            prog, feed=feed, fetch_list=[m.loss_name],
+            scope=scope)[0])) for _ in range(3)]
+        prof = monitor.mem_profile_split(key=f"{key}:dp") or {}
+        return scope, losses, (prof.get("peak") or {}).get(
+            "model_bytes") or 0
+
+    monitor.reset()
+    monitor.enable()
+    try:
+        _, dp_losses, dp_peak = train(
+            sh.PartitionRules([(r".*", [])], {"dp": 2}), "tp_vs_dp_dp")
+        tp_scope, tp_losses, tp_peak = train(m.partition_rules(),
+                                             "tp_vs_dp_tp")
+    finally:
+        monitor.disable()
+        monitor.reset()
+    static_peak = (plan_rec["static_peak_bytes"]
+                   + plan_rec["static_state_bytes"])
+
+    # the TP checkpoint (sharded leaves; npz, the collective-free writer
+    # an elastic survivor would use) onto {dp=4}
+    ckpt_dir = str(tmp_path_factory.mktemp("tp_ckpt"))
+    tp_state = {n: v for n, v in tp_scope.vars.items() if v is not None}
+    ckpt.save_checkpoint(ckpt_dir, tp_state, 3, writer="npz")
+    template = {n: np.empty(np.shape(v), v.dtype)
+                for n, v in tp_state.items()}
+    restored, _ = ckpt.restore_resharded(
+        ckpt_dir, template, mesh=build_rule_mesh({"dp": 4}))
+    return {
+        "loss_allclose_vs_dp": bool(np.allclose(
+            dp_losses, tp_losses, rtol=2e-3, atol=2e-5)),
+        "mem_within_25pct": tp_peak > 0 and abs(
+            static_peak - tp_peak) / tp_peak <= 0.25,
+        "tp_peak_below_dp_peak": 0 < tp_peak < dp_peak,
+        "topology_mesh_axes": (ckpt.load_topology(ckpt_dir) or {}).get(
+            "mesh_axes") == {"dp": 2, "mp": 2},
+        "ckpt_reshard_bitwise": all(
+            np.array_equal(np.asarray(restored[n]), np.asarray(v))
+            for n, v in tp_state.items()),
+    }
+
+
+@pytest.mark.parametrize("check", [
+    "loss_allclose_vs_dp", "mem_within_25pct", "tp_peak_below_dp_peak",
+    "topology_mesh_axes", "ckpt_reshard_bitwise"])
+def test_tp_run_against_its_dp_reference(tp_against_dp, check):
+    assert tp_against_dp[check], tp_against_dp
 
 
 # ---------------------------------------------------------------------

@@ -10,7 +10,7 @@ wall-clock column mainly confirms the interleaved schedule adds no
 overhead.  On real chips the fill ticks are idle hardware and the
 analytic fraction IS the wall-clock saving.
 
-Usage: python tools/pipeline_bubble_bench.py [pp] [virtual] [microbatches]
+Usage: python tools/pipeline_bubble.py [pp] [virtual] [microbatches]
 """
 
 import os
