@@ -75,11 +75,61 @@ into a previously-released slot, emits token-for-token what
 generate() emits (greedy; asserted dense + MoE in
 tests/test_decode_serving.py).
 
+**The loop runs one decode step ahead of its host** (ISSUE 32).  A
+step's inputs never leave the device (`token/pos/active/stop/eos` are
+in the donated state, and `_decode_step_impl` itself drops a slot that
+reached its stop or its eos), so step n+1 needs nothing the host learns
+from step n.  The loop thread (`_run_ahead`) therefore keeps one step
+queued on the device behind the one that is running, and reads an
+answer only after the work that follows it has been enqueued.  Going
+into an iteration the device's queue holds step n (running), the
+prefills admitted last time and step n+1; the iteration collects n
+(`engine.decode_wait`) and emits it (`engine.emit`), collects those
+prefills and books their first tokens (`engine.prefill_wait`,
+`engine.prefill_book`), sweeps budgets (`engine.sweep`), listens
+(`engine.listen_wait`: it waits on the submit condition while the
+device works on n+1, and each arrival is admitted, `engine.admit`, and
+its prefill enqueued, `engine.prefill_host`, at once, behind n+1), and
+enqueues step n+2 (`engine.decode_host`) with the kill mask and the
+slot -> request snapshot of that moment, which stay with the step until
+it is emitted: all inside one `engine.step` span.  `step()`, by hand,
+runs the same primitives (`_enqueue_prefill`, `_enqueue_step`,
+`_collect`) serially: every program is answered before the next goes
+out, and nothing is in flight when it returns.
+
+What holds in either order.  (1) Every time is read when the token is
+on the host: `first_token_t`, `last_token_t` and what `note_prefill`,
+`note_decode_step` and `note_token_latency` get take the clock after
+the answer is fetched, so a first token pays its prefill's device time
+and the step it queued behind.  (2) A request that answers a token of
+step n is prefilled directly behind step n+1: after the emit the loop
+listens, and sends step n+2 only when the device's queue is about to
+run dry (`_deadline`: when n+1 started, plus what the fastest of the
+last steps took, less twice what the dearest of the last enqueues cost
+the host, all measured by the engine on its own clock; no flag or
+field sets it), or at once when there is nothing to listen for (no
+slot free, or nothing in flight to hide the wait behind).  A slot that
+ended in step n is inactive in step n+1 by the device's own state: it
+gains no token from the step queued behind it and sits out that one
+step, no more.  (3) A program in flight (`_Flight`) belongs to the
+requests of its snapshot from its enqueue until its answer is
+collected: the watchdog tracks it all that time (the fetch included), a
+budget that passes meanwhile resolves its request once (`kill` reaches
+the device with the next step that goes out, an answer for a resolved
+request is dropped), and a wedged or failed program breaks the engine
+and resolves queued, resident and in-flight requests exactly once; on
+`close()` the loop answers what it has in flight before it leaves.
+`DecodeStats.summary()["decode"]["lookahead"]` says whether it engages:
+decode steps, those enqueued while the step before them was still
+unanswered (`ahead`, also on `engine.decode_wait`), and admissions that
+landed behind the step running at their submission (`in_time`) or
+behind a later one (`late`, also on `engine.prefill_wait`).
+
 Hardening is the PR-8 stack rewired for token granularity: per-TOKEN
 deadline budgets (TTFT included) feeding the outcome ledger
 (requests == sum(outcomes) stays the invariant), the circuit breaker
-around both dispatch kinds, the hang watchdog tracking each in-flight
-step (a wedged decode step gets a flight-recorder post-mortem and its
+around both dispatch kinds, the hang watchdog tracking each program in
+flight (a wedged decode step gets a flight-recorder post-mortem and its
 requests fail classified — the donated state is inside the wedged
 call, so the engine marks itself broken rather than pretend the cache
 survived), and DecodeStats publishing tokens/s, TTFT and inter-token
@@ -187,7 +237,8 @@ class _DecodeRequest:
     __slots__ = ("prompt", "max_new", "eos_id", "temperature",
                  "token_budget_s", "rid", "future", "tokens",
                  "enqueue_t", "last_token_t", "first_token_t", "slot",
-                 "bucket", "kill", "key", "trace", "qspan", "dspan")
+                 "bucket", "kill", "key", "trace", "qspan", "dspan",
+                 "seen_step")
 
     def __init__(self, prompt, max_new, eos_id, temperature,
                  token_budget_s, rid, bucket, key):
@@ -206,6 +257,7 @@ class _DecodeRequest:
         self.first_token_t = None
         self.slot = None
         self.kill = False                 # expired while slot-resident
+        self.seen_step = 0                # decode steps answered at submit
         # request-scoped trace context (monitor/tracing.py); None when
         # FLAGS_request_tracing is off
         self.trace = None
@@ -229,6 +281,30 @@ class _DecodeRequest:
 class EngineBrokenError(RuntimeError):
     """The engine lost its donated device state (a wedged or failed
     decode step) and cannot continue; submit() fails fast."""
+
+
+class _Flight:
+    """One program on the device's queue, from its enqueue until its
+    answer is collected.  It belongs to the requests it carries
+    (`requests`: a prefill's one, a decode step's residents of the
+    moment it went out): their budgets, the watchdog's entry and a
+    failure are theirs for as long as it is in flight."""
+
+    __slots__ = ("op", "meta", "requests", "wd", "stalled", "launched",
+                 "done", "state", "results", "error", "launched_t",
+                 "done_t", "slot", "req", "admit_t", "pspan", "late",
+                 "slot_reqs", "kill", "ahead")
+
+    def __init__(self, op, meta, requests, **own):
+        self.op = op                      # "prefill" or "decode"
+        self.meta = meta
+        self.requests = requests
+        self.launched = threading.Event()  # the launch returned
+        self.done = threading.Event()      # the answer is on the host
+        self.state = self.results = self.error = None
+        self.launched_t = self.done_t = None   # engine clock, the worker's
+        for k, v in own.items():
+            setattr(self, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -354,7 +430,9 @@ def _expert_load(counts):
 
 class DecodeEngine:
     """See module docstring.  `auto_start=False` keeps the loop thread
-    off so tests drive scheduling deterministically via `step()`."""
+    off so tests drive scheduling deterministically via `step()`, which
+    answers every program before it returns; the loop thread runs one
+    decode step ahead."""
 
     def __init__(self, model_or_params, config=None, auto_start=True,
                  **kw):
@@ -388,6 +466,20 @@ class DecodeEngine:
         self._cond = threading.Condition(self._lock)
         self._queue = deque()
         self._slot_req = [None] * cfg.slots
+        # slot -> request whose prefill is on the device's queue and not
+        # yet answered: the slot is taken, its first token not booked
+        self._prefilling = {}
+        # programs on the device's queue, oldest first (loop thread's)
+        self._flights = deque()
+        self._steps_enqueued = 0
+        self._steps_answered = 0
+        # what the loop measures about itself to time its listening:
+        # the device's time for its last decode steps, what enqueueing
+        # one cost the host, and when the device was last seen to end a
+        # program (engine clock, the fetching thread's reading)
+        self._pace = deque(maxlen=8)
+        self._launch_cost = deque(maxlen=8)
+        self._freed_t = float("-inf")
         self._live = set()
         self._rid = 0
         self._closed = False
@@ -503,6 +595,13 @@ class DecodeEngine:
             leftovers = list(self._live)
             self._queue.clear()
             self._slot_req = [None] * self.config.slots
+            self._prefilling.clear()
+            # the loop answered what it had in flight before it left; a
+            # loop that did not leave (wedged) keeps nothing tracked
+            flights = list(self._flights)
+            self._flights.clear()
+        for flight in flights:
+            self.watchdog.untrack(flight.wd)
         for req in leftovers:
             self._resolve_error(req, err, "cancelled")
         self.watchdog.stop()
@@ -575,6 +674,7 @@ class DecodeEngine:
                                  float(temperature),
                                  token_budget_s, rid, bucket, key)
             req.enqueue_t = cfg.clock()
+            req.seen_step = self._steps_answered
             if trace is not None:
                 trace.rid = rid
                 req.trace = trace
@@ -599,8 +699,9 @@ class DecodeEngine:
                 (shed.append if req.expired(now)
                  else keep.append)(req)
             self._queue = keep
-            # slot-resident: mark for the next step's kill mask
-            for req in self._slot_req:
+            # slot-resident, or its prefill on the device's queue: mark
+            # for the next step's kill mask
+            for req in self._slot_req + list(self._prefilling.values()):
                 if req is not None and not req.kill \
                         and not req.future.done() and req.expired(now):
                     req.kill = True
@@ -645,36 +746,40 @@ class DecodeEngine:
 
     def _mark_broken(self, why):
         """The donated device state rode a doomed call: drain EVERY
-        unresolved request — queued AND slot-resident — as cancelled,
-        so no future (and no trace) stays open behind a dead engine.
-        Requests the failing dispatch already resolved (stalled/
+        unresolved request — queued, slot-resident AND carried by a
+        program still in flight — as cancelled, so no future (and no
+        trace) stays open behind a dead engine, and no program stays
+        tracked.  Requests the failure already resolved (stalled/
         failed) are skipped by the idempotent resolve."""
         with self._lock:
             self._broken = True
-            queued = list(self._queue)
+            doomed = list(self._queue)
             self._queue.clear()
-            resident = [r for r in self._slot_req if r is not None]
+            doomed += [r for r in self._slot_req if r is not None]
             self._slot_req = [None] * self.config.slots
+            self._prefilling.clear()
+            flights = list(self._flights)
+            self._flights.clear()
+        for flight in flights:
+            self.watchdog.untrack(flight.wd)
+            doomed += flight.requests
         err = EngineBrokenError(f"decode engine broken: {why}")
-        for req in queued:
-            self._resolve_error(req, err, "cancelled")
-        for req in resident:
+        for req in doomed:
             self._resolve_error(req, err, "cancelled")
         _fr().note_event("decode_engine_broken", severe=True,
                          label=self.config.label, reason=why)
 
-    # -- guarded dispatch ----------------------------------------------
-    def _dispatch(self, call, meta, requests):
-        """Run one device call (prefill or decode step) on a worker
-        thread under watchdog + retry + breaker, enforcing per-token
-        budgets of the carried requests while it is in flight.
-        Returns the call's value, or None when the dispatch stalled or
-        failed (requests resolved, engine marked broken — the donated
-        state rode the doomed call)."""
+    # -- the device's queue --------------------------------------------
+    def _enqueue(self, flight, call):
+        """Put one program on the device's queue, behind what is there.
+        A worker thread launches it (`call(state)`, under retry and the
+        fault points) and then fetches its answer; the watchdog tracks
+        it from here until `_answer` has collected it.  Returns once the
+        launch has, with the program's state as the engine's; False
+        when the launch wedged or failed (`_await`)."""
         cfg = self.config
-        token, stalled = self.watchdog.track(meta)
-        done = threading.Event()
-        box = {}
+        flight.wd, flight.stalled = self.watchdog.track(flight.meta)
+        state = self._state
 
         def runner():
             try:
@@ -682,67 +787,97 @@ class DecodeEngine:
                     if faultinject.is_armed():
                         faultinject.check_transient()
                         faultinject.stall_point("decode.step")
-                    return call()
+                    return call(state)
 
                 if cfg.retry_policy is not None:
-                    box["out"] = call_with_retry(
+                    out = call_with_retry(
                         _call, cfg.retry_policy,
                         on_retry=lambda *a: self.stats.note_retry())
                 else:
-                    box["out"] = _call()
+                    out = _call()
+                flight.state, *results = out
+                flight.launched_t = cfg.clock()
+                flight.launched.set()
+                flight.results = [np.asarray(r) for r in results]
+                flight.done_t = cfg.clock()
             except BaseException as e:  # noqa: BLE001
-                box["error"] = e
+                flight.error = e
             finally:
-                done.set()
+                flight.launched.set()
+                flight.done.set()
+                with self._cond:           # a listening loop looks again
+                    self._cond.notify_all()
 
-        t = threading.Thread(target=runner, daemon=True,
-                             name=f"{cfg.label}-dispatch")
-        t.start()
-        try:
-            while not done.wait(timeout=0.002):
-                self.sweep_expired()
-                # requests riding THIS dispatch may not be queue- or
-                # slot-resident yet (a prefill's request is in limbo
-                # between the two) — enforce their budgets directly
-                now = cfg.clock()
-                for req in requests:
-                    if not req.future.done() and req.expired(now):
-                        req.kill = True
-                        self._resolve_error(
-                            req, DeadlineExceeded(
-                                "per-token budget expired in flight",
-                                budget_s=req.token_budget_s),
-                            "expired")
-                if stalled.is_set():
-                    stall = WatchdogStall(
-                        f"decode {meta.get('op')} step in flight > "
-                        f"{cfg.watchdog_stall_s}s")
-                    self.breaker.note_failure(stall)
-                    for req in requests:
-                        self._resolve_error(req, stall, "stalled")
-                    self._mark_broken("watchdog_stall")
-                    return None
-        finally:
-            self.watchdog.untrack(token)
-        if "error" in box:
-            e = box["error"]
-            self.breaker.note_failure(e)
+        self._flights.append(flight)
+        threading.Thread(target=runner, daemon=True,
+                         name=f"{cfg.label}-dispatch").start()
+        if not self._await(flight, flight.launched):
+            return False
+        self._state = flight.state
+        return True
+
+    def _await(self, flight, event):
+        """Block the loop on `event` of `flight` (its launch or its
+        answer), enforcing budgets meanwhile.  False when a program in
+        flight wedged or failed: the requests of every program in
+        flight are resolved (stalled/failed; those queued behind the
+        doomed one ride the same lost state), everything else is
+        cancelled and the engine is broken."""
+        cfg = self.config
+        while not event.wait(timeout=0.002):
+            self.sweep_expired()
+            wedged = next((f for f in self._flights
+                           if f.stalled.is_set()), None)
+            if wedged is not None:
+                self._abandon(WatchdogStall(
+                    f"decode {wedged.meta.get('op')} step in flight > "
+                    f"{cfg.watchdog_stall_s}s"), "stalled",
+                    "watchdog_stall")
+                return False
+        if flight.error is not None:
+            e = flight.error
             _fr().note_event(
                 "decode_dispatch_failed", label=cfg.label,
                 error=f"{type(e).__name__}: {e}"[:200],
-                **{k: v for k, v in meta.items()
+                **{k: v for k, v in flight.meta.items()
                    if k not in ("request_ids", "trace_ids")})
-            for req in requests:
-                self._resolve_error(req, e, "failed")
-            self._mark_broken("dispatch_failed")
+            self._abandon(e, "failed", "dispatch_failed")
             self.emit_telemetry()
+            return False
+        return True
+
+    def _abandon(self, exc, outcome, why):
+        self.breaker.note_failure(exc)
+        for flight in list(self._flights):
+            for req in flight.requests:
+                self._resolve_error(req, exc, outcome)
+        self._mark_broken(why)
+
+    def _answer(self, flight):
+        """Wait for the answer of `flight`, the oldest program in
+        flight, and take it off the queue.  Returns the engine clock
+        read once the answer is on the host (the time of its tokens),
+        or None when the engine broke instead."""
+        if not self._await(flight, flight.done):
             return None
+        self._flights.popleft()
+        self.watchdog.untrack(flight.wd)
         self.breaker.note_success()
-        return box["out"]
+        if flight.op == "decode":
+            with self._lock:
+                self._steps_answered += 1
+            # the device's time for this step: from when it could start
+            # (the program before it ended, or it was launched) to its
+            # answer
+            self._pace.append(flight.done_t - max(self._freed_t,
+                                                  flight.launched_t))
+        self._freed_t = flight.done_t
+        return self.config.clock()
 
     # -- scheduling -----------------------------------------------------
     def _free_slots_locked(self):
-        return [i for i, r in enumerate(self._slot_req) if r is None]
+        return [i for i, r in enumerate(self._slot_req)
+                if r is None and i not in self._prefilling]
 
     def _admit_locked(self):
         """Pick (slot, request) pairs to prefill this iteration: any
@@ -759,24 +894,41 @@ class DecodeEngine:
         self.stats.note_queue_depth(len(self._queue))
         return picks
 
+    def _has_work_locked(self):
+        return bool(self._queue or self._flights or any(self._slot_req))
+
     def step(self):
-        """One engine iteration: sweep budgets, refill free slots via
-        prefill, then run one full-width decode step.  Returns the
-        number of device dispatches made (0 = idle).
+        """One engine iteration by hand: sweep budgets, refill free
+        slots via prefill, then run one full-width decode step, each
+        program answered before the next goes out.  Returns the number
+        of device dispatches made (0 = idle), with all of it answered
+        and emitted.  The loop thread runs the same primitives
+        (`_enqueue_prefill`, `_enqueue_step`, `_collect`) in another
+        order, one decode step ahead of its host: see `_run_ahead`.
 
         While a profiler session runs, an iteration with work is one
-        `engine.step` span cut into its phases, in this order:
+        `engine.step` span cut into its phases, here in this order:
         `engine.sweep`, `engine.admit`; for each admitted request
-        `engine.prefill_host`, `engine.prefill_wait`,
+        `engine.prefill_host` (its program built and launched),
+        `engine.prefill_wait` (until its first token is on the host),
         `engine.prefill_book`; then `engine.decode_host`,
         `engine.decode_wait`, `engine.emit` and, every 64th step,
-        `engine.telemetry`.  The `*_wait` spans are the host blocked on
-        the device's answer; all the others are the host's own work.  Where the model counts
-        expert assignments, the `*_wait` spans gain `expert_tokens` and
-        `expert_load_max` once the answer is in (in `spans()`; the
-        trace's copy of the span was opened before they were known)."""
+        `engine.telemetry`.  The loop thread's iteration holds the same
+        phases from `engine.decode_wait` of the oldest step round to
+        `engine.decode_host` of the newest, and `engine.listen_wait`
+        where it waited for submissions.  In the `*_wait` spans the
+        host is blocked (on the device's answer, or listening); all the
+        others are the host's own work.  `engine.prefill_wait` carries
+        `bucket`, `slot`, `rid`, `queue_wait_s` (submit to admission),
+        `turnaround_s` (admission to the first token on the host) and
+        `late`; `engine.decode_wait` carries `active` and `ahead`; where
+        the model counts expert assignments, both gain `expert_tokens`
+        and `expert_load_max`.  What is known only once the answer is
+        in (`turnaround_s`, the experts' counts) is in `spans()`; the
+        trace's copy of the span was opened before (its `turnaround_s`
+        runs to the launch's return)."""
         with self._lock:
-            if not self._queue and not any(self._slot_req):
+            if not self._has_work_locked():
                 return 0                   # nothing to do: no span
         with RecordEvent("engine.step"):
             return self._step()
@@ -784,13 +936,59 @@ class DecodeEngine:
     def _step(self):
         with RecordEvent("engine.sweep"):
             self.sweep_expired()
+        dispatched = 0
+        for _ in self._prefills(self._admit()):
+            dispatched += 1
+            self._collect()
+        if not self._broken and self._enqueue_step() is not None:
+            dispatched += 1
+            self._collect()
+        return dispatched
+
+    def _run_ahead(self):
+        """One iteration of the loop thread.  Going in, the device's
+        queue holds step n (running), the prefills admitted last time
+        and step n+1: collect n and emit it, collect those prefills,
+        listen for submissions while the device works on n+1 (each
+        arrival's prefill goes out at once, behind n+1), and enqueue
+        n+2 when the queue is about to run dry.  An answer is only ever
+        read after the work that follows it has been enqueued, so the
+        device waits for nothing the host does in between."""
+        newest = max((i for i, f in enumerate(self._flights)
+                      if f.op == "decode"), default=0)
+        for _ in range(newest):            # all that precedes step n+1
+            if not self._collect():
+                return 1
+        with RecordEvent("engine.sweep"):
+            self.sweep_expired()
+        enqueued = self._listen()
+        if self._broken or self._closed:   # the loop answers what is out
+            return 1
+        if self._enqueue_step() is not None:
+            enqueued += 1
+        elif not self._broken:
+            # nothing goes out behind what is in flight (no slot would
+            # gain by a step, or the breaker is open): answer it now
+            while self._flights and self._collect():
+                newest += 1
+        return newest + enqueued
+
+    def _admit(self):
         with RecordEvent("engine.admit"):
             with self._lock:
-                if self._broken:
-                    return 0
-                picks = self._admit_locked()
-        dispatched = 0
-        for idx, (slot, req) in enumerate(picks):
+                return [] if self._broken else self._admit_locked()
+
+    def _prefills(self, picks):
+        """Enqueue the prefills of `picks` one by one, yielding each as
+        it goes out; the caller may collect it before the next."""
+        pending = deque(picks)
+        while pending:
+            if self._broken:
+                err = EngineBrokenError(
+                    "decode engine broke mid-admission")
+                for _, r in pending:
+                    self._resolve_error(r, err, "cancelled")
+                return
             if not self.breaker.allow():
                 # breaker open: requeue the whole remainder and let
                 # budgets shed; the cooldown probe reopens admission.
@@ -798,23 +996,61 @@ class DecodeEngine:
                 # span never ended — requeued wait keeps accruing);
                 # the detour is a point annotation, not a new tree.
                 with self._lock:
-                    for _, r in reversed(picks[idx:]):
+                    for _, r in reversed(pending):
                         if r.trace is not None:
                             r.trace.annotate(r.trace.root,
                                              "breaker_requeue")
                         self._queue.appendleft(r)
-                picks = picks[:idx]
-                break
-            if not self._prefill(slot, req):
-                err = EngineBrokenError(
-                    "decode engine broke mid-admission")
-                for _, r in picks[idx + 1:]:
-                    self._resolve_error(r, err, "cancelled")
-                return dispatched + 1      # engine broken
-            dispatched += 1
-        return dispatched + self._decode_once()
+                return
+            flight = self._enqueue_prefill(*pending.popleft())
+            if flight is not None:
+                yield flight
 
-    def _prefill(self, slot, req):
+    def _listen(self):
+        """Admit what is queued and, while the device works on the
+        newest step, wait on the submit condition and admit each
+        arrival at once, its prefill landing behind that step.  Ends
+        when there is nothing to listen for (no slot free; nothing in
+        flight that hides the wait) or when the next step has to go
+        out (`_deadline`).  Returns the prefills enqueued."""
+        cfg = self.config
+        deadline = self._deadline()
+        enqueued = 0
+        while not self._broken:
+            with self._cond:
+                while True:
+                    free = self._free_slots_locked()
+                    if free and self._queue:
+                        break
+                    if self._closed or not free or not self._flights \
+                            or self._flights[-1].done.is_set() \
+                            or cfg.clock() >= deadline:
+                        return enqueued
+                    with RecordEvent("engine.listen_wait"):
+                        self._cond.wait(deadline - cfg.clock())
+            for _ in self._prefills(self._admit()):
+                enqueued += 1
+        return enqueued
+
+    def _deadline(self):
+        """When the next decode step has to be on its way so that the
+        device's queue does not run dry, from what the engine measured
+        of itself: the newest step in flight started when the program
+        before it ended (or when it was launched), takes what the
+        fastest of the last steps took, and enqueueing the next one
+        costs the host what the dearest of the last enqueues cost,
+        allowed for twice.  No reading yet, or no step in flight: at
+        once."""
+        steps = [f for f in self._flights if f.op == "decode"]
+        if not steps or not self._pace:
+            return float("-inf")
+        return max(self._freed_t, steps[-1].launched_t) \
+            + min(self._pace) - 2 * max(self._launch_cost)
+
+    def _enqueue_prefill(self, slot, req):
+        """Launch `req`'s prefill into `slot`, behind what is on the
+        device's queue.  Returns its flight, or None when the launch
+        broke the engine."""
         cfg = self.config
         # the engine turns to this request: its wait in the queue ends
         # here and its prefill's turnaround begins
@@ -834,33 +1070,115 @@ class DecodeEngine:
                 pspan = req.trace.child(f"prefill/b{bucket}", "prefill",
                                         attrs={"bucket": bucket,
                                                "slot": slot})
+            with self._lock:
+                self._prefilling[slot] = req
+                # it lands behind a later step than the one that was
+                # running when it was submitted
+                late = self._steps_enqueued > req.seen_step + 1
+            flight = _Flight("prefill", meta, [req], slot=slot, req=req,
+                             admit_t=admit_t, pspan=pspan, late=late)
             fn = self._prefill_fns[bucket]
-            state = self._state
 
-            def call():
+            def call(state):
                 return fn(state, self._trees, prompt, np.int32(true_len),
                           np.int32(slot), np.int32(stop),
                           np.int32(-1 if req.eos_id is None
                                    else req.eos_id),
                           np.float32(req.temperature), req.key)
 
-            out = self._dispatch(call, meta, [req])
-        if out is None:
-            return False
-        self._state, first, active, *counts = out
-        # the first token's time, as the stats and the budgets have it:
-        # the program is launched, its answer not yet on the host
-        now = cfg.clock()
-        with RecordEvent("engine.prefill_wait", bucket=bucket, slot=slot,
-                         rid=req.rid, queue_wait_s=admit_t - req.enqueue_t,
-                         turnaround_s=now - admit_t) as span:
-            first = int(first)
-            active = bool(active)
+            return flight if self._enqueue(flight, call) else None
+
+    def _enqueue_step(self):
+        """Launch one full-width decode step behind what is on the
+        device's queue, if a slot can gain by one and the breaker
+        allows it, with the kill mask and the slot -> request snapshot
+        of this moment kept with it.  Returns its flight, or None."""
+        cfg = self.config
+        t0 = cfg.clock()
+        with RecordEvent("engine.decode_host"):
+            with self._lock:
+                slot_reqs = list(self._slot_req)
+                for slot, req in self._prefilling.items():
+                    slot_reqs[slot] = req
+            carried = [f for f in self._flights if f.op == "decode"]
+
+            def gains(i, r):
+                # tokens that the programs in flight will bring it: its
+                # prefill's, and one a step that carries it (the host
+                # cannot know of an eos on the way)
+                coming = (r.first_token_t is None) + sum(
+                    f.slot_reqs[i] is r for f in carried)
+                return not r.future.done() \
+                    and len(r.tokens) + coming < r.max_new
+
+            gaining = [r for i, r in enumerate(slot_reqs)
+                       if r is not None and not r.kill and gains(i, r)]
+            kill = np.array([r is not None and r.kill for r in slot_reqs],
+                            bool)
+            # a killed slot needs one step that carries its kill
+            unkilled = any(
+                k and not any(f.kill[i] and f.slot_reqs[i] is slot_reqs[i]
+                              for f in carried)
+                for i, k in enumerate(kill))
+            if not ((gaining or unkilled) and self.breaker.allow()):
+                return None
+            meta = {"op": "decode", "active": len(gaining),
+                    "request_ids": [r.rid for r in slot_reqs
+                                    if r is not None]}
+            tids = [r.trace.trace_id for r in slot_reqs
+                    if r is not None and r.trace is not None]
+            if tids:
+                # a wedged decode step's stall dump names every resident
+                # request's trace
+                meta["trace_ids"] = tids
+            flight = _Flight(
+                "decode", meta,
+                [r for r in slot_reqs
+                 if r is not None and not r.future.done()],
+                slot_reqs=slot_reqs, kill=kill,
+                # the step before it is still unanswered: this one runs
+                # ahead of the host
+                ahead=bool(carried) and not carried[-1].done.is_set())
+            with self._lock:
+                self._steps_enqueued += 1
+
+            def call(state):
+                return self._step_fn(state, self._trees, kill)
+
+            if not self._enqueue(flight, call):
+                return None
+            self._launch_cost.append(flight.launched_t - t0)
+        return flight
+
+    def _collect(self):
+        """Answer of the oldest program in flight: a prefill's first
+        token is booked, a decode step's tokens are emitted.  False when
+        the engine broke instead."""
+        flight = self._flights[0]
+        if flight.op == "prefill":
+            return self._collect_prefill(flight)
+        return self._collect_step(flight)
+
+    def _collect_prefill(self, flight):
+        req, slot = flight.req, flight.slot
+        with RecordEvent(
+                "engine.prefill_wait", bucket=req.bucket, slot=slot,
+                rid=req.rid, queue_wait_s=flight.admit_t - req.enqueue_t,
+                turnaround_s=flight.launched_t - flight.admit_t,
+                late=flight.late) as span:
+            # the first token's time, for the stats and the budgets: its
+            # answer is on the host
+            now = self._answer(flight)
+            if now is None:
+                return False
+            first, active, *counts = flight.results
             load = _expert_load(counts)
-            span.attrs.update(load)
+            span.attrs.update(load, turnaround_s=now - flight.admit_t)
         self.stats.note_experts(**load)
         with RecordEvent("engine.prefill_book"):
-            self._prefill_book(slot, req, first, active, pspan, now)
+            self._prefill_book(slot, req, int(first), bool(active),
+                               flight.pspan, now)
+            self.stats.note_admission(flight.late)
         return True
 
     def _prefill_book(self, slot, req, first, active, pspan, now):
@@ -868,88 +1186,56 @@ class DecodeEngine:
         if req.trace is not None:
             req.trace.annotate(pspan, "first_token")
             req.trace.end(pspan)
+        resident = req if active else None
         if req.future.done():              # expired mid-prefill
             self.stats.note_prefill(ttft_s=None, now=now)
             req.kill = True
-            with self._lock:
-                self._slot_req[slot] = req if active else None
-            return
-        self.stats.note_prefill(ttft_s=now - req.enqueue_t, now=now)
-        req.tokens.append(first)
-        req.slot = slot
-        if not active:                     # max_new == 1 or instant eos
-            self._resolve_ok(req, now)
-            with self._lock:
-                self._slot_req[slot] = None
         else:
-            if req.trace is not None:
+            self.stats.note_prefill(ttft_s=now - req.enqueue_t, now=now)
+            req.tokens.append(first)
+            req.slot = slot
+            if not active:                 # max_new == 1 or instant eos
+                self._resolve_ok(req, now)
+            elif req.trace is not None:
                 # slot-resident decode: one span from slot entry to
                 # the last token, per-token progress as annotations
                 req.dspan = req.trace.child("decode", "decode",
                                             attrs={"slot": slot})
-            with self._lock:
-                self._slot_req[slot] = req
+        with self._lock:
+            self._prefilling.pop(slot, None)
+            self._slot_req[slot] = resident
 
-    def _decode_once(self):
-        """One full-width decode step if a slot needs one and the
-        breaker allows it.  Returns the dispatches made (0 or 1)."""
-        cfg = self.config
-        with RecordEvent("engine.decode_host"):
-            with self._lock:
-                slot_reqs = list(self._slot_req)
-            want_step = any(
-                r is not None and (r.kill or not r.future.done())
-                for r in slot_reqs)
-            if not (want_step and self.breaker.allow()):
-                return 0
-            kill = np.array([r is not None and r.kill for r in slot_reqs],
-                            bool)
-            rids = [r.rid for r in slot_reqs if r is not None]
-            meta = {"op": "decode", "active": int(sum(
-                r is not None and not r.kill for r in slot_reqs)),
-                "request_ids": rids}
-            tids = [r.trace.trace_id for r in slot_reqs
-                    if r is not None and r.trace is not None]
-            if tids:
-                # a wedged decode step's stall dump names every resident
-                # request's trace
-                meta["trace_ids"] = tids
-            state = self._state
-
-            def call():
-                return self._step_fn(state, self._trees, kill)
-
-            waiting = [r for r in slot_reqs
-                       if r is not None and not r.future.done()]
-            out = self._dispatch(call, meta, waiting)
-        if out is None:
-            return 1
-        self._state, tokens, was_active, still, *counts = out
-        now = cfg.clock()
+    def _collect_step(self, flight):
         with RecordEvent("engine.decode_wait",
-                         active=meta["active"]) as span:
-            tokens = np.asarray(tokens)
-            was_active = np.asarray(was_active)
-            still = np.asarray(still)
+                         active=flight.meta["active"],
+                         ahead=flight.ahead) as span:
+            now = self._answer(flight)
+            if now is None:
+                return False
+            tokens, was_active, still, *counts = flight.results
             load = _expert_load(counts)
             span.attrs.update(load)
         self.stats.note_experts(**load)
         with RecordEvent("engine.emit"):
-            self._emit(slot_reqs, tokens, was_active, still, now)
+            self._emit(flight.slot_reqs, tokens, was_active, still, now)
+            self.stats.note_lookahead(flight.ahead)
         if self.stats.decode_steps % 64 == 0:
             with RecordEvent("engine.telemetry"):
                 self.emit_telemetry()
-        return 1
+        return True
 
     def _emit(self, slot_reqs, tokens, was_active, still, now):
-        """Hand the step's tokens to their requests, resolve the
-        finished ones and release their slots."""
+        """Hand the step's tokens to their requests (`slot_reqs`: the
+        snapshot the step went out with), resolve the finished ones and
+        release their slots."""
         emitted = 0
         for i, req in enumerate(slot_reqs):
             if req is None:
                 continue
             if not was_active[i]:
-                # killed (budget-expired) or raced to done: release
+                # killed (budget-expired), raced to done, or ended in
+                # the step before this one, which was already queued
+                # behind it: release
                 with self._lock:
                     if self._slot_req[i] is req:
                         self._slot_req[i] = None
@@ -978,14 +1264,19 @@ class DecodeEngine:
     def _loop(self):
         while True:
             with self._cond:
-                while not self._closed and not self._queue \
-                        and not any(r is not None
-                                    for r in self._slot_req):
+                while not self._closed and not self._has_work_locked():
                     self._cond.wait(0.02)
-                if self._closed or self._broken:
+                if self._broken or (self._closed and not self._flights):
                     return
+                closed = self._closed
             try:
-                did = self.step()
+                with RecordEvent("engine.step"):
+                    if closed:
+                        # nothing stays uncollected behind a closed engine
+                        while self._flights and self._collect():
+                            pass
+                        return
+                    did = self._run_ahead()
             except Exception as e:  # noqa: BLE001
                 _fr().note_event(
                     "decode_engine_error", severe=True,

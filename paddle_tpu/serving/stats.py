@@ -284,6 +284,14 @@ class DecodeStats(ServingStats):
         self.expert_programs = 0
         self.expert_tokens_total = 0
         self.expert_load_max = 0
+        # how far the engine's loop ran ahead of its host: decode steps
+        # enqueued while the step before them was still unanswered (or
+        # not), and admissions whose prefill went out directly behind
+        # the step that was running at their submission (or a later one)
+        self.steps_ahead = 0
+        self.steps_not_ahead = 0
+        self.admitted_in_time = 0
+        self.admitted_late = 0
 
     # -- recording ------------------------------------------------------
     def note_prefill(self, ttft_s=None, now=None):
@@ -337,6 +345,24 @@ class DecodeStats(ServingStats):
             self.expert_load_max = max(self.expert_load_max,
                                        expert_load_max)
 
+    def note_lookahead(self, ahead):
+        """One decode step answered: was it enqueued while the step
+        before it was still unanswered?"""
+        with self._lock:
+            if ahead:
+                self.steps_ahead += 1
+            else:
+                self.steps_not_ahead += 1
+
+    def note_admission(self, late):
+        """One prefill answered: did it land behind a later decode step
+        than the one that was running when its request was submitted?"""
+        with self._lock:
+            if late:
+                self.admitted_late += 1
+            else:
+                self.admitted_in_time += 1
+
     def note_token_latency(self, latency_s):
         with self._lock:
             if len(self._tok_lat) == self._tok_lat.maxlen:
@@ -379,6 +405,11 @@ class DecodeStats(ServingStats):
                     round(self._occupancy_sum / steps, 4) if steps
                     and self.slots else None),
             }
+            out["lookahead"] = {
+                "steps": self.steps_ahead + self.steps_not_ahead,
+                "ahead": self.steps_ahead,
+                "in_time": self.admitted_in_time,
+                "late": self.admitted_late}
             if self.cache is not None:
                 out["cache"] = dict(self.cache)
             if self.expert_programs:

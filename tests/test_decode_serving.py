@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+from engine_fakes import Gate, hold_prefills, hold_steps, until
 from paddle_tpu import monitor
 from paddle_tpu.models import generate as G
 from paddle_tpu.models.gpt import GPT, GPTConfig
@@ -168,6 +169,191 @@ def test_eos_early_stop(dense_model):
     got = f.result(timeout=0)
     stop = int(np.argmax(full == eos)) + 1
     assert np.array_equal(got, full[:stop])
+    eng.close()
+
+
+# ---------------------------------------------------------------------
+# the loop thread, one decode step ahead of its host (ISSUE 32)
+# ---------------------------------------------------------------------
+
+def _lookahead_adds_up(summary):
+    dec = summary["decode"]
+    look = dec["lookahead"]
+    assert look["steps"] == dec["decode_steps"]
+    assert 0 <= look["ahead"] <= look["steps"]
+    assert look["in_time"] + look["late"] == dec["prefill_steps"]
+    return look
+
+
+@pytest.mark.parametrize("kind", ["dense", "moe"])
+def test_loop_thread_serves_generates_tokens_with_joins_and_leaves(
+        kind, dense_model, moe_model):
+    """With the loop thread running one step ahead (every step's answer
+    comes 10 ms after its launch, so the next is always enqueued before
+    it is read), requests of mixed lengths that queue for a slot, leave
+    and are followed by later arrivals emit token for token what
+    generate() emits."""
+    model, vocab = {"dense": (dense_model, 97), "moe": (moe_model, 64)}[kind]
+    eng = _engine(model, slots=2, max_len=24, buckets=(8,))
+    hold_steps(eng, Gate(delay_s=0.01))
+    eng.start()
+    rng = np.random.default_rng(32)
+    prompts = [rng.integers(0, vocab, size=n) for n in (4, 7, 3, 6, 5, 8)]
+    news = (9, 2, 6, 1, 7, 4)
+    futs = [eng.submit(p, n) for p, n in zip(prompts[:4], news[:4])]
+    futs[1].result(timeout=60)             # a slot has been released
+    futs += [eng.submit(p, n) for p, n in zip(prompts[4:], news[4:])]
+    for p, n, f in zip(prompts, news, futs):
+        ref = np.asarray(G.generate(model, p[None, :],
+                                    max_new_tokens=n))[0]
+        assert np.array_equal(f.result(timeout=60), ref)
+    eng.close()
+    s = eng.summary()
+    assert s["outcomes"]["completed"] == 6
+    assert s["requests"] == sum(s["outcomes"].values())
+    look = _lookahead_adds_up(s)
+    # the host is never 10 ms behind: but for the steps that found the
+    # queue empty (the first, one after a pause), they went out ahead
+    assert look["ahead"] >= look["steps"] // 2 > 0
+
+
+def test_eos_gains_no_token_from_the_step_queued_behind_it(dense_model):
+    """A request that reaches its eos in step n is still in the
+    snapshot of step n+1, which was enqueued before step n was read:
+    it gains nothing from it, and the next tenant of its slot (prefilled
+    behind that step) is exact."""
+    rng = np.random.default_rng(5)
+    p1, p2 = rng.integers(0, 97, size=6), rng.integers(0, 97, size=9)
+    full = np.asarray(G.generate(dense_model, p1[None, :],
+                                 max_new_tokens=10))[0]
+    eos = int(full[3])
+    stop = int(np.argmax(full == eos)) + 1
+    eng = _engine(dense_model, slots=1, buckets=(8, 16))
+    hold_steps(eng, Gate(delay_s=0.01))
+    eng.start()
+    f1 = eng.submit(p1, 10, eos_id=eos)
+    f2 = eng.submit(p2, 5)                 # waits for the one slot
+    assert np.array_equal(f1.result(timeout=60), full[:stop])
+    ref2 = np.asarray(G.generate(dense_model, p2[None, :],
+                                 max_new_tokens=5))[0]
+    assert np.array_equal(f2.result(timeout=60), ref2)
+    eng.close()
+    s = eng.summary()
+    look = _lookahead_adds_up(s)
+    assert look["ahead"] >= 1 and stop < 10
+    # the step behind the eos ran for nothing and emitted nothing
+    assert s["decode"]["tokens_total"] == (stop - 1) + 4
+
+
+def test_step_by_hand_answers_everything_before_it_returns(dense_model):
+    """`step()` runs the same primitives serially: nothing is in flight
+    when it returns, no step ran ahead, and an admission is never late
+    while a slot is free."""
+    eng = _engine(dense_model, buckets=(8,))
+    rng = np.random.default_rng(33)
+    futs = [eng.submit(rng.integers(0, 97, size=5), 4) for _ in range(2)]
+    assert eng.step() == 3                 # two prefills, one decode step
+    assert not eng._flights and not eng._prefilling
+    assert eng.summary()["in_flight"] == 0
+    assert [len(r.tokens) for r in eng._slot_req if r is not None] == [2, 2]
+    _drain(eng, futs)
+    look = _lookahead_adds_up(eng.summary())
+    assert look["ahead"] == 0 and look["late"] == 0
+    eng.close()
+
+
+def test_times_are_read_once_the_answer_is_on_the_host(dense_model):
+    """A first token's time and every gap include the device's time
+    for the program (an answer that comes 50 ms after the launch shows
+    in the TTFT and in the inter-token gaps the stats report)."""
+    eng = _engine(dense_model, slots=1, buckets=(8,))
+    gate = Gate(delay_s=0.05)
+    hold_prefills(eng, gate)
+    hold_steps(eng, gate)
+    rng = np.random.default_rng(34)
+    f = eng.submit(rng.integers(0, 97, size=5), 3)
+    _drain(eng, [f])
+    eng.close()
+    assert min(eng.stats.ttft_samples()) >= 0.05
+    gaps = eng.stats.token_latency_samples()
+    assert len(gaps) == 2 and min(gaps) >= 0.05
+    assert eng.summary()["latency"]["max_ms"] >= 150.0
+
+
+def test_budget_expiring_under_two_programs_in_flight_resolves_once(
+        dense_model):
+    """A request whose budget passes while two decode steps carry it
+    (one running, one queued behind) is resolved 'expired' once; the
+    tokens those steps bring are dropped, a later step kills its slot,
+    and the next tenant decodes token-exact."""
+    clk = FakeClock()
+    eng = _engine(dense_model, clock=clk, slots=1, buckets=(8,))
+    gate = Gate()
+    hold_steps(eng, gate)
+    eng.start()
+    rng = np.random.default_rng(35)
+    p1, p2 = rng.integers(0, 97, size=5), rng.integers(0, 97, size=6)
+    f1 = eng.submit(p1, 8, token_budget_s=0.5)
+    until(lambda: gate.held == 2, what="two decode steps in flight")
+    assert eng.summary()["in_flight"] == 2 and not f1.done()
+    clk.advance(1.0)
+    assert isinstance(f1.exception(timeout=30), DeadlineExceeded)
+    f2 = eng.submit(p2, 4)
+    gate.open()
+    ref = np.asarray(G.generate(dense_model, p2[None, :],
+                                max_new_tokens=4))[0]
+    assert np.array_equal(f2.result(timeout=60), ref)
+    eng.close()
+    s = eng.summary()
+    assert s["outcomes"]["expired"] == 1 and s["outcomes"]["completed"] == 1
+    assert s["requests"] == sum(s["outcomes"].values()) == 2
+    assert s["pending"] == 0 and s["in_flight"] == 0
+    _lookahead_adds_up(s)
+    # the two steps that carried the expired request emitted nothing
+    assert s["decode"]["tokens_total"] == 3
+
+
+def test_a_wedged_queued_step_breaks_the_engine_once_for_everyone(
+        dense_model, tmp_path):
+    """Step n+1, queued behind step n, never answers: the watchdog has
+    tracked it since its enqueue, the engine breaks, and the resident
+    request, the one whose prefill is in flight and the queued one are
+    each resolved exactly once."""
+    old = fluid.get_flags("FLAGS_flight_recorder_dir")
+    fluid.set_flags({"FLAGS_flight_recorder_dir": str(tmp_path)})
+    steps, prefills = Gate(), Gate()
+    try:
+        eng = _engine(dense_model, slots=2, buckets=(8,),
+                      watchdog_stall_s=1.0, watchdog_poll_s=0.02,
+                      retry_policy=None)
+        hold_steps(eng, steps)
+        hold_prefills(eng, prefills)
+        eng.start()
+        rng = np.random.default_rng(36)
+        f1 = eng.submit(rng.integers(0, 97, size=4), 8)
+        prefills.release()                 # the first token of request 1
+        until(lambda: steps.held == 2, what="two decode steps in flight")
+        f2 = eng.submit(rng.integers(0, 97, size=5), 8)
+        steps.release()                    # step 1 answers; step 2 never
+        until(lambda: steps.held == 3, what="the third step enqueued")
+        f3 = eng.submit(rng.integers(0, 97, size=6), 8)   # no slot: queued
+        assert eng.summary()["in_flight"] == 3    # step, prefill, step
+        errs = [f.exception(timeout=30) for f in (f1, f2, f3)]
+        assert isinstance(errs[0], WatchdogStall)     # resident
+        assert isinstance(errs[1], WatchdogStall)     # prefill in flight
+        assert isinstance(errs[2], EngineBrokenError)  # queued
+        with pytest.raises(EngineBrokenError):
+            eng.submit(rng.integers(0, 97, size=4), 2)
+        s = eng.summary()
+        assert s["outcomes"]["stalled"] == 2
+        assert s["outcomes"]["cancelled"] == 1
+        assert s["requests"] == sum(s["outcomes"].values()) == 3
+        assert s["pending"] == 0 and s["in_flight"] == 0
+        assert s["watchdog_stalls"] >= 1
+    finally:
+        steps.open()
+        prefills.open()
+        fluid.set_flags(old)
     eng.close()
 
 

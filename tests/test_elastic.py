@@ -550,8 +550,10 @@ def test_dispatch_blip_with_live_peers_is_not_a_death(tmp_path):
     retry/propagation path), not a fleet-wide rank_death — shrinking
     around live peers split-brains the store."""
     monitor.enable()
-    c0 = _coord(tmp_path, 0, 2).install()
-    c1 = _coord(tmp_path, 1, 2).install()
+    # a heart that a loaded machine starves for 0.4 s is not dead: the
+    # staleness threshold here is one no scheduler hiccup reaches
+    c0 = _coord(tmp_path, 0, 2, peer_timeout_s=2.0).install()
+    c1 = _coord(tmp_path, 1, 2, peer_timeout_s=2.0).install()
     try:
         ev = c0.on_dispatch_error(
             ConnectionResetError("one-off transport blip"), step=2)

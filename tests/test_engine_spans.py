@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from engine_fakes import Gate, hold_steps, until
 from paddle_tpu import monitor, profiler
 from paddle_tpu.models.gpt import GPT, GPTConfig
 from paddle_tpu.reader import device_prefetch
@@ -182,6 +183,86 @@ def test_first_token_split_adds_up_to_ttft(model, traced):
         assert a["queue_wait_s"] + a["turnaround_s"] == ttft_s
     # one slot: the later requests waited for the earlier ones
     assert attrs[2]["queue_wait_s"] > attrs[0]["queue_wait_s"]
+
+
+# ---------------------------------------------------------------------
+# the loop thread: one step ahead, listening while the device works
+# ---------------------------------------------------------------------
+
+def test_loop_listens_until_its_deadline_and_counts_who_came_in_time(
+        model, traced):
+    """The loop thread under an injected clock and decode steps whose
+    answers the test releases.  Step 1 takes one second of that clock,
+    so the loop expects step 2 to: it listens (`engine.listen_wait`)
+    for a second after step 1's emit.  A request submitted then is
+    prefilled behind step 2, which was running at its submission: in
+    time.  One submitted after step 3 went out, while step 2 still
+    runs, lands behind step 3: late.  The counter adds up, and the
+    spans carry the same two facts."""
+    class Clock:
+        t = 100.0
+
+        def __call__(self):
+            return self.t
+
+    clk, gate = Clock(), Gate()
+    eng = _engine(model, clock=clk, slots=3, buckets=(8,))
+    hold_steps(eng, gate)
+    rng = np.random.default_rng(32)
+    prompts = [rng.integers(0, 97, size=5) for _ in range(3)]
+    futs = []
+
+    def answered():
+        return eng.stats.decode_steps
+
+    def body():
+        eng.start()
+        futs.append(eng.submit(prompts[0], 12))
+        until(lambda: gate.held == 2, what="steps 1 and 2 in flight")
+        clk.t += 1.0
+        gate.release()                     # step 1 answers, a second on
+        until(lambda: answered() == 1, what="step 1 emitted")
+        # the loop listens: step 2 has a second to run by its measure
+        futs.append(eng.submit(prompts[1], 10))
+        until(lambda: eng.summary()["queue_depth"] == 0,
+              what="request 2 admitted")
+        assert gate.held == 2              # step 3 waits for the deadline
+        clk.t += 1.0                       # ... which passes
+        with eng._cond:
+            eng._cond.notify_all()
+        until(lambda: gate.held == 3, what="step 3 enqueued")
+        futs.append(eng.submit(prompts[2], 8))   # step 2 still runs
+        clk.t += 1.0
+        gate.open()
+        for f in futs:
+            f.result(timeout=60)
+        eng.close()
+
+    traced(body)
+    look = eng.summary()["decode"]["lookahead"]
+    dec = eng.summary()["decode"]
+    assert look["steps"] == dec["decode_steps"] and look["ahead"] >= 2
+    assert (look["in_time"], look["late"]) == (2, 1)
+    assert look["in_time"] + look["late"] == dec["prefill_steps"] == 3
+    spans = profiler.spans("engine.")
+    pre = {a["rid"]: a for n, _, _, a in spans
+           if n == "engine.prefill_wait"}
+    assert [pre[r]["late"] for r in (1, 2, 3)] == [False, False, True]
+    waits = [a for n, _, _, a in spans if n == "engine.decode_wait"]
+    assert len(waits) == dec["decode_steps"]
+    assert sum(a["ahead"] for a in waits) == look["ahead"]
+    assert not waits[0]["ahead"] and waits[1]["ahead"] and waits[2]["ahead"]
+    # the listening is a span of its own, in which the host only waits;
+    # an iteration that emitted has its emit inside its engine.step
+    names = {n for n, _, _, _ in spans}
+    assert "engine.listen_wait" in names
+    assert names <= set(PHASES) | {"engine.step", "engine.listen_wait"}
+    steps = [(s, e) for n, s, e, _ in spans if n == "engine.step"]
+    for n, s, e, _ in spans:
+        if n != "engine.step":
+            assert any(lo <= s and e <= hi for lo, hi in steps), n
+    emits = [s for n, s, _, _ in spans if n == "engine.emit"]
+    assert len(emits) == dec["decode_steps"]
 
 
 # ---------------------------------------------------------------------

@@ -184,6 +184,40 @@ def test_engine_serves_what_the_reference_computes(toy):
         "kind": toy.kcfg.cache_kind, "bytes": 3 * 2 * 40 * 64 * 4}
 
 
+def test_loop_thread_one_step_ahead_serves_what_the_reference_computes(
+        toy):
+    """The same five requests through two slots with the loop thread
+    running one decode step ahead of its host (each step's four results
+    reach the host 10 ms after its launch): requests join a slot that
+    another released while a step that still carried the old tenant is
+    queued, and every served token is the reference's first choice."""
+    from engine_fakes import Gate, hold_steps
+
+    eng = toy.engine()
+    hold_steps(eng, Gate(delay_s=0.01))
+    eng.start()
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, toy.kcfg.vocab_size, n).astype(np.int32)
+               for n in (5, 20, 9, 30, 12)]
+    try:
+        futs = [eng.submit(p, 10 + 3 * i) for i, p in enumerate(prompts)]
+        served = [f.result(timeout=120) for f in futs]
+    finally:
+        eng.close()
+    summary = eng.summary()
+    for p, out, i in zip(prompts, served, range(5)):
+        assert out.size == 10 + 3 * i
+        assert toy.ref.token_gaps(p, out).max() <= TOL
+    look = summary["decode"]["lookahead"]
+    assert look["steps"] == summary["decode"]["decode_steps"]
+    assert look["ahead"] >= look["steps"] // 2 > 0
+    assert look["in_time"] + look["late"] == \
+        summary["decode"]["prefill_steps"] == 5
+    # the experts' counts come with every answer, ahead or not
+    assert summary["decode"]["experts"]["programs"] == 5 + look["steps"]
+    assert summary["requests"] == sum(summary["outcomes"].values()) == 5
+
+
 def test_gpt_runs_through_the_same_seam():
     np.random.seed(29)
     model = GPT(GPTConfig(vocab_size=97, hidden_size=48, num_layers=2,

@@ -7,7 +7,15 @@ how often it ran, the host time it took, and how much of the device's
 idle time (the gaps between the device's ops inside the benchmark's
 window) lay under it.  Both are events of one trace, so they share a
 clock.  What the benchmark folds into the one label `engine_step`
-(`breakdown.idle_gaps`) is split by phase here.
+(`breakdown.idle_gaps`) is split by phase here.  The `*_wait` phases
+are those in which the host is blocked: `engine.decode_wait` and
+`engine.prefill_wait` on the device's answer, `engine.listen_wait` (the
+loop thread, since ISSUE 32) on the submit condition while the device
+works; idle time under the others is the device waiting for the host.
+Beside the table stands the engine's `lookahead` counter at the
+window's close (`DecodeStats.summary()["decode"]["lookahead"]`): decode
+steps, those enqueued while the step before them was unanswered, and
+admissions in time and late.
 
 Also printed: the spread of `start_ns - pc_ns` over the spans (how well
 `profiler.trace_clock_offset_ns` ties `perf_counter_ns` to the trace's
@@ -141,14 +149,23 @@ def main(argv=None):
                     "to this JSON file")
     args = ap.parse_args(argv)
 
+    from paddle_tpu.serving import DecodeEngine
+
     captured = {}
     load = trace_reduce.load_xplane
+    summary = DecodeEngine.summary
 
     def load_and_keep(path, *a, **kw):
         captured["trace"] = read_trace(path)
         return load(path, *a, **kw)
 
+    def summary_and_keep(engine):
+        out = summary(engine)
+        captured["lookahead"] = out["decode"].get("lookahead")
+        return out
+
     trace_reduce.load_xplane = load_and_keep
+    DecodeEngine.summary = summary_and_keep
     cmd = ["--workload", args.workload, "--seed", str(args.seed),
            "--seconds", str(args.seconds), "--trace", "1"]
     if args.rehearse:
@@ -159,6 +176,7 @@ def main(argv=None):
             code = bench_run.main(cmd)
     finally:
         trace_reduce.load_xplane = load
+        DecodeEngine.summary = summary
     if code or "trace" not in captured:
         print(out.getvalue(), file=sys.stderr)
         return code or 1
@@ -169,7 +187,14 @@ def main(argv=None):
     report = {"workload": args.workload, "seed": args.seed,
               "device": line["device"], "metrics": line["metrics"],
               "breakdown": line.get("breakdown", {}),
-              "programs": programs, "spans_in_trace": len(spans)}
+              "programs": programs, "spans_in_trace": len(spans),
+              "lookahead": captured.get("lookahead")}
+    look = report["lookahead"]
+    if look:
+        print(f"lookahead: {look['ahead']} of {look['steps']} decode steps "
+              f"enqueued ahead ({100 * look['ahead'] / max(look['steps'], 1):.2f}%); "
+              f"admissions {look['in_time']} in time, {look['late']} late "
+              f"({100 * look['late'] / max(look['in_time'] + look['late'], 1):.2f}% late)")
     if programs:
         print("programs run (XLA Modules): " + ", ".join(
             f"{n} x{c}" for n, c in sorted(programs.items())))
@@ -187,9 +212,16 @@ def main(argv=None):
         print(f"window {report['window_s']:.3f} s, device idle "
               f"{idle_s:.4f} s, of it outside any engine.step "
               f"{outside_s:.4f} s")
-        print(f"{'phase':<34}{'count':>7}{'host s':>10}{'idle s':>10}")
+        print(f"{'phase':<34}{'count':>7}{'host s':>10}{'idle s':>10}"
+              f"{'idle %':>8}")
         for name, count, host_s, under_s in table:
-            print(f"{name:<34}{count:>7}{host_s:>10.4f}{under_s:>10.4f}")
+            print(f"{name:<34}{count:>7}{host_s:>10.4f}{under_s:>10.4f}"
+                  f"{100 * under_s / idle_s if idle_s else 0.0:>8.1f}")
+        blocked = sum(i for n, _, _, i in table if n.endswith("_wait"))
+        report["idle_under_waits_s"] = blocked
+        print(f"idle under the *_wait phases (host blocked) {blocked:.4f} s, "
+              f"under the host's own work "
+              f"{idle_s - outside_s - blocked:.4f} s")
         host = line["metrics"].get("engine_host_ms_per_step.serve")
         waits = sum(c for n, c, _, _ in table if n == "engine.decode_wait")
         labelled = dict(map(tuple, report["breakdown"].get(
