@@ -558,6 +558,109 @@ def phase_k2(hf, slots, max_len, buckets, prompt_lens, new_tokens,
 
 
 # ---------------------------------------------------------------------------
+# AFMoE (Trinity) behind the decode engine
+# ---------------------------------------------------------------------------
+
+def phase_afmoe(hf, slots, max_len, buckets, prompt_lens, new_tokens,
+                decode_lengths, prefill_seq, tol, gap_tol):
+    """`hf`: the model's sizes under its config.json keys.  (1)
+    `kv_append` + `gqa_decode` at the model's widths against their XLA
+    mathematics at ragged lengths, over a full cache and over a ring
+    that has wrapped, and `flash_fwd` with the window and grouped heads
+    against the XLA mask at `prefill_seq`; (2) the engine's first
+    `new_tokens` tokens of each prompt (some shorter than the window,
+    some longer, some that cross it while decoding) against the
+    unbatched forward pass (`afmoe.full_logits` over prompt and served
+    tokens: no cache, no ring): the widest gap by which a served
+    token's logit lies under that pass's best."""
+    from paddle_tpu.kernels import attention
+    from paddle_tpu.models import afmoe
+    from paddle_tpu.serving import DecodeConfig, DecodeEngine
+
+    cfg = afmoe.AfmoeCfg.from_hf(hf, max_seq_len=max_len)
+    dtype = jnp.dtype(cfg.dtype)
+    rng = np.random.default_rng(3)
+    s, kvh, d = len(decode_lengths), cfg.num_kv_heads, cfg.head_dim
+
+    def rand(shape):
+        return jnp.asarray(rng.standard_normal(shape), dtype)
+
+    out = {}
+    pos = jnp.asarray(decode_lengths, jnp.int32) - 1
+    for name, depth, ring in (("full", max_len, False),
+                              ("ring", cfg.sliding_window, True)):
+        args = (rand((s, cfg.num_heads, 1, d)), rand((s, kvh, 1, d)),
+                rand((s, kvh, 1, d)), rand((2, s, kvh, d, depth)),
+                rand((2, s, kvh, d, depth)))
+        fn = functools.partial(attention.resident_decode_attention,
+                               layer=1, pos=pos, ring=ring)
+        got = {}
+        for kernel in (True, False):
+            # the dispatch reads the switch while the call is traced
+            os.environ["PADDLE_TPU_FORCE_FLASH_DECODE"] = str(int(kernel))
+            try:
+                got[kernel] = (jax.jit(fn) if kernel
+                               else _highest(fn))(*args)
+            finally:
+                del os.environ["PADDLE_TPU_FORCE_FLASH_DECODE"]
+        _check(all(bool((a == b).all()) for a, b in zip(got[True][1:],
+                                                        got[False][1:])),
+               f"kv_append ({name}) differs from the XLA write")
+        err, rel = _err(got[True][0], got[False][0])
+        _check(rel <= tol, f"gqa_decode ({name}): error {err:.3g} is "
+                           f"{rel:.3g} of the reference's max, over {tol}")
+        out[f"gqa_decode_{name}"] = {"max_abs_err": err, "rel_to_max": rel}
+
+    q = rand((1, cfg.num_heads, prefill_seq, d))
+    k, v = rand((1, kvh, prefill_seq, d)), rand((1, kvh, prefill_seq, d))
+    for name, window in (("window", cfg.sliding_window), ("full", None)):
+        fn = functools.partial(
+            attention.dot_product_attention, is_causal=True, training=False,
+            window=window)
+        err, rel = _err(
+            jax.jit(functools.partial(fn, use_flash=True))(q, k, v),
+            _highest(functools.partial(fn, use_flash=False))(q, k, v))
+        _check(rel <= tol, f"flash_fwd ({name}, grouped): error {err:.3g} "
+                           f"is {rel:.3g} of the reference's max, over {tol}")
+        out[f"flash_fwd_{name}"] = {"max_abs_err": err, "rel_to_max": rel}
+
+    params = afmoe.AfmoeParams.from_flat(
+        cfg, afmoe.init_params(cfg, jax.random.PRNGKey(34), bias_std=0.002))
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in prompt_lens]
+    eng = DecodeEngine(params, config=DecodeConfig(
+        slots=slots, max_len=max_len, buckets=buckets))
+    try:
+        futs = [eng.submit(p, max_new_tokens=new_tokens) for p in prompts]
+        served = [np.asarray(f.result(timeout=900)) for f in futs]
+        summary = eng.summary()
+    finally:
+        eng.close()
+    forward = jax.jit(functools.partial(afmoe.full_logits, cfg))
+    widest, same = 0.0, 0
+    for p, toks in zip(prompts, served):
+        _check(toks.shape == (new_tokens,), f"prompt {p.size}: {toks.shape}")
+        logits = np.asarray(forward(
+            params.trees, np.concatenate([p, toks])), np.float32)
+        rows = logits[p.size - 1:p.size - 1 + new_tokens]
+        widest = max(widest, float((rows.max(axis=1) - rows[
+            np.arange(new_tokens), toks]).max()))
+        same += int((rows.argmax(axis=1) == toks).sum())
+    _check(widest <= gap_tol, f"engine tokens lie up to {widest:.3g} under "
+                              f"the forward pass's best, over {gap_tol}")
+    dec = summary["decode"]
+    _check(dec["experts"]["tokens_total"] > 0, f"no expert counts: {dec}")
+    _check([a["depth"] for a in dec["cache"]["arrays"]]
+           == [max_len] * 2 + [min(cfg.sliding_window, max_len)] * 2,
+           f"the caches' depths: {dec['cache']}")
+    out.update({"requests": len(prompts), "widest_logit_gap": widest,
+                "tokens_equal_to_forward":
+                    f"{same}/{len(prompts) * new_tokens}",
+                "cache": dec["cache"], "experts": dec["experts"]})
+    return out
+
+
+# ---------------------------------------------------------------------------
 # four chips
 # ---------------------------------------------------------------------------
 
@@ -683,6 +786,16 @@ def main():
         prompt_lens=[40, 200, 900, 513, 700, 40, 255, 1000, 333, 90],
         new_tokens=32, decode_lengths=[1, 40, 1024, 2048, 777, 128, 129,
                                        2047], tol=5e-2, gap_tol=0.25)
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmarks", "configs",
+                           "trinity-mini.json")) as f:
+        trinity = json.load(f)
+    run("afmoe", phase_afmoe, trinity, slots=8, max_len=4096,
+        buckets=(512, 2048, 4096),
+        prompt_lens=[40, 2040, 2049, 3000, 700],
+        new_tokens=32, decode_lengths=[1, 40, 1024, 2048, 777, 128, 2049,
+                                       4096], prefill_seq=4096, tol=5e-2,
+        gap_tol=0.45)     # read 0.3125 (145 of 160 tokens equal), PR 34
     if device["count"] >= 4:
         run("four_chips", phase_four_chips, GPT_FULL, 8, 2048, 3,
             layer["losses"][0], STATIC_GPT_FULL, static_batch,

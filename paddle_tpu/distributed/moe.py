@@ -223,7 +223,7 @@ def grouped_product(rows, weights, counts, use_kernel=None):
 
 
 def routed_experts(h, router, bias, experts_held, first_expert, n_routed,
-                   top_k, scale, valid=None, use_kernel=None):
+                   top_k, scale, valid=None, use_kernel=None, slack=None):
     """The routed part of an expert layer on a chip that holds experts
     [first_expert, first_expert + held) of `n_routed`.
 
@@ -237,6 +237,15 @@ def routed_experts(h, router, bias, experts_held, first_expert, n_routed,
     product (`grouped_product`: the Pallas call `moe_grouped_mm` on the
     TPU) over static N * top_k rows, so nothing is dropped at any
     imbalance; what the absent experts would have added is left out.
+
+    `slack` (a prefill's: many tokens, of which a chip's experts get
+    their share) makes the usual case cheaper and drops nothing either:
+    where the held assignments fit `slack` times their expected number,
+    N * top_k * held / n_routed rows (`_rows_kept`), the gathers, the
+    products and the sum back into the tokens run over that many rows
+    only; where they do not, over all N * top_k as without it
+    (`jax.lax.cond`: the device runs one of the two).
+
     Returns (y [N, D] in h's type, assignments on each held expert
     int32 [held]); the caller adds the shared expert."""
     gate_up, down = experts_held
@@ -257,14 +266,50 @@ def routed_experts(h, router, bias, experts_held, first_expert, n_routed,
     order = jnp.argsort(group, stable=True)
     counts = jnp.sum(group[:, None] == jnp.arange(held)[None, :],
                      axis=0, dtype=jnp.int32)
-    rows = jnp.take(h, order // top_k, axis=0)
-    gu = grouped_product(rows, gate_up, counts, use_kernel)
-    f = gu.shape[1] // 2
-    act = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(h.dtype)
-    out = grouped_product(act, down, counts, use_kernel)
-    # back into the tokens' order; rows past the held assignments hold
-    # whatever the grouped product left there
-    back = jnp.take(out, jnp.argsort(order), axis=0).reshape(n, top_k, d)
-    y = jnp.sum(jnp.where(here[..., None],
-                          back * weights[..., None], 0.0), axis=1)
+
+    def experts_of(order):
+        """The held experts' SwiGLU of the assignments `order` names,
+        float32 [len(order), D]; rows past the held assignments hold
+        whatever the grouped product left there."""
+        rows = jnp.take(h, order // top_k, axis=0)
+        gu = grouped_product(rows, gate_up, counts, use_kernel)
+        f = gu.shape[1] // 2
+        act = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(h.dtype)
+        return grouped_product(act, down, counts, use_kernel)
+
+    def every_row():
+        # back into the tokens' order
+        back = jnp.take(experts_of(order), jnp.argsort(order),
+                        axis=0).reshape(n, top_k, d)
+        return jnp.sum(jnp.where(here[..., None],
+                                 back * weights[..., None], 0.0), axis=1)
+
+    kept = _rows_kept(n * top_k, held / n_routed, slack)
+    if kept is None:
+        return every_row().astype(h.dtype), counts
+
+    def rows_kept():
+        # the held assignments are the first of `order`: each row times
+        # its weight, added to its token
+        first = order[:kept]
+        held_row = (jnp.arange(kept) < counts.sum())[:, None]
+        out = jnp.where(
+            held_row,
+            experts_of(first) * weights.reshape(-1)[first][:, None], 0.0)
+        return jnp.zeros((n, d), jnp.float32).at[first // top_k].add(out)
+
+    y = jax.lax.cond(counts.sum() <= kept, rows_kept, every_row)
     return y.astype(h.dtype), counts
+
+
+def _rows_kept(rows, share, slack):
+    """Static rows of `routed_experts`' cheaper case: `slack` times the
+    held experts' expected share of `rows` assignments, in whole row
+    tiles of the grouped product; None (no such case) without a slack or
+    where that is no fewer than half of `rows`."""
+    if slack is None:
+        return None
+    from ..kernels.grouped_mm import ROW_TILE
+
+    kept = -(-int(math.ceil(slack * share * rows)) // ROW_TILE) * ROW_TILE
+    return kept if 2 * kept <= rows else None

@@ -23,12 +23,23 @@ DECODE_NEG_INF = -1e30
 
 
 def _xla_attention(q, k, v, mask, scale, is_causal, dropout_p, training,
-                   rng_key):
-    # q,k,v: [B, H, S, D]
+                   rng_key, window=None):
+    # q,k,v: [B, H, S, D]; or k, v [B, KVH, S, D], H / KVH query heads
+    # reading one head of K and V: their rows then lie one head after
+    # the other against that head, and K and V are not repeated
+    b, h, s_q, d = q.shape
+    grouped = k.shape[1] != h
+    if grouped:
+        q = q.reshape(b, k.shape[1], -1, d)
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     if is_causal:
-        s_q, s_k = logits.shape[-2], logits.shape[-1]
+        s_k = logits.shape[-1]
         causal = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
+        if window is not None:
+            # a query sees the `window` keys up to and with its own
+            causal &= ~jnp.tril(causal, k=s_k - s_q - window)
+        if grouped:
+            causal = jnp.tile(causal, (h // k.shape[1], 1))
         logits = jnp.where(causal, logits, NEG_INF)
     if mask is not None:
         if mask.dtype == jnp.bool_:
@@ -43,7 +54,8 @@ def _xla_attention(q, k, v, mask, scale, is_causal, dropout_p, training,
             rng_key = default_rng.next_key()
         keep = jax.random.bernoulli(rng_key, 1.0 - dropout_p, probs.shape)
         probs = jnp.where(keep, probs / (1.0 - dropout_p), 0.0)
-    return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+    out = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+    return out.reshape(b, h, s_q, d) if grouped else out
 
 
 def _flash_per_shard(q, k, v, causal, scale):
@@ -156,7 +168,7 @@ def decode_attention(q, k, v, pos=None, mask=None, scale=None,
 
 
 def resident_decode_attention(q, k_new, v_new, k_cache, v_cache, layer,
-                              pos, scale=None):
+                              pos, scale=None, ring=False):
     """One layer of the decode engine's step on the cache it owns
     (serving/decode.py): write each slot's new column, then attend.
 
@@ -168,6 +180,14 @@ def resident_decode_attention(q, k_new, v_new, k_cache, v_cache, layer,
     write into the slot's own last column.  Returns (o [S, H, 1, D],
     k_cache, v_cache).
 
+    k_new, v_new and the caches may hold fewer heads than q (grouped
+    queries: query head h reads head h // (H / KVH), K and V never
+    repeated).  With `ring` the cache is a ring over the last T
+    positions (a window layer's): position p lives in column p mod T,
+    the step writes column pos mod T and attends min(pos + 1, T)
+    columns, in whatever order they lie (the softmax does not mind, and
+    a rotary phase is in the stored key).
+
     Reader and writer both take the stacked cache as it lies, so the
     caller can carry it through its layer loop and the compiled step
     holds one copy of it.  Where `_decode_takes_kernel` holds these are
@@ -176,25 +196,48 @@ def resident_decode_attention(q, k_new, v_new, k_cache, v_cache, layer,
     what keeps the engine token-exact against generate())."""
     t = k_cache.shape[-1]
     pos = jnp.asarray(pos, jnp.int32)
-    posw = jnp.minimum(pos, t - 1)
-    k_col, v_col = k_new[:, :, 0, :], v_new[:, :, 0, :]       # [S, H, D]
+    posw = pos % t if ring else jnp.minimum(pos, t - 1)
+    live = jnp.minimum(pos + 1, t) if ring else pos + 1
+    grouped = k_cache.shape[2] != q.shape[1]
+    k_col, v_col = k_new[:, :, 0, :], v_new[:, :, 0, :]     # [S, KVH, D]
     if _decode_takes_kernel(t, q.shape[-1]):
-        from .flash_attention import flash_decode_resident, kv_append
+        from .flash_attention import (flash_decode_resident,
+                                      gqa_decode_resident, kv_append)
 
         k_cache, v_cache = kv_append(k_cache, v_cache, k_col, v_col,
                                      layer, posw)
-        o = flash_decode_resident(q, k_cache, v_cache, layer, pos + 1,
-                                  sm_scale=scale)
+        attend = gqa_decode_resident if grouped else flash_decode_resident
+        o = attend(q, k_cache, v_cache, layer, live, sm_scale=scale)
         return o, k_cache, v_cache
     slots = jnp.arange(q.shape[0])
     k_cache = k_cache.at[layer, slots, :, :, posw].set(
         k_col.astype(k_cache.dtype))
     v_cache = v_cache.at[layer, slots, :, :, posw].set(
         v_col.astype(v_cache.dtype))
+    if grouped or ring:
+        return _grouped_decode(q, k_cache[layer], v_cache[layer], live,
+                               scale), k_cache, v_cache
     o = decode_attention(q, jnp.swapaxes(k_cache[layer], -1, -2),
                          jnp.swapaxes(v_cache[layer], -1, -2), pos=pos,
                          scale=scale, use_flash=False)
     return o, k_cache, v_cache
+
+
+def _grouped_decode(q, k, v, live, scale):
+    """The XLA mathematics of `gqa_decode_resident`: q [S, H, 1, D]
+    against one layer k, v [S, KVH, D, T] of which each slot's first
+    `live` [S] columns count; float32 scores and softmax as
+    `decode_attention`'s."""
+    s, h, _, d = q.shape
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    qg = q.reshape(s, k.shape[1], -1, d)
+    sc = jnp.einsum("skgd,skdt->skgt", qg, k.astype(q.dtype)) * scale
+    seen = jnp.arange(k.shape[-1], dtype=jnp.int32)[None, :] < live[:, None]
+    sc = jnp.where(seen[:, None, None, :], sc.astype(jnp.float32),
+                   DECODE_NEG_INF)
+    p = jax.nn.softmax(sc, axis=-1).astype(q.dtype)
+    return jnp.einsum("skgt,skdt->skgd", p, v.astype(q.dtype)).reshape(
+        s, h, 1, d)
 
 
 def resident_mla_attention(q_latent, q_rope, new, latent, layer, pos,
@@ -239,9 +282,17 @@ def resident_mla_attention(q_latent, q_rope, new, latent, layer, pos,
 
 def dot_product_attention(q, k, v, mask=None, dropout_p=0.0, is_causal=False,
                           scale=None, training=True, rng_key=None,
-                          use_flash=None):
-    """q/k/v: [batch, heads, seq, head_dim] -> [batch, heads, seq, head_dim]."""
+                          use_flash=None, window=None):
+    """q/k/v: [batch, heads, seq, head_dim] -> [batch, heads, seq, head_dim].
+
+    Forward only (`training=False`), causal: k and v may hold fewer
+    heads than q (grouped queries, never repeated in memory), and with
+    `window` a query sees the `window` keys up to and with its own."""
     head_dim = q.shape[-1]
+    served = window is not None or k.shape[1] != q.shape[1]
+    if served and (training or not is_causal or mask is not None):
+        raise ValueError("grouped heads and a window are served forward "
+                         "only: training=False, is_causal=True, no mask")
     scale = scale if scale is not None else 1.0 / math.sqrt(head_dim)
 
     # the flash kernel supports neither arbitrary masks nor in-kernel
@@ -255,6 +306,7 @@ def dot_product_attention(q, k, v, mask=None, dropout_p=0.0, is_causal=False,
         and q.shape[-2] == k.shape[-2]
         and seq % 128 == 0
         and head_dim in (64, 128, 256)
+        and (window is None or window % 128 == 0)
     )
     forced_flash = use_flash is True
     if use_flash is None:
@@ -279,7 +331,11 @@ def dot_product_attention(q, k, v, mask=None, dropout_p=0.0, is_causal=False,
             f"head_dim={head_dim}; needs no mask, no train-dropout, "
             "self-attention, seq%128==0, head_dim in 64/128/256) — "
             "falling back to the XLA path", stacklevel=2)
+    if use_flash and can_flash and served:
+        from .flash_attention import flash_attention_fwd
+
+        return flash_attention_fwd(q, k, v, sm_scale=scale, window=window)
     if use_flash and can_flash:
         return _flash_per_shard(q, k, v, is_causal, scale)
     return _xla_attention(q, k, v, mask, scale, is_causal, dropout_p,
-                          training, rng_key)
+                          training, rng_key, window)

@@ -122,13 +122,18 @@ def _fit(want, seq):
     return seq
 
 
-def flash_tiling(seq, head_dim, causal, block_q=None, block_k=None):
+def flash_tiling(seq, head_dim, causal, block_q=None, block_k=None,
+                 window=None):
     """The tiling of `flash_fwd`, `flash_dq` and `flash_dkv` for one
     (seq, head_dim, causal), and the count that says the causal pruning
     engages: `tiles_visited` of `tiles_total` tiles of chunk_q x
     chunk_q, those on or under the diagonal when causal and all of them
     when not.  `block_q` / `block_k` override the blocks (tests, the
-    interpreter).
+    interpreter).  With `window` (forward only, causal: a query sees
+    the `window` keys up to its own) the q block is one that divides
+    the window too, so that the band's far edge runs along a tile's
+    diagonal as its near edge does, and `tiles_visited` counts the
+    band's tiles only (`_band_strips`).
 
     Measured on the v5e at the train cell's [128, 1024, 64], causal
     (PERF.md section 6, PR 30).  Rows of a grid step: 1024 beat 512 and
@@ -140,11 +145,17 @@ def flash_tiling(seq, head_dim, causal, block_q=None, block_k=None):
     against 0.362 and 0.414 at 128), 128 for `flash_dkv` (0.486 against
     0.564 at 256)."""
     rows = 1024 if seq * 1024 * 4 <= _STRIP_BYTES else 512
+    if window is not None and not block_q:
+        block_q = _fit(rows, math.gcd(seq, window))
     block_q = min(block_q, seq) if block_q else _fit(rows, seq)
     block_k = min(block_k, seq) if block_k else _fit(rows, seq)
     if seq % block_q or seq % block_k:
         raise ValueError(
             f"seq {seq} must be divisible by block sizes ({block_q},{block_k})")
+    if window is not None and (not causal or window % block_q):
+        raise ValueError(
+            f"a window ({window}) needs causal=True and a q block "
+            f"({block_q}) that divides it")
     chunk_q, chunk_k = _fit(256, block_q), _fit(128, block_k)
     # the other operand whole where it and the strip fit, else the
     # largest power-of-two share of it that both blocks divide
@@ -156,6 +167,14 @@ def flash_tiling(seq, head_dim, causal, block_q=None, block_k=None):
         block_major //= 2
     n = seq // chunk_q
     visited = n * (n + 1) // 2 if causal else n * n
+    if window is not None:
+        # a q block's diagonal tile and the band's edge tile, chunk by
+        # chunk a triangle each, and the whole tiles between them
+        per, reach = block_q // chunk_q, window // block_q
+        tri = per * (per + 1) // 2
+        visited = sum(tri + min(t, reach - 1) * per * per
+                      + (tri if t >= reach else 0)
+                      for t in range(seq // block_q))
     return FlashTiling(block_q, block_k, chunk_q, chunk_k, block_major,
                        visited, n * n)
 
@@ -211,6 +230,32 @@ def _query_strips(diag, block, chunk, block_major):
                   if after < block_major else ())
 
 
+def _band_strips(diag, block, chunk, n_major, reach):
+    """Static strips (row0, row1, col0, width, kind) of a q block's
+    scores against the resident keys when a query sees the `reach`
+    tiles of keys up to its own only (`flash_fwd` with a window).
+    `diag` is the place of the diagonal tile among the resident
+    operand's `n_major` tiles, and may lie past them.  The band's far
+    edge is the diagonal of tile `diag - reach`, of which the part
+    above it is live: key chunk by key chunk, the rows up to the
+    chunk's own (kind "edge": in the last of them a row sees the keys
+    after its own place).  Then the whole tiles between, in one
+    product; then the diagonal tile as `_key_strips` cuts it (kind
+    "diag").  Tiles outside the resident operand are left out."""
+    edge, per = diag - reach, block // chunk
+    strips = []
+    if 0 <= edge < n_major:
+        strips += [(0, (j + 1) * chunk, edge * block + j * chunk, chunk,
+                    "edge") for j in range(per)]
+    lo, hi = max(edge + 1, 0), min(diag, n_major)
+    if hi > lo:
+        strips.append((0, block, lo * block, (hi - lo) * block, None))
+    if diag < n_major:
+        strips += [(j * chunk, block, diag * block + j * chunk, chunk,
+                    "diag") for j in range(per)]
+    return tuple(strips)
+
+
 def _seen(rows, chunk, keys_on_rows=False):
     """[rows, chunk] bool, the causal mask of a strip inside the diagonal
     tile: queries on the rows, counted from the first that meets the
@@ -227,7 +272,7 @@ def _seen(rows, chunk, keys_on_rows=False):
 # --------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
-                sm_scale, causal, block_q, chunk):
+                sm_scale, causal, block_q, chunk, reach=None):
     qi = pl.program_id(1)
     kb = pl.program_id(2)
     block_major = k_ref.shape[0]
@@ -255,27 +300,39 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         strip by strip (`_key_strips`), the softmax between them chunk
         of rows by chunk of rows over whatever strips reach it, so that
         a row's maximum and sum are formed once."""
-        strips = _key_strips(diag, block_q, chunk, block_major)
+        if reach is None:
+            strips = tuple(
+                (row0, block_q, col0, width, "diag" if masked else None)
+                for row0, col0, width, masked in _key_strips(
+                    diag, block_q, chunk, block_major))
+        else:
+            strips = _band_strips(diag, block_q, chunk,
+                                  block_major // block_q, reach)
         scores = [jax.lax.dot_general(
-            q_ref[row0:, :], k_ref[col0:col0 + width, :], _NT,
+            q_ref[row0:row1, :], k_ref[col0:col0 + width, :], _NT,
             preferred_element_type=jnp.float32)
-            for row0, col0, width, _ in strips]
-        seen = _seen(chunk, chunk)
+            for row0, row1, col0, width, _ in strips]
+        seen = {"diag": _seen(chunk, chunk)}
+        if reach is not None:
+            seen["edge"] = jnp.logical_not(seen["diag"])
 
         def _tiles(of, i):
             """Rows [i*chunk, (i+1)*chunk) of every strip that has them:
-            (strip, tile, whether the diagonal crosses the tile)."""
-            for si, (row0, _, _, masked) in enumerate(strips):
-                r = i * chunk - row0
-                if r >= 0:
-                    yield si, of[si][r:r + chunk], masked and r == 0
+            (strip, tile, the edge of the band that crosses the tile)."""
+            for si, (row0, row1, _, _, kind) in enumerate(strips):
+                r, end = i * chunk - row0, (i + 1) * chunk
+                if r >= 0 and end <= row1:
+                    crossed = (kind == "diag" and r == 0) \
+                        or (kind == "edge" and end == row1)
+                    yield si, of[si][r:r + chunk], kind if crossed else None
 
         probs = [[] for _ in strips]    # per strip, p by chunk of rows
         stats = []
         for i in range(n_rows):
             rows = slice(i * chunk, (i + 1) * chunk)
-            tiles = [(si, jnp.where(seen, t * sm_scale, NEG_INF) if crossed
-                      else t * sm_scale) for si, t, crossed in _tiles(scores, i)]
+            tiles = [(si, jnp.where(seen[crossed], t * sm_scale, NEG_INF)
+                      if crossed else t * sm_scale)
+                     for si, t, crossed in _tiles(scores, i)]
             m = functools.reduce(jnp.maximum, [
                 t.max(axis=-1, keepdims=True) for _, t in tiles])
             if streamed:
@@ -290,7 +347,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             ps[0] if len(ps) == 1 else jnp.concatenate(ps, axis=0),
             v_ref[col0:col0 + width, :], _NN,
             preferred_element_type=jnp.float32)
-            for ps, (_, col0, width, _) in zip(probs, strips)]
+            for ps, (_, _, col0, width, _) in zip(probs, strips)]
         for i, (m, l) in enumerate(stats):
             rows = slice(i * chunk, (i + 1) * chunk)
             acc = functools.reduce(
@@ -303,8 +360,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             else:
                 _finalize(rows, m, l, acc)
 
-    _strips(causal, qi * block_q - kb * block_major, block_q,
-            block_major // block_q, _run)
+    offset = qi * block_q - kb * block_major
+    if reach is None:
+        _strips(causal, offset, block_q, block_major // block_q, _run)
+    else:
+        # one branch per place of the diagonal from which the band still
+        # reaches the resident keys, of which at most one runs
+        n_major = block_major // block_q
+        for c in range(n_major + reach if streamed else n_major):
+            pl.when(offset == c * block_q)(functools.partial(_run, c))
 
     if streamed:
         @pl.when(kb == pl.num_programs(2) - 1)
@@ -312,37 +376,46 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             _finalize(slice(None), m_ref[...], l_ref[...], acc_ref[...])
 
 
-def _major_index(causal, block, block_major):
+def _major_index(causal, block, block_major, group=1, window=None):
     """Index map of the streamed operand of `flash_fwd` / `flash_dq`:
     K/V block `kb` for q block `qi`.  Causal, a block wholly past the
     diagonal names the last live one again, which Pallas does not fetch
-    twice."""
+    twice; with a window, so does a block wholly before the band, the
+    first live one.  `group` query heads read one head of K and V."""
     def index(bh, qi, kb):
         last_live = (qi * block) // block_major
-        return bh, (jnp.minimum(kb, last_live) if causal else kb), 0
+        kb = jnp.minimum(kb, last_live) if causal else kb
+        if window is not None:
+            kb = jnp.maximum(
+                kb, jnp.maximum(qi * block - window, 0) // block_major)
+        return (bh if group == 1 else bh // group), kb, 0
     return index
 
 
-def _fwd(q, k, v, sm_scale, causal, tiling):
+def _fwd(q, k, v, sm_scale, causal, tiling, window=None):
     """-> out [b, h, s, d], lse [b*h, 1, s] (the kernels' layout)."""
-    return _fwd_call(q, k, v, sm_scale, causal, tiling, interpret())
+    return _fwd_call(q, k, v, sm_scale, causal, tiling, interpret(), window)
 
 
 # jitted, so that a model's layers trace and lower each kernel once and
 # not once a layer (the kernels' bodies are unrolled, static code);
 # `interpreted` is an argument so that it is part of the cache's key
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6), inline=True)
-def _fwd_call(q, k, v, sm_scale, causal, tiling, interpreted):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7), inline=True)
+def _fwd_call(q, k, v, sm_scale, causal, tiling, interpreted, window=None):
+    """k and v may hold fewer heads than q (a whole number of query
+    heads to each): the index map sends a query head to its own."""
     b, h, s, d = q.shape
     block_q, chunk, block_major = (tiling.block_q, tiling.chunk_q,
                                    tiling.block_major)
-    q3, k3, v3 = (x.reshape(b * h, s, d) for x in (q, k, v))
+    q3, k3, v3 = (x.reshape(-1, s, d) for x in (q, k, v))
     q_spec = _vmem_spec((None, block_q, d), lambda bh, qi, kb: (bh, qi, 0))
     kv_spec = _vmem_spec((None, block_major, d),
-                         _major_index(causal, block_q, block_major))
+                         _major_index(causal, block_q, block_major,
+                                      h // k.shape[1], window))
+    windowed = {} if window is None else {"reach": window // block_q}
     call = pl.pallas_call(
         functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, chunk=chunk),
+                          block_q=block_q, chunk=chunk, **windowed),
         grid=(b * h, s // block_q, s // block_major),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[
@@ -731,6 +804,111 @@ def flash_decode_resident(q, k_cache, v_cache, layer, lengths,
     return out.reshape(s, h, 1, d)
 
 
+def _gqa_decode_kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
+                       acc_ref, m_ref, l_ref, *, sm_scale, block_k, group):
+    # one slot a grid row: q [KVH, group, D], tiles [KVH, D, block_k];
+    # the `group` query heads of a K/V head meet its tile where it lies
+    ki = pl.program_id(1)
+    length = len_ref[pl.program_id(0)]
+    kv_heads = k_ref.shape[0]
+
+    @pl.when(ki == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(ki * block_k < length)
+    def _tile():
+        def heads_of(h):
+            return slice(h * group, (h + 1) * group)
+
+        s = jnp.concatenate([jax.lax.dot_general(
+            q_ref[h], k_ref[h], _NN,
+            preferred_element_type=jnp.float32)
+            for h in range(kv_heads)], axis=0) * sm_scale   # [H, bk] f32
+        k_pos = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        s = jnp.where(k_pos < length, s, NEG_INF)
+        m_prev = m_ref[:, 0:1]
+        m_cur = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.exp(s - m_cur)
+        l_ref[...] = jnp.broadcast_to(
+            l_ref[:, 0:1] * alpha + p.sum(axis=1, keepdims=True),
+            l_ref.shape)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.concatenate([
+            jax.lax.dot_general(p[heads_of(h)].astype(v_ref.dtype),
+                                v_ref[h], _NT,
+                                preferred_element_type=jnp.float32)
+            for h in range(kv_heads)], axis=0)              # [H, D]
+        m_ref[...] = jnp.broadcast_to(m_cur, m_ref.shape)
+
+    @pl.when(ki == pl.num_programs(1) - 1)
+    def _finalize():
+        l = l_ref[:, 0:1]
+        o_ref[...] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
+
+
+def gqa_decode_resident(q, k_cache, v_cache, layer, lengths, sm_scale=None):
+    """`flash_decode_resident` where several query heads share a head of
+    K and V (grouped-query attention).
+
+    q: [S, H, 1, D]; k_cache/v_cache: [L, S, KVH, D, T] with H a
+    multiple of KVH (query head h reads K/V head h // (H / KVH));
+    layer: int32 scalar; lengths: int32 [S], the leading columns of the
+    slot that are live (a ring that has wrapped gives its whole depth).
+    Grid (S, T // block_k): a grid step holds one slot's tile of every
+    K/V head, [KVH, D, block_k], fetched once for all the query heads
+    that read it, so K and V are never repeated in memory; blocks past a
+    slot's length are neither computed (`pl.when`) nor fetched (their
+    index names the last live block again).  Float32 scores and
+    accumulation; the result is [S, H, 1, D] in q's type."""
+    s, h, q_len, d = q.shape
+    if q_len != 1:
+        raise ValueError(f"gqa_decode needs q_len == 1, got {q_len}")
+    kvh, t = k_cache.shape[2], k_cache.shape[-1]
+    if k_cache.shape[1:] != (s, kvh, d, t) or h % kvh \
+            or v_cache.shape != k_cache.shape:
+        raise ValueError(
+            f"resident caches must be [L, {s}, KVH, {d}, T] with KVH "
+            f"dividing {h}, got {k_cache.shape} and {v_cache.shape}")
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d)
+    block_k = _decode_block_k(t, None)
+    lengths = jnp.asarray(lengths, jnp.int32).reshape(s)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    group = h // kvh
+    q_spec = _vmem_spec((None, kvh, group, d),
+                        lambda i, ki, *_: (i, 0, 0, 0))
+    o_spec = _vmem_spec((None, h, d), lambda i, ki, *_: (i, 0, 0))
+    kv_spec = _vmem_spec(
+        (None, None, kvh, d, block_k),
+        lambda i, ki, lens, layer: (
+            layer[0], i, 0, 0, jnp.minimum(ki, (lens[i] - 1) // block_k)))
+    call = pl.pallas_call(
+        functools.partial(_gqa_decode_kernel, sm_scale=float(sm_scale),
+                          block_k=block_k, group=group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(s, t // block_k),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=o_spec,
+            scratch_shapes=[_scratch((h, d)), _scratch((h, _LANES)),
+                            _scratch((h, _LANES))]),
+        out_shape=jax.ShapeDtypeStruct((s, h, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret(),
+        name="gqa_decode",
+    )
+    with jax.named_scope("gqa_decode"):
+        out = call(lengths, layer, q.reshape(s, kvh, group, d), k_cache,
+                   v_cache)
+    return out.reshape(s, h, 1, d)
+
+
 def _append_kernel(pos_ref, layer_ref, kc_ref, vc_ref, kn_ref, vn_ref,
                    ko_ref, vo_ref):
     # one slot a grid step: tiles [H, D, 128], new columns [D, H]
@@ -845,6 +1023,27 @@ def flash_attention(q, k, v, causal=False, sm_scale=None,
     64/128/256.  Returns the same shape/dtype as q.
     """
     return _flash(q, k, v, *_resolve(q, sm_scale, causal, block_q, block_k))
+
+
+def flash_attention_fwd(q, k, v, sm_scale=None, window=None, block_q=None):
+    """Causal forward pass only (a served model's prefill), for what the
+    training entry points above do not take: k and v [batch, kv_heads,
+    seq, head_dim] may hold fewer heads than q [batch, heads, seq,
+    head_dim] (grouped-query attention: query head h reads head h //
+    (heads / kv_heads), through the index map, K and V never repeated),
+    and with `window` a query sees the `window` keys up to and with its
+    own, the tiles wholly outside that band not visited
+    (`flash_tiling(..., window=)`)."""
+    if q.shape[1] % k.shape[1] or k.shape != v.shape:
+        raise ValueError(f"{q.shape[1]} query heads over K {k.shape}, "
+                         f"V {v.shape}")
+    seq = q.shape[-2]
+    if window is not None and window >= seq:
+        window = None
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    tiling = flash_tiling(seq, q.shape[-1], True, block_q, None, window)
+    return _fwd(q, k, v, float(sm_scale), True, tiling, window)[0]
 
 
 def flash_attention_with_lse(q, k, v, causal=False, sm_scale=None,

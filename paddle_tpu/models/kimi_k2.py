@@ -47,6 +47,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .blocks import rms_norm, swiglu
+
 __all__ = ["K2Cfg", "K2Params", "param_shapes", "init_params",
            "yarn_inv_freq", "full_logits"]
 
@@ -250,24 +252,10 @@ def _rope(cfg, x, pos):
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
 
 
-def _rms(cfg, x, gain):
-    x32 = x.astype(jnp.float32)
-    x32 = x32 * jax.lax.rsqrt(
-        jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-        + cfg.rms_norm_eps)
-    return (x32 * gain.astype(jnp.float32)).astype(x.dtype)
-
-
-def _swiglu(h, gate_up, down):
-    gu = jnp.dot(h, gate_up, preferred_element_type=jnp.float32)
-    f = gu.shape[-1] // 2
-    return (jax.nn.silu(gu[..., :f]) * gu[..., f:]).astype(h.dtype) @ down
-
-
 def _ffn(cfg, lp, h, counts, valid=None):
     """The layer's FFN of tokens h [N, H]: dense, or routed + shared."""
     if "router" not in lp:
-        return _swiglu(h, lp["gate_up"], lp["down"]), counts
+        return swiglu(h, lp["gate_up"], lp["down"]), counts
     from ..distributed.moe import routed_experts
 
     routed, c = routed_experts(
@@ -275,7 +263,7 @@ def _ffn(cfg, lp, h, counts, valid=None):
         (lp["experts_gate_up"], lp["experts_down"]), cfg.first_expert,
         cfg.n_routed, cfg.num_experts_per_tok, cfg.routed_scaling_factor,
         valid=valid)
-    return routed + _swiglu(h, lp["shared_gate_up"], lp["shared_down"]), \
+    return routed + swiglu(h, lp["shared_gate_up"], lp["shared_down"]), \
         counts + c
 
 
@@ -283,12 +271,12 @@ def _queries_and_latent(cfg, lp, h, pos):
     """h [N, H] at positions pos [N] -> (q_nope [N, heads, nope], q_rope
     [N, heads, rope], latent [N, rank + rope]), rotated and normalised."""
     n = h.shape[0]
-    cq = _rms(cfg, h @ lp["q_a"], lp["q_a_norm"])
+    cq = rms_norm(cfg, h @ lp["q_a"], lp["q_a_norm"])
     q = (cq @ lp["q_b"]).reshape(n, cfg.num_heads, -1)
     q_nope = q[..., :cfg.qk_nope_head_dim]
     q_rope = _rope(cfg, q[..., cfg.qk_nope_head_dim:], pos[:, None])
     kv = h @ lp["kv_a"]
-    c_kv = _rms(cfg, kv[:, :cfg.kv_lora_rank], lp["kv_a_norm"])
+    c_kv = rms_norm(cfg, kv[:, :cfg.kv_lora_rank], lp["kv_a_norm"])
     k_rope = _rope(cfg, kv[:, cfg.kv_lora_rank:], pos)
     return q_nope, q_rope.astype(h.dtype), jnp.concatenate(
         [c_kv, k_rope.astype(h.dtype)], axis=-1)
@@ -309,7 +297,7 @@ def _decode(cfg, trees, cache, token, pos):
     x = jnp.take(trees["embed"], token, axis=0)
     counts = jnp.zeros(cfg.experts_held, jnp.int32)
     for l, lp in enumerate(trees["layers"]):
-        h = _rms(cfg, x, lp["attn_norm"])
+        h = rms_norm(cfg, x, lp["attn_norm"])
         q_nope, q_rope, new = _queries_and_latent(cfg, lp, h, pos)
         w_uk, w_uv = _kv_b(cfg, lp)
         q_latent = jnp.einsum("shd,chd->shc", q_nope, w_uk)
@@ -317,9 +305,9 @@ def _decode(cfg, trees, cache, token, pos):
             q_latent, q_rope, new, latent, l, pos, cfg.softmax_scale)
         o = jnp.einsum("shc,chd->shd", o, w_uv)
         x = x + o.reshape(o.shape[0], -1) @ lp["o"]
-        y, counts = _ffn(cfg, lp, _rms(cfg, x, lp["ffn_norm"]), counts)
+        y, counts = _ffn(cfg, lp, rms_norm(cfg, x, lp["ffn_norm"]), counts)
         x = x + y
-    return {"latent": latent}, _rms(cfg, x, trees["final_norm"]), \
+    return {"latent": latent}, rms_norm(cfg, x, trees["final_norm"]), \
         {"expert_counts": counts}
 
 
@@ -354,7 +342,7 @@ def _forward(cfg, trees, ids, valid):
     counts = jnp.zeros(cfg.experts_held, jnp.int32)
     latents = []
     for lp in trees["layers"]:
-        h = _rms(cfg, x, lp["attn_norm"])
+        h = rms_norm(cfg, x, lp["attn_norm"])
         q_nope, q_rope, new = _queries_and_latent(cfg, lp, h, pos)
         latents.append(new)
         w_uk, w_uv = _kv_b(cfg, lp)
@@ -368,7 +356,7 @@ def _forward(cfg, trees, ids, valid):
         o = _causal_attention(q[None], k[None], v[None],
                               cfg.softmax_scale)[0]            # [H, N, v]
         x = x + o.swapaxes(0, 1).reshape(n, -1) @ lp["o"]
-        y, counts = _ffn(cfg, lp, _rms(cfg, x, lp["ffn_norm"]), counts,
+        y, counts = _ffn(cfg, lp, rms_norm(cfg, x, lp["ffn_norm"]), counts,
                          valid=valid)
         x = x + y
     return x, latents, {"expert_counts": counts}
@@ -379,7 +367,7 @@ def full_logits(cfg, trees, ids):
     published form, no cache (what the engine's tokens are held against
     where no float32 reference fits: chip_smoke.py)."""
     x, _, _ = _forward(cfg, trees, ids, None)
-    return cfg.head(trees, _rms(cfg, x, trees["final_norm"]))
+    return cfg.head(trees, rms_norm(cfg, x, trees["final_norm"]))
 
 
 def _prefill(cfg, trees, cache, prompt, true_len, slot):
@@ -400,4 +388,4 @@ def _prefill(cfg, trees, cache, prompt, true_len, slot):
         cache["latent"], block.astype(cache["latent"].dtype),
         (0, slot, 0, 0))
     h = jax.lax.dynamic_slice(x, (true_len - 1, 0), (1, cfg.hidden_size))
-    return {"latent": latent}, _rms(cfg, h, trees["final_norm"]), counters
+    return {"latent": latent}, rms_norm(cfg, h, trees["final_norm"]), counters

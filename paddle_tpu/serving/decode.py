@@ -23,13 +23,21 @@ Everything after the hidden state (head, greedy or sampled token,
 slots, budgets, spans, stats, hardening) is the engine's and shared.
 `models/generate.py`'s `DecCfg` (the GPT family; a `models/gpt.py` GPT
 is accepted as it is) and `models/kimi_k2.py`'s `K2Cfg` implement it.
-`counters` is a dict of small arrays; `expert_counts` (assignments on
-each expert held here, summed over the expert layers), where a model
-gives it, is one more result of the program after the parent's (a model
-without it answers with exactly what the engine always fetched) and
-becomes the `expert_tokens` / `expert_load_max` attributes of
-`engine.*_wait` and `DecodeStats`' running totals.  What follows
-describes the engine with the GPT family's cache:
+`counters` is a dict of small arrays, the last result of each program
+and read by key (a model that counts nothing gives an empty one, and its
+program answers with exactly what the engine always fetched):
+`expert_counts` (assignments on each expert held here, summed over the
+expert layers) becomes the `expert_tokens` / `expert_load_max`
+attributes of `engine.*_wait` and `DecodeStats`' running totals;
+`cache_reads`, of a model whose cache arrays differ in depth
+(`models/afmoe.py`: full layers beside window rings), is {name: the
+cached positions the program read in one layer of that kind}, for each
+slot in a decode step and for the prompt in a prefill, and becomes
+attributes of those names on `engine.*_wait` (a decode step's summed
+over the slots that were active in it).  `cache_arrays` are all
+`[layers, slots, ..., depth]`, and `DecodeStats.summary()["decode"]
+["cache"]["arrays"]` lists each by name.  What follows describes the
+engine with the GPT family's cache:
 
 - ONE compiled decode step owns the whole serving state: a fixed
   ring-buffer KV cache plus per-slot `pos/active/token/stop/eos/temp/
@@ -400,28 +408,31 @@ def _prefill_impl(state, trees, prompt, true_len, slot, stop, eos,
     return out, first, active, counters
 
 
-def _with_counts(impl, cfg):
-    """`impl` as a program: its results as they are, and after them the
-    model's `expert_counts` where it gives them."""
-    def fn(*args):
-        *results, counters = impl(*args, cfg=cfg)
-        if "expert_counts" in counters:
-            results.append(counters["expert_counts"])
-        return tuple(results)
+def _on_host(results):
+    """A program's results, counters and all, fetched."""
+    import jax
 
-    return fn
+    return jax.tree.map(np.asarray, results)
 
 
-def _expert_load(counts):
-    """The span attributes of a program's expert counters (`counts`: what
-    the program answered after the engine's own results): assignments
+def _expert_load(counters):
+    """The span attributes of a program's `expert_counts`: assignments
     that fell on the experts held here, and the fullest one's.  A model
     without experts gives none."""
-    if not counts:
+    counts = counters.get("expert_counts")
+    if counts is None:
         return {}
-    counts = np.asarray(counts[0])
     return {"expert_tokens": int(counts.sum()),
             "expert_load_max": int(counts.max())}
+
+
+def _cache_reads(counters, active=None):
+    """The span attributes of a program's `cache_reads`: under each name
+    the cached positions read, of a prefill's prompt, or summed over the
+    slots `active` in a decode step.  A model with one cache gives
+    none."""
+    return {name: int(v.sum(where=active) if active is not None else v)
+            for name, v in counters.get("cache_reads", {}).items()}
 
 
 # ---------------------------------------------------------------------------
@@ -503,7 +514,9 @@ class DecodeEngine:
         # `jit_decode_step` and `jit_prefill_b<bucket>` (a jitted
         # functools.partial reads `jit__unknown`)
         def named(name, impl):
-            fn = _with_counts(impl, dec_cfg)
+            def fn(*args):
+                return impl(*args, cfg=dec_cfg)
+
             fn.__name__ = fn.__qualname__ = name
             return jax.jit(fn, donate_argnums=(0,))
 
@@ -530,9 +543,7 @@ class DecodeEngine:
             raise ValueError(
                 f"the model's cache arrays {taken} carry names the engine "
                 f"keeps for its own per-slot vectors {_SLOT_KEYS}")
-        self.stats.note_cache(
-            self.params.cfg.cache_kind,
-            sum(a.size * a.dtype.itemsize for a in cache.values()))
+        self.stats.note_cache(self.params.cfg.cache_kind, cache)
         return {
             **cache,
             "pos": jnp.zeros(cfg.slots, jnp.int32),
@@ -798,7 +809,7 @@ class DecodeEngine:
                 flight.state, *results = out
                 flight.launched_t = cfg.clock()
                 flight.launched.set()
-                flight.results = [np.asarray(r) for r in results]
+                flight.results = _on_host(results)
                 flight.done_t = cfg.clock()
             except BaseException as e:  # noqa: BLE001
                 flight.error = e
@@ -923,10 +934,12 @@ class DecodeEngine:
         `turnaround_s` (admission to the first token on the host) and
         `late`; `engine.decode_wait` carries `active` and `ahead`; where
         the model counts expert assignments, both gain `expert_tokens`
-        and `expert_load_max`.  What is known only once the answer is
-        in (`turnaround_s`, the experts' counts) is in `spans()`; the
-        trace's copy of the span was opened before (its `turnaround_s`
-        runs to the launch's return)."""
+        and `expert_load_max`, and where its caches differ in depth, the
+        cached positions read in one layer of each (`live_full` and
+        `live_window` of `models/afmoe.py`).  What is known only once
+        the answer is in (`turnaround_s`, the model's counters) is in
+        `spans()`; the trace's copy of the span was opened before (its
+        `turnaround_s` runs to the launch's return)."""
         with self._lock:
             if not self._has_work_locked():
                 return 0                   # nothing to do: no span
@@ -1171,9 +1184,10 @@ class DecodeEngine:
             now = self._answer(flight)
             if now is None:
                 return False
-            first, active, *counts = flight.results
-            load = _expert_load(counts)
-            span.attrs.update(load, turnaround_s=now - flight.admit_t)
+            first, active, counters = flight.results
+            load = _expert_load(counters)
+            span.attrs.update(load, **_cache_reads(counters),
+                              turnaround_s=now - flight.admit_t)
         self.stats.note_experts(**load)
         with RecordEvent("engine.prefill_book"):
             self._prefill_book(slot, req, int(first), bool(active),
@@ -1212,9 +1226,9 @@ class DecodeEngine:
             now = self._answer(flight)
             if now is None:
                 return False
-            tokens, was_active, still, *counts = flight.results
-            load = _expert_load(counts)
-            span.attrs.update(load)
+            tokens, was_active, still, counters = flight.results
+            load = _expert_load(counters)
+            span.attrs.update(load, **_cache_reads(counters, was_active))
         self.stats.note_experts(**load)
         with RecordEvent("engine.emit"):
             self._emit(flight.slot_reqs, tokens, was_active, still, now)

@@ -277,7 +277,7 @@ class DecodeStats(ServingStats):
         self.tok_lat_dropped = 0
         self._first_t = None           # first/last token wall-clock
         self._last_t = None            # (engine clock) for tokens/s
-        self.cache = None              # {"kind", "bytes"}: note_cache
+        self.cache = None              # {"kind", "bytes", "arrays"}
         # programs that routed over experts held here, their assignments
         # on those experts, and the fullest single expert's count of
         # one program
@@ -330,9 +330,14 @@ class DecodeStats(ServingStats):
             if self.slots:
                 mon.gauge("serving.decode_active_slots").set(active)
 
-    def note_cache(self, kind, nbytes):
-        """What the engine's cache is and holds, as its model says."""
-        self.cache = {"kind": kind, "bytes": int(nbytes)}
+    def note_cache(self, kind, arrays):
+        """What the engine's cache is and holds, as its model says:
+        `arrays` {name: array [layers, slots, ..., depth]}."""
+        each = [{"name": n, "layers": a.shape[0], "depth": a.shape[-1],
+                 "bytes": int(a.size * a.dtype.itemsize)}
+                for n, a in arrays.items()]
+        self.cache = {"kind": kind, "bytes": sum(a["bytes"] for a in each),
+                      "arrays": each}
 
     def note_experts(self, expert_tokens=None, expert_load_max=0):
         """One program's (prefill or decode step) assignments on the

@@ -11,6 +11,7 @@ the host."""
 import threading
 import time
 
+import jax
 import numpy as np
 
 
@@ -67,7 +68,8 @@ def hold_answers(fn, gate):
                     gate.wait()
                     waited.append(True)
 
-        return (state, *[_Held(r, turn) for r in results])
+        # leaf by leaf: the last result is the model's dict of counters
+        return (state, *jax.tree.map(lambda r: _Held(r, turn), results))
 
     return program
 

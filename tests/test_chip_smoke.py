@@ -88,6 +88,26 @@ def test_k2_phase_at_the_rehearsal_size():
     assert r["experts"]["tokens_total"] > 0
 
 
+def test_afmoe_phase_at_the_rehearsal_size():
+    import json
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "trinity-mini.json")) as f:
+        hf = json.load(f)
+    # the rehearsal's toy widths, but heads and a window the kernels tile
+    hf.update(hf["rehearse"])
+    hf.update(dtype="float32", head_dim=64, sliding_window=128)
+    r = chip_smoke.phase_afmoe(
+        hf, slots=2, max_len=256, buckets=(64, 256),
+        prompt_lens=[5, 120, 200, 17], new_tokens=12,
+        decode_lengths=[1, 40, 128, 129, 256], prefill_seq=256, tol=1e-4,
+        gap_tol=1e-4)
+    assert r["requests"] == 4
+    assert r["tokens_equal_to_forward"] == "48/48"
+    assert [a["depth"] for a in r["cache"]["arrays"]] == [256, 256, 128, 128]
+    assert r["experts"]["tokens_total"] > 0
+
+
 def test_four_chips_phase_on_the_cpu_mesh():
     """The CPU-mesh twin of the four-chip phase: shards on four distinct
     devices, half a tensor-parallel leaf on each, first-step losses

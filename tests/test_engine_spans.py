@@ -156,6 +156,25 @@ def test_every_iteration_holds_its_phases_in_order(model, traced):
     waits = [a for n, _, _, a in spans if n == "engine.decode_wait"]
     assert len(waits) == steps_run[0]
     assert all(1 <= a["active"] <= 3 for a in waits)
+    # a model with one cache and no experts: the spans carry what they
+    # always carried, and nothing of a second cache's
+    assert all(set(a) == {"active", "ahead"} for a in waits)
+    fills = [a for n, _, _, a in spans if n == "engine.prefill_wait"]
+    assert fills and all(set(a) == {
+        "bucket", "slot", "rid", "queue_wait_s", "turnaround_s", "late"}
+        for a in fills)
+
+
+def test_the_summary_lists_the_gpt_cache_under_its_own_names(model):
+    eng = _engine(model)
+    cache = eng.summary()["decode"]["cache"]
+    eng.close()
+    one = 2 * 3 * 4 * 12 * 32 * 4       # layers, slots, heads, d, depth
+    assert cache == {
+        "kind": "kv [layers, slots, heads, head_dim, max_len]",
+        "bytes": 2 * one,
+        "arrays": [{"name": "k", "layers": 2, "depth": 32, "bytes": one},
+                   {"name": "v", "layers": 2, "depth": 32, "bytes": one}]}
 
 
 def test_first_token_split_adds_up_to_ttft(model, traced):
@@ -442,8 +461,10 @@ def _named(name):
 
 def _kernel_jaxprs():
     from paddle_tpu.kernels.flash_attention import (flash_attention,
+                                                    flash_attention_fwd,
                                                     flash_decode,
                                                     flash_decode_resident,
+                                                    gqa_decode_resident,
                                                     kv_append)
     from paddle_tpu.kernels.layer_norm import layer_norm_pallas
     from paddle_tpu.kernels.topk_threshold import dgc_topk_mask_pallas
@@ -473,6 +494,15 @@ def _kernel_jaxprs():
         "engine": str(jax.make_jaxpr(engine_layer)(
             jax.ShapeDtypeStruct((2, 2, 1, 64), f32), cache, cache, new,
             new, layer, per_slot)),
+        # grouped queries over the same cache, and a windowed prefill
+        "grouped": str(jax.make_jaxpr(gqa_decode_resident)(
+            jax.ShapeDtypeStruct((2, 8, 1, 64), f32), cache, cache, layer,
+            per_slot)),
+        "windowed": str(jax.make_jaxpr(
+            lambda q, k, v: flash_attention_fwd(q, k, v, window=128))(
+            jax.ShapeDtypeStruct((1, 4, 256, 64), f32),
+            jax.ShapeDtypeStruct((1, 2, 256, 64), f32),
+            jax.ShapeDtypeStruct((1, 2, 256, 64), f32))),
         "flash": str(jax.make_jaxpr(jax.grad(total(causal), (0, 1, 2)))(
             q, q, q)),
         "decode": str(jax.make_jaxpr(flash_decode)(
@@ -496,7 +526,8 @@ def kernel_jaxprs():
 @pytest.mark.parametrize("where,kernel", [
     ("flash", "flash_fwd"), ("flash", "flash_dq"), ("flash", "flash_dkv"),
     ("decode", "flash_decode"), ("engine", "kv_append"),
-    ("engine", "flash_decode"), ("layer_norm", "layer_norm_fwd"),
+    ("engine", "flash_decode"), ("grouped", "gqa_decode"),
+    ("windowed", "flash_fwd"), ("layer_norm", "layer_norm_fwd"),
     ("layer_norm", "layer_norm_bwd"), ("topk", "topk_threshold")])
 def test_every_pallas_call_has_its_name(kernel_jaxprs, where, kernel):
     assert f"name={kernel}\n" in kernel_jaxprs[where] \

@@ -482,3 +482,171 @@ def test_k2_program_holds_one_copy_of_the_latent_cache(k2_programs,
 def test_k2_decode_step_names_its_calls(k2_programs):
     calls = set(_mosaic_calls(k2_programs["decode_step"].as_text()))
     assert calls == {"latent_append", "mla_decode", "moe_grouped_mm"}
+
+
+# ---------------------------------------------------------------------
+# AFMoE (Trinity) behind the same engine: grouped heads, a window, and
+# two caches of different depth, each held once
+# ---------------------------------------------------------------------
+
+AF_SLOTS, AF_DEPTH, AF_WINDOW = 16, 4096, 2048
+AF_KVH, AF_HEADS, AF_DIM = 4, 32, 128
+AF_TYPES = ["sliding_attention"] * 3 + ["full_attention"]
+AF_RING_ELEMS = AF_SLOTS * AF_KVH * AF_DIM * AF_WINDOW     # one ring layer
+AF_CACHE_BYTES = 2 * (3 * AF_RING_ELEMS
+                      + AF_SLOTS * AF_KVH * AF_DIM * AF_DEPTH) * 2
+
+
+def _afmoe_cases():
+    from paddle_tpu.kernels.flash_attention import (flash_attention_fwd,
+                                                    gqa_decode_resident)
+
+    # the cell's caches (BENCHMARK.json, trinity-mini): 64 slots, 3 full
+    # layers 9,728 deep, 9 rings of 2,048
+    scalar, per_slot = ((), jnp.int32), ((64,), jnp.int32)
+    q = ((64, AF_HEADS, 1, AF_DIM), BF16)
+    new = ((64, AF_KVH, AF_DIM), BF16)
+    out = {}
+    for name, layers, depth in (("full", 3, 9728), ("ring", 9, 2048)):
+        cache = ((layers, 64, AF_KVH, AF_DIM, depth), BF16)
+        out[f"gqa_decode_{name}"] = (
+            gqa_decode_resident, [q, cache, cache, scalar, per_slot],
+            ["gqa_decode"])
+        out[f"kv_append_{name}"] = (
+            lambda *a: kv_append(*a)[0],
+            [cache, cache, new, new, scalar, per_slot], ["kv_append"])
+    for seq in (2048, 8192):
+        for window in (None, AF_WINDOW):
+            out[f"flash_fwd_{seq}_{window}"] = (
+                lambda q, k, v, window=window: flash_attention_fwd(
+                    q, k, v, window=window),
+                [((1, AF_HEADS, seq, AF_DIM), BF16)]
+                + [((1, AF_KVH, seq, AF_DIM), BF16)] * 2, ["flash_fwd"])
+    return out
+
+
+@pytest.mark.parametrize("name", [
+    "gqa_decode_full", "gqa_decode_ring", "kv_append_full",
+    "kv_append_ring", "flash_fwd_2048_None", "flash_fwd_2048_2048",
+    "flash_fwd_8192_None", "flash_fwd_8192_2048"])
+def test_afmoe_kernel_compiles_for_v5e(compiled_kernels, v5e, name):
+    fn, args, calls = _afmoe_cases()[name]
+    one = jax.sharding.SingleDeviceSharding(v5e[0])
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in args]
+    text = jax.jit(fn).trace(*avals).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert sorted(set(_mosaic_calls(text))) == calls
+
+
+@pytest.fixture(scope="module")
+def afmoe_programs(v5e):
+    """(decode step, prefill at bucket 4096) of `DecodeEngine` over
+    `models/afmoe.py` at the published widths, one period of the layer
+    pattern (three window layers and a full one; the first dense),
+    compiled for the described v5e with the state donated, as the
+    engine jits them."""
+    import functools
+    import json
+    import os
+
+    from paddle_tpu.models import afmoe
+    from paddle_tpu.serving import decode as D
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "trinity-mini.json")) as f:
+        cfg = dict(json.load(f), num_hidden_layers=4, layer_types=AF_TYPES)
+    acfg = afmoe.AfmoeCfg.from_hf(cfg, max_seq_len=AF_DEPTH)
+    one = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    trees = afmoe.AfmoeParams.from_flat(acfg, {
+        n: aval(s, F32 if kind == "bias" else BF16)
+        for n, (s, kind) in afmoe.param_shapes(acfg).items()}).trees
+    i32 = jnp.int32
+    cache = {n: aval(a.shape, a.dtype) for n, a in jax.eval_shape(
+        lambda: acfg.cache_arrays(AF_SLOTS, AF_DEPTH)).items()}
+    assert {n: a.shape for n, a in cache.items()} == {
+        "k_full": (1, AF_SLOTS, AF_KVH, AF_DIM, AF_DEPTH),
+        "v_full": (1, AF_SLOTS, AF_KVH, AF_DIM, AF_DEPTH),
+        "k_window": (3, AF_SLOTS, AF_KVH, AF_DIM, AF_WINDOW),
+        "v_window": (3, AF_SLOTS, AF_KVH, AF_DIM, AF_WINDOW)}
+    state = dict(cache, pos=aval((AF_SLOTS,), i32),
+                 active=aval((AF_SLOTS,), bool),
+                 token=aval((AF_SLOTS,), i32), stop=aval((AF_SLOTS,), i32),
+                 eos=aval((AF_SLOTS,), i32), temp=aval((AF_SLOTS,), F32),
+                 key=aval((AF_SLOTS, 2), jnp.uint32))
+
+    def compiled(impl, *args):
+        return jax.jit(functools.partial(impl, cfg=acfg),
+                       donate_argnums=(0,)).trace(
+            state, trees, *args).lower(
+            lowering_platforms=("tpu",)).compile()
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(backend, "is_tpu_backend", lambda: True)
+        return {
+            "decode_step": compiled(D._decode_step_impl,
+                                    aval((AF_SLOTS,), bool)),
+            "prefill_b4096": compiled(
+                D._prefill_impl, aval((1, 4096), i32), aval((), i32),
+                aval((), i32), aval((), i32), aval((), i32),
+                aval((), F32), aval((2,), jnp.uint32)),
+        }
+
+
+_AF_CACHE = re.compile(rf"\[[13],{AF_SLOTS},{AF_KVH},{AF_DIM},"
+                       rf"(?:{AF_DEPTH}|{AF_WINDOW})\]")
+
+
+@pytest.mark.parametrize("program,in_place", [
+    ("decode_step", set()),
+    ("prefill_b4096", {"dynamic-update-slice", "fusion"})])
+def test_afmoe_program_moves_no_layer_of_either_cache(afmoe_programs,
+                                                      program, in_place):
+    text = afmoe_programs[program].as_text()
+    large = [(op, line) for op, line in _large_results(text, AF_RING_ELEMS)
+             # the prefill's activations over 4,096 tokens are as large
+             # as a ring's layer: only results with a cache's own
+             # dimensions are judged
+             if _AF_CACHE.search(line.split(" = ", 1)[1].split("(")[0])]
+    assert large, "the caches are not in the program at all"
+    odd = [(op, line) for op, line in large
+           if op not in PASSES_ALONG | in_place]
+    assert not odd, odd
+    for op, line in large:
+        if op == "fusion":
+            assert "dynamic-update-slice_fusion" in line, line
+    # nothing holds one layer of a cache (or one ring sliced out of the
+    # three), and K and V are nowhere repeated over the 32 query heads
+    depths = (AF_DEPTH, AF_WINDOW)
+    for dims in (
+            [f"[{AF_SLOTS},{AF_KVH},{AF_DIM},{d}]" for d in depths]
+            + [f"[1,{AF_SLOTS},{AF_KVH},{AF_DIM},{AF_WINDOW}]"]
+            + [f"{AF_SLOTS},{AF_HEADS},{AF_DIM},{d}]" for d in depths]
+            + [f"{AF_SLOTS},{AF_KVH},8,{AF_DIM},{d}]" for d in depths]):
+        assert dims not in text, dims
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_b4096"])
+def test_afmoe_program_holds_one_copy_of_each_cache(afmoe_programs,
+                                                    program):
+    mem = afmoe_programs[program].memory_analysis()
+    assert mem.alias_size_in_bytes >= AF_CACHE_BYTES
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 1 << 20
+    # temporaries: activations of 16 tokens (4,096 in the prefill, with
+    # the grouped product's 32,768 rows) at these widths, never a
+    # second cache
+    limit = 0.05 if program == "decode_step" else 2.0
+    assert mem.temp_size_in_bytes < limit * AF_CACHE_BYTES
+
+
+def test_afmoe_decode_step_names_its_calls(afmoe_programs):
+    calls = _mosaic_calls(afmoe_programs["decode_step"].as_text())
+    assert sorted(set(calls)) == ["gqa_decode", "kv_append",
+                                  "moe_grouped_mm"]
+    assert calls.count("gqa_decode") == calls.count("kv_append") == 4
+    fills = _mosaic_calls(afmoe_programs["prefill_b4096"].as_text())
+    assert fills.count("flash_fwd") == 4

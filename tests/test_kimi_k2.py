@@ -181,7 +181,9 @@ def test_engine_serves_what_the_reference_computes(toy):
         assert toy.ref.token_gaps(p, served).max() <= TOL
     assert summary["decode"]["prefill_steps"] == 5
     assert summary["decode"]["cache"] == {
-        "kind": toy.kcfg.cache_kind, "bytes": 3 * 2 * 40 * 64 * 4}
+        "kind": toy.kcfg.cache_kind, "bytes": 3 * 2 * 40 * 64 * 4,
+        "arrays": [{"name": "latent", "layers": 3, "depth": 64,
+                    "bytes": 3 * 2 * 40 * 64 * 4}]}
 
 
 def test_loop_thread_one_step_ahead_serves_what_the_reference_computes(
@@ -402,7 +404,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
             (w["gate_up"][s:s + 4], w["down"][s:s + 4]), s, 16,
             cfg["num_experts_per_tok"], cfg["routed_scaling_factor"])
             for s in (0, 4, 8, 12)])
-        shared = kimi_k2._swiglu(h, shared_gu, shared_down)
+        shared = kimi_k2.swiglu(h, shared_gu, shared_down)
     np.testing.assert_allclose(np.asarray(sum(parts) + shared),
                                np.asarray(whole), atol=1e-5)
     # every assignment fell on exactly one share
