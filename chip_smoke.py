@@ -468,10 +468,10 @@ def phase_kernels(attn, decode, decode_lengths, ln, topk_n, dtype, tol):
 def phase_k2(hf, slots, max_len, buckets, prompt_lens, new_tokens,
              decode_lengths, tol, gap_tol):
     """`hf`: the model's sizes under its config.json keys.  (1)
-    `latent_append` + `mla_decode` at the model's widths against their
-    XLA mathematics, at ragged lengths, and the routed experts' grouped
-    product (`moe_grouped_mm`) against each group's rows times its
-    expert, in numpy; (2) the engine's first `new_tokens` tokens of each
+    `mla_decode` at the model's widths (the column it writes and what
+    it attends to) against its XLA mathematics, at ragged lengths, and
+    the routed experts' grouped product (`moe_grouped_mm`) against each
+    group's rows times its expert, in numpy; (2) the engine's first `new_tokens` tokens of each
     prompt against the unbatched forward pass (`kimi_k2.full_logits`
     over prompt and served tokens, the published form of attention, no
     cache): the widest gap by which a served token's logit lies under
@@ -501,7 +501,7 @@ def phase_k2(hf, slots, max_len, buckets, prompt_lens, new_tokens,
         outs[use_kernel] = (_highest(fn) if not use_kernel
                             else jax.jit(fn))(*args)
     _check(bool((outs[True][1] == outs[False][1]).all()),
-           "latent_append differs from the XLA write")
+           "mla_decode's written column differs from the XLA write")
     err, rel = _err(outs[True][0], outs[False][0])
     _check(rel <= tol, f"mla_decode: error {err:.3g} is {rel:.3g} of the "
                        f"reference's max, over {tol}")
@@ -548,8 +548,8 @@ def phase_k2(hf, slots, max_len, buckets, prompt_lens, new_tokens,
                               f"the forward pass's best, over {gap_tol}")
     dec = summary["decode"]
     _check(dec["experts"]["tokens_total"] > 0, f"no expert counts: {dec}")
-    return {"mla_decode": {"max_abs_err": err, "rel_to_max": rel},
-            "latent_append": "exact",
+    return {"mla_decode": {"max_abs_err": err, "rel_to_max": rel,
+                           "written_column": "exact"},
             "moe_grouped_mm": {"max_abs_err": gerr, "rel_to_max": grel},
             "requests": len(prompts),
             "widest_logit_gap": widest,

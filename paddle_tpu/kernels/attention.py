@@ -255,19 +255,17 @@ def resident_mla_attention(q_latent, q_rope, new, latent, layer, pos,
 
     Under the same predicate as `resident_decode_attention`
     (`_decode_takes_kernel`, on the rotary width, and a rank of whole
-    lanes; `use_kernel` as its `use_flash`) these are the Pallas calls
-    `latent_append` and `mla_decode` (kernels/mla.py); elsewhere the
-    same mathematics in XLA."""
+    lanes; `use_kernel` as its `use_flash`) both are the one Pallas call
+    `mla_decode` (kernels/mla.py), which writes the column into the tile
+    it reads; elsewhere the same mathematics in XLA."""
     rank, t = q_latent.shape[-1], latent.shape[-1]
     pos = jnp.asarray(pos, jnp.int32)
     posw = jnp.minimum(pos, t - 1)
     if rank % 128 == 0 and _decode_takes_kernel(t, q_rope.shape[-1],
                                                 use_kernel):
-        from .mla import latent_append, mla_decode
+        from .mla import mla_decode
 
-        latent = latent_append(latent, new, layer, posw)
-        return mla_decode(q_latent, q_rope, latent, layer, posw + 1,
-                          scale), latent
+        return mla_decode(q_latent, q_rope, new, latent, layer, posw, scale)
     latent = latent.at[layer, jnp.arange(pos.shape[0]), :, posw].set(
         new.astype(latent.dtype))
     c = latent[layer].astype(q_latent.dtype)              # [S, R+r, T]

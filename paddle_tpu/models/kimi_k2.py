@@ -22,8 +22,8 @@ head from the latent (the published form) and writes the latent, not K
 and V, into its slot.  A decode step never builds K or V: with
 `W_kvb^h = [W_uk^h ; W_uv^h]`, `q~_h = q_nope_h W_uk^h^T` gives scores
 `q~_h . c_kv + q_rope_h . k_rope`, and `o_h = (P_h c_kv) W_uv^h`
-(`kernels/attention.py` `resident_mla_attention`: the Pallas calls
-`latent_append` and `mla_decode` on the TPU).
+(`kernels/attention.py` `resident_mla_attention`: the Pallas call
+`mla_decode` on the TPU, which writes the step's column and attends).
 
 The cache is one resident array `[layers, slots, kv_lora_rank +
 qk_rope_head_dim, max_len]`, depth minor (the 576 rows are whole
@@ -120,6 +120,21 @@ class K2Cfg(NamedTuple):
         return {"latent": jnp.zeros(
             (self.num_layers, slots, self.latent_width, max_len),
             self.dtype)}
+
+    def cache_walk(self, lengths, slots, max_len):
+        """What `mla_decode` walks in one layer of a decode step whose
+        active slots hold `lengths` cached positions, the step's own
+        among them: `latent_tiles`, beside the `latent_grid` of tiles
+        that a rectangle over every slot's whole depth holds.  Nothing
+        where the kernel does not tile the latent (`kernels/attention.py`
+        `resident_mla_attention`)."""
+        from ..kernels.mla import mla_tiling, tiles_walked
+
+        if max_len % 128 or self.kv_lora_rank % 128:
+            return {}
+        tile = mla_tiling(self.latent_width, max_len).tile
+        return {"latent_tiles": tiles_walked(lengths, tile),
+                "latent_grid": slots * (max_len // tile)}
 
     def prefill(self, trees, cache, prompt, true_len, slot):
         return _prefill(self, trees, cache, prompt, true_len, slot)

@@ -34,7 +34,13 @@ attributes of `engine.*_wait` and `DecodeStats`' running totals;
 cached positions the program read in one layer of that kind}, for each
 slot in a decode step and for the prompt in a prefill, and becomes
 attributes of those names on `engine.*_wait` (a decode step's summed
-over the slots that were active in it).  `cache_arrays` are all
+over the slots that were active in it).  A model whose decode kernel
+walks its cache in tiles may give `cache_walk(lengths, slots, max_len)`
+-> {name: count} of one layer of one decode step (`models/kimi_k2.py`:
+`latent_tiles` walked of the `latent_grid` a rectangle over every slot
+would hold); the engine calls it on the host with the lengths its
+active slots had, puts the counts on `engine.decode_wait` and their
+totals under `summary()["decode"]["cache"]`.  `cache_arrays` are all
 `[layers, slots, ..., depth]`, and `DecodeStats.summary()["decode"]
 ["cache"]["arrays"]` lists each by name.  What follows describes the
 engine with the GPT family's cache:
@@ -424,6 +430,19 @@ def _expert_load(counters):
         return {}
     return {"expert_tokens": int(counts.sum()),
             "expert_load_max": int(counts.max())}
+
+
+def _cache_walk(cfg, config, slot_reqs, active):
+    """The span attributes of what a decode step's attention walked, for
+    a model that says so (`cfg.cache_walk`, of the lengths the step's
+    active slots had: a request's prompt and the tokens it held when the
+    step was answered).  From what the host holds: nothing is fetched."""
+    walk = getattr(cfg, "cache_walk", None)
+    if walk is None:
+        return {}
+    return walk([r.prompt.size + len(r.tokens)
+                 for r, live in zip(slot_reqs, active)
+                 if live and r is not None], config.slots, config.max_len)
 
 
 def _cache_reads(counters, active=None):
@@ -936,7 +955,9 @@ class DecodeEngine:
         the model counts expert assignments, both gain `expert_tokens`
         and `expert_load_max`, and where its caches differ in depth, the
         cached positions read in one layer of each (`live_full` and
-        `live_window` of `models/afmoe.py`).  What is known only once
+        `live_window` of `models/afmoe.py`); a decode step also what the
+        model's `cache_walk` counts (`latent_tiles` and `latent_grid`
+        of `models/kimi_k2.py`).  What is known only once
         the answer is in (`turnaround_s`, the model's counters) is in
         `spans()`; the trace's copy of the span was opened before (its
         `turnaround_s` runs to the launch's return)."""
@@ -1228,8 +1249,12 @@ class DecodeEngine:
                 return False
             tokens, was_active, still, counters = flight.results
             load = _expert_load(counters)
-            span.attrs.update(load, **_cache_reads(counters, was_active))
+            walk = _cache_walk(self.params.cfg, self.config,
+                               flight.slot_reqs, was_active)
+            span.attrs.update(load, **_cache_reads(counters, was_active),
+                              **walk)
         self.stats.note_experts(**load)
+        self.stats.note_cache_walk(walk)
         with RecordEvent("engine.emit"):
             self._emit(flight.slot_reqs, tokens, was_active, still, now)
             self.stats.note_lookahead(flight.ahead)

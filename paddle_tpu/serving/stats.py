@@ -278,6 +278,7 @@ class DecodeStats(ServingStats):
         self._first_t = None           # first/last token wall-clock
         self._last_t = None            # (engine clock) for tokens/s
         self.cache = None              # {"kind", "bytes", "arrays"}
+        self.cache_walk = {}           # totals of the steps' `cache_walk`
         # programs that routed over experts held here, their assignments
         # on those experts, and the fullest single expert's count of
         # one program
@@ -338,6 +339,13 @@ class DecodeStats(ServingStats):
                 for n, a in arrays.items()]
         self.cache = {"kind": kind, "bytes": sum(a["bytes"] for a in each),
                       "arrays": each}
+
+    def note_cache_walk(self, walk):
+        """One decode step's `cache_walk` counts (decode.py, "The
+        seam"), summed by name into the summary's `cache`."""
+        with self._lock:
+            for name, n in walk.items():
+                self.cache_walk[name] = self.cache_walk.get(name, 0) + n
 
     def note_experts(self, expert_tokens=None, expert_load_max=0):
         """One program's (prefill or decode step) assignments on the
@@ -416,7 +424,7 @@ class DecodeStats(ServingStats):
                 "in_time": self.admitted_in_time,
                 "late": self.admitted_late}
             if self.cache is not None:
-                out["cache"] = dict(self.cache)
+                out["cache"] = dict(self.cache, **self.cache_walk)
             if self.expert_programs:
                 out["experts"] = {
                     "programs": self.expert_programs,

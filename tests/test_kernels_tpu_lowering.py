@@ -351,18 +351,22 @@ K2_CACHE_BYTES = K2_LAYERS * K2_LAYER_ELEMS * 2
 
 def _k2_cases():
     from paddle_tpu.distributed.moe import routed_experts
-    from paddle_tpu.kernels.mla import latent_append, mla_decode
+    from paddle_tpu.kernels.mla import mla_decode
 
-    latent = ((2, 64, 576, 1024), BF16)
-    per_slot = ((64,), jnp.int32)
+    def latent_step(layers, slots, depth):
+        # the queries, this step's columns, the cache, the positions
+        return (lambda ql, qr, new, c, pos: mla_decode(
+                    ql, qr, new, c, 1, pos, 0.1),
+                [((slots, 64, 512), BF16), ((slots, 64, 64), BF16),
+                 ((slots, 576), BF16), ((layers, slots, 576, depth), BF16),
+                 ((slots,), jnp.int32)], ["mla_decode"])
+
     return {
-        "mla_decode": (
-            lambda ql, qr, c, n: mla_decode(ql, qr, c, 1, n, 0.1),
-            [((64, 64, 512), BF16), ((64, 64, 64), BF16), latent, per_slot],
-            ["mla_decode"]),
-        "latent_append": (
-            lambda c, new, pos: latent_append(c, new, 1, pos),
-            [latent, ((64, 576), BF16), per_slot], ["latent_append"]),
+        # the K2 cell's latent cache (BENCHMARK.json), chip_smoke.py's,
+        # and two layers of 64 slots
+        "mla_decode_cell": latent_step(5, 256, 4096),
+        "mla_decode_smoke": latent_step(2, 8, 2048),
+        "mla_decode": latent_step(2, 64, 1024),
         # a decode step's tokens over 12 of 384 experts of width 2048
         "routed_experts": (
             lambda h, r, b, gu, d: routed_experts(
@@ -373,15 +377,22 @@ def _k2_cases():
     }
 
 
-@pytest.mark.parametrize("name", ["mla_decode", "latent_append",
-                                  "routed_experts"])
+@pytest.mark.parametrize("name", ["mla_decode_cell", "mla_decode_smoke",
+                                  "mla_decode", "routed_experts"])
 def test_k2_kernel_compiles_for_v5e(compiled_kernels, v5e, name):
     fn, args, calls = _k2_cases()[name]
     one = jax.sharding.SingleDeviceSharding(v5e[0])
     avals = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in args]
-    text = jax.jit(fn).trace(*avals).lower(
-        lowering_platforms=("tpu",)).compile().as_text()
-    assert sorted(set(_mosaic_calls(text))) == calls
+    donated = [i for i, (s, _) in enumerate(args) if len(s) == 4]
+    compiled = jax.jit(fn, donate_argnums=donated).trace(*avals).lower(
+        lowering_platforms=("tpu",)).compile()
+    assert sorted(set(_mosaic_calls(compiled.as_text()))) == calls
+    if donated:
+        # the cache is written where it lies: no second one
+        cache = math.prod(args[donated[0]][0]) * 2
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= cache
+        assert mem.temp_size_in_bytes < cache // 2
 
 
 @pytest.fixture(scope="module")
@@ -481,7 +492,7 @@ def test_k2_program_holds_one_copy_of_the_latent_cache(k2_programs,
 
 def test_k2_decode_step_names_its_calls(k2_programs):
     calls = set(_mosaic_calls(k2_programs["decode_step"].as_text()))
-    assert calls == {"latent_append", "mla_decode", "moe_grouped_mm"}
+    assert calls == {"mla_decode", "moe_grouped_mm"}
 
 
 # ---------------------------------------------------------------------

@@ -431,29 +431,113 @@ def test_the_shares_add_up_to_the_uncut_layer():
 # the kernels, interpreted, against the XLA mathematics
 # ---------------------------------------------------------------------
 
-def test_mla_kernels_equal_their_xla_mathematics():
-    rng = np.random.default_rng(6)
-    s, heads, rank, rope, t, layers = 3, 8, 128, 64, 384, 2
-    ql = jnp.asarray(rng.standard_normal((s, heads, rank)), jnp.float32)
-    qr = jnp.asarray(rng.standard_normal((s, heads, rope)), jnp.float32)
-    latent = jnp.asarray(rng.standard_normal((layers, s, rank + rope, t)),
-                         jnp.float32)
-    new = jnp.asarray(rng.standard_normal((s, rank + rope)), jnp.float32)
-    pos = jnp.asarray([5, 130, 383], jnp.int32)
-    want_o, want_latent = resident_mla_attention(ql, qr, new, latent, 1,
-                                                 pos, 0.07)
-    got_latent = mla.latent_append(latent, new, 1, pos)
+def _latent_step(rng, pos, heads=8, rank=128, rope=64, t=512, layers=2):
+    s = len(pos)
+
+    def rand(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    return (rand(s, heads, rank), rand(s, heads, rope), rand(s, rank + rope),
+            rand(layers, s, rank + rope, t), jnp.asarray(pos, jnp.int32))
+
+
+# a tile of 128 here: lengths (pos + 1) of 1, of one less than, exactly
+# and one more than a tile (and a part of 256, of which a tile of 512
+# has two), of the whole depth, and long slots beside short ones, so
+# that the copies in flight belong to other slots than the one computed
+WALKS = {
+    "one_position": [0, 0, 0],
+    "around_a_tile": [126, 127, 128],
+    "around_a_part": [254, 255, 256, 257],
+    "the_whole_depth": [511, 511],
+    "long_after_short": [3, 500, 7, 383, 0, 255],
+    "short_after_long": [500, 3, 383, 7, 255, 0],
+    "issue_29s": [5, 130, 383],
+}
+
+
+@pytest.mark.parametrize("block_k", [128, 256, 512])
+@pytest.mark.parametrize("walk", list(WALKS))
+def test_mla_decode_equals_its_xla_mathematics(walk, block_k):
+    """The kernel, interpreted, against `resident_mla_attention`'s XLA
+    mathematics: what the heads attend to, and the cache after the step,
+    which differs from the cache before it in exactly each slot's column
+    `pos` of that layer."""
+    ql, qr, new, latent, pos = _latent_step(np.random.default_rng(6),
+                                            WALKS[walk])
+    want_o, want_latent = resident_mla_attention(
+        ql, qr, new, latent, 1, pos, 0.07, use_kernel=False)
+    got_o, got_latent = mla.mla_decode(ql, qr, new, latent, 1, pos, 0.07,
+                                       block_k=block_k)
+    np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o),
+                               atol=2e-5)
     np.testing.assert_array_equal(np.asarray(got_latent),
                                   np.asarray(want_latent))
     changed = np.asarray(got_latent != latent)
-    assert changed.sum() == s * (rank + rope) and not changed[0].any()
-    for block_k in (128, 384):
-        got_o = mla.mla_decode(ql, qr, got_latent, 1, pos + 1, 0.07,
-                               block_k=block_k)
-        np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o),
-                                   atol=2e-5)
+    assert not changed[0].any()
+    for i, p in enumerate(WALKS[walk]):
+        assert changed[1, i, :, p].all()
+    assert changed.sum() == len(WALKS[walk]) * new.shape[1]
+
+
+def test_mla_decode_at_the_tile_its_tiling_names():
+    """A depth of 1,024 is one tile of four parts: slots whose last tile
+    holds one, two, three and four live parts, in both orders."""
+    pos = [0, 255, 256, 600, 1023, 767, 511, 3]
+    ql, qr, new, latent, pos = _latent_step(np.random.default_rng(8), pos,
+                                            heads=4, t=1024)
+    assert mla.mla_tiling(192, 1024) == (1024, 256)
+    want_o, want_latent = resident_mla_attention(
+        ql, qr, new, latent, 1, pos, 0.07, use_kernel=False)
+    got_o, got_latent = mla.mla_decode(ql, qr, new, latent, 1, pos, 0.07)
+    np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o),
+                               atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(got_latent),
+                                  np.asarray(want_latent))
+
+
+def test_a_stale_position_writes_the_slots_own_last_column():
+    """An inactive slot's pos may lie past the depth: the dispatch clamps
+    it, and the kernel (at the tile `mla_tiling` names: 128 at a depth of
+    384) writes and reads inside the slot."""
+    ql, qr, new, latent, pos = _latent_step(
+        np.random.default_rng(7), [5, 384, 9000, 383], t=384)
+    want_o, want_latent = resident_mla_attention(
+        ql, qr, new, latent, 0, pos, 0.07, use_kernel=False)
+    got_o, got_latent = resident_mla_attention(
+        ql, qr, new, latent, 0, pos, 0.07, use_kernel=True)
+    np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o),
+                               atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(got_latent),
+                                  np.asarray(want_latent))
+    changed = np.asarray(got_latent != latent)
+    assert changed[0, 1:3, :, 383].all() and changed.sum() == 4 * 192
+
+
+@pytest.mark.parametrize("width, depth, tile, part", [
+    (576, 4096, 1024, 256),       # the K2 cell's latent
+    (576, 2048, 1024, 256),       # chip_smoke.py's
+    (192, 512, 512, 256), (192, 384, 128, 128), (192, 256, 256, 256),
+    (192, 128, 128, 128),
+    (2304, 4096, 256, 256),       # four times the rows: a quarter the tile
+])
+def test_mla_tiling_over_the_shapes_in_use(width, depth, tile, part):
+    assert mla.mla_tiling(width, depth) == (tile, part)
+    assert mla.mla_tiling(width, depth, block_k=128) == (128, 128)
+    lengths = [1, tile - 1, tile, min(tile + 1, depth), depth]
+    tiles = [1, 1, 1, min(2, depth // tile), depth // tile]
+    assert mla.tiles_walked(lengths, tile) == sum(tiles)
+    first, slot = mla._visits(jnp.asarray(lengths, jnp.int32), depth, tile)
+    assert list(np.asarray(first)) == [0] + list(np.cumsum(tiles))
+    assert list(np.asarray(slot)[:sum(tiles)]) == \
+        [i for i, n in enumerate(tiles) for _ in range(n)]
+
+
+@pytest.mark.parametrize("depth, block_k", [(384, 256), (100, None),
+                                            (512, 64)])
+def test_mla_tiling_refuses_what_does_not_tile(depth, block_k):
     with pytest.raises(ValueError):
-        mla.mla_decode(ql, qr, got_latent, 1, pos + 1, 0.07, block_k=256)
+        mla.mla_tiling(192, depth, block_k=block_k)
 
 
 @pytest.mark.parametrize("counts", [
@@ -501,9 +585,10 @@ def test_routed_experts_through_the_kernel_equals_the_dense_layer():
 
 def test_engine_through_the_kernels_serves_the_references_tokens(
         monkeypatch):
-    """The engine's decode step on `latent_append` and `mla_decode`
-    (interpreted), at a latent the kernels tile (rank 128, rope 64,
-    depth 128): still the reference's tokens, also in a refilled slot."""
+    """The engine's decode step on `mla_decode` (interpreted), the one
+    Pallas call a layer's attention makes, at a latent the kernel tiles
+    (rank 128, rope 64, depth 128): still the reference's tokens, also
+    in a refilled slot."""
     monkeypatch.setenv("PADDLE_TPU_FORCE_FLASH_DECODE", "1")
     toy = Toy(max_len=128, kv_lora_rank=128, qk_rope_head_dim=64,
               num_hidden_layers=2, max_position_embeddings=128)
@@ -512,7 +597,7 @@ def test_engine_through_the_kernels_serves_the_references_tokens(
         toy.params.trees, jax.eval_shape(
             lambda: toy.kcfg.cache_arrays(1, 128)),
         np.zeros(1, np.int32), np.zeros(1, np.int32)))
-    assert "name=latent_append" in step and "name=mla_decode" in step
+    assert step.count("name=mla_decode") == 2 and "append" not in step
     rng = np.random.default_rng(7)
     prompts = [rng.integers(0, toy.kcfg.vocab_size, n).astype(np.int32)
                for n in (12, 5)]
@@ -567,3 +652,47 @@ def test_wait_spans_and_summary_carry_the_expert_counts(toy, tmp_path):
         "load_max": max(a["expert_load_max"] for attrs in waits.values()
                         for a in attrs)}
     assert total > 0
+
+
+def test_decode_wait_and_summary_count_the_tiles_walked(monkeypatch,
+                                                         tmp_path):
+    """`latent_tiles` of `latent_grid` on `engine.decode_wait` and their
+    totals in the summary are what the requests' lengths give at the tile
+    `mla_tiling` names (128 at a depth of 384), the second request
+    crossing into its second tile on the way; and the decode program
+    answers with what it answered before: three results of the engine's
+    and the experts' counts."""
+    from paddle_tpu.serving import decode as D
+
+    monkeypatch.setenv("PADDLE_TPU_FORCE_FLASH_DECODE", "1")
+    toy = Toy(max_len=384, kv_lora_rank=128, qk_rope_head_dim=64,
+              num_hidden_layers=2, max_position_embeddings=384)
+    assert toy.kcfg.cache_walk([1, 128, 129], 4, 384) == {
+        "latent_tiles": 4, "latent_grid": 12}
+    eng = toy.engine(slots=2, buckets=(16, 128))
+    state = jax.eval_shape(eng._fresh_state)
+    answer = jax.eval_shape(
+        lambda st: D._decode_step_impl(st, toy.params.trees,
+                                       np.zeros(2, bool), toy.kcfg), state)
+    assert len(jax.tree.leaves(answer[1:])) == 4
+    assert set(answer[-1]) == {"expert_counts"}
+    rng = np.random.default_rng(9)
+    sizes = (12, 126)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        futs = [eng.submit(rng.integers(0, 211, size=n), 5) for n in sizes]
+        _drain(eng, futs)
+    finally:
+        jax.profiler.stop_trace()
+    summary = eng.summary()["decode"]
+    eng.close()
+    walks = [a for n, _, _, a in profiler.spans("engine.decode_wait")]
+    # a request's first token is its prefill's; decode step j reads its
+    # prompt and j tokens
+    want = [sum(-(-(n + j) // 128) for n in sizes) for j in range(1, 5)]
+    assert want == [2, 2, 3, 3]
+    assert [a["latent_tiles"] for a in walks] == want
+    assert {a["latent_grid"] for a in walks} == {2 * 3}
+    assert summary["cache"]["latent_tiles"] == sum(want)
+    assert summary["cache"]["latent_grid"] == 6 * len(want)
+    assert summary["decode_steps"] == len(want)
