@@ -660,6 +660,161 @@ def phase_afmoe(hf, slots, max_len, buckets, prompt_lens, new_tokens,
     return out
 
 
+def _ms_a_call(fn, args, state, norm, calls=5):
+    """Milliseconds a call of `fn(*args, state, norm)` -> (o, state,
+    norm), jitted with both arrays donated as the engine donates them
+    (a call that keeps its arguments pays a copy of both first), after
+    one call to compile."""
+    fn = jax.jit(fn, donate_argnums=(len(args), len(args) + 1))
+    _, state, norm = fn(*args, state + 0, norm + 0)
+    jax.block_until_ready(state)
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        _, state, norm = fn(*args, state, norm)
+    jax.block_until_ready(state)
+    return (time.perf_counter() - t0) / calls * 1e3
+
+
+def phase_brumby(hf, slots, max_len, buckets, prompt_lens, new_tokens,
+                 kernel_slots, kernel_bucket, tol, state_tol, gap_tol,
+                 tile_rows_tried=(None,)):
+    """`hf`: the model's sizes under its config.json keys.  (1) Both
+    retention kernels alone at the model's widths against their XLA
+    mathematics: a decode step over `kernel_slots` slots of which every
+    third is not active (those slots' states bit for bit as they were),
+    a prefill of `kernel_bucket` positions that ends inside the bucket's
+    padding, as served (its products against the state on bfloat16
+    operands), its written state and divisor's state within `state_tol`
+    of the recurrent form's in float32 (what holds the kernel to the
+    precision benchmarks/configs/brumby-14b.json states: with float32
+    operands, read beside, the same gap is 60 times smaller, operands
+    any coarser pass `state_tol`); milliseconds and bytes a call, the
+    decode step for each of `tile_rows_tried`; (2) the engine's first
+    `new_tokens` tokens of each prompt (several chunks long, ending inside a bucket's
+    padding) against the unbatched forward pass in the attention form
+    (`brumby.full_logits`: no state, no chunk): the widest gap by which
+    a served token's logit lies under that pass's best."""
+    from paddle_tpu.kernels import retention
+    from paddle_tpu.models import brumby
+    from paddle_tpu.serving import DecodeConfig, DecodeEngine
+
+    cfg = brumby.BrumbyCfg.from_hf(hf, max_seq_len=max_len)
+    dtype = jnp.dtype(cfg.dtype)
+    rng = np.random.default_rng(36)
+    h, kvh, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    rows = retention.phi_rows(d)
+
+    def rand(shape, scale=1.0, dt=dtype):
+        return jnp.asarray(rng.standard_normal(shape) * scale, dt)
+
+    def gates(shape):
+        # half-lives of 8 to 4,096 positions
+        return jnp.asarray(-np.log(2) / 8 ** rng.uniform(1, 4, shape),
+                           jnp.float32)
+
+    out = {}
+    s = kernel_slots
+    active = jnp.asarray(np.arange(s) % 3 != 1)
+    args = (rand((s, h, d)), rand((s, kvh, d)), rand((s, kvh, d)),
+            gates((s, kvh)))
+    state = rand((2, s, kvh, d, rows * d), 4.0, jnp.float32)
+    # a divisor's state is a sum of k k^T under decay: positive
+    # semi-definite
+    half = rand((2, s, kvh, d, d), 1.0, jnp.float32)
+    norm = jnp.einsum("lsjab,lsjcb->lsjac", half, half)
+    want = _highest(functools.partial(
+        retention.retention_decode, layer=1, active=active,
+        use_kernel=False))(*args, state, norm)
+    step_bytes = 2 * int(active.sum()) * kvh * (rows + 1) * d * d * 4
+    for tile_rows in tile_rows_tried:
+        fn = jax.jit(functools.partial(
+            retention.retention_decode, layer=1, active=active,
+            use_kernel=True, tile_rows=tile_rows))
+        got = fn(*args, state, norm)
+        idle = ~np.asarray(active)
+        _check(all(bool((a[:, idle] == b[:, idle]).all())
+                   for a, b in zip(got[1:], (state, norm))),
+               "retention_decode touched a slot that is not active")
+        _check(bool((got[1][0] == state[0]).all()),
+               "retention_decode touched another layer")
+        errs = [_err(a, b) for a, b in zip(got, want)]
+        _check(all(rel <= tol for _, rel in errs),
+               f"retention_decode ({tile_rows}): {errs}, over {tol}")
+        ms = _ms_a_call(fn, args, state, norm)
+        out[f"retention_decode_{tile_rows or 'tiled'}"] = {
+            "rel_to_max": [rel for _, rel in errs], "ms": ms,
+            "state_bytes": step_bytes, "gb_per_s": step_bytes / ms / 1e6}
+
+    t, true_len = kernel_bucket, kernel_bucket - kernel_bucket // 13 - 1
+    pargs = (rand((t, h, d)), rand((t, kvh, d)), rand((t, kvh, d)),
+             gates((t, kvh)))
+    _, st, z = _highest(retention.recurrent_form)(
+        *(a[:true_len] for a in pargs))
+
+    def prefill(**kw):
+        return lambda q, k, v, log_g, st, nm: retention.retention_prefill(
+            q, k, v, log_g, true_len, st, nm, 1, 2, **kw)
+
+    want_o = _highest(prefill(use_kernel=False))(*pargs, state, norm)[0]
+    fn = jax.jit(prefill(use_kernel=True))
+    o, got_s, got_z = fn(*pargs, state, norm)
+    errs = [_err(o[:true_len], want_o[:true_len]),
+            _err(got_s[1, 2], st), _err(got_z[1, 2], z)]
+    _check(errs[0][1] <= tol, f"retention_prefill: {errs}, over {tol}")
+    _check(all(rel <= state_tol for _, rel in errs[1:]),
+           f"retention_prefill's state: {errs[1:]}, over {state_tol}")
+    _check(bool((got_s[1, 0] == state[1, 0]).all())
+           and bool((got_s[0] == state[0]).all()),
+           "retention_prefill touched another slot or layer")
+    exact = jax.jit(prefill(use_kernel=True, operands="float32"))(
+        *pargs, state, norm)
+    out["retention_prefill"] = {
+        "rel_to_max": [rel for _, rel in errs],
+        "rel_to_max_float32_operands": [
+            _err(exact[0][:true_len], want_o[:true_len])[1],
+            _err(exact[1][1, 2], st)[1], _err(exact[2][1, 2], z)[1]],
+        "ms": _ms_a_call(fn, pargs, state, norm, calls=3), "bucket": t,
+        "chunks": t // retention.retention_tiling(d, t).chunk}
+    del state, norm, want, got, got_s, got_z, exact
+
+    params = brumby.BrumbyParams.from_flat(
+        cfg, brumby.init_params(cfg, jax.random.PRNGKey(36)))
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in prompt_lens]
+    forward = jax.jit(functools.partial(brumby.full_logits, cfg))
+    eng = DecodeEngine(params, config=DecodeConfig(
+        slots=slots, max_len=max_len, buckets=buckets))
+    try:
+        futs = [eng.submit(p, max_new_tokens=new_tokens) for p in prompts]
+        served = [np.asarray(f.result(timeout=900)) for f in futs]
+        summary = eng.summary()
+    finally:
+        eng.close()
+    widest, same = 0.0, 0
+    for p, toks in zip(prompts, served):
+        _check(toks.shape == (new_tokens,), f"prompt {p.size}: {toks.shape}")
+        ids = np.zeros(max_len, np.int32)          # one shape: one compile
+        ids[:p.size] = p
+        ids[p.size:p.size + new_tokens] = toks
+        logits = np.asarray(forward(params.trees, ids), np.float32)
+        picked = logits[p.size - 1:p.size - 1 + new_tokens]
+        widest = max(widest, float((picked.max(axis=1) - picked[
+            np.arange(new_tokens), toks]).max()))
+        same += int((picked.argmax(axis=1) == toks).sum())
+    _check(widest <= gap_tol, f"engine tokens lie up to {widest:.3g} under "
+                              f"the forward pass's best, over {gap_tol}")
+    cache = summary["decode"]["cache"]
+    _check([a["kind"] for a in cache["arrays"]] == ["state", "state"]
+           and not any("depth" in a for a in cache["arrays"]),
+           f"the cache's arrays: {cache}")
+    _check(cache["state_bytes"] > 0 and cache["chunks"] > 0,
+           f"no state traffic counted: {cache}")
+    out.update({"requests": len(prompts), "widest_logit_gap": widest,
+                "tokens_equal_to_forward":
+                    f"{same}/{len(prompts) * new_tokens}", "cache": cache})
+    return out
+
+
 # ---------------------------------------------------------------------------
 # four chips
 # ---------------------------------------------------------------------------
@@ -761,6 +916,31 @@ def main():
               f"phase_s={time.perf_counter() - t0:.1f}", flush=True)
         return result
 
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "brumby-14b.json")) as f:
+        brumby = dict(json.load(f), num_hidden_layers=2)
+
+    def run_brumby(**kw):
+        # prompts of several chunks of 256 that end inside their bucket's
+        # padding; the kernels alone at the cell's 16 slots
+        return run("brumby", phase_brumby, brumby, slots=4, max_len=2048,
+                   buckets=(1024, 1536),
+                   prompt_lens=[600, 1000, 1300, 1025], new_tokens=48,
+                   kernel_slots=16, kernel_bucket=2048,
+                   # the state a prefill writes, against the recurrent
+                   # form's: bfloat16 operands read 3.5e-3 of the
+                   # state's largest entry, float32 ones 5.5e-5 (my chip
+                   # runs, PR 36)
+                   tol=2e-2, state_tol=8e-3, gap_tol=0.25, **kw)
+
+    if sys.argv[1:2] == ["brumby"]:
+        # this phase alone: `chip_smoke.py brumby [rows a decode tile
+        # ...]`
+        run_brumby(tile_rows_tried=(None,) + tuple(
+            int(a) for a in sys.argv[2:]))
+        print(json.dumps({"ok": True, "device": device}), flush=True)
+        return 0
     layer = run("train_layer", phase_train_layer, GPT_FULL, batch=8,
                 seq=2048, steps=5)
     _check(layer["mosaic_calls_in_hlo"] > 0,
@@ -796,6 +976,7 @@ def main():
         new_tokens=32, decode_lengths=[1, 40, 1024, 2048, 777, 128, 2049,
                                        4096], prefill_seq=4096, tol=5e-2,
         gap_tol=0.45)     # read 0.3125 (145 of 160 tokens equal), PR 34
+    run_brumby()
     if device["count"] >= 4:
         run("four_chips", phase_four_chips, GPT_FULL, 8, 2048, 3,
             layer["losses"][0], STATIC_GPT_FULL, static_batch,

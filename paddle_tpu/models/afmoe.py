@@ -61,9 +61,8 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from .blocks import rms_norm, swiglu
+from .blocks import normed_heads, rms_norm, rope, swiglu
 
 __all__ = ["AfmoeCfg", "AfmoeParams", "param_shapes", "init_params",
            "full_logits"]
@@ -152,7 +151,7 @@ class AfmoeCfg(NamedTuple):
     def prefill(self, trees, cache, prompt, true_len, slot):
         return _prefill(self, trees, cache, prompt, true_len, slot)
 
-    def decode(self, trees, cache, token, pos):
+    def decode(self, trees, cache, token, pos, active=None):
         return _decode(self, trees, cache, token, pos)
 
     def head(self, trees, hidden):
@@ -238,21 +237,6 @@ def init_params(cfg, key, std=0.02, bias_std=0.02):
 # the layer
 # ---------------------------------------------------------------------------
 
-def _rope(cfg, x, pos):
-    """Rotate x [N, heads, d] at positions pos [N], as HF's
-    `apply_rotary_pos_emb`: lane i pairs with lane i + d / 2."""
-    half = cfg.head_dim // 2
-    inv_freq = cfg.rope_theta ** (
-        -np.arange(half, dtype=np.float64) / half)
-    angle = pos.astype(jnp.float32)[:, None, None] \
-        * inv_freq.astype(np.float32)
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
-    x32 = x.astype(jnp.float32)
-    a, b = x32[..., :half], x32[..., half:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
-
-
 def _ffn(cfg, lp, h, counts, valid=None, slack=None):
     """The layer's FFN of tokens h [N, H]: dense, or routed + shared."""
     if "router" not in lp:
@@ -272,14 +256,11 @@ def _projections(cfg, lp, a, pos, window):
     """a [N, H] at positions pos [N] -> (q [N, heads, d], k, v
     [N, kv_heads, d], gate [N, heads * d]): q and k normalised per head
     and, in a window layer, rotated."""
-    n, d = a.shape[0], cfg.head_dim
-    q = rms_norm(cfg, (a @ lp["q"]).reshape(n, cfg.num_heads, d),
-                 lp["q_norm"])
-    k = rms_norm(cfg, (a @ lp["k"]).reshape(n, cfg.num_kv_heads, d),
-                 lp["k_norm"])
-    v = (a @ lp["v"]).reshape(n, cfg.num_kv_heads, d)
+    q = normed_heads(cfg, a, lp["q"], cfg.num_heads, lp["q_norm"])
+    k = normed_heads(cfg, a, lp["k"], cfg.num_kv_heads, lp["k_norm"])
+    v = (a @ lp["v"]).reshape(a.shape[0], cfg.num_kv_heads, cfg.head_dim)
     if window:
-        q, k = _rope(cfg, q, pos), _rope(cfg, k, pos)
+        q, k = rope(cfg, q, pos), rope(cfg, k, pos)
     return q, k, v, a @ lp["attn_gate"]
 
 

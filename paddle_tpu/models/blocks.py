@@ -1,10 +1,12 @@
 """Layer pieces the served decoders share (`models/kimi_k2.py`,
-`models/afmoe.py`): RMSNorm and the SwiGLU feed-forward."""
+`models/afmoe.py`, `models/brumby.py`): RMSNorm, the SwiGLU
+feed-forward, heads normalised one by one, and the rotation."""
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-__all__ = ["rms_norm", "swiglu"]
+__all__ = ["rms_norm", "swiglu", "normed_heads", "rope"]
 
 
 def rms_norm(cfg, x, gain):
@@ -23,3 +25,25 @@ def swiglu(h, gate_up, down):
     gu = jnp.dot(h, gate_up, preferred_element_type=jnp.float32)
     f = gu.shape[-1] // 2
     return (jax.nn.silu(gu[..., :f]) * gu[..., f:]).astype(h.dtype) @ down
+
+
+def normed_heads(cfg, a, w, heads, gain):
+    """a [N, H] through w [H, heads * d] -> [N, heads, d], each head
+    RMS-normalised over d with the learned `gain` [d] (QK-norm)."""
+    return rms_norm(cfg, (a @ w).reshape(a.shape[0], heads, cfg.head_dim),
+                    gain)
+
+
+def rope(cfg, x, pos):
+    """Rotate x [N, heads, d] at positions pos [N], as HF's
+    `apply_rotary_pos_emb`: lane i pairs with lane i + d / 2."""
+    half = cfg.head_dim // 2
+    inv_freq = cfg.rope_theta ** (
+        -np.arange(half, dtype=np.float64) / half)
+    angle = pos.astype(jnp.float32)[:, None, None] \
+        * inv_freq.astype(np.float32)
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)

@@ -67,7 +67,7 @@ class DecCfg(NamedTuple):
         return _slot_prefill(DecodeParams(*trees, self), cache, prompt,
                              true_len, slot)
 
-    def decode(self, trees, cache, token, pos):
+    def decode(self, trees, cache, token, pos, active=None):
         return _slot_decode(DecodeParams(*trees, self), cache, token, pos)
 
     def head(self, trees, hidden):

@@ -139,7 +139,7 @@ class K2Cfg(NamedTuple):
     def prefill(self, trees, cache, prompt, true_len, slot):
         return _prefill(self, trees, cache, prompt, true_len, slot)
 
-    def decode(self, trees, cache, token, pos):
+    def decode(self, trees, cache, token, pos, active=None):
         return _decode(self, trees, cache, token, pos)
 
     def head(self, trees, hidden):

@@ -11,11 +11,23 @@ static under jit), and `cfg` gives:
 
 - `cache_arrays(slots, max_len)` -> {name: array}, the model's cache
   (`cache_kind` says what it is), which the engine keeps in its donated
-  state beside its own per-slot vectors;
+  state beside its own per-slot vectors.  Every array is `[layers,
+  slots, ...]`.  One that ends in a depth (`[..., depth]`: a position a
+  column, `max_len` deep or a ring) grows with the context; one that
+  `cfg.cache_states` names is a **state**: of a fixed size a slot,
+  whatever the context, so that `max_len` bounds the positions and not
+  the slot's bytes (`models/brumby.py`: a recurrent state and its
+  divisor's);
 - `prefill(trees, cache, prompt[1, bucket], true_len, slot)` ->
-  (cache, hidden [1, H] at the true last position, counters);
-- `decode(trees, cache, token[S], pos[S])` -> (cache, hidden [S, H],
-  counters), one step of every slot at its own position;
+  (cache, hidden [1, H] at the true last position, counters).  Of a
+  state it writes the slot's whole: a slot that is refilled keeps
+  nothing of its last tenant (columns need no clearing, being read only
+  up to the position; a state has no such bound);
+- `decode(trees, cache, token[S], pos[S], active[S])` -> (cache, hidden
+  [S, H], counters), one step of every slot at its own position;
+  `active` says which slots hold a request (a model with columns may
+  ignore it: what it writes for the others is never read; one with a
+  state leaves theirs alone, and moves no byte of them);
 - `head(trees, hidden)` -> logits; and `max_seq_len`.
 
 Everything after the hidden state (head, greedy or sampled token,
@@ -40,10 +52,18 @@ walks its cache in tiles may give `cache_walk(lengths, slots, max_len)`
 `latent_tiles` walked of the `latent_grid` a rectangle over every slot
 would hold); the engine calls it on the host with the lengths its
 active slots had, puts the counts on `engine.decode_wait` and their
-totals under `summary()["decode"]["cache"]`.  `cache_arrays` are all
-`[layers, slots, ..., depth]`, and `DecodeStats.summary()["decode"]
-["cache"]["arrays"]` lists each by name.  What follows describes the
-engine with the GPT family's cache:
+totals under `summary()["decode"]["cache"]`.  Where the model keeps a
+state the engine counts its traffic itself, on the host, from what the
+seam says of a state: `state_bytes`, the bytes of state a program read
+and wrote, is a decode step's active slots' states once each way and a
+prefill's own slot's once (a slot's bytes are those of the arrays
+`cache_states` names, over the slots); a prefill's counters may give
+`chunks`, the chunks its scan walked; both become attributes of those
+names on `engine.*_wait` and totals under `summary()["decode"]
+["cache"]`.  `DecodeStats.summary()["decode"]["cache"]["arrays"]` lists
+each array by name, with its kind (`depth`, and how deep, or `state`)
+and its bytes.  What follows describes the engine with the GPT
+family's cache:
 
 - ONE compiled decode step owns the whole serving state: a fixed
   ring-buffer KV cache plus per-slot `pos/active/token/stop/eos/temp/
@@ -353,7 +373,8 @@ def _decode_step_impl(state, trees, kill, cfg):
     active = jnp.logical_and(state["active"], jnp.logical_not(kill))
     pos = state["pos"]
     tok = state["token"]
-    cache, hidden, counters = cfg.decode(trees, _cache_of(state), tok, pos)
+    cache, hidden, counters = cfg.decode(trees, _cache_of(state), tok, pos,
+                                         active)
     logits = cfg.head(trees, hidden)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     temp = state["temp"]
@@ -443,6 +464,21 @@ def _cache_walk(cfg, config, slot_reqs, active):
     return walk([r.prompt.size + len(r.tokens)
                  for r, live in zip(slot_reqs, active)
                  if live and r is not None], config.slots, config.max_len)
+
+
+def _state_traffic(slot_state_bytes, counters, active=None):
+    """The span attributes of a program's traffic with the states:
+    `state_bytes`, the states of the slots `active` in a decode step read
+    and written, or a prefill's own slot's written, as Python ints (a
+    step of 16 slots x 40 layers of models/brumby.py passes 2**31); and
+    a prefill's `chunks`.  A model without a state gives none."""
+    out = {}
+    if slot_state_bytes:
+        out["state_bytes"] = slot_state_bytes if active is None \
+            else 2 * int(active.sum()) * slot_state_bytes
+    if "chunks" in counters:
+        out["chunks"] = int(counters["chunks"])
+    return out
 
 
 def _cache_reads(counters, active=None):
@@ -562,7 +598,12 @@ class DecodeEngine:
             raise ValueError(
                 f"the model's cache arrays {taken} carry names the engine "
                 f"keeps for its own per-slot vectors {_SLOT_KEYS}")
-        self.stats.note_cache(self.params.cfg.cache_kind, cache)
+        states = getattr(self.params.cfg, "cache_states", ())
+        self.stats.note_cache(self.params.cfg.cache_kind, cache,
+                              states=states)
+        self._slot_state_bytes = sum(
+            int(a.size * a.dtype.itemsize) for n, a in cache.items()
+            if n in states) // cfg.slots
         return {
             **cache,
             "pos": jnp.zeros(cfg.slots, jnp.int32),
@@ -957,7 +998,9 @@ class DecodeEngine:
         cached positions read in one layer of each (`live_full` and
         `live_window` of `models/afmoe.py`); a decode step also what the
         model's `cache_walk` counts (`latent_tiles` and `latent_grid`
-        of `models/kimi_k2.py`).  What is known only once
+        of `models/kimi_k2.py`); where the model keeps a state, both
+        gain `state_bytes` and a prefill its `chunks`.  What is known
+        only once
         the answer is in (`turnaround_s`, the model's counters) is in
         `spans()`; the trace's copy of the span was opened before (its
         `turnaround_s` runs to the launch's return)."""
@@ -1207,9 +1250,11 @@ class DecodeEngine:
                 return False
             first, active, counters = flight.results
             load = _expert_load(counters)
-            span.attrs.update(load, **_cache_reads(counters),
+            traffic = _state_traffic(self._slot_state_bytes, counters)
+            span.attrs.update(load, **_cache_reads(counters), **traffic,
                               turnaround_s=now - flight.admit_t)
         self.stats.note_experts(**load)
+        self.stats.note_cache_walk(traffic)
         with RecordEvent("engine.prefill_book"):
             self._prefill_book(slot, req, int(first), bool(active),
                                flight.pspan, now)
@@ -1251,10 +1296,12 @@ class DecodeEngine:
             load = _expert_load(counters)
             walk = _cache_walk(self.params.cfg, self.config,
                                flight.slot_reqs, was_active)
+            traffic = _state_traffic(self._slot_state_bytes, counters,
+                                     was_active)
             span.attrs.update(load, **_cache_reads(counters, was_active),
-                              **walk)
+                              **walk, **traffic)
         self.stats.note_experts(**load)
-        self.stats.note_cache_walk(walk)
+        self.stats.note_cache_walk({**walk, **traffic})
         with RecordEvent("engine.emit"):
             self._emit(flight.slot_reqs, tokens, was_active, still, now)
             self.stats.note_lookahead(flight.ahead)

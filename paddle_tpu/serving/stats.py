@@ -278,7 +278,9 @@ class DecodeStats(ServingStats):
         self._first_t = None           # first/last token wall-clock
         self._last_t = None            # (engine clock) for tokens/s
         self.cache = None              # {"kind", "bytes", "arrays"}
-        self.cache_walk = {}           # totals of the steps' `cache_walk`
+        # totals of the programs' counts by name: the steps'
+        # `cache_walk`, a state's `state_bytes` and `chunks`
+        self.cache_walk = {}
         # programs that routed over experts held here, their assignments
         # on those experts, and the fullest single expert's count of
         # one program
@@ -331,18 +333,26 @@ class DecodeStats(ServingStats):
             if self.slots:
                 mon.gauge("serving.decode_active_slots").set(active)
 
-    def note_cache(self, kind, arrays):
+    def note_cache(self, kind, arrays, states=()):
         """What the engine's cache is and holds, as its model says:
-        `arrays` {name: array [layers, slots, ..., depth]}."""
-        each = [{"name": n, "layers": a.shape[0], "depth": a.shape[-1],
-                 "bytes": int(a.size * a.dtype.itemsize)}
-                for n, a in arrays.items()]
+        `arrays` {name: array [layers, slots, ...]}; those named in
+        `states` are of a fixed size a slot, the others end in a
+        depth."""
+        each = []
+        for n, a in arrays.items():
+            one = {"name": n, "kind": "state" if n in states else "depth",
+                   "layers": a.shape[0],
+                   "bytes": int(a.size * a.dtype.itemsize)}
+            if n not in states:
+                one["depth"] = a.shape[-1]
+            each.append(one)
         self.cache = {"kind": kind, "bytes": sum(a["bytes"] for a in each),
                       "arrays": each}
 
     def note_cache_walk(self, walk):
-        """One decode step's `cache_walk` counts (decode.py, "The
-        seam"), summed by name into the summary's `cache`."""
+        """One program's counts by name (a decode step's `cache_walk`,
+        a state's `state_bytes` and `chunks`; decode.py, "The seam"),
+        summed by name into the summary's `cache`."""
         with self._lock:
             for name, n in walk.items():
                 self.cache_walk[name] = self.cache_walk.get(name, 0) + n

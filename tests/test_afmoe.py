@@ -243,7 +243,7 @@ def test_the_rotation_is_hf_rotate_half(toy):
     """Lane i pairs with lane i + d / 2, angle pos * theta ** (-2i/d)."""
     x = _rand(np.random.default_rng(6), (5, 3, 16))
     pos = jnp.asarray([0, 1, 7, 100, 900], jnp.int32)
-    got = np.asarray(afmoe._rope(toy.acfg, x, pos))
+    got = np.asarray(afmoe.rope(toy.acfg, x, pos))
     inv = 10000.0 ** (-np.arange(8) / 8)
     ang = np.asarray(pos)[:, None, None] * inv
     a, b = np.asarray(x)[..., :8], np.asarray(x)[..., 8:]
@@ -550,13 +550,13 @@ def test_summary_lists_both_caches(toy):
     eng.close()
     assert cache["kind"] == toy.acfg.cache_kind
     assert cache["arrays"] == [
-        {"name": "k_full", "layers": 2, "depth": 64,
+        {"name": "k_full", "kind": "depth", "layers": 2, "depth": 64,
          "bytes": 2 * 3 * 2 * 16 * 64 * 4},
-        {"name": "v_full", "layers": 2, "depth": 64,
+        {"name": "v_full", "kind": "depth", "layers": 2, "depth": 64,
          "bytes": 2 * 3 * 2 * 16 * 64 * 4},
-        {"name": "k_window", "layers": 6, "depth": 16,
+        {"name": "k_window", "kind": "depth", "layers": 6, "depth": 16,
          "bytes": 6 * 3 * 2 * 16 * 16 * 4},
-        {"name": "v_window", "layers": 6, "depth": 16,
+        {"name": "v_window", "kind": "depth", "layers": 6, "depth": 16,
          "bytes": 6 * 3 * 2 * 16 * 16 * 4}]
     assert cache["bytes"] == sum(a["bytes"] for a in cache["arrays"])
 

@@ -108,6 +108,32 @@ def test_afmoe_phase_at_the_rehearsal_size():
     assert r["experts"]["tokens_total"] > 0
 
 
+def test_brumby_phase_at_the_rehearsal_size():
+    import json
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "brumby-14b.json")) as f:
+        hf = json.load(f)
+    # the rehearsal's toy widths, but heads the kernels tile: both run
+    # in the interpreter, alone, against their XLA mathematics
+    hf.update(hf["rehearse"])
+    hf.update(dtype="float32", head_dim=128, num_attention_heads=5,
+              num_key_value_heads=1, num_hidden_layers=2)
+    r = chip_smoke.phase_brumby(
+        hf, slots=2, max_len=128, buckets=(32, 64), prompt_lens=[5, 40, 60],
+        new_tokens=12, kernel_slots=3, kernel_bucket=32, tol=2e-2,
+        state_tol=8e-3, gap_tol=1e-3)
+    assert r["requests"] == 3
+    assert r["tokens_equal_to_forward"] == "36/36"
+    assert [a["kind"] for a in r["cache"]["arrays"]] == ["state", "state"]
+    assert r["cache"]["chunks"] == 3 and r["cache"]["state_bytes"] > 0
+    assert max(r["retention_decode_tiled"]["rel_to_max"]) < 1e-4
+    # as served, on bfloat16 operands, and beside it on float32 ones
+    fill = r["retention_prefill"]
+    assert 1e-4 < max(fill["rel_to_max"]) < 2e-2
+    assert max(fill["rel_to_max_float32_operands"]) < 1e-4
+
+
 def test_four_chips_phase_on_the_cpu_mesh():
     """The CPU-mesh twin of the four-chip phase: shards on four distinct
     devices, half a tensor-parallel leaf on each, first-step losses

@@ -173,8 +173,66 @@ def test_the_summary_lists_the_gpt_cache_under_its_own_names(model):
     assert cache == {
         "kind": "kv [layers, slots, heads, head_dim, max_len]",
         "bytes": 2 * one,
-        "arrays": [{"name": "k", "layers": 2, "depth": 32, "bytes": one},
-                   {"name": "v", "layers": 2, "depth": 32, "bytes": one}]}
+        "arrays": [{"name": "k", "kind": "depth", "layers": 2, "bytes": one,
+                    "depth": 32},
+                   {"name": "v", "kind": "depth", "layers": 2, "bytes": one,
+                    "depth": 32}]}
+
+
+def test_a_models_state_traffic_rides_the_wait_spans(traced):
+    """A model whose cache is a state (`models/brumby.py`): every
+    `engine.decode_wait` carries `state_bytes`, the active slots' states
+    once each way, every `engine.prefill_wait` the slot's own and the
+    `chunks` its scan walked, and the summary totals both."""
+    from paddle_tpu.models import brumby
+
+    cfg = brumby.BrumbyCfg(
+        vocab_size=97, hidden_size=32, num_layers=2, num_heads=4,
+        num_kv_heads=2, head_dim=8, intermediate_size=48, rms_norm_eps=1e-6,
+        rope_theta=1e6, max_seq_len=64, dtype="float32")
+    params = brumby.BrumbyParams.from_flat(cfg, brumby.init_params(
+        cfg, jax.random.PRNGKey(0), std=0.3, half_life=(2.0, 32.0)))
+    eng = DecodeEngine(params, config=DecodeConfig(
+        slots=3, max_len=64, buckets=(8, 32), label="spans_state"),
+        auto_start=False)
+    rng = np.random.default_rng(0)
+
+    def body():
+        futs = [eng.submit(rng.integers(0, 97, n), max_new_tokens=5)
+                for n in (5, 20)]
+        while not all(f.done() for f in futs):
+            eng.step()
+
+    traced(body)
+    cache = eng.summary()["decode"]["cache"]
+    eng.close()
+    spans = profiler.spans("engine.")
+    steps = [a for n, _, _, a in spans if n == "engine.decode_wait"]
+    fills = [a for n, _, _, a in spans if n == "engine.prefill_wait"]
+    slot = cfg.slot_state_bytes
+    assert slot == 2 * 2 * (5 + 1) * 8 * 8 * 4
+    assert [a["state_bytes"] for a in steps] == [2 * 2 * slot] * 4
+    assert all(a["state_bytes"] == 2 * a["active"] * slot for a in steps)
+    assert [(a["state_bytes"], a["chunks"]) for a in fills] \
+        == [(slot, 1), (slot, 1)]
+    assert all(isinstance(a["state_bytes"], int) for a in steps + fills)
+    assert cache["state_bytes"] == sum(a["state_bytes"]
+                                       for a in steps + fills)
+    assert cache["chunks"] == 2
+
+
+def test_state_traffic_is_counted_in_python_ints():
+    """The published depth's step (16 slots x 40 layers of 34.6 MB each
+    way) passes 2**31: the engine counts on the host, in Python ints."""
+    from paddle_tpu.serving.decode import _state_traffic
+
+    slot = 40 * 8 * 66 * 128 * 128 * 4
+    step = _state_traffic(slot, {}, np.ones(16, bool))
+    assert step == {"state_bytes": 2 * 16 * slot} and step["state_bytes"] \
+        > 2 ** 31 and type(step["state_bytes"]) is int
+    assert _state_traffic(slot, {"chunks": np.int32(32)}) \
+        == {"state_bytes": slot, "chunks": 32}
+    assert _state_traffic(0, {}, np.ones(4, bool)) == {}
 
 
 def test_first_token_split_adds_up_to_ttft(model, traced):
@@ -515,7 +573,30 @@ def _kernel_jaxprs():
         "topk": str(jax.make_jaxpr(
             lambda g: dgc_topk_mask_pallas(g, 0.99))(
             jax.ShapeDtypeStruct((64, 128), f32))),
+        "retention": _retention_jaxpr(),
     }
+
+
+def _retention_jaxpr():
+    """A prefill and a decode step on one resident recurrent state."""
+    from paddle_tpu.kernels.retention import (retention_decode,
+                                              retention_prefill)
+
+    f32 = jnp.float32
+
+    def both(q, k, v, log_g, state, norm):
+        _, state, norm = retention_prefill(
+            q, k, v, log_g, 5, state, norm, 0, 1, use_kernel=True, chunk=8)
+        return retention_decode(q[:2], k[:2], v[:2], log_g[:2], state, norm,
+                                0, jnp.ones(2, bool), use_kernel=True)
+
+    return str(jax.make_jaxpr(both)(
+        jax.ShapeDtypeStruct((8, 2, 128), f32),
+        jax.ShapeDtypeStruct((8, 1, 128), f32),
+        jax.ShapeDtypeStruct((8, 1, 128), f32),
+        jax.ShapeDtypeStruct((8, 1), f32),
+        jax.ShapeDtypeStruct((1, 2, 1, 128, 65 * 128), f32),
+        jax.ShapeDtypeStruct((1, 2, 1, 128, 128), f32)))
 
 
 @pytest.fixture(scope="module")
@@ -528,7 +609,8 @@ def kernel_jaxprs():
     ("decode", "flash_decode"), ("engine", "kv_append"),
     ("engine", "flash_decode"), ("grouped", "gqa_decode"),
     ("windowed", "flash_fwd"), ("layer_norm", "layer_norm_fwd"),
-    ("layer_norm", "layer_norm_bwd"), ("topk", "topk_threshold")])
+    ("layer_norm", "layer_norm_bwd"), ("topk", "topk_threshold"),
+    ("retention", "retention_prefill"), ("retention", "retention_decode")])
 def test_every_pallas_call_has_its_name(kernel_jaxprs, where, kernel):
     assert f"name={kernel}\n" in kernel_jaxprs[where] \
         or f"name={kernel} " in kernel_jaxprs[where]

@@ -661,3 +661,151 @@ def test_afmoe_decode_step_names_its_calls(afmoe_programs):
     assert calls.count("gqa_decode") == calls.count("kv_append") == 4
     fills = _mosaic_calls(afmoe_programs["prefill_b4096"].as_text())
     assert fills.count("flash_fwd") == 4
+
+
+# ---------------------------------------------------------------------
+# Brumby behind the same engine: a recurrent state a slot, held once
+# ---------------------------------------------------------------------
+
+BR_SLOTS, BR_LAYERS, BR_HEADS, BR_KVH, BR_DIM = 16, 2, 40, 8, 128
+BR_WIDE = (BR_DIM // 2 + 1) * BR_DIM                  # 65 rows of phi
+BR_STATE = (BR_LAYERS, BR_SLOTS, BR_KVH, BR_DIM, BR_WIDE)
+BR_NORM = (BR_LAYERS, BR_SLOTS, BR_KVH, BR_DIM, BR_DIM)
+BR_LAYER_ELEMS = math.prod(BR_STATE[1:])
+BR_STATE_BYTES = (math.prod(BR_STATE) + math.prod(BR_NORM)) * 4
+
+
+def _brumby_cases():
+    from paddle_tpu.kernels.retention import (retention_decode,
+                                              retention_prefill)
+
+    # the cell's state (BENCHMARK.json, brumby-14b): 16 slots, 8 K/V
+    # heads of 128 under 40 query heads; two layers of it
+    state, norm = (BR_STATE, F32), (BR_NORM, F32)
+    out = {"retention_decode": (
+        lambda q, k, v, g, st, nm, act: retention_decode(
+            q, k, v, g, st, nm, 1, act)[0],
+        [((BR_SLOTS, BR_HEADS, BR_DIM), BF16)]
+        + [((BR_SLOTS, BR_KVH, BR_DIM), BF16)] * 2
+        + [((BR_SLOTS, BR_KVH), F32), state, norm, ((BR_SLOTS,), bool)],
+        ["retention_decode"])}
+    for bucket in (6144, 8192):
+        for operands in ("float32", "bfloat16"):
+            out[f"retention_prefill_{bucket}_{operands}"] = (
+                lambda q, k, v, g, n, st, nm, slot, operands=operands:
+                retention_prefill(q, k, v, g, n, st, nm, 1, slot,
+                                  operands=operands)[0],
+                [((bucket, BR_HEADS, BR_DIM), BF16)]
+                + [((bucket, BR_KVH, BR_DIM), BF16)] * 2
+                + [((bucket, BR_KVH), F32), ((), jnp.int32), state, norm,
+                   ((), jnp.int32)], ["retention_prefill"])
+    return out
+
+
+@pytest.mark.parametrize("name", [
+    "retention_decode", "retention_prefill_6144_float32",
+    "retention_prefill_8192_float32", "retention_prefill_6144_bfloat16",
+    "retention_prefill_8192_bfloat16"])
+def test_brumby_kernel_compiles_for_v5e(compiled_kernels, v5e, name):
+    fn, args, calls = _brumby_cases()[name]
+    one = jax.sharding.SingleDeviceSharding(v5e[0])
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in args]
+    text = jax.jit(fn).trace(*avals).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert sorted(set(_mosaic_calls(text))) == calls
+
+
+@pytest.fixture(scope="module")
+def brumby_programs(v5e):
+    """(decode step, prefill at bucket 6144) of `DecodeEngine` over
+    `models/brumby.py` at the published widths, two layers and the
+    cell's 16 slots, compiled for the described v5e with the state
+    donated, as the engine jits them."""
+    import functools
+    import json
+    import os
+
+    from paddle_tpu.models import brumby
+    from paddle_tpu.serving import decode as D
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "brumby-14b.json")) as f:
+        cfg = dict(json.load(f), num_hidden_layers=BR_LAYERS)
+    bcfg = brumby.BrumbyCfg.from_hf(cfg, max_seq_len=10240)
+    one = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    trees = brumby.BrumbyParams.from_flat(bcfg, {
+        n: aval(s, F32 if kind == "gate_bias" else BF16)
+        for n, (s, kind) in brumby.param_shapes(bcfg).items()}).trees
+    i32 = jnp.int32
+    cache = {n: aval(a.shape, a.dtype) for n, a in jax.eval_shape(
+        lambda: bcfg.cache_arrays(BR_SLOTS, 10240)).items()}
+    assert {n: a.shape for n, a in cache.items()} == {
+        "state": BR_STATE, "norm": BR_NORM}
+    state = dict(cache, pos=aval((BR_SLOTS,), i32),
+                 active=aval((BR_SLOTS,), bool),
+                 token=aval((BR_SLOTS,), i32), stop=aval((BR_SLOTS,), i32),
+                 eos=aval((BR_SLOTS,), i32), temp=aval((BR_SLOTS,), F32),
+                 key=aval((BR_SLOTS, 2), jnp.uint32))
+
+    def compiled(impl, *args):
+        return jax.jit(functools.partial(impl, cfg=bcfg),
+                       donate_argnums=(0,)).trace(
+            state, trees, *args).lower(
+            lowering_platforms=("tpu",)).compile()
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(backend, "is_tpu_backend", lambda: True)
+        return {
+            "decode_step": compiled(D._decode_step_impl,
+                                    aval((BR_SLOTS,), bool)),
+            "prefill_b6144": compiled(
+                D._prefill_impl, aval((1, 6144), i32), aval((), i32),
+                aval((), i32), aval((), i32), aval((), i32),
+                aval((), F32), aval((2,), jnp.uint32)),
+        }
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_b6144"])
+def test_brumby_program_moves_no_layer_of_the_state(brumby_programs,
+                                                    program):
+    """No copy, slice, update or re-layout of the state or of a layer of
+    it: only the aliased Mosaic calls touch it."""
+    text = brumby_programs[program].as_text()
+    dims = f"{BR_SLOTS},{BR_KVH},{BR_DIM},{BR_WIDE}]"
+    large = [(op, line) for op, line in _large_results(text, BR_LAYER_ELEMS)
+             # the prefill's activations over 6,144 tokens and the
+             # vocabulary's matrices are larger than a layer of the
+             # state: only results with the state's own dimensions are
+             # judged
+             if dims in line.split(" = ", 1)[1].split("(")[0]]
+    assert large, "the state is not in the program at all"
+    odd = [(op, line) for op, line in large if op not in PASSES_ALONG]
+    assert not odd, odd
+    # nothing holds one layer or one slot of it sliced out
+    assert f"f32[{dims}" not in text.replace(f"f32[{BR_LAYERS},{dims}", "")
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_b6144"])
+def test_brumby_program_holds_one_copy_of_the_state(brumby_programs,
+                                                    program):
+    mem = brumby_programs[program].memory_analysis()
+    assert mem.alias_size_in_bytes >= BR_STATE_BYTES
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 1 << 20
+    # temporaries: activations of 16 tokens, or of 6,144 (the SwiGLU's
+    # [6144, 34816] in float32 is 0.86 GB); never a second state
+    limit = 0.05 if program == "decode_step" else 1.0
+    assert mem.temp_size_in_bytes < limit * BR_STATE_BYTES
+    if program == "prefill_b6144":
+        assert mem.temp_size_in_bytes < 1.2e9
+
+
+def test_brumby_programs_name_their_calls(brumby_programs):
+    calls = _mosaic_calls(brumby_programs["decode_step"].as_text())
+    assert calls == ["retention_decode"] * BR_LAYERS
+    fills = _mosaic_calls(brumby_programs["prefill_b6144"].as_text())
+    assert fills == ["retention_prefill"] * BR_LAYERS

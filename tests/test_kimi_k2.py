@@ -182,8 +182,8 @@ def test_engine_serves_what_the_reference_computes(toy):
     assert summary["decode"]["prefill_steps"] == 5
     assert summary["decode"]["cache"] == {
         "kind": toy.kcfg.cache_kind, "bytes": 3 * 2 * 40 * 64 * 4,
-        "arrays": [{"name": "latent", "layers": 3, "depth": 64,
-                    "bytes": 3 * 2 * 40 * 64 * 4}]}
+        "arrays": [{"name": "latent", "kind": "depth", "layers": 3,
+                    "depth": 64, "bytes": 3 * 2 * 40 * 64 * 4}]}
 
 
 def test_loop_thread_one_step_ahead_serves_what_the_reference_computes(
