@@ -564,16 +564,20 @@ def phase_k2(hf, slots, max_len, buckets, prompt_lens, new_tokens,
 def phase_afmoe(hf, slots, max_len, buckets, prompt_lens, new_tokens,
                 decode_lengths, prefill_seq, tol, gap_tol):
     """`hf`: the model's sizes under its config.json keys.  (1)
-    `kv_append` + `gqa_decode` at the model's widths against their XLA
-    mathematics at ragged lengths, over a full cache and over a ring
-    that has wrapped, and `flash_fwd` with the window and grouped heads
-    against the XLA mask at `prefill_seq`; (2) the engine's first
+    `gqa_decode` at the model's widths (the column it writes and what it
+    attends to) against the XLA mathematics at ragged lengths, over a
+    full cache and over a ring that has wrapped, both caches donated:
+    the caches equal everywhere, milliseconds a call and the tiles it
+    walked of the rectangle's; and `flash_fwd` with the window and
+    grouped heads against the XLA mask at `prefill_seq`; (2) the
+    engine's first
     `new_tokens` tokens of each prompt (some shorter than the window,
     some longer, some that cross it while decoding) against the
     unbatched forward pass (`afmoe.full_logits` over prompt and served
     tokens: no cache, no ring): the widest gap by which a served
     token's logit lies under that pass's best."""
     from paddle_tpu.kernels import attention
+    from paddle_tpu.kernels.flash_attention import gqa_tiling, tiles_walked
     from paddle_tpu.models import afmoe
     from paddle_tpu.serving import DecodeConfig, DecodeEngine
 
@@ -590,26 +594,32 @@ def phase_afmoe(hf, slots, max_len, buckets, prompt_lens, new_tokens,
     for name, depth, ring in (("full", max_len, False),
                               ("ring", cfg.sliding_window, True)):
         args = (rand((s, cfg.num_heads, 1, d)), rand((s, kvh, 1, d)),
-                rand((s, kvh, 1, d)), rand((2, s, kvh, d, depth)),
-                rand((2, s, kvh, d, depth)))
+                rand((s, kvh, 1, d)))
+        caches = (rand((2, s, kvh, d, depth)), rand((2, s, kvh, d, depth)))
         fn = functools.partial(attention.resident_decode_attention,
                                layer=1, pos=pos, ring=ring)
-        got = {}
-        for kernel in (True, False):
-            # the dispatch reads the switch while the call is traced
-            os.environ["PADDLE_TPU_FORCE_FLASH_DECODE"] = str(int(kernel))
-            try:
-                got[kernel] = (jax.jit(fn) if kernel
-                               else _highest(fn))(*args)
-            finally:
-                del os.environ["PADDLE_TPU_FORCE_FLASH_DECODE"]
-        _check(all(bool((a == b).all()) for a, b in zip(got[True][1:],
-                                                        got[False][1:])),
-               f"kv_append ({name}) differs from the XLA write")
-        err, rel = _err(got[True][0], got[False][0])
+        # the dispatch reads the switch while the call is traced
+        os.environ["PADDLE_TPU_FORCE_FLASH_DECODE"] = "0"
+        try:
+            want = _highest(fn)(*args, *caches)
+            os.environ["PADDLE_TPU_FORCE_FLASH_DECODE"] = "1"
+            got = jax.jit(fn, donate_argnums=(3, 4))(
+                *args, *(c + 0 for c in caches))
+            ms = _ms_a_call(fn, args, *caches)
+        finally:
+            del os.environ["PADDLE_TPU_FORCE_FLASH_DECODE"]
+        _check(all(bool((a == b).all()) for a, b in zip(got[1:], want[1:])),
+               f"gqa_decode ({name}): a cache is not its input with the "
+               f"new columns")
+        err, rel = _err(got[0], want[0])
         _check(rel <= tol, f"gqa_decode ({name}): error {err:.3g} is "
                            f"{rel:.3g} of the reference's max, over {tol}")
-        out[f"gqa_decode_{name}"] = {"max_abs_err": err, "rel_to_max": rel}
+        tile = gqa_tiling(kvh, d, depth).tile
+        out[f"gqa_decode_{name}"] = {
+            "max_abs_err": err, "rel_to_max": rel, "written_column": "exact",
+            "ms_a_call": ms, "tiles_walked": tiles_walked(
+                [min(n, depth) for n in decode_lengths], tile),
+            "tiles_of_the_grid": s * (depth // tile)}
 
     q = rand((1, cfg.num_heads, prefill_seq, d))
     k, v = rand((1, kvh, prefill_seq, d)), rand((1, kvh, prefill_seq, d))
@@ -662,9 +672,10 @@ def phase_afmoe(hf, slots, max_len, buckets, prompt_lens, new_tokens,
 
 def _ms_a_call(fn, args, state, norm, calls=5):
     """Milliseconds a call of `fn(*args, state, norm)` -> (o, state,
-    norm), jitted with both arrays donated as the engine donates them
-    (a call that keeps its arguments pays a copy of both first), after
-    one call to compile."""
+    norm), the two being arrays the call writes in place (a recurrent
+    state and its divisor's; a K and a V cache), jitted with both
+    donated as the engine donates them (a call that keeps its arguments
+    pays a copy of both first), after one call to compile."""
     fn = jax.jit(fn, donate_argnums=(len(args), len(args) + 1))
     _, state, norm = fn(*args, state + 0, norm + 0)
     jax.block_until_ready(state)
@@ -934,11 +945,26 @@ def main():
                    # runs, PR 36)
                    tol=2e-2, state_tol=8e-3, gap_tol=0.25, **kw)
 
-    if sys.argv[1:2] == ["brumby"]:
-        # this phase alone: `chip_smoke.py brumby [rows a decode tile
-        # ...]`
-        run_brumby(tile_rows_tried=(None,) + tuple(
-            int(a) for a in sys.argv[2:]))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "trinity-mini.json")) as f:
+        trinity = json.load(f)
+
+    def run_afmoe():
+        return run(
+            "afmoe", phase_afmoe, trinity, slots=8, max_len=4096,
+            buckets=(512, 2048, 4096),
+            prompt_lens=[40, 2040, 2049, 3000, 700], new_tokens=32,
+            decode_lengths=[1, 40, 1024, 2048, 777, 128, 2049, 4096],
+            prefill_seq=4096, tol=5e-2,
+            gap_tol=0.45)  # read 0.3125 (145 of 160 tokens equal), PR 34
+
+    # one phase alone: `chip_smoke.py brumby [rows a decode tile ...]`,
+    # `chip_smoke.py afmoe`
+    alone = {"brumby": lambda: run_brumby(tile_rows_tried=(None,) + tuple(
+                 int(a) for a in sys.argv[2:])),
+             "afmoe": run_afmoe}.get(sys.argv[1]) if sys.argv[1:] else None
+    if alone is not None:
+        alone()
         print(json.dumps({"ok": True, "device": device}), flush=True)
         return 0
     layer = run("train_layer", phase_train_layer, GPT_FULL, batch=8,
@@ -966,16 +992,7 @@ def main():
         prompt_lens=[40, 200, 900, 513, 700, 40, 255, 1000, 333, 90],
         new_tokens=32, decode_lengths=[1, 40, 1024, 2048, 777, 128, 129,
                                        2047], tol=5e-2, gap_tol=0.25)
-    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                           "benchmarks", "configs",
-                           "trinity-mini.json")) as f:
-        trinity = json.load(f)
-    run("afmoe", phase_afmoe, trinity, slots=8, max_len=4096,
-        buckets=(512, 2048, 4096),
-        prompt_lens=[40, 2040, 2049, 3000, 700],
-        new_tokens=32, decode_lengths=[1, 40, 1024, 2048, 777, 128, 2049,
-                                       4096], prefill_seq=4096, tol=5e-2,
-        gap_tol=0.45)     # read 0.3125 (145 of 160 tokens equal), PR 34
+    run_afmoe()
     run_brumby()
     if device["count"] >= 4:
         run("four_chips", phase_four_chips, GPT_FULL, 8, 2048, 3,

@@ -167,8 +167,24 @@ def decode_attention(q, k, v, pos=None, mask=None, scale=None,
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(q.dtype))
 
 
+def resident_decode_walk(pos, k_cache):
+    """What `resident_decode_attention` derives from the positions alone
+    for grouped caches of this shape `[L, S, KVH, D, T]`, ring or not:
+    `gqa_decode`'s visit tables over each slot's min(pos + 1, T) live
+    columns, the same in every layer of one depth, so a decode step
+    builds them once and hands them to each layer's call (`walk=`).
+    None where that call does not take the kernel."""
+    _, _, kv_heads, head_dim, t = k_cache.shape
+    if not _decode_takes_kernel(t, head_dim):
+        return None
+    from .flash_attention import gqa_walk
+
+    return gqa_walk(jnp.minimum(jnp.asarray(pos, jnp.int32) + 1, t),
+                    kv_heads, head_dim, t)
+
+
 def resident_decode_attention(q, k_new, v_new, k_cache, v_cache, layer,
-                              pos, scale=None, ring=False):
+                              pos, scale=None, ring=False, walk=None):
     """One layer of the decode engine's step on the cache it owns
     (serving/decode.py): write each slot's new column, then attend.
 
@@ -190,10 +206,14 @@ def resident_decode_attention(q, k_new, v_new, k_cache, v_cache, layer,
 
     Reader and writer both take the stacked cache as it lies, so the
     caller can carry it through its layer loop and the compiled step
-    holds one copy of it.  Where `_decode_takes_kernel` holds these are
-    the Pallas calls `kv_append` and `flash_decode`; elsewhere the same
-    mathematics in XLA, through `decode_attention`'s own code (which is
-    what keeps the engine token-exact against generate())."""
+    holds one copy of it.  Where `_decode_takes_kernel` holds, grouped
+    heads are the one Pallas call `gqa_decode`, which walks each slot's
+    live tiles and writes the column into the tile it reads (`walk`:
+    `resident_decode_walk(pos, k_cache)`, where the caller built
+    it for all its layers), and equal heads the calls `kv_append` and
+    `flash_decode`; elsewhere the same mathematics in XLA, through
+    `decode_attention`'s own code (which is what keeps the engine
+    token-exact against generate())."""
     t = k_cache.shape[-1]
     pos = jnp.asarray(pos, jnp.int32)
     posw = pos % t if ring else jnp.minimum(pos, t - 1)
@@ -201,13 +221,17 @@ def resident_decode_attention(q, k_new, v_new, k_cache, v_cache, layer,
     grouped = k_cache.shape[2] != q.shape[1]
     k_col, v_col = k_new[:, :, 0, :], v_new[:, :, 0, :]     # [S, KVH, D]
     if _decode_takes_kernel(t, q.shape[-1]):
-        from .flash_attention import (flash_decode_resident,
-                                      gqa_decode_resident, kv_append)
+        from .flash_attention import (flash_decode_resident, gqa_decode,
+                                      kv_append)
 
+        if grouped:
+            return gqa_decode(q, k_col, v_col, k_cache, v_cache, layer,
+                              posw, jnp.minimum(pos + 1, t), walk=walk,
+                              sm_scale=scale)
         k_cache, v_cache = kv_append(k_cache, v_cache, k_col, v_col,
                                      layer, posw)
-        attend = gqa_decode_resident if grouped else flash_decode_resident
-        o = attend(q, k_cache, v_cache, layer, live, sm_scale=scale)
+        o = flash_decode_resident(q, k_cache, v_cache, layer, live,
+                                  sm_scale=scale)
         return o, k_cache, v_cache
     slots = jnp.arange(q.shape[0])
     k_cache = k_cache.at[layer, slots, :, :, posw].set(
@@ -224,7 +248,7 @@ def resident_decode_attention(q, k_new, v_new, k_cache, v_cache, layer,
 
 
 def _grouped_decode(q, k, v, live, scale):
-    """The XLA mathematics of `gqa_decode_resident`: q [S, H, 1, D]
+    """The XLA mathematics of `gqa_decode`'s attention: q [S, H, 1, D]
     against one layer k, v [S, KVH, D, T] of which each slot's first
     `live` [S] columns count; float32 scores and softmax as
     `decode_attention`'s."""

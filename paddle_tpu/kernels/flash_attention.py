@@ -640,10 +640,11 @@ def _bwd_call(sm_scale, causal, tiling, interpreted, q, k, v, do, lse,
 #   dimension of 64 would be padded to 128 lanes), so neither XLA nor the
 #   Mosaic call has a reason to re-lay the cache.
 #
-# `kv_append` is the engine's only writer inside the decode step: per
-# slot, the 128-lane tile that holds column `pos[s]` comes in, the new
-# column is merged under an iota mask, the same tile goes out, on the
-# cache aliased to the call's output.  (An XLA scatter or
+# `kv_append` is that reader's writer inside the decode step (grouped
+# heads write through `gqa_decode`, further down): per slot, the
+# 128-lane tile that holds column `pos[s]` comes in, the new column is
+# merged under an iota mask, the same tile goes out, on the cache
+# aliased to the call's output.  (An XLA scatter or
 # dynamic_update_slice of one column re-lays the whole stacked cache
 # around the write: the update's minor dimension of 1 pulls the operand's
 # layout with it.)
@@ -804,111 +805,6 @@ def flash_decode_resident(q, k_cache, v_cache, layer, lengths,
     return out.reshape(s, h, 1, d)
 
 
-def _gqa_decode_kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
-                       acc_ref, m_ref, l_ref, *, sm_scale, block_k, group):
-    # one slot a grid row: q [KVH, group, D], tiles [KVH, D, block_k];
-    # the `group` query heads of a K/V head meet its tile where it lies
-    ki = pl.program_id(1)
-    length = len_ref[pl.program_id(0)]
-    kv_heads = k_ref.shape[0]
-
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(ki * block_k < length)
-    def _tile():
-        def heads_of(h):
-            return slice(h * group, (h + 1) * group)
-
-        s = jnp.concatenate([jax.lax.dot_general(
-            q_ref[h], k_ref[h], _NN,
-            preferred_element_type=jnp.float32)
-            for h in range(kv_heads)], axis=0) * sm_scale   # [H, bk] f32
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(k_pos < length, s, NEG_INF)
-        m_prev = m_ref[:, 0:1]
-        m_cur = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)
-        l_ref[...] = jnp.broadcast_to(
-            l_ref[:, 0:1] * alpha + p.sum(axis=1, keepdims=True),
-            l_ref.shape)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.concatenate([
-            jax.lax.dot_general(p[heads_of(h)].astype(v_ref.dtype),
-                                v_ref[h], _NT,
-                                preferred_element_type=jnp.float32)
-            for h in range(kv_heads)], axis=0)              # [H, D]
-        m_ref[...] = jnp.broadcast_to(m_cur, m_ref.shape)
-
-    @pl.when(ki == pl.num_programs(1) - 1)
-    def _finalize():
-        l = l_ref[:, 0:1]
-        o_ref[...] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(
-            o_ref.dtype)
-
-
-def gqa_decode_resident(q, k_cache, v_cache, layer, lengths, sm_scale=None):
-    """`flash_decode_resident` where several query heads share a head of
-    K and V (grouped-query attention).
-
-    q: [S, H, 1, D]; k_cache/v_cache: [L, S, KVH, D, T] with H a
-    multiple of KVH (query head h reads K/V head h // (H / KVH));
-    layer: int32 scalar; lengths: int32 [S], the leading columns of the
-    slot that are live (a ring that has wrapped gives its whole depth).
-    Grid (S, T // block_k): a grid step holds one slot's tile of every
-    K/V head, [KVH, D, block_k], fetched once for all the query heads
-    that read it, so K and V are never repeated in memory; blocks past a
-    slot's length are neither computed (`pl.when`) nor fetched (their
-    index names the last live block again).  Float32 scores and
-    accumulation; the result is [S, H, 1, D] in q's type."""
-    s, h, q_len, d = q.shape
-    if q_len != 1:
-        raise ValueError(f"gqa_decode needs q_len == 1, got {q_len}")
-    kvh, t = k_cache.shape[2], k_cache.shape[-1]
-    if k_cache.shape[1:] != (s, kvh, d, t) or h % kvh \
-            or v_cache.shape != k_cache.shape:
-        raise ValueError(
-            f"resident caches must be [L, {s}, KVH, {d}, T] with KVH "
-            f"dividing {h}, got {k_cache.shape} and {v_cache.shape}")
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(d)
-    block_k = _decode_block_k(t, None)
-    lengths = jnp.asarray(lengths, jnp.int32).reshape(s)
-    layer = jnp.asarray(layer, jnp.int32).reshape(1)
-    group = h // kvh
-    q_spec = _vmem_spec((None, kvh, group, d),
-                        lambda i, ki, *_: (i, 0, 0, 0))
-    o_spec = _vmem_spec((None, h, d), lambda i, ki, *_: (i, 0, 0))
-    kv_spec = _vmem_spec(
-        (None, None, kvh, d, block_k),
-        lambda i, ki, lens, layer: (
-            layer[0], i, 0, 0, jnp.minimum(ki, (lens[i] - 1) // block_k)))
-    call = pl.pallas_call(
-        functools.partial(_gqa_decode_kernel, sm_scale=float(sm_scale),
-                          block_k=block_k, group=group),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(s, t // block_k),
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=o_spec,
-            scratch_shapes=[_scratch((h, d)), _scratch((h, _LANES)),
-                            _scratch((h, _LANES))]),
-        out_shape=jax.ShapeDtypeStruct((s, h, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret(),
-        name="gqa_decode",
-    )
-    with jax.named_scope("gqa_decode"):
-        out = call(lengths, layer, q.reshape(s, kvh, group, d), k_cache,
-                   v_cache)
-    return out.reshape(s, h, 1, d)
-
-
 def _append_kernel(pos_ref, layer_ref, kc_ref, vc_ref, kn_ref, vn_ref,
                    ko_ref, vo_ref):
     # one slot a grid step: tiles [H, D, 128], new columns [D, H]
@@ -961,6 +857,323 @@ def kv_append(k_cache, v_cache, k_new, v_new, layer, pos):
         return call(pos, layer, k_cache, v_cache,
                     jnp.swapaxes(k_new, 1, 2).astype(k_cache.dtype),
                     jnp.swapaxes(v_new, 1, 2).astype(v_cache.dtype))
+
+
+# --------------------------------------------------------------------------
+# grouped-query decode over the resident cache: a walk of the live tiles
+# --------------------------------------------------------------------------
+#
+# `gqa_decode` writes this step's column of K and V of every slot and
+# attends, in one call, on caches `[L, S, KVH, D, T]` whose head of K and
+# V is read by `group = H / KVH` query heads (1 for plain multi-head
+# attention).  It is `kernels/mla.py`'s design over two arrays.  **The
+# walk has as many steps as the slots have live tiles.**  A visit is one
+# (slot, tile) pair that holds a live column; `_visits` lists them in XLA
+# from the lengths (each slot's first visit, the slot of each visit) and
+# the tables are scalar-prefetched.  The grid is `(slots,)`: a grid step
+# brings the slot's queries and takes its result through BlockSpecs, and
+# its body loops over the slot's visits only.  The caches stay where they
+# lie (`pl.ANY`); the kernel copies a visit's `[KVH, D, tile]` of K and of
+# V into one of `GqaTiling.buffers` VMEM buffers itself, always `buffers
+# - 1` visits ahead of the one it computes, whatever slot those belong
+# to.  Visit v lives in buffer v mod `buffers`, so no turn is carried
+# from one grid step to the next.  A tile is copied in parts, and of a
+# slot's last tile only the parts that hold a live column: what lies past
+# them in the buffer is an earlier visit's, finite and masked.
+#
+# The new column is written by the kernel that reads it: on the visit
+# whose tile holds the write position the 128 lanes around it take this
+# step's K and V in VMEM before the scores are computed, and the same 128
+# lanes go back to the caches, which are aliased to the results, through
+# BlockSpecs.  Nothing else of the caches is written.  The write
+# position and the live length are two inputs: in a ring that has
+# wrapped the column `pos mod T` lies in any tile, not the last.
+
+class GqaTiling(NamedTuple):
+    """How `gqa_decode` walks one slot's K and V."""
+    tile: int       # columns of a visit: one step of the online softmax
+    part: int       # columns of one copy; a tile is whole parts
+    buffers: int    # tiles in VMEM: the one computed and those on their way
+
+
+# elements of K and of V the buffers may hold: with both, 8 MiB in
+# bfloat16, half of what a kernel is given on the v5e
+_GQA_BUFFER_ELEMS = 2 << 20
+
+
+def gqa_tiling(kv_heads, head_dim, depth, block_k=None):
+    """The tiling of `gqa_decode` over K and V `[kv_heads, head_dim,
+    depth]` a slot.  The tile is the largest power-of-two multiple of
+    128, at most 512, that divides the depth and whose four buffers fit
+    `_GQA_BUFFER_ELEMS` (`block_k` overrides it: tests and the
+    interpreter, for small depths); it is copied in parts of 128
+    columns into one of four buffers.
+
+    Measured on the v5e at the Trinity cell's `[3, 64, 4, 128, 9728]`
+    and `[9, 64, 4, 128, 2048]`, 32 query heads, lengths drawn as its
+    (mean 3,619: 474 MB live a full layer, 255 MB a ring), the kernel
+    alone with the caches donated, ms a call of a full layer / of a ring
+    (PERF.md section 6, PR 37).  The rectangle before it, `kv_append`
+    and all: 0.931 / 0.431.  Tiles of 512 in parts of 128, four buffers:
+    0.717 / 0.383 (0.706 in a second run), which is what its copies alone
+    take (0.718 / 0.383: 700 GB/s with the 17 MB of written lanes); the
+    computation alone takes 0.629 / 0.337 and hides behind them.  Parts
+    of 256: 0.744 / 0.397, of 512: 0.732 / 0.400 (fewer copies in
+    flight); a copy a K/V head: 0.737 to 0.912 (more descriptors).  Three
+    buffers 0.725 / 0.386, two 0.861, six 0.718 / 0.383.  Tiles of 256:
+    0.745 to 0.753; of 1,024 and 2,048 in a ring: 0.398 to 0.402.  The
+    whole last tile copied, not its live parts: 0.757 / 0.393."""
+    buffers, tile = 4, block_k
+    if tile is None:
+        tile = 512
+        while tile > _LANES and (
+                depth % tile
+                or buffers * kv_heads * head_dim * tile > _GQA_BUFFER_ELEMS):
+            tile //= 2
+    if depth % tile or tile % _LANES:
+        raise ValueError(f"cache depth {depth} must be a multiple of the "
+                         f"tile {tile}, and that of {_LANES}")
+    return GqaTiling(tile, _LANES, buffers)
+
+
+def tiles_walked(lengths, tile):
+    """Visits of one walk over slots of these lengths."""
+    return sum(-(-int(n) // tile) for n in lengths)
+
+
+def _visits(lengths, depth, tile):
+    """lengths int32 [S], each in [1, depth] -> (first visit of each slot
+    and, last, the number of visits [S + 1]; slot of each visit
+    [S * depth / tile], entries past the last visit never read)."""
+    s = lengths.shape[0]
+    ends = jnp.cumsum((lengths + tile - 1) // tile)
+    v = jnp.arange(s * (depth // tile), dtype=jnp.int32)
+    # the slots whose visits all lie before v (a search would be a loop)
+    slot = jnp.minimum((ends[None, :] <= v[:, None]).sum(axis=1), s - 1)
+    first = jnp.concatenate([jnp.zeros(1, ends.dtype), ends])
+    return first.astype(jnp.int32), slot.astype(jnp.int32)
+
+
+def gqa_walk(lengths, kv_heads, head_dim, depth, block_k=None):
+    """The visit tables of `gqa_decode` for slots of these live lengths
+    (int32 [S], each in [1, depth]): they depend on the lengths alone, so
+    a decode step builds them once for all its layers of one depth."""
+    lengths = jnp.asarray(lengths, jnp.int32)
+    return _visits(lengths, depth,
+                   gqa_tiling(kv_heads, head_dim, depth, block_k).tile)
+
+
+def _gqa_decode_kernel(len_ref, at_ref, first_ref, slot_ref, layer_ref,
+                       q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref, ko_ref,
+                       vo_ref, kbuf_ref, vbuf_ref, sem_ref, acc_ref, m_ref,
+                       l_ref, *, sm_scale, tile, part, buffers):
+    i = pl.program_id(0)
+    length, at = len_ref[i], at_ref[i]
+    layer = layer_ref[0]
+    begin, end = first_ref[i], first_ref[i + 1]
+    visits = first_ref[pl.num_programs(0)]
+    parts = tile // part
+    kv_heads = q_ref.shape[0]
+    sides = ((k_hbm, kbuf_ref), (v_hbm, vbuf_ref))
+
+    def copies(v, j, slot=0, column=0):
+        return [pltpu.make_async_copy(
+            hbm.at[layer, slot, :, :, pl.ds(column + j * part, part)],
+            buf.at[v % buffers, :, :, pl.ds(j * part, part)],
+            sem_ref.at[n, v % buffers, j])
+            for n, (hbm, buf) in enumerate(sides)]
+
+    def live_parts(v, slot):
+        left = len_ref[slot] - (v - first_ref[slot]) * tile
+        return jnp.minimum((left + part - 1) // part, parts)
+
+    def fetch(v):
+        @pl.when(v < visits)
+        def _start():
+            slot = slot_ref[v]
+            column = pl.multiple_of((v - first_ref[slot]) * tile, tile)
+            live = live_parts(v, slot)
+            for j in range(parts):
+                @pl.when(j < live)
+                def _part():
+                    for c in copies(v, j, slot, column):
+                        c.start()
+
+    @pl.when(i == 0)
+    def _prime():
+        # no part of a buffer is ever read before something was put there
+        kbuf_ref[...] = jnp.zeros_like(kbuf_ref)
+        vbuf_ref[...] = jnp.zeros_like(vbuf_ref)
+        for v in range(buffers - 1):
+            fetch(v)
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def visit(v, last):
+        fetch(v + buffers - 1)
+        live = live_parts(v, i) if last else None
+        for j in range(parts):
+            for c in copies(v, j):
+                if last:
+                    pl.when(j < live)(c.wait)
+                else:
+                    c.wait()
+        kbuf, vbuf = kbuf_ref.at[v % buffers], vbuf_ref.at[v % buffers]
+        column = (v - begin) * tile
+
+        @pl.when((at >= column) & (at < column + tile))
+        def _write():
+            # this step's column into the 128 lanes around it: the slot's
+            # own lane of its 128 slots' columns, spread over the lanes
+            around = pl.ds(pl.multiple_of(
+                (at - column) // _LANES * _LANES, _LANES), _LANES)
+            lane = jax.lax.broadcasted_iota(jnp.int32, ko_ref.shape[1:], 1)
+            for new_ref, buf, out_ref in ((kn_ref, kbuf, ko_ref),
+                                          (vn_ref, vbuf, vo_ref)):
+                for h in range(kv_heads):
+                    new = jnp.where(lane == i % _LANES,
+                                    new_ref[h].astype(jnp.float32), 0.0)
+                    lanes = jnp.where(
+                        lane == at % _LANES,
+                        new.sum(axis=1, keepdims=True).astype(out_ref.dtype),
+                        buf[h, :, around])
+                    buf[h, :, around] = lanes
+                    out_ref[h] = lanes
+
+        for h in range(kv_heads):
+            k, v_blk = kbuf[h], vbuf[h]                    # [D, tile]
+            s = jax.lax.dot_general(
+                q_ref[h], k, _NN,
+                preferred_element_type=jnp.float32) * sm_scale
+            if last:
+                k_pos = column + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 1)
+                s = jnp.where(k_pos < length, s, NEG_INF)  # [group, tile]
+            m_prev = m_ref[h, :, 0:1]
+            m_cur = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_cur)
+            p = jnp.exp(s - m_cur)
+            l_ref[h] = jnp.broadcast_to(
+                l_ref[h, :, 0:1] * alpha + p.sum(axis=1, keepdims=True),
+                l_ref.shape[1:])
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                p.astype(v_blk.dtype), v_blk, _NT,
+                preferred_element_type=jnp.float32)        # [group, D]
+            m_ref[h] = jnp.broadcast_to(m_cur, m_ref.shape[1:])
+
+    jax.lax.fori_loop(begin, end - 1,
+                      lambda v, _: visit(v, last=False), None)
+    visit(end - 1, last=True)
+    o_ref[...] = (acc_ref[...] / l_ref[:, :, 0:1]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(10, 11), inline=True)
+def _gqa_decode_call(lengths, at, first, slot, layer, q, k_new, v_new,
+                     k_cache, v_cache, sm_scale, tiling):
+    # jitted so that the layers of one depth share one trace of the
+    # kernel (the layer is data), inlined so that the caller's program
+    # holds the call itself, aliases and all
+    s, kvh, group, d = q.shape
+    tile, part, buffers = tiling
+
+    def per_slot(i, *_):
+        return i, 0, 0, 0
+
+    def columns(i, *_):
+        return i // _LANES, 0, 0, 0
+
+    def written(i, lens, at, first, slot, layer):
+        return layer[0], i, 0, 0, at[i] // _LANES
+
+    lanes = _vmem_spec((None, None, kvh, d, _LANES), written)
+    call = pl.pallas_call(
+        functools.partial(_gqa_decode_kernel, sm_scale=sm_scale, tile=tile,
+                          part=part, buffers=buffers),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(s,),
+            in_specs=[
+                _vmem_spec((None, kvh, group, d), per_slot),
+                _vmem_spec((None, kvh, d, _LANES), columns),
+                _vmem_spec((None, kvh, d, _LANES), columns),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[_vmem_spec((None, kvh, group, d), per_slot),
+                       lanes, lanes],
+            scratch_shapes=[
+                pltpu.VMEM((buffers, kvh, d, tile), k_cache.dtype),
+                pltpu.VMEM((buffers, kvh, d, tile), v_cache.dtype),
+                pltpu.SemaphoreType.DMA((2, buffers, tile // part)),
+                _scratch((kvh, group, d)), _scratch((kvh, group, _LANES)),
+                _scratch((kvh, group, _LANES))]),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
+                   jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)],
+        # operand numbers count the scalar-prefetch arguments
+        input_output_aliases={8: 1, 9: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret(),
+        name="gqa_decode",
+    )
+    with jax.named_scope("gqa_decode"):
+        return call(lengths, at, first, slot, layer, q, k_new, v_new,
+                    k_cache, v_cache)
+
+
+def gqa_decode(q, k_new, v_new, k_cache, v_cache, layer, at, lengths,
+               walk=None, sm_scale=None, block_k=None):
+    """Write each slot's new column of K and V, then every query head of
+    every slot against its head of the slot's K and V up to its live
+    length, read where they lie.
+
+    q: [S, H, 1, D]; k_new, v_new: [S, KVH, D], this step's columns;
+    k_cache/v_cache: the resident caches [L, S, KVH, D, T], T a multiple
+    of 128 and H of KVH (query head h reads K/V head h // (H / KVH), K
+    and V never repeated; H == KVH is plain multi-head attention);
+    layer: int32 scalar, traced or not; at: int32 [S], the column each
+    slot writes, inside [0, T); lengths: int32 [S], the leading columns
+    of the slot that are live once the column is written, each in
+    (at, T] (a ring that has wrapped gives its whole depth, and writes
+    anywhere in it); walk: `gqa_walk(lengths, KVH, D, T)` where the
+    caller has it already (the same for every layer of one depth).
+    Returns (o [S, H, 1, D] in q's type, float32 scores and
+    accumulation; the caches with slot s's column at[s] of `layer`
+    written and nothing else changed: they are aliased to the results,
+    and only the 128 lanes around each column are written)."""
+    s, h, q_len, d = q.shape
+    if q_len != 1:
+        raise ValueError(f"gqa_decode needs q_len == 1, got {q_len}")
+    kvh, t = k_cache.shape[2], k_cache.shape[-1]
+    if k_cache.shape[1:] != (s, kvh, d, t) or h % kvh \
+            or v_cache.shape != k_cache.shape:
+        raise ValueError(
+            f"resident caches must be [L, {s}, KVH, {d}, T] with KVH "
+            f"dividing {h}, got {k_cache.shape} and {v_cache.shape}")
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d)
+    tiling = gqa_tiling(kvh, d, t, block_k)
+    lengths = jnp.asarray(lengths, jnp.int32).reshape(s)
+    first, slot = walk if walk is not None else _visits(
+        lengths, t, tiling.tile)
+
+    def side_by_side(new, dtype):
+        # the slots' columns side by side, 128 slots a block: a slot's
+        # column is one lane of its block
+        blocks = -(-s // _LANES)
+        new = jnp.pad(new.astype(dtype),
+                      ((0, blocks * _LANES - s), (0, 0), (0, 0)))
+        return new.reshape(blocks, _LANES, kvh, d).transpose(0, 2, 3, 1)
+
+    o, k_cache, v_cache = _gqa_decode_call(
+        lengths, jnp.asarray(at, jnp.int32).reshape(s), first, slot,
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        q.reshape(s, kvh, h // kvh, d), side_by_side(k_new, k_cache.dtype),
+        side_by_side(v_new, v_cache.dtype), k_cache, v_cache,
+        float(sm_scale), tiling)
+    return o.reshape(s, h, 1, d), k_cache, v_cache
 
 
 # --------------------------------------------------------------------------

@@ -14,8 +14,9 @@ broadcasting the latent to the heads would read it heads times).
 `mla_decode` writes this step's column of every slot and attends, in one
 call.  **The walk has as many steps as the slots have live tiles.**  A
 visit is one (slot, tile) pair that holds a live position; `_visits`
-lists them in XLA from the lengths (each slot's first visit, the slot of
-each visit), and the tables are scalar-prefetched.  The grid is
+(flash_attention.py: `gqa_decode` walks by the same tables) lists them
+in XLA from the lengths (each slot's first visit, the slot of each
+visit), and the tables are scalar-prefetched.  The grid is
 `(slots,)`: a grid step brings the slot's queries and takes its result
 through BlockSpecs, and its body loops over the slot's visits only.  The
 latent stays where it lies (`pl.ANY`) and the kernel copies a visit's
@@ -47,7 +48,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .backend import interpret
-from .flash_attention import NEG_INF, _LANES, _scratch, _vmem_spec
+from .flash_attention import (NEG_INF, _LANES, _scratch, _visits,
+                              _vmem_spec)
 
 # tiles in VMEM: the one computed and those on their way
 _BUFFERS = 4
@@ -89,24 +91,6 @@ def mla_tiling(width, depth, block_k=None):
         raise ValueError(f"cache depth {depth} must be a multiple of the "
                          f"tile {tile}, and that of {_LANES}")
     return MlaTiling(tile, _LANES if tile % (2 * _LANES) else 2 * _LANES)
-
-
-def tiles_walked(lengths, tile):
-    """Visits of one `mla_decode` call over slots of these lengths."""
-    return sum(-(-int(n) // tile) for n in lengths)
-
-
-def _visits(lengths, depth, tile):
-    """lengths int32 [S], each in [1, depth] -> (first visit of each slot
-    and, last, the number of visits [S + 1]; slot of each visit
-    [S * depth / tile], entries past the last visit never read)."""
-    s = lengths.shape[0]
-    ends = jnp.cumsum((lengths + tile - 1) // tile)
-    v = jnp.arange(s * (depth // tile), dtype=jnp.int32)
-    # the slots whose visits all lie before v (a search would be a loop)
-    slot = jnp.minimum((ends[None, :] <= v[:, None]).sum(axis=1), s - 1)
-    first = jnp.concatenate([jnp.zeros(1, ends.dtype), ends])
-    return first.astype(jnp.int32), slot.astype(jnp.int32)
 
 
 def _mla_kernel(len_ref, first_ref, slot_ref, ql_ref, qr_ref, new_ref,

@@ -37,9 +37,11 @@ writes column pos mod window and attends min(pos + 1, window) columns.
 Rotary phases are in the stored keys, so the order of a ring's columns
 does not matter to the softmax.  The `heads / kv_heads` query heads of a
 K/V head read its columns where they lie (`kernels/attention.py`
-`resident_decode_attention`: the Pallas calls `kv_append` and
-`gqa_decode` on the TPU; `flash_fwd` with a window and grouped heads in
-the prefill): K and V are never repeated over the query heads.
+`resident_decode_attention`: on the TPU the one Pallas call `gqa_decode`
+a layer, which walks each slot's live tiles and writes the step's column
+into the tile it reads, by visit tables built once a step for the full
+caches and once for the rings; `flash_fwd` with a window and grouped
+heads in the prefill): K and V are never repeated over the query heads.
 
 Types: weights, activations (the residual stream too), K, V and the
 caches are `cfg.dtype` (bfloat16 as served); norms, router scores,
@@ -132,20 +134,45 @@ class AfmoeCfg(NamedTuple):
     cache_kind = ("kv [full layers, slots, kv_heads, head_dim, max_len] + "
                   "ring [window layers, slots, kv_heads, head_dim, window]")
 
+    def _caches(self, max_len):
+        """(name, kind of layer, depth) of the two caches."""
+        return (("full", FULL, max_len),
+                ("window", WINDOW, min(self.sliding_window, max_len)))
+
     def cache_arrays(self, slots, max_len):
         """K and V of the full layers, as deep as a request may grow, and
         of the window layers, a ring as deep as the window (no deeper
         than a request may grow); a kind of layer the model lacks has no
         array."""
         out = {}
-        for name, kind, depth in (
-                ("full", FULL, max_len),
-                ("window", WINDOW, min(self.sliding_window, max_len))):
+        for name, kind, depth in self._caches(max_len):
             shape = (self.layers_of(kind), slots, self.num_kv_heads,
                      self.head_dim, depth)
             if shape[0]:
                 out["k_" + name] = jnp.zeros(shape, self.dtype)
                 out["v_" + name] = jnp.zeros(shape, self.dtype)
+        return out
+
+    def cache_walk(self, lengths, slots, max_len):
+        """What `gqa_decode` walks in one full layer and in one ring of
+        a decode step whose active slots hold `lengths` cached
+        positions, the step's own among them: `full_tiles` and
+        `window_tiles`, beside the `full_grid` and `window_grid` of
+        tiles that a rectangle over every slot's whole depth holds.
+        Nothing for a cache whose shape the kernel does not tile
+        (`kernels/attention.py` `_decode_takes_kernel`)."""
+        from ..kernels.attention import _decode_takes_kernel
+        from ..kernels.flash_attention import gqa_tiling, tiles_walked
+
+        out = {}
+        for name, kind, depth in self._caches(max_len):
+            if not self.layers_of(kind) or not _decode_takes_kernel(
+                    depth, self.head_dim, use_flash=True):
+                continue
+            tile = gqa_tiling(self.num_kv_heads, self.head_dim, depth).tile
+            out[name + "_tiles"] = tiles_walked(
+                [min(n, depth) for n in lengths], tile)
+            out[name + "_grid"] = slots * (depth // tile)
         return out
 
     def prefill(self, trees, cache, prompt, true_len, slot):
@@ -283,12 +310,17 @@ def _decode(cfg, trees, cache, token, pos):
     hidden [S, H], counters: `expert_counts` int32 [held], and
     `cache_reads`, the cached positions each slot's step reads in one
     full layer and in one ring, int32 [S] each)."""
-    from ..kernels.attention import resident_decode_attention
+    from ..kernels.attention import (resident_decode_attention,
+                                     resident_decode_walk)
 
     cache = dict(cache)
     x = _embed(cfg, trees, token)
     counts = jnp.zeros(cfg.experts_held, jnp.int32)
     seen = {WINDOW: 0, FULL: 0}
+    # the kernel's visit tables follow from the positions alone: once a
+    # step for each depth, not once a layer
+    walks = {name: resident_decode_walk(pos, cache["k_" + name])
+             for name in ("full", "window") if "k_" + name in cache}
     for lp, kind in zip(trees["layers"], cfg.layer_types):
         name = "window" if kind == WINDOW else "full"
         q, k, v, gate = _projections(
@@ -297,7 +329,7 @@ def _decode(cfg, trees, cache, token, pos):
             resident_decode_attention(
                 q[:, :, None], k[:, :, None], v[:, :, None],
                 cache["k_" + name], cache["v_" + name], seen[kind], pos,
-                ring=kind == WINDOW)
+                ring=kind == WINDOW, walk=walks[name])
         seen[kind] += 1
         x, counts = _after_attention(
             cfg, lp, x, o.reshape(o.shape[0], -1), gate, counts)

@@ -128,7 +128,8 @@ class K2Cfg(NamedTuple):
         that a rectangle over every slot's whole depth holds.  Nothing
         where the kernel does not tile the latent (`kernels/attention.py`
         `resident_mla_attention`)."""
-        from ..kernels.mla import mla_tiling, tiles_walked
+        from ..kernels.flash_attention import tiles_walked
+        from ..kernels.mla import mla_tiling
 
         if max_len % 128 or self.kv_lora_rank % 128:
             return {}

@@ -50,7 +50,8 @@ over the slots that were active in it).  A model whose decode kernel
 walks its cache in tiles may give `cache_walk(lengths, slots, max_len)`
 -> {name: count} of one layer of one decode step (`models/kimi_k2.py`:
 `latent_tiles` walked of the `latent_grid` a rectangle over every slot
-would hold); the engine calls it on the host with the lengths its
+would hold; `models/afmoe.py`: `full_tiles` of `full_grid` in a full
+layer, `window_tiles` of `window_grid` in a ring); the engine calls it on the host with the lengths its
 active slots had, puts the counts on `engine.decode_wait` and their
 totals under `summary()["decode"]["cache"]`.  Where the model keeps a
 state the engine counts its traffic itself, on the host, from what the
@@ -998,7 +999,8 @@ class DecodeEngine:
         cached positions read in one layer of each (`live_full` and
         `live_window` of `models/afmoe.py`); a decode step also what the
         model's `cache_walk` counts (`latent_tiles` and `latent_grid`
-        of `models/kimi_k2.py`); where the model keeps a state, both
+        of `models/kimi_k2.py`; `full_tiles`, `full_grid`,
+        `window_tiles`, `window_grid` of `models/afmoe.py`); where the model keeps a state, both
         gain `state_bytes` and a prefill its `chunks`.  What is known
         only once
         the answer is in (`turnaround_s`, the model's counters) is in

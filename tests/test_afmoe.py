@@ -330,10 +330,12 @@ def _resident(rng, layers, slots, kvh, d, depth):
     (True, [0, 39, 127, 128, 255, 1000])])
 def test_ring_decode_kernels_equal_their_xla_mathematics(monkeypatch, ring,
                                                          pos):
-    """`kv_append` + `gqa_decode`, interpreted, against the XLA path of
-    the same call and against numpy: 8 query heads over 2 K/V heads, a
-    full cache 256 deep and a ring 128 deep at positions before and
-    after it wraps."""
+    """`resident_decode_attention` through `gqa_decode` (interpreted,
+    at the tile the tiling names; tests/test_resident_kv_cache.py walks
+    the kernel itself over several tiles) against the XLA path of the
+    same call and against numpy: 8 query heads over 2 K/V heads, a full
+    cache 256 deep and a ring 128 deep at positions before and after it
+    wraps."""
     rng = np.random.default_rng(9)
     s, depth = len(pos), 128 if ring else 256
     q, k_new, v_new = (_rand(rng, (s, h, 1, 64)) for h in (8, 2, 2))
@@ -404,10 +406,11 @@ def test_equal_heads_and_no_ring_give_the_parents_result_bit_for_bit(
 
 def test_engine_through_the_kernels_serves_the_references_tokens(
         monkeypatch):
-    """The engine's decode step on `kv_append` and `gqa_decode`
-    (interpreted) at shapes the kernels tile (heads of 64, a window of
-    128 in a max_len of 256): still the reference's tokens, across the
-    ring's wrap."""
+    """The engine's decode step on `gqa_decode` (interpreted) at shapes
+    the kernel tiles (heads of 64, a window of 128 in a max_len of 256):
+    one call a layer and no `kv_append`, the visit tables built once for
+    the full cache and once for the rings, not once a layer; still the
+    reference's tokens, across the ring's wrap."""
     monkeypatch.setenv("PADDLE_TPU_FORCE_FLASH_DECODE", "1")
     toy = Toy(max_len=256, head_dim=64, sliding_window=128,
               num_hidden_layers=4, layer_types=_toy_cfg()["layer_types"][:4],
@@ -417,8 +420,9 @@ def test_engine_through_the_kernels_serves_the_references_tokens(
         toy.params.trees, jax.eval_shape(
             lambda: toy.acfg.cache_arrays(1, 256)),
         np.zeros(1, np.int32), np.zeros(1, np.int32)))
-    assert step.count("name=kv_append") == 4
     assert step.count("name=gqa_decode") == 4
+    assert "name=kv_append" not in step
+    assert step.count("name=cumsum") == 2
     rng = np.random.default_rng(11)
     prompts = [rng.integers(0, toy.acfg.vocab_size, n).astype(np.int32)
                for n in (120, 9)]

@@ -106,6 +106,13 @@ def test_afmoe_phase_at_the_rehearsal_size():
     assert r["tokens_equal_to_forward"] == "48/48"
     assert [a["depth"] for a in r["cache"]["arrays"]] == [256, 256, 128, 128]
     assert r["experts"]["tokens_total"] > 0
+    # the kernel alone on both caches, at the tiles `gqa_tiling` names:
+    # one of 256 a slot of the full cache, one of 128 of the ring
+    for name, walked in (("full", 5), ("ring", 5)):
+        k = r[f"gqa_decode_{name}"]
+        assert k["written_column"] == "exact" and k["rel_to_max"] < 1e-4
+        assert (k["tiles_walked"], k["tiles_of_the_grid"]) == (walked, 5)
+        assert k["ms_a_call"] > 0
 
 
 def test_brumby_phase_at_the_rehearsal_size():

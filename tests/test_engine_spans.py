@@ -221,6 +221,65 @@ def test_a_models_state_traffic_rides_the_wait_spans(traced):
     assert cache["chunks"] == 2
 
 
+def test_a_grouped_models_walk_rides_the_decode_wait_spans(traced):
+    """A model with window and full layers whose caches the decode
+    kernel tiles (`models/afmoe.py`; heads of 64, a full cache 384 deep
+    in tiles of 128 beside a ring of one tile of 128, as `gqa_tiling`
+    names them): every `engine.decode_wait` carries `full_tiles` of
+    `full_grid` and `window_tiles` of `window_grid`, the visits of one
+    layer of each kind by the lengths the host holds, and the summary
+    their totals; the second request crosses into its second tile of the
+    full cache on the way."""
+    from paddle_tpu.kernels.flash_attention import gqa_tiling
+    from paddle_tpu.models import afmoe
+
+    cfg = afmoe.AfmoeCfg.from_hf(dict(
+        vocab_size=97, hidden_size=32, num_hidden_layers=2,
+        num_dense_layers=1,
+        layer_types=["sliding_attention", "full_attention"],
+        num_attention_heads=2, num_key_value_heads=1, head_dim=64,
+        sliding_window=128, intermediate_size=48, moe_intermediate_size=16,
+        num_experts=2, num_experts_per_tok=1, route_scale=1.0,
+        rms_norm_eps=1e-6, rope_theta=1e4, max_position_embeddings=384,
+        dtype="float32"))
+    assert gqa_tiling(1, 64, 384).tile == gqa_tiling(1, 64, 128).tile == 128
+    assert cfg.cache_walk([1, 128, 129, 384], 4, 384) == {
+        "full_tiles": 1 + 1 + 2 + 3, "full_grid": 4 * 3,
+        "window_tiles": 4, "window_grid": 4}
+    # depths the kernel does not tile are not walked
+    assert cfg.cache_walk([1, 50], 4, 96) == {}
+    params = afmoe.AfmoeParams.from_flat(
+        cfg, afmoe.init_params(cfg, jax.random.PRNGKey(0)))
+    eng = DecodeEngine(params, config=DecodeConfig(
+        slots=2, max_len=384, buckets=(16, 128), label="spans_walk"),
+        auto_start=False)
+    rng = np.random.default_rng(0)
+    sizes = (12, 126)
+
+    def body():
+        futs = [eng.submit(rng.integers(0, 97, n), max_new_tokens=5)
+                for n in sizes]
+        while not all(f.done() for f in futs):
+            eng.step()
+
+    traced(body)
+    summary = eng.summary()["decode"]
+    eng.close()
+    steps = [a for n, _, _, a in profiler.spans("engine.decode_wait")]
+    # a request's first token is its prefill's; decode step j reads its
+    # prompt and j tokens
+    want = [sum(-(-(n + j) // 128) for n in sizes) for j in range(1, 5)]
+    assert want == [2, 2, 3, 3]
+    assert [a["full_tiles"] for a in steps] == want
+    assert [a["window_tiles"] for a in steps] == [2] * 4
+    assert {(a["full_grid"], a["window_grid"]) for a in steps} == {(6, 2)}
+    assert all(type(a["full_tiles"]) is int for a in steps)
+    cache = summary["cache"]
+    assert (cache["full_tiles"], cache["full_grid"]) == (sum(want), 6 * 4)
+    assert (cache["window_tiles"], cache["window_grid"]) == (2 * 4, 2 * 4)
+    assert summary["decode_steps"] == len(want)
+
+
 def test_state_traffic_is_counted_in_python_ints():
     """The published depth's step (16 slots x 40 layers of 34.6 MB each
     way) passes 2**31: the engine counts on the host, in Python ints."""
@@ -522,8 +581,7 @@ def _kernel_jaxprs():
                                                     flash_attention_fwd,
                                                     flash_decode,
                                                     flash_decode_resident,
-                                                    gqa_decode_resident,
-                                                    kv_append)
+                                                    gqa_decode, kv_append)
     from paddle_tpu.kernels.layer_norm import layer_norm_pallas
     from paddle_tpu.kernels.topk_threshold import dgc_topk_mask_pallas
 
@@ -553,9 +611,9 @@ def _kernel_jaxprs():
             jax.ShapeDtypeStruct((2, 2, 1, 64), f32), cache, cache, new,
             new, layer, per_slot)),
         # grouped queries over the same cache, and a windowed prefill
-        "grouped": str(jax.make_jaxpr(gqa_decode_resident)(
-            jax.ShapeDtypeStruct((2, 8, 1, 64), f32), cache, cache, layer,
-            per_slot)),
+        "grouped": str(jax.make_jaxpr(gqa_decode)(
+            jax.ShapeDtypeStruct((2, 8, 1, 64), f32), new, new, cache,
+            cache, layer, per_slot, per_slot)),
         "windowed": str(jax.make_jaxpr(
             lambda q, k, v: flash_attention_fwd(q, k, v, window=128))(
             jax.ShapeDtypeStruct((1, 4, 256, 64), f32),
@@ -637,3 +695,10 @@ def test_idle_split_attributes_gaps_to_phases():
     assert rows["engine.decode_host"] == (1, 5, 3)
     assert rows["engine.step outside its phases"] == (1, 2, 2)
     assert round(outside_s * 1e9) == 15   # 0-10 and 95-100
+    # the walk's counters of the summary's `cache`, a pair a name
+    assert tool.cache_walk({
+        "kind": "kv", "bytes": 1, "arrays": [], "full_tiles": 9,
+        "full_grid": 19, "window_tiles": 4, "window_grid": 4,
+        "state_bytes": 7}) == {"full": (9, 19), "window": (4, 4)}
+    assert tool.cache_walk({"latent_tiles": 5, "latent_grid": 8}) \
+        == {"latent": (5, 8)}

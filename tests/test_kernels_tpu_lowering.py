@@ -510,22 +510,27 @@ AF_CACHE_BYTES = 2 * (3 * AF_RING_ELEMS
 
 def _afmoe_cases():
     from paddle_tpu.kernels.flash_attention import (flash_attention_fwd,
-                                                    gqa_decode_resident)
+                                                    gqa_decode)
 
-    # the cell's caches (BENCHMARK.json, trinity-mini): 64 slots, 3 full
-    # layers 9,728 deep, 9 rings of 2,048
-    scalar, per_slot = ((), jnp.int32), ((64,), jnp.int32)
-    q = ((64, AF_HEADS, 1, AF_DIM), BF16)
-    new = ((64, AF_KVH, AF_DIM), BF16)
-    out = {}
-    for name, layers, depth in (("full", 3, 9728), ("ring", 9, 2048)):
-        cache = ((layers, 64, AF_KVH, AF_DIM, depth), BF16)
-        out[f"gqa_decode_{name}"] = (
-            gqa_decode_resident, [q, cache, cache, scalar, per_slot],
-            ["gqa_decode"])
-        out[f"kv_append_{name}"] = (
-            lambda *a: kv_append(*a)[0],
-            [cache, cache, new, new, scalar, per_slot], ["kv_append"])
+    def step(layers, slots, kvh, heads, d, depth):
+        # the queries, this step's columns, the caches, the column each
+        # slot writes and its live length
+        cache = ((layers, slots, kvh, d, depth), BF16)
+        new, per_slot = ((slots, kvh, d), BF16), ((slots,), jnp.int32)
+        return (lambda q, kn, vn, kc, vc, at, live: gqa_decode(
+                    q, kn, vn, kc, vc, 1, at, live),
+                [((slots, heads, 1, d), BF16), new, new, cache, cache,
+                 per_slot, per_slot], ["gqa_decode"])
+
+    out = {
+        # the cell's caches (BENCHMARK.json, trinity-mini): 64 slots, 3
+        # full layers 9,728 deep, 9 rings of 2,048; chip_smoke.py's; and
+        # one K/V head a query head at the GPT cell's widths
+        "gqa_decode_full": step(3, 64, AF_KVH, AF_HEADS, AF_DIM, 9728),
+        "gqa_decode_ring": step(9, 64, AF_KVH, AF_HEADS, AF_DIM, 2048),
+        "gqa_decode_smoke": step(2, 8, AF_KVH, AF_HEADS, AF_DIM, 4096),
+        "gqa_decode_group1": step(2, 64, 16, 16, 64, 1024),
+    }
     for seq in (2048, 8192):
         for window in (None, AF_WINDOW):
             out[f"flash_fwd_{seq}_{window}"] = (
@@ -537,16 +542,23 @@ def _afmoe_cases():
 
 
 @pytest.mark.parametrize("name", [
-    "gqa_decode_full", "gqa_decode_ring", "kv_append_full",
-    "kv_append_ring", "flash_fwd_2048_None", "flash_fwd_2048_2048",
+    "gqa_decode_full", "gqa_decode_ring", "gqa_decode_smoke",
+    "gqa_decode_group1", "flash_fwd_2048_None", "flash_fwd_2048_2048",
     "flash_fwd_8192_None", "flash_fwd_8192_2048"])
 def test_afmoe_kernel_compiles_for_v5e(compiled_kernels, v5e, name):
     fn, args, calls = _afmoe_cases()[name]
     one = jax.sharding.SingleDeviceSharding(v5e[0])
     avals = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in args]
-    text = jax.jit(fn).trace(*avals).lower(
-        lowering_platforms=("tpu",)).compile().as_text()
-    assert sorted(set(_mosaic_calls(text))) == calls
+    donated = [i for i, (s, _) in enumerate(args) if len(s) == 5]
+    compiled = jax.jit(fn, donate_argnums=donated).trace(*avals).lower(
+        lowering_platforms=("tpu",)).compile()
+    assert sorted(set(_mosaic_calls(compiled.as_text()))) == calls
+    if donated:
+        # both caches are written where they lie: no second one
+        caches = 2 * math.prod(args[donated[0]][0]) * 2
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= caches
+        assert mem.temp_size_in_bytes < caches // 8
 
 
 @pytest.fixture(scope="module")
@@ -656,9 +668,8 @@ def test_afmoe_program_holds_one_copy_of_each_cache(afmoe_programs,
 
 def test_afmoe_decode_step_names_its_calls(afmoe_programs):
     calls = _mosaic_calls(afmoe_programs["decode_step"].as_text())
-    assert sorted(set(calls)) == ["gqa_decode", "kv_append",
-                                  "moe_grouped_mm"]
-    assert calls.count("gqa_decode") == calls.count("kv_append") == 4
+    assert sorted(set(calls)) == ["gqa_decode", "moe_grouped_mm"]
+    assert calls.count("gqa_decode") == 4
     fills = _mosaic_calls(afmoe_programs["prefill_b4096"].as_text())
     assert fills.count("flash_fwd") == 4
 

@@ -22,7 +22,7 @@ import pytest
 
 from paddle_tpu import monitor, profiler
 from paddle_tpu.distributed.moe import routed_experts, sigmoid_top_k
-from paddle_tpu.kernels import grouped_mm, mla
+from paddle_tpu.kernels import flash_attention, grouped_mm, mla
 from paddle_tpu.kernels.attention import resident_mla_attention
 from paddle_tpu.models import kimi_k2
 from paddle_tpu.models.gpt import GPT, GPTConfig
@@ -526,7 +526,7 @@ def test_mla_tiling_over_the_shapes_in_use(width, depth, tile, part):
     assert mla.mla_tiling(width, depth, block_k=128) == (128, 128)
     lengths = [1, tile - 1, tile, min(tile + 1, depth), depth]
     tiles = [1, 1, 1, min(2, depth // tile), depth // tile]
-    assert mla.tiles_walked(lengths, tile) == sum(tiles)
+    assert flash_attention.tiles_walked(lengths, tile) == sum(tiles)
     first, slot = mla._visits(jnp.asarray(lengths, jnp.int32), depth, tile)
     assert list(np.asarray(first)) == [0] + list(np.cumsum(tiles))
     assert list(np.asarray(slot)[:sum(tiles)]) == \

@@ -15,7 +15,11 @@ works; idle time under the others is the device waiting for the host.
 Beside the table stands the engine's `lookahead` counter at the
 window's close (`DecodeStats.summary()["decode"]["lookahead"]`): decode
 steps, those enqueued while the step before them was unanswered, and
-admissions in time and late.
+admissions in time and late; and, where the model's decode kernel walks
+its cache in tiles (`cfg.cache_walk`: `latent_tiles` of `latent_grid`
+for `models/kimi_k2.py`, `full_tiles` of `full_grid` and `window_tiles`
+of `window_grid` for `models/afmoe.py`), the tiles walked of the
+rectangle's, summed over the run's decode steps.
 
 Also printed: the spread of `start_ns - pc_ns` over the spans (how well
 `profiler.trace_clock_offset_ns` ties `perf_counter_ns` to the trace's
@@ -139,6 +143,14 @@ def split(spans, ops, window):
     return table, idle / 1e9, (idle - steps[2]) / 1e9
 
 
+def cache_walk(cache):
+    """{name: (tiles walked, tiles of the rectangle)} of the counts a
+    model's `cache_walk` left in the summary's `cache`."""
+    names = [k.removesuffix("_tiles") for k in cache if k.endswith("_tiles")]
+    return {n: (cache[n + "_tiles"], cache[n + "_grid"]) for n in names
+            if n + "_grid" in cache}
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", default="gpt2-medium.serve-closed-c64")
@@ -162,6 +174,7 @@ def main(argv=None):
     def summary_and_keep(engine):
         out = summary(engine)
         captured["lookahead"] = out["decode"].get("lookahead")
+        captured["walk"] = cache_walk(out["decode"].get("cache", {}))
         return out
 
     trace_reduce.load_xplane = load_and_keep
@@ -188,7 +201,12 @@ def main(argv=None):
               "device": line["device"], "metrics": line["metrics"],
               "breakdown": line.get("breakdown", {}),
               "programs": programs, "spans_in_trace": len(spans),
-              "lookahead": captured.get("lookahead")}
+              "lookahead": captured.get("lookahead"),
+              "cache_walk": captured.get("walk", {})}
+    for name, (tiles, grid) in report["cache_walk"].items():
+        print(f"cache walk: {name}_tiles {tiles} of {name}_grid {grid} "
+              f"({100 * tiles / max(grid, 1):.2f}%), one layer, all decode "
+              f"steps")
     look = report["lookahead"]
     if look:
         print(f"lookahead: {look['ahead']} of {look['steps']} decode steps "
