@@ -175,6 +175,15 @@ class AfmoeCfg(NamedTuple):
             out[name + "_grid"] = slots * (depth // tile)
         return out
 
+    def cache_reads(self, lengths):
+        """The cached positions that requests of `lengths` positions
+        read in one full layer (`live_full`) and in one window layer
+        (`live_window`), summed; on the host, from lengths the engine
+        holds."""
+        return {"live_full": sum(lengths),
+                "live_window": sum(min(n, self.sliding_window)
+                                   for n in lengths)}
+
     def prefill(self, trees, cache, prompt, true_len, slot):
         return _prefill(self, trees, cache, prompt, true_len, slot)
 
@@ -307,9 +316,7 @@ def _embed(cfg, trees, ids):
 
 def _decode(cfg, trees, cache, token, pos):
     """One step of every slot: token [S] at pos [S] -> (cache, final
-    hidden [S, H], counters: `expert_counts` int32 [held], and
-    `cache_reads`, the cached positions each slot's step reads in one
-    full layer and in one ring, int32 [S] each)."""
+    hidden [S, H], counters: `expert_counts` int32 [held])."""
     from ..kernels.attention import (resident_decode_attention,
                                      resident_decode_walk)
 
@@ -334,14 +341,7 @@ def _decode(cfg, trees, cache, token, pos):
         x, counts = _after_attention(
             cfg, lp, x, o.reshape(o.shape[0], -1), gate, counts)
     return cache, rms_norm(cfg, x, trees["final_norm"]), {
-        "expert_counts": counts, "cache_reads": _cache_reads(cfg, pos + 1)}
-
-
-def _cache_reads(cfg, ctx):
-    """Cached positions a request of `ctx` positions reads in one full
-    layer and in one window layer."""
-    return {"live_full": ctx,
-            "live_window": jnp.minimum(ctx, cfg.sliding_window)}
+        "expert_counts": counts}
 
 
 def _forward(cfg, trees, ids, valid):
@@ -428,5 +428,4 @@ def _prefill(cfg, trees, cache, prompt, true_len, slot):
                 block.astype(cache[which + name].dtype), (0, slot, 0, 0, 0))
     h = jax.lax.dynamic_slice(x, (true_len - 1, 0), (1, cfg.hidden_size))
     return cache, rms_norm(cfg, h, trees["final_norm"]), {
-        "expert_counts": counts,
-        "cache_reads": _cache_reads(cfg, true_len)}
+        "expert_counts": counts}

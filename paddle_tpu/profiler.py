@@ -11,6 +11,7 @@ aggregate-table + chrome-trace-export shape.
 """
 
 import contextlib
+import gc
 import json
 import os
 import statistics
@@ -180,7 +181,64 @@ def _note_trace_session(enabled):
             _active["tracing"] = enabled
         if began and not _active["on"]:
             reset_profiler()
+        _hook_gc()
     return enabled
+
+
+# What pauses the process itself: each garbage collection that runs
+# while a session is on becomes a `host.gc` span (`generation`,
+# `collected`) in the store and, under a jax trace, in the trace.  The
+# hook is in `gc.callbacks` only while a session is on (`_hook_gc`: a
+# `start_profiler` session, or a jax session a span site has noted), so
+# a process that never traces runs no code of it.  It runs inside the
+# interpreter's collector, on whatever thread allocated, so it takes no
+# lock: its events go to a list of their own, registered here once, and
+# a collection under way is one slot (the collector does not nest).  A
+# jax session whose start no span has noted yet (`_note_trace_session`)
+# records no collection, since the noting clears the store.
+GC_SPAN = "host.gc"
+_gc_events = []
+_event_lists.append(_gc_events)
+_gc_open = None      # (start_ns, epoch, annotation) of the collection
+
+
+def _on_gc(phase, info):
+    global _gc_open
+    if phase == "start":
+        tracing = _trace_enabled()
+        if not ((tracing and _active["tracing"]) or _active["on"]):
+            return
+        start = time.perf_counter_ns()
+        annotation = None
+        if tracing:
+            annotation = jax.profiler.TraceAnnotation(
+                TRACE_PREFIX + GC_SPAN, pc_ns=start,
+                generation=info["generation"])
+            annotation.__enter__()
+        _gc_open = (start, _active["epoch"], annotation)
+    elif _gc_open is not None:
+        start, epoch, annotation = _gc_open
+        _gc_open = None
+        attrs = {"generation": info["generation"],
+                 "collected": info["collected"]}
+        if annotation is not None:
+            annotation.set_metadata(collected=info["collected"])
+            annotation.__exit__(None, None, None)
+        if epoch == _active["epoch"]:
+            _gc_events.append(_event(
+                GC_SPAN, start, time.perf_counter_ns(),
+                len(getattr(_state, "stack", ())), attrs))
+
+
+def _hook_gc():
+    """Hook `_on_gc` into the collector while a session is on, and
+    take it out when none is."""
+    hooked = _on_gc in gc.callbacks
+    if _active["on"] or _active["tracing"]:
+        if not hooked:
+            gc.callbacks.append(_on_gc)
+    elif hooked:
+        gc.callbacks.remove(_on_gc)
 
 
 def add_span(name, start_ns, end_ns, depth=0):
@@ -263,6 +321,7 @@ def start_profiler(state="All", tracer_option="Default"):
     _active["epoch"] += 1
     _active["owner"] = _caller()
     _active["on"] = True
+    _hook_gc()
 
 
 # Fluid-parity sort keys (profiler.py:196): each maps to the table
@@ -283,6 +342,7 @@ def stop_profiler(sorted_key=None, profile_path="/tmp/profile"):
     whose nesting depth starts again at 0."""
     _active["on"] = False
     _active["owner"] = None
+    _hook_gc()
     _events()            # this thread has a stack
     del _state.stack[:]  # its next session's spans start at depth 0
     if _active["jax_trace"]:

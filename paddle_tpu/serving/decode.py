@@ -40,19 +40,20 @@ and read by key (a model that counts nothing gives an empty one, and its
 program answers with exactly what the engine always fetched):
 `expert_counts` (assignments on each expert held here, summed over the
 expert layers) becomes the `expert_tokens` / `expert_load_max`
-attributes of `engine.*_wait` and `DecodeStats`' running totals;
-`cache_reads`, of a model whose cache arrays differ in depth
-(`models/afmoe.py`: full layers beside window rings), is {name: the
-cached positions the program read in one layer of that kind}, for each
-slot in a decode step and for the prompt in a prefill, and becomes
-attributes of those names on `engine.*_wait` (a decode step's summed
-over the slots that were active in it).  A model whose decode kernel
+attributes of `engine.*_wait` and `DecodeStats`' running totals.
+What a program read of the cache the engine counts on the host, from
+the lengths it holds (nothing is fetched): a model whose cache arrays
+differ in depth (`models/afmoe.py`: full layers beside window rings)
+gives `cache_reads(lengths)` -> {name: the cached positions read in one
+layer of that kind, summed over `lengths`}, which the engine calls with
+the lengths a decode step's active slots had, or a prefill's prompt
+length, and puts on `engine.*_wait`.  A model whose decode kernel
 walks its cache in tiles may give `cache_walk(lengths, slots, max_len)`
 -> {name: count} of one layer of one decode step (`models/kimi_k2.py`:
 `latent_tiles` walked of the `latent_grid` a rectangle over every slot
 would hold; `models/afmoe.py`: `full_tiles` of `full_grid` in a full
-layer, `window_tiles` of `window_grid` in a ring); the engine calls it on the host with the lengths its
-active slots had, puts the counts on `engine.decode_wait` and their
+layer, `window_tiles` of `window_grid` in a ring); the engine calls it
+with the same lengths, puts the counts on `engine.decode_wait` and their
 totals under `summary()["decode"]["cache"]`.  Where the model keeps a
 state the engine counts its traffic itself, on the host, from what the
 seam says of a state: `state_bytes`, the bytes of state a program read
@@ -158,7 +159,13 @@ and resolves queued, resident and in-flight requests exactly once; on
 decode steps, those enqueued while the step before them was still
 unanswered (`ahead`, also on `engine.decode_wait`), and admissions that
 landed behind the step running at their submission (`in_time`) or
-behind a later one (`late`, also on `engine.prefill_wait`).
+behind a later one (`late`, also on `engine.prefill_wait`).  Every
+program's device time is read from its own answers (`_answer`), with no
+profiler: `summary()["decode"]["device"]` holds it by kind (`prefill_s`,
+`decode_s`, `prefill_share`), the share of the positions the prefills
+computed that were padding (`padding_share`), and what the decode steps
+waited behind (`behind_ms`: the prefills between two steps, p50 / p90 /
+p99 / max).
 
 Hardening is the PR-8 stack rewired for token granularity: per-TOKEN
 deadline budgets (TTFT included) feeding the outcome ledger
@@ -327,8 +334,9 @@ class _Flight:
 
     __slots__ = ("op", "meta", "requests", "wd", "stalled", "launched",
                  "done", "state", "results", "error", "launched_t",
-                 "done_t", "slot", "req", "admit_t", "pspan", "late",
-                 "slot_reqs", "kill", "ahead")
+                 "ready_t", "done_t", "device_s", "behind_s", "behind",
+                 "slot", "req", "admit_t", "pspan", "late", "slot_reqs",
+                 "kill", "ahead")
 
     def __init__(self, op, meta, requests, **own):
         self.op = op                      # "prefill" or "decode"
@@ -337,7 +345,10 @@ class _Flight:
         self.launched = threading.Event()  # the launch returned
         self.done = threading.Event()      # the answer is on the host
         self.state = self.results = self.error = None
-        self.launched_t = self.done_t = None   # engine clock, the worker's
+        # engine clock, the worker's: the launch returned; the program's
+        # results were ready on the device; they were on the host
+        self.launched_t = self.ready_t = self.done_t = None
+        self.device_s = None              # `_answer`'s reading
         for k, v in own.items():
             setattr(self, k, v)
 
@@ -454,17 +465,32 @@ def _expert_load(counters):
             "expert_load_max": int(counts.max())}
 
 
-def _cache_walk(cfg, config, slot_reqs, active):
+def _lengths(slot_reqs, active):
+    """The cached positions each slot active in a decode step held: a
+    request's prompt and the tokens it held when the step was answered,
+    the step's own position among them.  From what the host holds:
+    nothing is fetched."""
+    return [r.prompt.size + len(r.tokens)
+            for r, live in zip(slot_reqs, active) if live and r is not None]
+
+
+def _cache_walk(cfg, config, lengths):
     """The span attributes of what a decode step's attention walked, for
-    a model that says so (`cfg.cache_walk`, of the lengths the step's
-    active slots had: a request's prompt and the tokens it held when the
-    step was answered).  From what the host holds: nothing is fetched."""
+    a model that says so (`cfg.cache_walk` of the step's `_lengths`)."""
     walk = getattr(cfg, "cache_walk", None)
     if walk is None:
         return {}
-    return walk([r.prompt.size + len(r.tokens)
-                 for r, live in zip(slot_reqs, active)
-                 if live and r is not None], config.slots, config.max_len)
+    return walk(lengths, config.slots, config.max_len)
+
+
+def _cache_reads(cfg, lengths):
+    """The span attributes of the cached positions a program read in one
+    layer of each kind, for a model whose caches differ in depth
+    (`cfg.cache_reads` of a decode step's `_lengths`, or of a prefill's
+    prompt length alone), summed over the slots.  A model with one cache
+    gives none."""
+    reads = getattr(cfg, "cache_reads", None)
+    return {} if reads is None else reads(lengths)
 
 
 def _state_traffic(slot_state_bytes, counters, active=None):
@@ -480,15 +506,6 @@ def _state_traffic(slot_state_bytes, counters, active=None):
     if "chunks" in counters:
         out["chunks"] = int(counters["chunks"])
     return out
-
-
-def _cache_reads(counters, active=None):
-    """The span attributes of a program's `cache_reads`: under each name
-    the cached positions read, of a prefill's prompt, or summed over the
-    slots `active` in a decode step.  A model with one cache gives
-    none."""
-    return {name: int(v.sum(where=active) if active is not None else v)
-            for name, v in counters.get("cache_reads", {}).items()}
 
 
 # ---------------------------------------------------------------------------
@@ -546,7 +563,10 @@ class DecodeEngine:
         # program (engine clock, the fetching thread's reading)
         self._pace = deque(maxlen=8)
         self._launch_cost = deque(maxlen=8)
-        self._freed_t = float("-inf")
+        self._freed_t = self._ready_t = float("-inf")
+        # the prefills answered since the last decode step: their summed
+        # device time and their number (what the next step waited behind)
+        self._behind = (0.0, 0)
         self._live = set()
         self._rid = 0
         self._closed = False
@@ -870,6 +890,10 @@ class DecodeEngine:
                 flight.state, *results = out
                 flight.launched_t = cfg.clock()
                 flight.launched.set()
+                # the program's end on the device: its first result is
+                # ready (all of them are, together), before the fetch
+                results[0].block_until_ready()
+                flight.ready_t = cfg.clock()
                 flight.results = _on_host(results)
                 flight.done_t = cfg.clock()
             except BaseException as e:  # noqa: BLE001
@@ -929,21 +953,39 @@ class DecodeEngine:
         """Wait for the answer of `flight`, the oldest program in
         flight, and take it off the queue.  Returns the engine clock
         read once the answer is on the host (the time of its tokens),
-        or None when the engine broke instead."""
+        or None when the engine broke instead.
+
+        Reads the program's device time, `flight.device_s`: from when it
+        could start (the program before it ended, or it was launched) to
+        when its results were ready on the device (`ready_t`, before the
+        fetch, whose length differs by program).  The device's queue is
+        FIFO, so the readings of consecutive programs telescope: their
+        sum is the time from the first one's start to the last answer,
+        less only the device's idle time.  The look-ahead's pace keeps
+        its own reading, to the answer on the host (`done_t`).  A decode
+        step also gets `behind_s` and `behind`: the device time and
+        number of the prefills answered since the step before it, which
+        ran between the two."""
         if not self._await(flight, flight.done):
             return None
         self._flights.popleft()
         self.watchdog.untrack(flight.wd)
         self.breaker.note_success()
+        flight.device_s = flight.ready_t - max(self._ready_t,
+                                               flight.launched_t)
         if flight.op == "decode":
             with self._lock:
                 self._steps_answered += 1
-            # the device's time for this step: from when it could start
-            # (the program before it ended, or it was launched) to its
-            # answer
+            # the device's time for this step as the host sees it: from
+            # when it could start to its answer on the host
             self._pace.append(flight.done_t - max(self._freed_t,
                                                   flight.launched_t))
-        self._freed_t = flight.done_t
+            flight.behind_s, flight.behind = self._behind
+            self._behind = (0.0, 0)
+        else:
+            self._behind = (self._behind[0] + flight.device_s,
+                            self._behind[1] + 1)
+        self._freed_t, self._ready_t = flight.done_t, flight.ready_t
         return self.config.clock()
 
     # -- scheduling -----------------------------------------------------
@@ -991,21 +1033,26 @@ class DecodeEngine:
         where it waited for submissions.  In the `*_wait` spans the
         host is blocked (on the device's answer, or listening); all the
         others are the host's own work.  `engine.prefill_wait` carries
-        `bucket`, `slot`, `rid`, `queue_wait_s` (submit to admission),
-        `turnaround_s` (admission to the first token on the host) and
-        `late`; `engine.decode_wait` carries `active` and `ahead`; where
-        the model counts expert assignments, both gain `expert_tokens`
-        and `expert_load_max`, and where its caches differ in depth, the
+        `bucket`, `true_len` (the prompt's length), `slot`, `rid`,
+        `queue_wait_s` (submit to admission), `turnaround_s` (admission
+        to the first token on the host), `late` and `device_s` (the
+        program's device time, `_answer`); `engine.decode_wait` carries
+        `active`, `ahead`, `device_s`, and `behind_s` and `behind`, the
+        device time and number of the prefills that ran since the step
+        before (a resident request's gap between two tokens is
+        `device_s + behind_s` and any idle time); where the model counts
+        expert assignments, both gain `expert_tokens` and
+        `expert_load_max`, and where its caches differ in depth, the
         cached positions read in one layer of each (`live_full` and
         `live_window` of `models/afmoe.py`); a decode step also what the
-        model's `cache_walk` counts (`latent_tiles` and `latent_grid`
-        of `models/kimi_k2.py`; `full_tiles`, `full_grid`,
-        `window_tiles`, `window_grid` of `models/afmoe.py`); where the model keeps a state, both
-        gain `state_bytes` and a prefill its `chunks`.  What is known
-        only once
-        the answer is in (`turnaround_s`, the model's counters) is in
-        `spans()`; the trace's copy of the span was opened before (its
-        `turnaround_s` runs to the launch's return)."""
+        model's `cache_walk` counts (`latent_tiles` and `latent_grid` of
+        `models/kimi_k2.py`; `full_tiles`, `full_grid`, `window_tiles`,
+        `window_grid` of `models/afmoe.py`); where the model keeps a
+        state, both gain `state_bytes` and a prefill its `chunks`.  What
+        is known only once the answer is in (`turnaround_s`, `device_s`,
+        `behind_s`, the counts) is in `spans()`; the trace's copy of the
+        span was opened before (its `turnaround_s` runs to the launch's
+        return)."""
         with self._lock:
             if not self._has_work_locked():
                 return 0                   # nothing to do: no span
@@ -1240,9 +1287,11 @@ class DecodeEngine:
 
     def _collect_prefill(self, flight):
         req, slot = flight.req, flight.slot
+        true_len = req.prompt.size
         with RecordEvent(
-                "engine.prefill_wait", bucket=req.bucket, slot=slot,
-                rid=req.rid, queue_wait_s=flight.admit_t - req.enqueue_t,
+                "engine.prefill_wait", bucket=req.bucket, true_len=true_len,
+                slot=slot, rid=req.rid,
+                queue_wait_s=flight.admit_t - req.enqueue_t,
                 turnaround_s=flight.launched_t - flight.admit_t,
                 late=flight.late) as span:
             # the first token's time, for the stats and the budgets: its
@@ -1253,10 +1302,14 @@ class DecodeEngine:
             first, active, counters = flight.results
             load = _expert_load(counters)
             traffic = _state_traffic(self._slot_state_bytes, counters)
-            span.attrs.update(load, **_cache_reads(counters), **traffic,
-                              turnaround_s=now - flight.admit_t)
+            span.attrs.update(
+                load, **_cache_reads(self.params.cfg, [true_len]),
+                **traffic, turnaround_s=now - flight.admit_t,
+                device_s=flight.device_s)
         self.stats.note_experts(**load)
         self.stats.note_cache_walk(traffic)
+        self.stats.note_device("prefill", flight.device_s,
+                               true_len=true_len, bucket=req.bucket)
         with RecordEvent("engine.prefill_book"):
             self._prefill_book(slot, req, int(first), bool(active),
                                flight.pspan, now)
@@ -1296,14 +1349,18 @@ class DecodeEngine:
                 return False
             tokens, was_active, still, counters = flight.results
             load = _expert_load(counters)
-            walk = _cache_walk(self.params.cfg, self.config,
-                               flight.slot_reqs, was_active)
+            lengths = _lengths(flight.slot_reqs, was_active)
+            walk = _cache_walk(self.params.cfg, self.config, lengths)
             traffic = _state_traffic(self._slot_state_bytes, counters,
                                      was_active)
-            span.attrs.update(load, **_cache_reads(counters, was_active),
-                              **walk, **traffic)
+            span.attrs.update(
+                load, **_cache_reads(self.params.cfg, lengths), **walk,
+                **traffic, device_s=flight.device_s,
+                behind_s=flight.behind_s, behind=flight.behind)
         self.stats.note_experts(**load)
         self.stats.note_cache_walk({**walk, **traffic})
+        self.stats.note_device("decode", flight.device_s,
+                               behind_s=flight.behind_s)
         with RecordEvent("engine.emit"):
             self._emit(flight.slot_reqs, tokens, was_active, still, now)
             self.stats.note_lookahead(flight.ahead)

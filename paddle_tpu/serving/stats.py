@@ -295,6 +295,15 @@ class DecodeStats(ServingStats):
         self.steps_not_ahead = 0
         self.admitted_in_time = 0
         self.admitted_late = 0
+        # the device's time of each program, as the engine reads it from
+        # the answers (decode.py `_answer`), by kind; the prompts'
+        # positions and their buckets'; and what each decode step waited
+        # behind (`behind_s`: the prefills that ran since the step before)
+        self.prefill_device_s = 0.0
+        self.decode_device_s = 0.0
+        self.prefill_positions = 0
+        self.prefill_bucket_positions = 0
+        self._behind = collections.deque(maxlen=_SAMPLE_CAP)
 
     # -- recording ------------------------------------------------------
     def note_prefill(self, ttft_s=None, now=None):
@@ -386,6 +395,19 @@ class DecodeStats(ServingStats):
             else:
                 self.admitted_in_time += 1
 
+    def note_device(self, op, device_s, true_len=0, bucket=0, behind_s=0.0):
+        """One program answered (`op` "prefill" or "decode") and its
+        device time; a prefill's prompt length and bucket, a decode
+        step's `behind_s`."""
+        with self._lock:
+            if op == "prefill":
+                self.prefill_device_s += device_s
+                self.prefill_positions += true_len
+                self.prefill_bucket_positions += bucket
+            else:
+                self.decode_device_s += device_s
+                self._behind.append(behind_s)
+
     def note_token_latency(self, latency_s):
         with self._lock:
             if len(self._tok_lat) == self._tok_lat.maxlen:
@@ -407,6 +429,25 @@ class DecodeStats(ServingStats):
         if dropped:
             out["samples_dropped"] = dropped
         return out
+
+    @staticmethod
+    def _device_summary(prefill_s, decode_s, positions, bucket_positions,
+                        behind):
+        """The `device` block: the programs' device time by kind, the
+        prefills' share of it, the share of the positions they computed
+        that were padding, and what the decode steps waited behind
+        (`behind` sorted, seconds)."""
+        return {
+            "prefill_s": round(prefill_s, 6),
+            "decode_s": round(decode_s, 6),
+            "prefill_share": round(prefill_s / (prefill_s + decode_s), 4),
+            "padding_share": round(1 - positions / bucket_positions, 4)
+            if bucket_positions else None,
+            "behind_ms": {
+                **{f"p{int(q * 100)}": round(
+                    exact_percentile(behind, q) * 1e3, 3)
+                   for q in (0.5, 0.9, 0.99)},
+                "max": round(behind[-1] * 1e3, 3)} if behind else None}
 
     def ttft_samples(self):
         with self._lock:
@@ -440,6 +481,9 @@ class DecodeStats(ServingStats):
                     "programs": self.expert_programs,
                     "tokens_total": self.expert_tokens_total,
                     "load_max": self.expert_load_max}
+            device = (self.prefill_device_s, self.decode_device_s,
+                      self.prefill_positions, self.prefill_bucket_positions)
+            behind = list(self._behind)
             span = (self._last_t - self._first_t
                     if self._first_t is not None
                     and self._last_t is not None else None)
@@ -449,6 +493,8 @@ class DecodeStats(ServingStats):
             tok_dropped = self.tok_lat_dropped
         if span and span > 0:
             out["tokens_per_s"] = round(out["tokens_total"] / span, 2)
+        if device[0] + device[1] > 0:
+            out["device"] = self._device_summary(*device, sorted(behind))
         ttft = self._percentiles(ttft_ring, dropped=ttft_dropped)
         if ttft:
             out["ttft"] = ttft

@@ -41,11 +41,15 @@ class Gate:
 
 
 class _Held:
-    """A program's result whose fetch waits for the gate (the first
-    fetched result of a program waits for all of them)."""
+    """A program's result whose readiness and fetch wait for the gate
+    (the first result of a program waits for all of them)."""
 
     def __init__(self, value, turn):
         self._value, self._turn = value, turn
+
+    def block_until_ready(self):
+        self._turn()
+        return self
 
     def __array__(self, dtype=None, copy=None):
         self._turn()

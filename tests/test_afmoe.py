@@ -233,10 +233,12 @@ def test_ring_columns_after_a_padded_prefill(toy, n):
     for l, (k, v) in enumerate(full):
         np.testing.assert_array_equal(got["k_full"][l, 1, :, :, :n],
                                       k[:, :, :n])
-    # the other slot is untouched, and the prompt's reads are counted
+    # the other slot is untouched; the program answers with its experts'
+    # counts alone (the reads are the host's: `cache_reads`)
     assert not np.asarray(got["k_window"][:, 0]).any()
-    assert int(counters["cache_reads"]["live_full"]) == n
-    assert int(counters["cache_reads"]["live_window"]) == min(n, 16)
+    assert set(counters) == {"expert_counts"}
+    assert toy.acfg.cache_reads([n]) == {"live_full": n,
+                                         "live_window": min(n, 16)}
 
 
 def test_the_rotation_is_hf_rotate_half(toy):
@@ -565,30 +567,55 @@ def test_summary_lists_both_caches(toy):
     assert cache["bytes"] == sum(a["bytes"] for a in cache["arrays"])
 
 
-def test_wait_spans_carry_the_reads_of_each_cache(toy, tmp_path):
-    """`live_full` / `live_window` of every `engine.decode_wait` equal a
-    count kept on the host (each resident request's prompt and tokens
-    but the one the step emits; in a ring no more than the window), and
-    a prefill's those of its prompt."""
-    eng = toy.engine()
+class _DeviceReads(afmoe.AfmoeCfg):
+    """The model as it was before ISSUE 38: its decode step also answers
+    with the cached positions each slot read, `pos + 1`, counted on the
+    device."""
+
+    def decode(self, trees, cache, token, pos, active=None):
+        cache, hidden, counters = super().decode(trees, cache, token, pos,
+                                                 active)
+        return cache, hidden, dict(counters, device_reads=pos + 1)
+
+
+@pytest.mark.parametrize("loop", [False, True], ids=["by_hand", "loop"])
+def test_wait_spans_carry_the_reads_of_each_cache(toy, tmp_path, loop):
+    """`live_full` / `live_window` of every `engine.decode_wait`, which
+    the host counts from the lengths it holds, equal what the program
+    counted on the device before ISSUE 38 (each active slot's `pos + 1`;
+    in a ring no more than the window), step by step, by hand and with
+    the loop thread one step ahead; a prefill's are those of its
+    prompt."""
+    eng = DecodeEngine(
+        afmoe.AfmoeParams(toy.params.trees, _DeviceReads(*toy.acfg)),
+        config=DecodeConfig(slots=2, max_len=toy.acfg.max_seq_len,
+                            buckets=(16, 32, 64), watchdog_stall_s=60.0,
+                            label=f"afmoe_reads_{loop}"),
+        auto_start=loop)
     rng = np.random.default_rng(13)
     prompts = [rng.integers(0, 211, size=n).astype(np.int32)
                for n in (6, 30, 19)]
     want = []
-    emit = eng._emit
+    answer = eng._answer
 
-    def counting(slot_reqs, tokens, was_active, still, now):
-        ctx = [r.prompt.size + len(r.tokens)
-               for i, r in enumerate(slot_reqs)
-               if r is not None and was_active[i]]
-        want.append((sum(ctx), sum(min(c, 16) for c in ctx)))
-        return emit(slot_reqs, tokens, was_active, still, now)
+    def counting(flight):
+        now = answer(flight)
+        if flight.op == "decode":
+            _, was_active, _, counters = flight.results
+            ctx = counters["device_reads"][was_active]
+            want.append((int(ctx.sum()),
+                         int(np.minimum(ctx, 16).sum())))
+        return now
 
-    eng._emit = counting
+    eng._answer = counting
     jax.profiler.start_trace(str(tmp_path))
     try:
         futs = [eng.submit(p, 14) for p in prompts]
-        _drain(eng, futs)
+        if loop:
+            for f in futs:
+                f.result(timeout=120)
+        else:
+            _drain(eng, futs)
     finally:
         jax.profiler.stop_trace()
     summary = eng.summary()["decode"]

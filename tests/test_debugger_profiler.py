@@ -217,7 +217,9 @@ def test_stop_profiler_resets_the_nesting_depth():
     with profiler.RecordEvent("top"):
         pass
     profiler.stop_profiler(profile_path=None)
-    assert [e["depth"] for e in profiler._all_events()] == [0]
+    # (a garbage collection in the session is a `host.gc` span of its own)
+    assert [e["depth"] for e in profiler._all_events()
+            if e["name"] != profiler.GC_SPAN] == [0]
 
 
 def test_a_failed_annotation_leaves_no_depth_behind(tmp_path, monkeypatch):
@@ -234,8 +236,57 @@ def test_a_failed_annotation_leaves_no_depth_behind(tmp_path, monkeypatch):
             pass
     finally:
         jax.profiler.stop_trace()
-    assert [(e["name"], e["depth"]) for e in profiler._all_events()] == \
-        [("after", 0)]
+    assert [(e["name"], e["depth"]) for e in profiler._all_events()
+            if e["name"] != profiler.GC_SPAN] == [("after", 0)]
+
+
+# ---------------------------------------------------------------------------
+# what pauses the process: a garbage collection is a span of its own
+# ---------------------------------------------------------------------------
+def _collections():
+    return [a for n, _, _, a in profiler.spans(profiler.GC_SPAN)]
+
+
+def test_a_collection_in_a_session_is_a_host_gc_span(tmp_path):
+    import gc
+
+    profiler.reset_profiler()
+    gc.collect()                      # no session: nothing is recorded
+    assert _collections() == []
+    assert profiler._on_gc not in gc.callbacks    # nor hooked
+    profiler.start_profiler(state="CPU")
+    try:
+        assert profiler._on_gc in gc.callbacks
+        gc.collect()
+    finally:
+        table = profiler.stop_profiler(profile_path=None)
+    assert profiler._on_gc not in gc.callbacks
+    got = _collections()
+    # at least the forced one: the count only rises with what else ran
+    assert table[profiler.GC_SPAN]["calls"] == len(got) >= 1
+    assert any(a["generation"] == 2 for a in got)
+    assert all(set(a) == {"generation", "collected"}
+               and isinstance(a["collected"], int) for a in got)
+
+    # under a jax trace the span lies in the trace as well, with both
+    # attributes; noted once a span site has seen the session
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with profiler.RecordEvent("noted"):
+            pass
+        gc.collect()
+    finally:
+        jax.profiler.stop_trace()
+    assert any(a["generation"] == 2 for a in _collections())
+    # the reading saw the session end: the hook is out again
+    assert profiler._on_gc not in gc.callbacks
+    files = list(pathlib.Path(tmp_path).rglob("*.xplane.pb"))
+    events = [e for p in jax.profiler.ProfileData.from_file(
+                  str(files[0])).planes
+              for line in p.lines for e in line.events
+              if e.name == profiler.TRACE_PREFIX + profiler.GC_SPAN]
+    assert events and {"pc_ns", "generation", "collected"} <= set(
+        dict(events[-1].stats))
 
 
 _LEAKY = """

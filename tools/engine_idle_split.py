@@ -21,10 +21,18 @@ for `models/kimi_k2.py`, `full_tiles` of `full_grid` and `window_tiles`
 of `window_grid` for `models/afmoe.py`), the tiles walked of the
 rectangle's, summed over the run's decode steps.
 
-Also printed: the spread of `start_ns - pc_ns` over the spans (how well
-`profiler.trace_clock_offset_ns` ties `perf_counter_ns` to the trace's
-clock), and `engine_host_ms_per_step.serve` x decode steps over the
-seconds of `engine_step` in the same run's `idle_gaps`.
+Also printed: the device's idle time under `host.gc` (the program's
+span for a garbage collection, on whatever thread it ran; since ISSUE
+38), beside the phases and not among them; the spread of `start_ns -
+pc_ns` over the spans (how well `profiler.trace_clock_offset_ns` ties
+`perf_counter_ns` to the trace's clock); `engine_host_ms_per_step.serve`
+x decode steps over the seconds of `engine_step` in the same run's
+`idle_gaps`; and the clock check of the engine's own device times
+(ISSUE 38) against the trace's programs, over the flights answered in
+the window: the median `device_s` of the decode steps against the
+median `jit_decode_step` run, the summed `device_s` of the prefills
+against the `jit_prefill_b*` runs they answered, and the percentiles of
+the decode steps' `behind_s`.
 
     python tools/engine_idle_split.py [--workload W] [--seed N]
         [--seconds S] [--rehearse] [--out FILE.json]
@@ -50,13 +58,14 @@ from benchmarks import run as bench_run  # noqa: E402
 from benchmarks import trace_reduce  # noqa: E402
 
 STEP = "engine.step"
+GC = "host.gc"
 
 
 def read_trace(path):
     """(program spans [(name, start, end)] without their prefix, clock
     offsets `start_ns - pc_ns`, device op intervals of the first chip,
     the benchmark's window or None, the runs of each program on that
-    chip by name), all on the trace's clock in ns."""
+    chip by name: [(start, end)]), all on the trace's clock in ns."""
     from jax.profiler import ProfileData
 
     from paddle_tpu import profiler
@@ -86,8 +95,9 @@ def read_trace(path):
                        for e in line.events]
             elif line.name == trace_reduce.MODULES_LINE:
                 for e in line.events:
-                    name = trace_reduce.program_name(e.name)
-                    programs[name] = programs.get(name, 0) + 1
+                    programs.setdefault(
+                        trace_reduce.program_name(e.name), []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
     return (spans, [o for o in offsets if o is not None], ops, window,
             programs)
 
@@ -104,10 +114,8 @@ def idle_under(span, gaps, starts):
     return total
 
 
-def split(spans, ops, window):
-    """Rows [phase, count, host seconds, idle seconds under it], the
-    device's idle seconds in the window, and the part of them under no
-    `engine.step` at all."""
+def idle_gaps(ops, window):
+    """The device's idle gaps inside `window` (sorted, disjoint)."""
     lo, hi = window
     clipped = [(max(s, lo), min(e, hi)) for s, e in ops
                if min(e, hi) > max(s, lo)]
@@ -117,7 +125,14 @@ def split(spans, ops, window):
                [(max(e for _, e in clipped), hi)]
     else:
         gaps = [(lo, hi)]
-    gaps = [g for g in gaps if g[1] > g[0]]
+    return [g for g in gaps if g[1] > g[0]]
+
+
+def split(spans, ops, window):
+    """Rows [phase, count, host seconds, idle seconds under it], the
+    device's idle seconds in the window, and the part of them under no
+    `engine.step` at all."""
+    gaps = idle_gaps(ops, window)
     idle = sum(e - s for s, e in gaps)
     starts = [g[0] for g in gaps]
 
@@ -143,6 +158,74 @@ def split(spans, ops, window):
     return table, idle / 1e9, (idle - steps[2]) / 1e9
 
 
+def idle_under_all(spans, ops, window):
+    """(count, host seconds, the device's idle seconds under them) of
+    `spans` [(start, end)], which do not overlap one another."""
+    gaps = idle_gaps(ops, window)
+    starts = [g[0] for g in gaps]
+    return (len(spans), sum(e - s for s, e in spans) / 1e9,
+            sum(idle_under(s, gaps, starts) for s in spans) / 1e9)
+
+
+def _ms(values, q):
+    from paddle_tpu.serving.stats import exact_percentile
+
+    return 1e3 * exact_percentile(sorted(values), q)
+
+
+def clock_check(waits, programs, offset, window):
+    """The engine's own device times against the trace's programs.
+    `waits`: the session's `engine.prefill_wait` / `engine.decode_wait`
+    spans from `profiler.spans` (perf_counter_ns, attributes and all),
+    laid on the trace's clock by `offset`; those that end in `window`
+    count.  Decode: the median `device_s` against the median
+    `jit_decode_step` run in the window.  Prefill: each `jit_prefill_b*`
+    run inside the window is paired with the first prefill answered
+    after it ended (the queue is FIFO and an answer follows its run),
+    and the paired flights' `device_s` summed against the runs'.  And
+    the decode steps' `behind_s` percentiles, ms, beside how many steps
+    waited behind a prefill and how many prefills that was (`behind`)."""
+    lo, hi = window
+    inside = [(n, s + offset, e + offset, a) for n, s, e, a in waits
+              if "device_s" in a and lo <= e + offset <= hi]
+    steps = [a for n, _, _, a in inside if n == "engine.decode_wait"]
+    fills = sorted((e, a["device_s"]) for n, _, e, a in inside
+                   if n == "engine.prefill_wait")
+    step_runs = [(e - s) / 1e9 for s, e in programs.get("jit_decode_step", [])
+                 if lo <= s and e <= hi]
+    fill_runs = sorted((e, (e - s) / 1e9) for name, runs in programs.items()
+                       if name.startswith("jit_prefill_b")
+                       for s, e in runs if lo <= s and e <= hi)
+    out = {}
+    if steps and step_runs:
+        mine = 1e3 * statistics.median(a["device_s"] for a in steps)
+        trace = 1e3 * statistics.median(step_runs)
+        out["decode"] = {"flights": len(steps), "runs": len(step_runs),
+                         "device_s_p50_ms": mine, "run_p50_ms": trace,
+                         "ratio": mine / trace}
+        out["behind_ms"] = {f"p{int(q * 100)}": _ms(
+            [a["behind_s"] for a in steps], q) for q in (0.5, 0.9, 0.99)}
+        out["behind_ms"]["max"] = 1e3 * max(a["behind_s"] for a in steps)
+        out["behind"] = {"steps": sum(a["behind"] > 0 for a in steps),
+                         "prefills": sum(a["behind"] for a in steps),
+                         "max": max(a["behind"] for a in steps)}
+    paired, i = [], 0
+    for end, run_s in fill_runs:
+        while i < len(fills) and fills[i][0] < end:
+            i += 1
+        if i == len(fills):
+            break
+        paired.append((fills[i][1], run_s))
+        i += 1
+    if paired:
+        mine = sum(p for p, _ in paired)
+        trace = sum(r for _, r in paired)
+        out["prefill"] = {"paired": len(paired), "runs": len(fill_runs),
+                          "device_s_sum": mine, "run_sum_s": trace,
+                          "ratio": mine / trace}
+    return out
+
+
 def cache_walk(cache):
     """{name: (tiles walked, tiles of the rectangle)} of the counts a
     model's `cache_walk` left in the summary's `cache`."""
@@ -163,6 +246,8 @@ def main(argv=None):
 
     from paddle_tpu.serving import DecodeEngine
 
+    from paddle_tpu import profiler
+
     captured = {}
     load = trace_reduce.load_xplane
     summary = DecodeEngine.summary
@@ -175,6 +260,7 @@ def main(argv=None):
         out = summary(engine)
         captured["lookahead"] = out["decode"].get("lookahead")
         captured["walk"] = cache_walk(out["decode"].get("cache", {}))
+        captured["device"] = out["decode"].get("device")
         return out
 
     trace_reduce.load_xplane = load_and_keep
@@ -196,13 +282,19 @@ def main(argv=None):
     line = json.loads(out.getvalue().strip().splitlines()[-1])
     spans, offsets, ops, window, programs = captured["trace"]
     engine = [s for s in spans if s[0].startswith("engine.")]
+    # the store's spans of the same session, with what is known only
+    # once an answer is in (`device_s`, `behind_s`)
+    waits = [s for s in profiler.spans("engine.")
+             if s[0] in ("engine.prefill_wait", "engine.decode_wait")]
 
     report = {"workload": args.workload, "seed": args.seed,
               "device": line["device"], "metrics": line["metrics"],
               "breakdown": line.get("breakdown", {}),
-              "programs": programs, "spans_in_trace": len(spans),
+              "programs": {n: len(r) for n, r in programs.items()},
+              "spans_in_trace": len(spans),
               "lookahead": captured.get("lookahead"),
-              "cache_walk": captured.get("walk", {})}
+              "cache_walk": captured.get("walk", {}),
+              "summary_device": captured.get("device")}
     for name, (tiles, grid) in report["cache_walk"].items():
         print(f"cache walk: {name}_tiles {tiles} of {name}_grid {grid} "
               f"({100 * tiles / max(grid, 1):.2f}%), one layer, all decode "
@@ -215,7 +307,9 @@ def main(argv=None):
               f"({100 * look['late'] / max(look['in_time'] + look['late'], 1):.2f}% late)")
     if programs:
         print("programs run (XLA Modules): " + ", ".join(
-            f"{n} x{c}" for n, c in sorted(programs.items())))
+            f"{n} x{c}" for n, c in sorted(report["programs"].items())))
+    if report["summary_device"]:
+        print(f"summary device (whole run): {report['summary_device']}")
     if offsets:
         report["clock_offset_ns"] = {
             "spans": len(offsets), "min": min(offsets),
@@ -240,6 +334,36 @@ def main(argv=None):
         print(f"idle under the *_wait phases (host blocked) {blocked:.4f} s, "
               f"under the host's own work "
               f"{idle_s - outside_s - blocked:.4f} s")
+        count, host_s, under_s = idle_under_all(
+            [(s, e) for n, s, e in spans if n == GC], ops, window)
+        report["host_gc"] = {"count": count, "host_s": host_s,
+                             "idle_s": under_s}
+        print(f"{GC + ' (any thread)':<34}{count:>7}{host_s:>10.4f}"
+              f"{under_s:>10.4f}"
+              f"{100 * under_s / idle_s if idle_s else 0.0:>8.1f}")
+        if offsets:
+            check = clock_check(waits, programs, statistics.median(offsets),
+                                window)
+            report["clock_check"] = check
+            if "decode" in check:
+                d = check["decode"]
+                print(f"clock check, decode: median device_s "
+                      f"{d['device_s_p50_ms']:.3f} ms over {d['flights']} "
+                      f"flights, median jit_decode_step "
+                      f"{d['run_p50_ms']:.3f} ms over {d['runs']} runs: "
+                      f"{100 * (d['ratio'] - 1):+.2f}%")
+                b = check["behind"]
+                print("behind_s of the decode steps, ms: " + ", ".join(
+                    f"{k} {v:.3f}" for k, v in check["behind_ms"].items())
+                      + f"; {b['steps']} of {d['flights']} steps behind "
+                      f"{b['prefills']} prefills, at most {b['max']} at once")
+            if "prefill" in check:
+                p = check["prefill"]
+                print(f"clock check, prefill: {p['paired']} of {p['runs']} "
+                      f"jit_prefill_b* runs paired, device_s "
+                      f"{p['device_s_sum']:.4f} s against their "
+                      f"{p['run_sum_s']:.4f} s: "
+                      f"{100 * (p['ratio'] - 1):+.2f}%")
         host = line["metrics"].get("engine_host_ms_per_step.serve")
         waits = sum(c for n, c, _, _ in table if n == "engine.decode_wait")
         labelled = dict(map(tuple, report["breakdown"].get(
