@@ -20,11 +20,16 @@ Forms:
   a grouped product over the experts this chip is told it holds.
 """
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
+
+# `routed_experts`' kept case runs this many times the rows that its held
+# experts expect
+KEPT_OVER_EXPECTED = 2
 
 
 def init_moe_params(key, num_experts, d_model, d_hidden, dtype=jnp.float32):
@@ -223,7 +228,7 @@ def grouped_product(rows, weights, counts, use_kernel=None):
 
 
 def routed_experts(h, router, bias, experts_held, first_expert, n_routed,
-                   top_k, scale, valid=None, use_kernel=None, slack=None):
+                   top_k, scale, valid=None, use_kernel=None):
     """The routed part of an expert layer on a chip that holds experts
     [first_expert, first_expert + held) of `n_routed`.
 
@@ -235,19 +240,21 @@ def routed_experts(h, router, bias, experts_held, first_expert, n_routed,
     chosen experts, held here or not); the assignments that fall on the
     experts held here are sorted by expert and go through one grouped
     product (`grouped_product`: the Pallas call `moe_grouped_mm` on the
-    TPU) over static N * top_k rows, so nothing is dropped at any
-    imbalance; what the absent experts would have added is left out.
+    TPU), so nothing is dropped at any imbalance; what the absent
+    experts would have added is left out.
 
-    `slack` (a prefill's: many tokens, of which a chip's experts get
-    their share) makes the usual case cheaper and drops nothing either:
-    where the held assignments fit `slack` times their expected number,
-    N * top_k * held / n_routed rows (`_rows_kept`), the gathers, the
-    products and the sum back into the tokens run over that many rows
-    only; where they do not, over all N * top_k as without it
-    (`jax.lax.cond`: the device runs one of the two).
+    Where the shape allows (`_rows_kept`: the held experts expect a
+    small enough share of the N * top_k assignments), the usual case
+    runs over the kept rows only: the gathers, the products and the sum
+    back into the tokens, where the held assignments fit them; where
+    they do not, every one of the N * top_k rows runs (`jax.lax.cond`:
+    the device runs one of the two), so nothing is dropped either way.
+    The layer is traced once a shape (`_routed`, jitted).
 
     Returns (y [N, D] in h's type, assignments on each held expert
-    int32 [held]); the caller adds the shared expert."""
+    int32 [held], int32 [] 1 where the layer ran over the kept rows and
+    0 where it ran over every row); the caller adds the shared
+    expert."""
     gate_up, down = experts_held
     held = gate_up.shape[0]
     if router.shape[1] != n_routed or not \
@@ -255,6 +262,18 @@ def routed_experts(h, router, bias, experts_held, first_expert, n_routed,
         raise ValueError(
             f"experts [{first_expert}, {first_expert + held}) do not lie "
             f"in a router over {router.shape[1]} (n_routed {n_routed})")
+    return _routed(h, router, bias, gate_up, down, valid,
+                   first_expert=first_expert, n_routed=n_routed,
+                   top_k=top_k, scale=float(scale), use_kernel=use_kernel)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "first_expert", "n_routed", "top_k", "scale", "use_kernel"))
+def _routed(h, router, bias, gate_up, down, valid, *, first_expert,
+            n_routed, top_k, scale, use_kernel):
+    """`routed_experts`' body: jitted and not inlined, so that a program
+    with several expert layers of one shape traces and lowers it once."""
+    held = gate_up.shape[0]
     n, d = h.shape
     experts, weights = sigmoid_top_k(h, router, bias, top_k, scale)
     local = experts - first_expert
@@ -284,9 +303,9 @@ def routed_experts(h, router, bias, experts_held, first_expert, n_routed,
         return jnp.sum(jnp.where(here[..., None],
                                  back * weights[..., None], 0.0), axis=1)
 
-    kept = _rows_kept(n * top_k, held / n_routed, slack)
+    kept = _rows_kept(n * top_k, held / n_routed)
     if kept is None:
-        return every_row().astype(h.dtype), counts
+        return every_row().astype(h.dtype), counts, jnp.int32(0)
 
     def rows_kept():
         # the held assignments are the first of `order`: each row times
@@ -298,18 +317,18 @@ def routed_experts(h, router, bias, experts_held, first_expert, n_routed,
             experts_of(first) * weights.reshape(-1)[first][:, None], 0.0)
         return jnp.zeros((n, d), jnp.float32).at[first // top_k].add(out)
 
-    y = jax.lax.cond(counts.sum() <= kept, rows_kept, every_row)
-    return y.astype(h.dtype), counts
+    fits = counts.sum() <= kept
+    y = jax.lax.cond(fits, rows_kept, every_row)
+    return y.astype(h.dtype), counts, fits.astype(jnp.int32)
 
 
-def _rows_kept(rows, share, slack):
-    """Static rows of `routed_experts`' cheaper case: `slack` times the
-    held experts' expected share of `rows` assignments, in whole row
-    tiles of the grouped product; None (no such case) without a slack or
-    where that is no fewer than half of `rows`."""
-    if slack is None:
-        return None
+def _rows_kept(rows, share):
+    """Static rows of `routed_experts`' kept case for `rows` assignments
+    of which the held experts expect `share`: `KEPT_OVER_EXPECTED` times
+    that many, in whole row tiles of the grouped product; None (no such
+    case: every row runs) where that is more than half of `rows`."""
     from ..kernels.grouped_mm import ROW_TILE
 
-    kept = -(-int(math.ceil(slack * share * rows)) // ROW_TILE) * ROW_TILE
+    kept = -(-int(math.ceil(KEPT_OVER_EXPECTED * share * rows))
+             // ROW_TILE) * ROW_TILE
     return kept if 2 * kept <= rows else None

@@ -64,15 +64,13 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from .blocks import normed_heads, rms_norm, rope, swiglu
+from .blocks import (expert_counters, expert_ffn, expert_layers_kept,
+                     normed_heads, rms_norm, rope, swiglu)
 
 __all__ = ["AfmoeCfg", "AfmoeParams", "param_shapes", "init_params",
            "full_logits"]
 
 WINDOW, FULL = "sliding_attention", "full_attention"
-# a whole sequence's expert layer runs over twice the rows its held
-# experts expect where their assignments fit (`routed_experts`' slack)
-SEQUENCE_SLACK = 2.0
 
 
 class AfmoeCfg(NamedTuple):
@@ -184,6 +182,14 @@ class AfmoeCfg(NamedTuple):
                 "live_window": sum(min(n, self.sliding_window)
                                    for n in lengths)}
 
+    def expert_layers(self, tokens):
+        """The expert layers of a program over `tokens` tokens (a decode
+        step's slots, a prefill's bucket) whose shape has
+        `routed_experts`' kept case."""
+        return expert_layers_kept(
+            tokens, self.num_experts_per_tok, self.experts_held,
+            self.n_routed, self.num_layers - self.num_dense_layers)
+
     def prefill(self, trees, cache, prompt, true_len, slot):
         return _prefill(self, trees, cache, prompt, true_len, slot)
 
@@ -273,19 +279,13 @@ def init_params(cfg, key, std=0.02, bias_std=0.02):
 # the layer
 # ---------------------------------------------------------------------------
 
-def _ffn(cfg, lp, h, counts, valid=None, slack=None):
+def _ffn(cfg, lp, h, counters, valid=None):
     """The layer's FFN of tokens h [N, H]: dense, or routed + shared."""
     if "router" not in lp:
-        return swiglu(h, lp["gate_up"], lp["down"]), counts
-    from ..distributed.moe import routed_experts
-
-    routed, c = routed_experts(
-        h, lp["router"], lp["expert_bias"],
-        (lp["experts_gate_up"], lp["experts_down"]), cfg.first_expert,
-        cfg.n_routed, cfg.num_experts_per_tok, cfg.route_scale,
-        valid=valid, slack=slack)
-    return routed + swiglu(h, lp["shared_gate_up"], lp["shared_down"]), \
-        counts + c
+        return swiglu(h, lp["gate_up"], lp["down"]), counters
+    return expert_ffn(h, lp, lp["expert_bias"], (
+        cfg.first_expert, cfg.n_routed, cfg.num_experts_per_tok,
+        cfg.route_scale), counters, valid)
 
 
 def _projections(cfg, lp, a, pos, window):
@@ -300,13 +300,13 @@ def _projections(cfg, lp, a, pos, window):
     return q, k, v, a @ lp["attn_gate"]
 
 
-def _after_attention(cfg, lp, x, o, gate, counts, valid=None, slack=None):
+def _after_attention(cfg, lp, x, o, gate, counters, valid=None):
     """The rest of a layer from the heads' outputs o [N, heads * d]."""
     o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
     x = x + rms_norm(cfg, o @ lp["o"], lp["post_attn_norm"])
-    f, counts = _ffn(cfg, lp, rms_norm(cfg, x, lp["pre_mlp_norm"]), counts,
-                     valid=valid, slack=slack)
-    return x + rms_norm(cfg, f, lp["post_mlp_norm"]), counts
+    f, counters = _ffn(cfg, lp, rms_norm(cfg, x, lp["pre_mlp_norm"]),
+                       counters, valid=valid)
+    return x + rms_norm(cfg, f, lp["post_mlp_norm"]), counters
 
 
 def _embed(cfg, trees, ids):
@@ -316,13 +316,14 @@ def _embed(cfg, trees, ids):
 
 def _decode(cfg, trees, cache, token, pos):
     """One step of every slot: token [S] at pos [S] -> (cache, final
-    hidden [S, H], counters: `expert_counts` int32 [held])."""
+    hidden [S, H], counters: `expert_counts` int32 [held] and
+    `expert_layers_kept` int32 [])."""
     from ..kernels.attention import (resident_decode_attention,
                                      resident_decode_walk)
 
     cache = dict(cache)
     x = _embed(cfg, trees, token)
-    counts = jnp.zeros(cfg.experts_held, jnp.int32)
+    counters = expert_counters(cfg.experts_held)
     seen = {WINDOW: 0, FULL: 0}
     # the kernel's visit tables follow from the positions alone: once a
     # step for each depth, not once a layer
@@ -338,23 +339,22 @@ def _decode(cfg, trees, cache, token, pos):
                 cache["k_" + name], cache["v_" + name], seen[kind], pos,
                 ring=kind == WINDOW, walk=walks[name])
         seen[kind] += 1
-        x, counts = _after_attention(
-            cfg, lp, x, o.reshape(o.shape[0], -1), gate, counts)
-    return cache, rms_norm(cfg, x, trees["final_norm"]), {
-        "expert_counts": counts}
+        x, counters = _after_attention(
+            cfg, lp, x, o.reshape(o.shape[0], -1), gate, counters)
+    return cache, rms_norm(cfg, x, trees["final_norm"]), counters
 
 
 def _forward(cfg, trees, ids, valid):
     """The published form over one sequence ids [N] (positions 0..N-1):
     (hidden [N, H] before the final norm, each layer's (k, v)
-    [kv_heads, d, N] as its cache holds them, expert counts).  Tokens
+    [kv_heads, d, N] as its cache holds them, counters).  Tokens
     that are not `valid` make no expert assignment."""
     from ..kernels.attention import dot_product_attention
 
     n = ids.shape[0]
     pos = jnp.arange(n, dtype=jnp.int32)
     x = _embed(cfg, trees, ids)
-    counts = jnp.zeros(cfg.experts_held, jnp.int32)
+    counters = expert_counters(cfg.experts_held)
     kvs = []
     for lp, kind in zip(trees["layers"], cfg.layer_types):
         q, k, v, gate = _projections(
@@ -365,10 +365,10 @@ def _forward(cfg, trees, ids, valid):
             q.swapaxes(0, 1)[None], k[None], v[None], is_causal=True,
             training=False,
             window=cfg.sliding_window if kind == WINDOW else None)[0]
-        x, counts = _after_attention(
-            cfg, lp, x, o.swapaxes(0, 1).reshape(n, -1), gate, counts,
-            valid=valid, slack=SEQUENCE_SLACK)
-    return x, kvs, counts
+        x, counters = _after_attention(
+            cfg, lp, x, o.swapaxes(0, 1).reshape(n, -1), gate, counters,
+            valid=valid)
+    return x, kvs, counters
 
 
 def full_logits(cfg, trees, ids):
@@ -408,7 +408,7 @@ def _prefill(cfg, trees, cache, prompt, true_len, slot):
     of a full layer, the ring's as `_ring_columns` lays them; the final
     hidden state at the true last position [1, H]; counters)."""
     bucket = prompt.shape[1]
-    x, kvs, counts = _forward(
+    x, kvs, counters = _forward(
         cfg, trees, prompt[0], jnp.arange(bucket, dtype=jnp.int32) < true_len)
     cache = dict(cache)
     for name, kind in (("full", FULL), ("window", WINDOW)):
@@ -427,5 +427,4 @@ def _prefill(cfg, trees, cache, prompt, true_len, slot):
                 cache[which + name],
                 block.astype(cache[which + name].dtype), (0, slot, 0, 0, 0))
     h = jax.lax.dynamic_slice(x, (true_len - 1, 0), (1, cfg.hidden_size))
-    return cache, rms_norm(cfg, h, trees["final_norm"]), {
-        "expert_counts": counts}
+    return cache, rms_norm(cfg, h, trees["final_norm"]), counters

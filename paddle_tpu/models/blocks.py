@@ -1,12 +1,14 @@
 """Layer pieces the served decoders share (`models/kimi_k2.py`,
 `models/afmoe.py`, `models/brumby.py`): RMSNorm, the SwiGLU
-feed-forward, heads normalised one by one, and the rotation."""
+feed-forward, heads normalised one by one, the rotation, and the expert
+layer's routed and shared parts with the counters it adds to."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["rms_norm", "swiglu", "normed_heads", "rope"]
+__all__ = ["rms_norm", "swiglu", "normed_heads", "rope", "expert_counters",
+           "expert_ffn", "expert_layers_kept"]
 
 
 def rms_norm(cfg, x, gain):
@@ -47,3 +49,37 @@ def rope(cfg, x, pos):
     a, b = x32[..., :half], x32[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
                            axis=-1).astype(x.dtype)
+
+
+def expert_counters(held):
+    """A program's counters before its first expert layer:
+    `expert_counts`, the assignments on each of the `held` experts, and
+    `expert_layers_kept`, the expert layers that ran over
+    `routed_experts`' kept rows."""
+    return {"expert_counts": jnp.zeros(held, jnp.int32),
+            "expert_layers_kept": jnp.zeros((), jnp.int32)}
+
+
+def expert_ffn(h, lp, bias, route, counters, valid=None):
+    """An expert layer's FFN of tokens h [N, H]: the routed part
+    (`distributed/moe.py` `routed_experts` with `route` = (first_expert,
+    n_routed, top_k, scale)) plus the shared expert; the layer's own
+    counts added to `counters`."""
+    from ..distributed.moe import routed_experts
+
+    routed, counts, kept = routed_experts(
+        h, lp["router"], bias, (lp["experts_gate_up"], lp["experts_down"]),
+        *route, valid=valid)
+    return routed + swiglu(h, lp["shared_gate_up"], lp["shared_down"]), {
+        "expert_counts": counters["expert_counts"] + counts,
+        "expert_layers_kept": counters["expert_layers_kept"] + kept}
+
+
+def expert_layers_kept(tokens, top_k, held, n_routed, layers):
+    """Of `layers` expert layers in a program over `tokens` tokens, those
+    whose shape has `routed_experts`' kept case (all or none); on the
+    host, from the shape."""
+    from ..distributed.moe import _rows_kept
+
+    return 0 if _rows_kept(tokens * top_k, held / n_routed) is None \
+        else layers

@@ -47,7 +47,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .blocks import rms_norm, swiglu
+from .blocks import (expert_counters, expert_ffn, expert_layers_kept,
+                     rms_norm, swiglu)
 
 __all__ = ["K2Cfg", "K2Params", "param_shapes", "init_params",
            "yarn_inv_freq", "full_logits"]
@@ -136,6 +137,14 @@ class K2Cfg(NamedTuple):
         tile = mla_tiling(self.latent_width, max_len).tile
         return {"latent_tiles": tiles_walked(lengths, tile),
                 "latent_grid": slots * (max_len // tile)}
+
+    def expert_layers(self, tokens):
+        """The expert layers of a program over `tokens` tokens (a decode
+        step's slots, a prefill's bucket) whose shape has
+        `routed_experts`' kept case."""
+        return expert_layers_kept(
+            tokens, self.num_experts_per_tok, self.experts_held,
+            self.n_routed, self.num_layers - self.first_k_dense)
 
     def prefill(self, trees, cache, prompt, true_len, slot):
         return _prefill(self, trees, cache, prompt, true_len, slot)
@@ -268,19 +277,13 @@ def _rope(cfg, x, pos):
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
 
 
-def _ffn(cfg, lp, h, counts, valid=None):
+def _ffn(cfg, lp, h, counters, valid=None):
     """The layer's FFN of tokens h [N, H]: dense, or routed + shared."""
     if "router" not in lp:
-        return swiglu(h, lp["gate_up"], lp["down"]), counts
-    from ..distributed.moe import routed_experts
-
-    routed, c = routed_experts(
-        h, lp["router"], lp["router_bias"],
-        (lp["experts_gate_up"], lp["experts_down"]), cfg.first_expert,
-        cfg.n_routed, cfg.num_experts_per_tok, cfg.routed_scaling_factor,
-        valid=valid)
-    return routed + swiglu(h, lp["shared_gate_up"], lp["shared_down"]), \
-        counts + c
+        return swiglu(h, lp["gate_up"], lp["down"]), counters
+    return expert_ffn(h, lp, lp["router_bias"], (
+        cfg.first_expert, cfg.n_routed, cfg.num_experts_per_tok,
+        cfg.routed_scaling_factor), counters, valid)
 
 
 def _queries_and_latent(cfg, lp, h, pos):
@@ -306,12 +309,13 @@ def _kv_b(cfg, lp):
 
 def _decode(cfg, trees, cache, token, pos):
     """One step of every slot: token [S] at pos [S] -> (cache, final
-    hidden [S, H], {"expert_counts": int32 [held]})."""
+    hidden [S, H], counters: `expert_counts` int32 [held] and
+    `expert_layers_kept` int32 [])."""
     from ..kernels.attention import resident_mla_attention
 
     latent = cache["latent"]
     x = jnp.take(trees["embed"], token, axis=0)
-    counts = jnp.zeros(cfg.experts_held, jnp.int32)
+    counters = expert_counters(cfg.experts_held)
     for l, lp in enumerate(trees["layers"]):
         h = rms_norm(cfg, x, lp["attn_norm"])
         q_nope, q_rope, new = _queries_and_latent(cfg, lp, h, pos)
@@ -321,10 +325,11 @@ def _decode(cfg, trees, cache, token, pos):
             q_latent, q_rope, new, latent, l, pos, cfg.softmax_scale)
         o = jnp.einsum("shc,chd->shd", o, w_uv)
         x = x + o.reshape(o.shape[0], -1) @ lp["o"]
-        y, counts = _ffn(cfg, lp, rms_norm(cfg, x, lp["ffn_norm"]), counts)
+        y, counters = _ffn(cfg, lp, rms_norm(cfg, x, lp["ffn_norm"]),
+                           counters)
         x = x + y
     return {"latent": latent}, rms_norm(cfg, x, trees["final_norm"]), \
-        {"expert_counts": counts}
+        counters
 
 
 def _pad_heads(x, width):
@@ -355,7 +360,7 @@ def _forward(cfg, trees, ids, valid):
     n = ids.shape[0]
     pos = jnp.arange(n, dtype=jnp.int32)
     x = jnp.take(trees["embed"], ids, axis=0)
-    counts = jnp.zeros(cfg.experts_held, jnp.int32)
+    counters = expert_counters(cfg.experts_held)
     latents = []
     for lp in trees["layers"]:
         h = rms_norm(cfg, x, lp["attn_norm"])
@@ -372,10 +377,10 @@ def _forward(cfg, trees, ids, valid):
         o = _causal_attention(q[None], k[None], v[None],
                               cfg.softmax_scale)[0]            # [H, N, v]
         x = x + o.swapaxes(0, 1).reshape(n, -1) @ lp["o"]
-        y, counts = _ffn(cfg, lp, rms_norm(cfg, x, lp["ffn_norm"]), counts,
-                         valid=valid)
+        y, counters = _ffn(cfg, lp, rms_norm(cfg, x, lp["ffn_norm"]),
+                           counters, valid=valid)
         x = x + y
-    return x, latents, {"expert_counts": counts}
+    return x, latents, counters
 
 
 def full_logits(cfg, trees, ids):

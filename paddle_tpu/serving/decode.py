@@ -40,7 +40,11 @@ and read by key (a model that counts nothing gives an empty one, and its
 program answers with exactly what the engine always fetched):
 `expert_counts` (assignments on each expert held here, summed over the
 expert layers) becomes the `expert_tokens` / `expert_load_max`
-attributes of `engine.*_wait` and `DecodeStats`' running totals.
+attributes of `engine.*_wait` and `DecodeStats`' running totals, and
+`expert_layers_kept` beside it (the expert layers that ran over
+`routed_experts`' kept rows) the attribute of that name, beside
+`expert_layers`, which such a model's `expert_layers(tokens)` gives
+from the program's shape.
 What a program read of the cache the engine counts on the host, from
 the lengths it holds (nothing is fetched): a model whose cache arrays
 differ in depth (`models/afmoe.py`: full layers beside window rings)
@@ -448,21 +452,29 @@ def _prefill_impl(state, trees, prompt, true_len, slot, stop, eos,
 
 
 def _on_host(results):
-    """A program's results, counters and all, fetched."""
+    """A program's results, counters and all, fetched: every transfer
+    started before the first is waited for."""
     import jax
 
+    for leaf in jax.tree.leaves(results):
+        getattr(leaf, "copy_to_host_async", lambda: None)()
     return jax.tree.map(np.asarray, results)
 
 
-def _expert_load(counters):
-    """The span attributes of a program's `expert_counts`: assignments
-    that fell on the experts held here, and the fullest one's.  A model
-    without experts gives none."""
+def _expert_load(cfg, counters, tokens):
+    """The span attributes of a program's expert counters: the
+    assignments that fell on the experts held here (`expert_counts`),
+    the fullest one's, and the expert layers that ran over
+    `routed_experts`' kept rows (`expert_layers_kept`) beside those of a
+    program over `tokens` tokens whose shape has that case
+    (`cfg.expert_layers`).  A model without experts gives none."""
     counts = counters.get("expert_counts")
     if counts is None:
         return {}
     return {"expert_tokens": int(counts.sum()),
-            "expert_load_max": int(counts.max())}
+            "expert_load_max": int(counts.max()),
+            "expert_layers_kept": int(counters["expert_layers_kept"]),
+            "expert_layers": cfg.expert_layers(tokens)}
 
 
 def _lengths(slot_reqs, active):
@@ -1041,8 +1053,10 @@ class DecodeEngine:
         device time and number of the prefills that ran since the step
         before (a resident request's gap between two tokens is
         `device_s + behind_s` and any idle time); where the model counts
-        expert assignments, both gain `expert_tokens` and
-        `expert_load_max`, and where its caches differ in depth, the
+        expert assignments, both gain `expert_tokens`,
+        `expert_load_max`, `expert_layers_kept` and `expert_layers`
+        (the layers over `routed_experts`' kept rows, of those whose
+        shape has that case), and where its caches differ in depth, the
         cached positions read in one layer of each (`live_full` and
         `live_window` of `models/afmoe.py`); a decode step also what the
         model's `cache_walk` counts (`latent_tiles` and `latent_grid` of
@@ -1300,7 +1314,7 @@ class DecodeEngine:
             if now is None:
                 return False
             first, active, counters = flight.results
-            load = _expert_load(counters)
+            load = _expert_load(self.params.cfg, counters, req.bucket)
             traffic = _state_traffic(self._slot_state_bytes, counters)
             span.attrs.update(
                 load, **_cache_reads(self.params.cfg, [true_len]),
@@ -1348,7 +1362,8 @@ class DecodeEngine:
             if now is None:
                 return False
             tokens, was_active, still, counters = flight.results
-            load = _expert_load(counters)
+            load = _expert_load(self.params.cfg, counters,
+                                self.config.slots)
             lengths = _lengths(flight.slot_reqs, was_active)
             walk = _cache_walk(self.params.cfg, self.config, lengths)
             traffic = _state_traffic(self._slot_state_bytes, counters,

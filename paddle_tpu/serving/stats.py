@@ -287,6 +287,10 @@ class DecodeStats(ServingStats):
         self.expert_programs = 0
         self.expert_tokens_total = 0
         self.expert_load_max = 0
+        # expert layers that ran over `routed_experts`' kept rows, of
+        # those whose shape has that case
+        self.expert_layers_kept = 0
+        self.expert_layers = 0
         # how far the engine's loop ran ahead of its host: decode steps
         # enqueued while the step before them was still unanswered (or
         # not), and admissions whose prefill went out directly behind
@@ -366,9 +370,11 @@ class DecodeStats(ServingStats):
             for name, n in walk.items():
                 self.cache_walk[name] = self.cache_walk.get(name, 0) + n
 
-    def note_experts(self, expert_tokens=None, expert_load_max=0):
+    def note_experts(self, expert_tokens=None, expert_load_max=0,
+                     expert_layers_kept=0, expert_layers=0):
         """One program's (prefill or decode step) assignments on the
-        experts held here; a model without experts notes nothing."""
+        experts held here, and its expert layers over the kept rows of
+        those that have them; a model without experts notes nothing."""
         if expert_tokens is None:
             return
         with self._lock:
@@ -376,6 +382,8 @@ class DecodeStats(ServingStats):
             self.expert_tokens_total += expert_tokens
             self.expert_load_max = max(self.expert_load_max,
                                        expert_load_max)
+            self.expert_layers_kept += expert_layers_kept
+            self.expert_layers += expert_layers
 
     def note_lookahead(self, ahead):
         """One decode step answered: was it enqueued while the step
@@ -480,7 +488,9 @@ class DecodeStats(ServingStats):
                 out["experts"] = {
                     "programs": self.expert_programs,
                     "tokens_total": self.expert_tokens_total,
-                    "load_max": self.expert_load_max}
+                    "load_max": self.expert_load_max,
+                    "layers_kept": self.expert_layers_kept,
+                    "layers": self.expert_layers}
             device = (self.prefill_device_s, self.decode_device_s,
                       self.prefill_positions, self.prefill_bucket_positions)
             behind = list(self._behind)

@@ -234,9 +234,9 @@ def test_ring_columns_after_a_padded_prefill(toy, n):
         np.testing.assert_array_equal(got["k_full"][l, 1, :, :, :n],
                                       k[:, :, :n])
     # the other slot is untouched; the program answers with its experts'
-    # counts alone (the reads are the host's: `cache_reads`)
+    # counters alone (the reads are the host's: `cache_reads`)
     assert not np.asarray(got["k_window"][:, 0]).any()
-    assert set(counters) == {"expert_counts"}
+    assert set(counters) == {"expert_counts", "expert_layers_kept"}
     assert toy.acfg.cache_reads([n]) == {"live_full": n,
                                          "live_window": min(n, 16)}
 
@@ -462,7 +462,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     mm = M._product("float32")
     whole, _ = M._moe(cfg, w, h, mm, None)
     with jax.default_matmul_precision("highest"):
-        parts, counts = zip(*[routed_experts(
+        parts, counts, _ = zip(*[routed_experts(
             h, w["router"], w["expert_bias"],
             (w["experts_gate_up"][s:s + 16], w["experts_down"][s:s + 16]),
             s, routed, k, cfg["route_scale"]) for s in range(0, routed, 16)])
@@ -481,65 +481,29 @@ def test_the_shares_add_up_to_the_uncut_layer():
                                atol=1e-5)
 
 
-@pytest.mark.parametrize("case", ["usual", "overflow", "padding",
-                                  "kernel"])
-def test_slack_runs_the_held_rows_only_and_drops_nothing(case):
-    """A prefill's `routed_experts(..., slack=2)`: 4 of 32 experts held,
-    top 8, so an eighth of the assignments is expected here and the
-    cheaper case keeps a quarter of the rows.  It equals the layer over
-    every row where the held assignments fit (also with padding that
-    makes none, also through `moe_grouped_mm`, interpreted), and where a
-    planted bias sends every token here, more than the kept rows hold,
-    the whole layer runs and nothing is dropped."""
-    from paddle_tpu.distributed.moe import _rows_kept
-
-    kernel = case == "kernel"
-    n, d, f = (256, 128, 128) if kernel else (300, 32, 16)
-    routed, held, k = 32, 4, 8
-    kept = _rows_kept(n * k, held / routed, 2.0)
-    assert kept == (512 if kernel else 640) and 4 * kept <= n * k + 512
-    rng = np.random.default_rng(21)
-    router = _rand(rng, (d, routed), 0.5)
-    bias = _rand(rng, (routed,), 0.05)
-    if case == "overflow":
-        bias = bias.at[8:12].add(10.0)
-    experts = (_rand(rng, (held, d, 2 * f), 0.2),
-               _rand(rng, (held, f, d), 0.2))
-    h = _rand(rng, (n, d))
-    valid = jnp.arange(n) < (250 if case == "padding" else n)
-
-    def layer(slack):
-        return jax.jit(lambda h, valid: routed_experts(
-            h, router, bias, experts, 8, routed, k, 2.826, valid=valid,
-            slack=slack, use_kernel=kernel or None))
-
-    text = str(jax.make_jaxpr(layer(2.0))(h, valid))
-    assert "cond[" in text and "scatter-add" in text
-    assert "scatter-add" not in str(jax.make_jaxpr(layer(None))(h, valid))
-    with jax.default_matmul_precision("highest"):
-        want, want_counts = layer(None)(h, valid)
-        got, counts = layer(2.0)(h, valid)
-    assert list(np.asarray(counts)) == list(np.asarray(want_counts))
-    assert (int(counts.sum()) > kept) == (case == "overflow")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=2e-4 if kernel else 1e-5)
-    assert np.asarray(want).any()
-    if case == "padding":
-        assert not np.asarray(got)[250:].any()
-
-
 def test_rows_kept_are_whole_row_tiles_and_under_half_of_all(toy):
-    """The cell's three buckets keep a quarter of their rows; a toy
-    prefill (4 of 16 held, top 2, 64 tokens) rounds up to a row tile
-    that is all of its rows and has no cheaper case, so it runs as
-    without a slack; the decode step passes none."""
+    """`_rows_kept` at the cells' shapes: twice the rows the held
+    experts expect, in whole row tiles, where that is at most half of
+    all rows (K2: 12 of 384 held, Trinity: 16 of 128, both top 8); the
+    rehearsal's toys (4 of 16 held, top 2, a few tokens) round up to a
+    row tile that is more than half of their rows and have no such
+    case, so the toy decode step holds no `cond`."""
     from paddle_tpu.distributed.moe import _rows_kept
 
-    assert [_rows_kept(b * 8, 16 / 128, 2.0)
-            for b in (2048, 4096, 8192)] == [4096, 8192, 16384]
-    assert _rows_kept(300 * 8, 4 / 32, 2.0) == 640    # 600 in tiles of 128
-    assert _rows_kept(64 * 2, 4 / 16, 2.0) is None
-    assert _rows_kept(8192 * 8, 16 / 128, None) is None
+    k2, trinity = 12 / 384, 16 / 128
+    # K2's decode step of 256 slots, its prefills at 256 to 2,048
+    assert _rows_kept(256 * 8, k2) == 128
+    assert [_rows_kept(b * 8, k2) for b in (256, 512, 1024, 2048)] == \
+        [128, 256, 512, 1024]
+    # Trinity's decode step of 64 slots, its prefills at 2,048 to 8,192
+    assert _rows_kept(64 * 8, trinity) == 128
+    assert [_rows_kept(b * 8, trinity) for b in (2048, 4096, 8192)] == \
+        [4096, 8192, 16384]
+    assert _rows_kept(300 * 8, 4 / 32) == 640         # 600 in tiles of 128
+    assert _rows_kept(64 * 2, 4 / 16) is None
+    assert _rows_kept(16 * 8, trinity) is None        # 32: a tile of 128
+    assert toy.acfg.expert_layers(2) == 0
+    assert toy.acfg.expert_layers(64) == 0
     step = str(jax.make_jaxpr(toy.acfg.decode)(
         toy.params.trees, toy.acfg.cache_arrays(2, 64),
         np.zeros(2, np.int32), np.zeros(2, np.int32)))
@@ -633,3 +597,7 @@ def test_wait_spans_carry_the_reads_of_each_cache(toy, tmp_path, loop):
     for a in steps + fills:
         assert 0 <= a["expert_load_max"] <= a["expert_tokens"]
         assert isinstance(a["live_full"], int)
+        # the toy's programs have no kept case: each says so
+        assert a["expert_layers_kept"] == a["expert_layers"] == 0
+    assert summary["experts"]["layers_kept"] == \
+        summary["experts"]["layers"] == 0

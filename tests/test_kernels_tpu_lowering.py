@@ -344,7 +344,7 @@ def test_decode_dispatch_picks_the_kernel_on_default_serving_depth(
 # prefill compiled whole over the latent cache
 # ---------------------------------------------------------------------
 
-K2_SLOTS, K2_DEPTH, K2_LAYERS = 64, 1024, 2          # 1 dense + 1 expert
+K2_SLOTS, K2_DEPTH, K2_LAYERS = 64, 2048, 2          # 1 dense + 1 expert
 K2_LAYER_ELEMS = K2_SLOTS * 576 * K2_DEPTH
 K2_CACHE_BYTES = K2_LAYERS * K2_LAYER_ELEMS * 2
 
@@ -397,10 +397,10 @@ def test_k2_kernel_compiles_for_v5e(compiled_kernels, v5e, name):
 
 @pytest.fixture(scope="module")
 def k2_programs(v5e):
-    """(decode step, prefill at bucket 256) of `DecodeEngine` over
-    `models/kimi_k2.py` at the published widths, one dense and one
-    expert layer, compiled for the described v5e with the state
-    donated, as the engine jits them."""
+    """(decode step, prefills at buckets 256 and 2,048) of
+    `DecodeEngine` over `models/kimi_k2.py` at the published widths, one
+    dense and one expert layer, compiled for the described v5e with the
+    state donated, as the engine jits them."""
     import functools
     import json
     import os
@@ -440,16 +440,18 @@ def k2_programs(v5e):
         return {
             "decode_step": compiled(D._decode_step_impl,
                                     aval((K2_SLOTS,), bool)),
-            "prefill_b256": compiled(
-                D._prefill_impl, aval((1, 256), i32), aval((), i32),
+            **{f"prefill_b{bucket}": compiled(
+                D._prefill_impl, aval((1, bucket), i32), aval((), i32),
                 aval((), i32), aval((), i32), aval((), i32),
-                aval((), F32), aval((2,), jnp.uint32)),
+                aval((), F32), aval((2,), jnp.uint32))
+               for bucket in (256, 2048)},
         }
 
 
 @pytest.mark.parametrize("program,in_place", [
     ("decode_step", set()),
-    ("prefill_b256", {"dynamic-update-slice", "fusion"})])
+    ("prefill_b256", {"dynamic-update-slice", "fusion"}),
+    ("prefill_b2048", {"dynamic-update-slice", "fusion"})])
 def test_k2_program_moves_no_layer_of_the_latent_cache(k2_programs, program,
                                                        in_place):
     text = k2_programs[program].as_text()
@@ -479,15 +481,34 @@ def test_k2_program_moves_no_layer_of_the_latent_cache(k2_programs, program,
         assert dims not in text, dims
 
 
-@pytest.mark.parametrize("program", ["decode_step", "prefill_b256"])
+@pytest.mark.parametrize("program", ["decode_step", "prefill_b256",
+                                     "prefill_b2048"])
 def test_k2_program_holds_one_copy_of_the_latent_cache(k2_programs,
                                                        program):
     mem = k2_programs[program].memory_analysis()
     assert mem.alias_size_in_bytes >= K2_CACHE_BYTES
     assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 1 << 20
-    # temporaries: activations of 64 tokens (256 in the prefill) at these
-    # widths, never a second cache
-    assert mem.temp_size_in_bytes < 0.75 * K2_CACHE_BYTES
+    # temporaries: activations of 64 tokens (256 and 2,048 in the
+    # prefills: every row of the 2,048 bucket's expert layer in float32,
+    # [16384, 7168], is 1.56 times this cache) at these widths, never a
+    # second cache (three eighths of this cache is three quarters of
+    # one half as deep)
+    limit = 3.5 if program == "prefill_b2048" else 0.375
+    assert mem.temp_size_in_bytes < limit * K2_CACHE_BYTES
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_b2048"])
+def test_k2_program_runs_the_kept_rows_where_they_fit(k2_programs, program):
+    """`routed_experts`' kept case is in the compiled program: 128 of
+    the decode step's 512 rows (64 slots x 8, a thirty-second held) and
+    1,024 of the prefill's 16,384, under a conditional whose other
+    branch runs every row."""
+    from paddle_tpu.distributed.moe import _rows_kept
+
+    tokens = K2_SLOTS if program == "decode_step" else 2048
+    assert _rows_kept(tokens * 8, 12 / 384) == (
+        128 if program == "decode_step" else 1024)
+    assert "conditional(" in k2_programs[program].as_text()
 
 
 def test_k2_decode_step_names_its_calls(k2_programs):

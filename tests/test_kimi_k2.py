@@ -28,6 +28,8 @@ from paddle_tpu.models import kimi_k2
 from paddle_tpu.models.gpt import GPT, GPTConfig
 from paddle_tpu.serving.decode import DecodeConfig, DecodeEngine
 
+from moe_reference import dense_moe as _dense_moe  # noqa: E402
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CONFIG = os.path.join(ROOT, "benchmarks", "configs", "kimi-k2.6")
 TOL = 2e-4
@@ -302,30 +304,6 @@ def test_router_chooses_by_score_plus_bias_and_weighs_by_score():
                                rtol=1e-6)
 
 
-def _dense_moe(h, router, bias, gate_up, down, top_k, scale, first=0,
-               n_held=None):
-    """Every token through its chosen experts, one at a time, in numpy
-    float64: what `routed_experts` must equal."""
-    h, router, gate_up, down = (np.asarray(a, np.float64)
-                                for a in (h, router, gate_up, down))
-    sig = 1.0 / (1.0 + np.exp(-(h @ router)))
-    n_held = gate_up.shape[0] if n_held is None else n_held
-    y = np.zeros_like(h)
-    counts = np.zeros(n_held, int)
-    for t in range(h.shape[0]):
-        chosen = np.argsort(-(sig[t] + np.asarray(bias)),
-                            kind="stable")[:top_k]
-        total = sig[t][chosen].sum()
-        for e in chosen:
-            if first <= e < first + n_held:
-                gu = h[t] @ gate_up[e - first]
-                f = gu.size // 2
-                act = gu[:f] / (1.0 + np.exp(-gu[:f])) * gu[f:]
-                y[t] += scale * sig[t][e] / total * (act @ down[e - first])
-                counts[e - first] += 1
-    return y, counts
-
-
 def _layer_weights(rng, d=24, f=16, experts=16):
     return dict(
         router=jnp.asarray(rng.standard_normal((d, experts)), jnp.float32),
@@ -342,7 +320,7 @@ def test_routed_experts_computes_its_own_experts_part_only():
     h = jnp.asarray(rng.standard_normal((13, 24)), jnp.float32)
     valid = jnp.arange(13) < 11
     with jax.default_matmul_precision("highest"):
-        y, counts = jax.jit(
+        y, counts, _ = jax.jit(
             lambda h, valid: routed_experts(
                 h, w["router"], w["bias"], (w["gate_up"][4:8],
                                             w["down"][4:8]),
@@ -366,9 +344,9 @@ def test_no_token_is_dropped_at_a_planted_imbalance():
     bias = w["bias"].at[2].set(10.0)
     h = jnp.asarray(rng.standard_normal((64, 24)), jnp.float32)
     with jax.default_matmul_precision("highest"):
-        y, counts = routed_experts(h, w["router"], bias,
-                                   (w["gate_up"][:4], w["down"][:4]),
-                                   0, 16, 2, 2.827)
+        y, counts, _ = routed_experts(h, w["router"], bias,
+                                      (w["gate_up"][:4], w["down"][:4]),
+                                      0, 16, 2, 2.827)
     want, want_counts = _dense_moe(h, w["router"], bias, w["gate_up"][:4],
                                    w["down"][:4], 2, 2.827)
     assert int(counts[2]) == 64 and list(np.asarray(counts)) == \
@@ -399,7 +377,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
                             "shared_gate_up": shared_gu,
                             "shared_down": shared_down}, h, mm, None)
     with jax.default_matmul_precision("highest"):
-        parts, counts = zip(*[routed_experts(
+        parts, counts, _ = zip(*[routed_experts(
             h, w["router"], w["bias"],
             (w["gate_up"][s:s + 4], w["down"][s:s + 4]), s, 16,
             cfg["num_experts_per_tok"], cfg["routed_scaling_factor"])
@@ -575,7 +553,7 @@ def test_routed_experts_through_the_kernel_equals_the_dense_layer():
             h, w["router"], bias, (w["gate_up"][4:8], w["down"][4:8]),
             4, 16, 2, 2.827, use_kernel=True))
         assert "name=moe_grouped_mm" in str(jax.make_jaxpr(fn)(h, bias))
-        y, counts = fn(h, bias)
+        y, counts, _ = fn(h, bias)
         want, want_counts = _dense_moe(h, w["router"], bias,
                                        w["gate_up"][4:8], w["down"][4:8],
                                        2, 2.827, first=4)
@@ -645,13 +623,66 @@ def test_wait_spans_and_summary_carry_the_expert_counts(toy, tmp_path):
         assert "active" in a
     for a, fut in zip(waits["engine.prefill_wait"], futs):
         assert {"bucket", "slot", "rid", "queue_wait_s"} <= set(a)
+    # the toy's programs (2 slots, buckets of 16 and 32) have no kept
+    # case: each says so, and none ran over kept rows
+    for attrs in waits.values():
+        for a in attrs:
+            assert a["expert_layers_kept"] == a["expert_layers"] == 0
     total = sum(a["expert_tokens"] for attrs in waits.values()
                 for a in attrs)
     assert summary["experts"] == {
         "programs": 3 + summary["decode_steps"], "tokens_total": total,
         "load_max": max(a["expert_load_max"] for attrs in waits.values()
-                        for a in attrs)}
+                        for a in attrs),
+        "layers_kept": 0, "layers": 0}
     assert total > 0
+
+
+@pytest.mark.parametrize("planted", [False, True],
+                         ids=["usual", "planted_bias"])
+def test_wait_spans_count_the_expert_layers_over_kept_rows(planted,
+                                                           tmp_path):
+    """A prefill at a bucket of 128 (256 rows, the 4 held of 16 experts
+    expecting 64: 128 kept) runs both expert layers over the kept rows,
+    and says so on `engine.prefill_wait` and in the summary beside the
+    two layers its shape gives the case; a planted bias that sends every
+    token to the held experts (200 assignments of 100 tokens a layer)
+    makes both run every row, and the counter drops to 0 of 2.  The
+    decode steps of 2 slots have no such case."""
+    toy = Toy(max_len=128, max_position_embeddings=128)
+    layers = toy.kcfg.num_layers - toy.kcfg.first_k_dense
+    assert toy.kcfg.expert_layers(128) == layers == 2
+    assert toy.kcfg.expert_layers(2) == 0
+    params = toy.params
+    if planted:
+        held = slice(toy.kcfg.first_expert,
+                     toy.kcfg.first_expert + toy.kcfg.experts_held)
+        params = kimi_k2.K2Params(dict(params.trees, layers=[
+            dict(lp, router_bias=lp["router_bias"].at[held].add(10.0))
+            if "router_bias" in lp else lp
+            for lp in params.trees["layers"]]), params.cfg)
+    eng = DecodeEngine(params, config=DecodeConfig(
+        slots=2, max_len=128, buckets=(128,), watchdog_stall_s=60.0,
+        label=f"k2_kept_{planted}"), auto_start=False)
+    prompt = np.random.default_rng(10).integers(0, 211, size=100)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fut = eng.submit(prompt, 3)
+        _drain(eng, [fut])
+    finally:
+        jax.profiler.stop_trace()
+    summary = eng.summary()["decode"]
+    eng.close()
+    fill, = [a for n, _, _, a in profiler.spans("engine.prefill_wait")]
+    steps = [a for n, _, _, a in profiler.spans("engine.decode_wait")]
+    assert fill["expert_layers"] == layers
+    if planted:
+        assert fill["expert_tokens"] == 200 * layers
+    assert fill["expert_layers_kept"] == (0 if planted else layers)
+    assert steps and all(a["expert_layers_kept"] == a["expert_layers"] == 0
+                         for a in steps)
+    assert summary["experts"]["layers_kept"] == (0 if planted else layers)
+    assert summary["experts"]["layers"] == layers
 
 
 def test_decode_wait_and_summary_count_the_tiles_walked(monkeypatch,
@@ -660,8 +691,8 @@ def test_decode_wait_and_summary_count_the_tiles_walked(monkeypatch,
     totals in the summary are what the requests' lengths give at the tile
     `mla_tiling` names (128 at a depth of 384), the second request
     crossing into its second tile on the way; and the decode program
-    answers with what it answered before: three results of the engine's
-    and the experts' counts."""
+    answers with three results of the engine's and the experts'
+    counters: their counts and the layers over kept rows."""
     from paddle_tpu.serving import decode as D
 
     monkeypatch.setenv("PADDLE_TPU_FORCE_FLASH_DECODE", "1")
@@ -674,8 +705,8 @@ def test_decode_wait_and_summary_count_the_tiles_walked(monkeypatch,
     answer = jax.eval_shape(
         lambda st: D._decode_step_impl(st, toy.params.trees,
                                        np.zeros(2, bool), toy.kcfg), state)
-    assert len(jax.tree.leaves(answer[1:])) == 4
-    assert set(answer[-1]) == {"expert_counts"}
+    assert len(jax.tree.leaves(answer[1:])) == 5
+    assert set(answer[-1]) == {"expert_counts", "expert_layers_kept"}
     rng = np.random.default_rng(9)
     sizes = (12, 126)
     jax.profiler.start_trace(str(tmp_path))
