@@ -826,6 +826,144 @@ def phase_brumby(hf, slots, max_len, buckets, prompt_lens, new_tokens,
     return out
 
 
+def phase_nemotron(hf, slots, max_len, buckets, prompt_lens, new_tokens,
+                   kernel_slots, kernel_bucket, tol, state_tol, gap_tol):
+    """`hf`: the model's sizes under its config.json keys.  (1) Both SSD
+    kernels alone at the model's widths: a decode step over
+    `kernel_slots` slots of which every third is not active (those
+    slots' states bit for bit as they were) against the XLA mathematics;
+    a prefill of `kernel_bucket` positions that ends inside the bucket's
+    padding, its written state within `state_tol` of the recurrence's a
+    position at a time in float32 (`ssd.ssd_recurrent`); milliseconds
+    and bytes a call, the state donated as the engine donates it; (2)
+    the engine's first `new_tokens` tokens of each prompt (several chunks
+    long, ending inside a bucket's padding, one of 2 positions) against
+    the unbatched forward pass (`nemotron_h.full_logits`: the chunked
+    form in XLA, no state, no cache): the widest gap by which a served
+    token's logit lies under that pass's best."""
+    from paddle_tpu.kernels import ssd
+    from paddle_tpu.models import nemotron_h
+    from paddle_tpu.serving import DecodeConfig, DecodeEngine
+
+    cfg = nemotron_h.NemotronHCfg.from_hf(hf, max_seq_len=max_len)
+    dtype = jnp.dtype(cfg.dtype)
+    rng = np.random.default_rng(41)
+    h, p, g, n = (cfg.mamba_heads, cfg.mamba_head_dim, cfg.n_groups,
+                  cfg.ssm_state_size)
+    pack = cfg.tiling.pack
+    a = -jnp.asarray(rng.uniform(1, 16, h), jnp.float32)
+
+    def rand(shape, scale=1.0, dt=dtype):
+        return jnp.asarray(rng.standard_normal(shape) * scale, dt)
+
+    def steps(shape):
+        # dt of 0.001 to 0.1: half-lives of 0.04 to 700 positions
+        return jnp.asarray(10 ** rng.uniform(-3, -1, shape), jnp.float32)
+
+    def ms_in_place(fn, args, state, calls=5):
+        fn = jax.jit(fn, donate_argnums=(len(args),))
+        _, state = fn(*args, state + 0)
+        jax.block_until_ready(state)
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            _, state = fn(*args, state)
+        jax.block_until_ready(state)
+        return (time.perf_counter() - t0) / calls * 1e3
+
+    out = {}
+    s = kernel_slots
+    active = jnp.asarray(np.arange(s) % 3 != 1)
+    args = (rand((s, h, p)), steps((s, h)), a, rand((s, g, n)),
+            rand((s, g, n)))
+    state = rand((2, s, h // pack * n, pack * p), 4.0, jnp.float32)
+
+    def decode(**kw):
+        return lambda x, dt, a, b, c, st: ssd.ssd_decode(
+            x, dt, a, b, c, st, 1, active, **kw)
+
+    want = _highest(decode(use_kernel=False))(*args, state)
+    fn = jax.jit(decode(use_kernel=True))
+    got = fn(*args, state)
+    idle = ~np.asarray(active)
+    _check(bool((got[1][:, idle] == state[:, idle]).all())
+           and bool((got[1][0] == state[0]).all()),
+           "ssd_decode touched a slot that is not active, or another layer")
+    errs = [_err(x, y) for x, y in zip(got, want)]
+    _check(all(rel <= tol for _, rel in errs),
+           f"ssd_decode: {errs}, over {tol}")
+    ms = ms_in_place(decode(use_kernel=True), args, state)
+    step_bytes = 2 * int(active.sum()) * h * p * n * 4
+    out["ssd_decode"] = {"rel_to_max": [rel for _, rel in errs], "ms": ms,
+                         "state_bytes": step_bytes,
+                         "gb_per_s": step_bytes / ms / 1e6}
+
+    t, true_len = kernel_bucket, kernel_bucket - kernel_bucket // 13 - 1
+    pargs = (rand((t, h, p)), steps((t, h)), a, rand((t, g, n)),
+             rand((t, g, n)))
+    want_y, want_s = _highest(ssd.ssd_recurrent)(
+        *(z[:true_len] if z.ndim > 1 else z for z in pargs))
+
+    def prefill(**kw):
+        return lambda x, dt, a, b, c, st: ssd.ssd_prefill(
+            x, dt, a, b, c, true_len, st, 1, 2, chunk=cfg.chunk_size, **kw)
+
+    fn = jax.jit(prefill(use_kernel=True))
+    y, got_s = fn(*pargs, state)
+    errs = [_err(y[:true_len], want_y),
+            _err(ssd.unpack_state(got_s[1, 2], h, pack), want_s)]
+    _check(errs[0][1] <= tol, f"ssd_prefill: {errs}, over {tol}")
+    _check(errs[1][1] <= state_tol,
+           f"ssd_prefill's state: {errs[1]}, over {state_tol}")
+    _check(bool((got_s[1, 0] == state[1, 0]).all())
+           and bool((got_s[0] == state[0]).all()),
+           "ssd_prefill touched another slot or layer")
+    out["ssd_prefill"] = {
+        "rel_to_max": [rel for _, rel in errs],
+        "ms": ms_in_place(prefill(use_kernel=True), pargs, state, calls=3),
+        "bucket": t, "chunks": t // cfg.chunk_size}
+    del state, want, got, got_s
+
+    params = nemotron_h.NemotronHParams.from_flat(
+        cfg, nemotron_h.init_params(cfg, jax.random.PRNGKey(41)))
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in prompt_lens]
+    forward = jax.jit(functools.partial(nemotron_h.full_logits, cfg))
+    eng = DecodeEngine(params, config=DecodeConfig(
+        slots=slots, max_len=max_len, buckets=buckets))
+    try:
+        futs = [eng.submit(p, max_new_tokens=new_tokens) for p in prompts]
+        served = [np.asarray(f.result(timeout=900)) for f in futs]
+        summary = eng.summary()
+    finally:
+        eng.close()
+    widest, same = 0.0, 0
+    for p, toks in zip(prompts, served):
+        _check(toks.shape == (new_tokens,), f"prompt {p.size}: {toks.shape}")
+        ids = np.zeros(max_len, np.int32)          # one shape: one compile
+        ids[:p.size] = p
+        ids[p.size:p.size + new_tokens] = toks
+        logits = np.asarray(forward(params.trees, ids), np.float32)
+        picked = logits[p.size - 1:p.size - 1 + new_tokens]
+        widest = max(widest, float((picked.max(axis=1) - picked[
+            np.arange(new_tokens), toks]).max()))
+        same += int((picked.argmax(axis=1) == toks).sum())
+    _check(widest <= gap_tol, f"engine tokens lie up to {widest:.3g} under "
+                              f"the forward pass's best, over {gap_tol}")
+    dec = summary["decode"]
+    cache = dec["cache"]
+    _check([(a["name"], a["kind"]) for a in cache["arrays"]]
+           == [("ssd", "state"), ("conv", "state"), ("k", "depth"),
+               ("v", "depth")], f"the cache's arrays: {cache}")
+    _check(cache["state_bytes"] > 0 and cache["chunks"] > 0,
+           f"no state traffic counted: {cache}")
+    _check(dec["experts"]["tokens_total"] > 0, f"no expert counts: {dec}")
+    out.update({"requests": len(prompts), "widest_logit_gap": widest,
+                "tokens_equal_to_forward":
+                    f"{same}/{len(prompts) * new_tokens}", "cache": cache,
+                "experts": dec["experts"]})
+    return out
+
+
 # ---------------------------------------------------------------------------
 # four chips
 # ---------------------------------------------------------------------------
@@ -958,11 +1096,29 @@ def main():
             prefill_seq=4096, tol=5e-2,
             gap_tol=0.45)  # read 0.3125 (145 of 160 tokens equal), PR 34
 
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "nemotron-3-nano-30b-a3b.json")) as f:
+        nemotron = json.load(f)
+
+    def run_nemotron():
+        # the cell's 13 layers on 4 slots: prompts of several chunks of
+        # 128 that end inside their bucket's padding, and one of 2 (a conv
+        # window mostly zeros); the kernels alone at 64 slots
+        return run("nemotron", phase_nemotron, nemotron, slots=4,
+                   max_len=2048, buckets=(256, 1024),
+                   prompt_lens=[200, 700, 2, 1000, 129], new_tokens=32,
+                   kernel_slots=64, kernel_bucket=2048,
+                   # float32 on both sides of each comparison: a product
+                   # on bfloat16 operands would read about 4e-3
+                   tol=1e-3, state_tol=1e-3, gap_tol=0.5)
+
     # one phase alone: `chip_smoke.py brumby [rows a decode tile ...]`,
-    # `chip_smoke.py afmoe`
+    # `chip_smoke.py afmoe`, `chip_smoke.py nemotron`
     alone = {"brumby": lambda: run_brumby(tile_rows_tried=(None,) + tuple(
                  int(a) for a in sys.argv[2:])),
-             "afmoe": run_afmoe}.get(sys.argv[1]) if sys.argv[1:] else None
+             "afmoe": run_afmoe,
+             "nemotron": run_nemotron}.get(sys.argv[1]) if sys.argv[1:] \
+        else None
     if alone is not None:
         alone()
         print(json.dumps({"ok": True, "device": device}), flush=True)
@@ -994,6 +1150,7 @@ def main():
                                        2047], tol=5e-2, gap_tol=0.25)
     run_afmoe()
     run_brumby()
+    run_nemotron()
     if device["count"] >= 4:
         run("four_chips", phase_four_chips, GPT_FULL, 8, 2048, 3,
             layer["losses"][0], STATIC_GPT_FULL, static_batch,

@@ -212,10 +212,12 @@ def sigmoid_top_k(h, router, bias, top_k, scale):
 def grouped_product(rows, weights, counts, use_kernel=None):
     """rows [M, K] sorted by group, group g owning the next counts[g];
     weights [G, K, N] -> float32 [M, N], row r of group g being
-    `rows[r] @ weights[g]`; rows past the groups' hold anything.  On the
-    TPU, at shapes it tiles, the Pallas call `moe_grouped_mm`
-    (kernels/grouped_mm.py); elsewhere `jax.lax.ragged_dot`.
-    `use_kernel` forces the choice (the tests' interpreter runs)."""
+    `rows[r] @ weights[g]`; rows past the groups' hold anything: either
+    product of an expert, whatever its function.  On the TPU, at shapes
+    it tiles, the Pallas call `moe_grouped_mm` (kernels/grouped_mm.py;
+    `takes_kernel` logs, once a shape, why it refuses one); elsewhere
+    `jax.lax.ragged_dot`.  `use_kernel` forces the choice (the tests'
+    interpreter runs)."""
     from ..kernels import grouped_mm
     from ..kernels.backend import is_tpu_backend
 
@@ -227,15 +229,31 @@ def grouped_product(rows, weights, counts, use_kernel=None):
                               preferred_element_type=jnp.float32)
 
 
+def swiglu_act(gu):
+    """SwiGLU's activation of the first product, gate and up side by
+    side: float32 [M, 2F] -> [M, F]."""
+    f = gu.shape[-1] // 2
+    return jax.nn.silu(gu[..., :f]) * gu[..., f:]
+
+
+def relu2_act(u):
+    """The squared ReLU (Primer; `relu2`) of the first product: float32
+    [M, F] -> [M, F]."""
+    return jnp.square(jax.nn.relu(u))
+
+
 def routed_experts(h, router, bias, experts_held, first_expert, n_routed,
-                   top_k, scale, valid=None, use_kernel=None):
+                   top_k, scale, valid=None, use_kernel=None, act=swiglu_act):
     """The routed part of an expert layer on a chip that holds experts
     [first_expert, first_expert + held) of `n_routed`.
 
-    h [N, D]; router [D, n_routed]; bias [n_routed]; experts_held =
-    (gate_up [held, D, 2F], down [held, F, D]), SwiGLU experts with gate
-    and up side by side; valid: bool [N] or None, tokens that are
-    padding make no assignment.  Every token is routed over all
+    h [N, D]; router [D, n_routed]; bias [n_routed]; experts_held = (the
+    first matrix [held, D, F'], down [held, F, D]) and `act` the model's
+    expert function between them, a module-level function of the first
+    product's float32 [M, F'] -> [M, F]: `swiglu_act` (gate and up side
+    by side, F' = 2F; K2 and afmoe) or `relu2_act` (up alone, F' = F;
+    nemotron_h); valid: bool [N] or None, tokens that are padding make
+    no assignment.  Every token is routed over all
     `n_routed` (`sigmoid_top_k`, the weights normalised over all its
     chosen experts, held here or not); the assignments that fall on the
     experts held here are sorted by expert and go through one grouped
@@ -255,25 +273,26 @@ def routed_experts(h, router, bias, experts_held, first_expert, n_routed,
     int32 [held], int32 [] 1 where the layer ran over the kept rows and
     0 where it ran over every row); the caller adds the shared
     expert."""
-    gate_up, down = experts_held
-    held = gate_up.shape[0]
+    first, down = experts_held
+    held = first.shape[0]
     if router.shape[1] != n_routed or not \
             0 <= first_expert <= n_routed - held:
         raise ValueError(
             f"experts [{first_expert}, {first_expert + held}) do not lie "
             f"in a router over {router.shape[1]} (n_routed {n_routed})")
-    return _routed(h, router, bias, gate_up, down, valid,
+    return _routed(h, router, bias, first, down, valid,
                    first_expert=first_expert, n_routed=n_routed,
-                   top_k=top_k, scale=float(scale), use_kernel=use_kernel)
+                   top_k=top_k, scale=float(scale), use_kernel=use_kernel,
+                   act=act)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "first_expert", "n_routed", "top_k", "scale", "use_kernel"))
-def _routed(h, router, bias, gate_up, down, valid, *, first_expert,
-            n_routed, top_k, scale, use_kernel):
+    "first_expert", "n_routed", "top_k", "scale", "use_kernel", "act"))
+def _routed(h, router, bias, w_in, down, valid, *, first_expert,
+            n_routed, top_k, scale, use_kernel, act):
     """`routed_experts`' body: jitted and not inlined, so that a program
     with several expert layers of one shape traces and lowers it once."""
-    held = gate_up.shape[0]
+    held = w_in.shape[0]
     n, d = h.shape
     experts, weights = sigmoid_top_k(h, router, bias, top_k, scale)
     local = experts - first_expert
@@ -287,14 +306,13 @@ def _routed(h, router, bias, gate_up, down, valid, *, first_expert,
                      axis=0, dtype=jnp.int32)
 
     def experts_of(order):
-        """The held experts' SwiGLU of the assignments `order` names,
+        """The held experts' function of the assignments `order` names,
         float32 [len(order), D]; rows past the held assignments hold
         whatever the grouped product left there."""
         rows = jnp.take(h, order // top_k, axis=0)
-        gu = grouped_product(rows, gate_up, counts, use_kernel)
-        f = gu.shape[1] // 2
-        act = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(h.dtype)
-        return grouped_product(act, down, counts, use_kernel)
+        gu = grouped_product(rows, w_in, counts, use_kernel)
+        return grouped_product(act(gu).astype(h.dtype), down, counts,
+                               use_kernel)
 
     def every_row():
         # back into the tokens' order

@@ -28,6 +28,7 @@ and reads them at four fifths (1.05 ms):
 """
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
@@ -42,18 +43,46 @@ ROW_TILE = 128
 
 def _tile(n, largest):
     """The largest power-of-two multiple of 128, at most `largest`, that
-    divides n; None where 128 does not."""
+    divides n; where that is 128 alone (n / 128 odd: nemotron_h's 2,688
+    and its experts' 1,920), the largest multiple of 128 at most
+    `largest` that divides n, so that a weight's tile is not a sliver of
+    128 x 128; None where 128 does not divide n."""
     t = largest
     while t >= _LANES:
         if n % t == 0:
-            return t
+            break
         t //= 2
-    return None
+    if t < _LANES:
+        return None
+    if t == _LANES:
+        t = max(c for c in range(_LANES, largest + 1, _LANES) if n % c == 0)
+    return t
+
+
+_REFUSED = set()
+
+
+def refusal(m, k, n):
+    """Why `moe_grouped_mm` does not tile rows [m, k] against weights
+    [G, k, n], or None where it does: whole row tiles and whole lanes."""
+    why = [f"{name} {v} is not a multiple of {unit}" for name, v, unit in
+           (("rows", m, ROW_TILE), ("K", k, _LANES), ("N", n, _LANES))
+           if v % unit]
+    return "; ".join(why) or None
 
 
 def takes_kernel(m, k, n):
-    """Shapes `moe_grouped_mm` tiles: whole row tiles and whole lanes."""
-    return m % ROW_TILE == 0 and k % _LANES == 0 and n % _LANES == 0
+    """Shapes `moe_grouped_mm` tiles: whole row tiles and whole lanes.
+    A shape it refuses is logged once, with the reason (a model whose
+    expert width is not whole lanes pads its stored experts, as
+    models/nemotron_h.py does)."""
+    why = refusal(m, k, n)
+    if why is not None and (m, k, n) not in _REFUSED:
+        _REFUSED.add((m, k, n))
+        logging.getLogger(__name__).info(
+            "moe_grouped_mm refuses [%d, %d] x [G, %d, %d] (%s): "
+            "jax.lax.ragged_dot instead", m, k, k, n, why)
+    return why is None
 
 
 def _visits(counts, m, tm):

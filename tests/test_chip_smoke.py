@@ -141,6 +141,29 @@ def test_brumby_phase_at_the_rehearsal_size():
     assert max(fill["rel_to_max_float32_operands"]) < 1e-4
 
 
+def test_nemotron_phase_at_the_rehearsal_size():
+    import json
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "nemotron-3-nano-30b-a3b.json")) as f:
+        hf = json.load(f)
+    # the rehearsal's toy widths, but Mamba heads of 64 that the kernels
+    # tile (2 a row of lanes): both run in the interpreter, alone,
+    # against the XLA mathematics and the recurrence
+    hf.update(hf["rehearse"])
+    hf.update(dtype="float32", mamba_head_dim=64)
+    r = chip_smoke.phase_nemotron(
+        hf, slots=2, max_len=128, buckets=(32, 64), prompt_lens=[5, 40, 2],
+        new_tokens=12, kernel_slots=3, kernel_bucket=32, tol=1e-4,
+        state_tol=1e-4, gap_tol=1e-3)
+    assert r["requests"] == 3
+    assert r["tokens_equal_to_forward"] == "36/36"
+    assert [a["kind"] for a in r["cache"]["arrays"]] == [
+        "state", "state", "depth", "depth"]
+    assert r["cache"]["chunks"] > 0 and r["cache"]["state_bytes"] > 0
+    assert r["ssd_prefill"]["chunks"] == 2
+
+
 def test_four_chips_phase_on_the_cpu_mesh():
     """The CPU-mesh twin of the four-chip phase: shards on four distinct
     devices, half a tensor-parallel leaf on each, first-step losses

@@ -841,3 +841,167 @@ def test_brumby_programs_name_their_calls(brumby_programs):
     assert calls == ["retention_decode"] * BR_LAYERS
     fills = _mosaic_calls(brumby_programs["prefill_b6144"].as_text())
     assert fills == ["retention_prefill"] * BR_LAYERS
+
+
+# ---------------------------------------------------------------------
+# nemotron_h (Mamba-2 states beside grouped-query caches): both SSD
+# kernels at the cell's widths, and the decode engine's programs compiled
+# whole: each state held once, no layer of the SSD state moved
+# ---------------------------------------------------------------------
+
+NM_SLOTS, NM_MAMBA, NM_HEADS, NM_DIM, NM_GROUPS, NM_N = 512, 6, 64, 64, 8, 128
+NM_SSD = (NM_MAMBA, NM_SLOTS, NM_HEADS // 2 * NM_N, 2 * NM_DIM)
+NM_LAYER_ELEMS = math.prod(NM_SSD[1:])
+
+
+def _nemotron_cases():
+    from paddle_tpu.kernels.grouped_mm import moe_grouped_mm
+    from paddle_tpu.kernels.ssd import ssd_decode, ssd_prefill
+
+    s, h, p, g, n = NM_SLOTS, NM_HEADS, NM_DIM, NM_GROUPS, NM_N
+    state = (NM_SSD, F32)
+    out = {"ssd_decode": (
+        lambda x, dt, a, b, c, st, act: ssd_decode(
+            x, dt, a, b, c, st, 3, act)[0],
+        [((s, h, p), BF16), ((s, h), F32), ((h,), F32), ((s, g, n), BF16),
+         ((s, g, n), BF16), state, ((s,), bool)], ["ssd_decode"])}
+    for bucket in (256, 2048):
+        out[f"ssd_prefill_{bucket}"] = (
+            lambda x, dt, a, b, c, t, st, slot: ssd_prefill(
+                x, dt, a, b, c, t, st, 2, slot, chunk=128)[0],
+            [((bucket, h, p), BF16), ((bucket, h), F32), ((h,), F32),
+             ((bucket, g, n), BF16), ((bucket, g, n), BF16), ((), jnp.int32),
+             state, ((), jnp.int32)], ["ssd_prefill"])
+    # the held experts' two products at the width stored (1,856 padded
+    # to 1,920), 768 kept rows of a decode step of 512 slots
+    out["moe_grouped_mm_up_1920"] = (
+        lambda r, w, c: moe_grouped_mm(r, w, c),
+        [((768, 2688), BF16), ((16, 2688, 1920), BF16), ((16,), jnp.int32)],
+        ["moe_grouped_mm"])
+    out["moe_grouped_mm_down_1920"] = (
+        lambda r, w, c: moe_grouped_mm(r, w, c),
+        [((768, 1920), BF16), ((16, 1920, 2688), BF16), ((16,), jnp.int32)],
+        ["moe_grouped_mm"])
+    return out
+
+
+@pytest.mark.parametrize("name", [
+    "ssd_decode", "ssd_prefill_256", "ssd_prefill_2048",
+    "moe_grouped_mm_up_1920", "moe_grouped_mm_down_1920"])
+def test_nemotron_kernel_compiles_for_v5e(compiled_kernels, v5e, name):
+    fn, args, calls = _nemotron_cases()[name]
+    one = jax.sharding.SingleDeviceSharding(v5e[0])
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in args]
+    text = jax.jit(fn).trace(*avals).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert sorted(set(_mosaic_calls(text))) == calls
+
+
+@pytest.fixture(scope="module")
+def nemotron_programs(v5e):
+    """(decode step, prefill at bucket 2048) of `DecodeEngine` over
+    `models/nemotron_h.py` at the cell's widths, 13 layers and 512
+    slots x 3,072, compiled for the described v5e with the state
+    donated, as the engine jits them."""
+    import functools
+    import json
+    import os
+
+    from paddle_tpu.models import nemotron_h
+    from paddle_tpu.serving import decode as D
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "nemotron-3-nano-30b-a3b.json")) as f:
+        cfg = json.load(f)
+    ncfg = nemotron_h.NemotronHCfg.from_hf(cfg, max_seq_len=3072)
+    one = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    flat = {n: aval(s, F32 if kind in ("dt_bias", "A_log", "D", "bias")
+                    else BF16)
+            for n, (s, kind) in nemotron_h.param_shapes(ncfg).items()}
+    trees = jax.tree.map(
+        lambda a: aval(a.shape, a.dtype),
+        jax.eval_shape(lambda f: nemotron_h.NemotronHParams.from_flat(
+            ncfg, f).trees, flat))
+    i32 = jnp.int32
+    cache = {n: aval(a.shape, a.dtype) for n, a in jax.eval_shape(
+        lambda: ncfg.cache_arrays(NM_SLOTS, 3072)).items()}
+    assert {n: a.shape for n, a in cache.items()} == {
+        "ssd": NM_SSD, "conv": (NM_MAMBA, 3, NM_SLOTS, 6144),
+        "k": (2, NM_SLOTS, 2, 128, 3072), "v": (2, NM_SLOTS, 2, 128, 3072)}
+    s = NM_SLOTS
+    state = dict(cache, pos=aval((s,), i32), active=aval((s,), bool),
+                 token=aval((s,), i32), stop=aval((s,), i32),
+                 eos=aval((s,), i32), temp=aval((s,), F32),
+                 key=aval((s, 2), jnp.uint32))
+
+    def compiled(impl, *args):
+        return jax.jit(functools.partial(impl, cfg=ncfg),
+                       donate_argnums=(0,)).trace(
+            state, trees, *args).lower(
+            lowering_platforms=("tpu",)).compile()
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(backend, "is_tpu_backend", lambda: True)
+        return {
+            "cache_bytes": sum(math.prod(a.shape) * a.dtype.itemsize
+                               for a in cache.values()),
+            "decode_step": compiled(D._decode_step_impl, aval((s,), bool)),
+            "prefill_b2048": compiled(
+                D._prefill_impl, aval((1, 2048), i32), aval((), i32),
+                aval((), i32), aval((), i32), aval((), i32),
+                aval((), F32), aval((2,), jnp.uint32)),
+        }
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_b2048"])
+def test_nemotron_program_moves_no_layer_of_the_ssd_state(nemotron_programs,
+                                                          program):
+    """No copy, slice, update or re-layout of the SSD state or of a layer
+    of it: only the aliased Mosaic calls touch it."""
+    text = nemotron_programs[program].as_text()
+    dims = f"{NM_SLOTS},{NM_SSD[2]},{NM_SSD[3]}]"
+    large = [(op, line) for op, line in _large_results(text, NM_LAYER_ELEMS)
+             if dims in line.split(" = ", 1)[1].split("(")[0]]
+    assert large, "the state is not in the program at all"
+    odd = [(op, line) for op, line in large if op not in PASSES_ALONG]
+    assert not odd, odd
+    assert f"f32[{dims}" not in text.replace(f"f32[{NM_MAMBA},{dims}", "")
+    # nor is the conv window copied whole (a layout other than the
+    # parameter's would copy it in and out, 113 MB each way)
+    conv = f"bf16[{NM_MAMBA},3,{NM_SLOTS},6144]"
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= \S*" + re.escape(conv) + r"\S* copy", line)]
+    assert not copies, copies
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_b2048"])
+def test_nemotron_program_holds_one_copy_of_each_state(nemotron_programs,
+                                                       program):
+    """Both states and K and V (9.8 GB) are aliased, arguments to
+    results; temporaries are a step's or a prompt's activations, never a
+    second state."""
+    mem = nemotron_programs[program].memory_analysis()
+    cache = nemotron_programs["cache_bytes"]
+    assert mem.alias_size_in_bytes >= cache
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 1 << 20
+    assert mem.temp_size_in_bytes < 0.05 * cache
+
+
+def test_nemotron_programs_name_their_calls(nemotron_programs):
+    """6 Mamba layers of `ssd_decode` or `ssd_prefill`, 2 attention
+    layers of `gqa_decode` (the prefill's attention is `flash_fwd`), and
+    the 5 expert layers' two products in each branch of the kept rows'
+    cond."""
+    from collections import Counter
+
+    calls = Counter(_mosaic_calls(
+        nemotron_programs["decode_step"].as_text()))
+    assert calls == {"ssd_decode": 6, "gqa_decode": 2, "moe_grouped_mm": 20}
+    fills = Counter(_mosaic_calls(
+        nemotron_programs["prefill_b2048"].as_text()))
+    assert fills == {"ssd_prefill": 6, "flash_fwd": 2, "moe_grouped_mm": 20}

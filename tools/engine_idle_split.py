@@ -19,7 +19,10 @@ admissions in time and late; and, where the model's decode kernel walks
 its cache in tiles (`cfg.cache_walk`: `latent_tiles` of `latent_grid`
 for `models/kimi_k2.py`, `full_tiles` of `full_grid` and `window_tiles`
 of `window_grid` for `models/afmoe.py`), the tiles walked of the
-rectangle's, summed over the run's decode steps.
+rectangle's, summed over the run's decode steps; and the means of the
+counters each kind of `*_wait` span carries where the model gives them
+(`COUNTERS`: a state's `state_bytes` and a prefill's `chunks`, the
+cached positions read, the experts' load and kept rows).
 
 Also printed: the device's idle time under `host.gc` (the program's
 span for a garbage collection, on whatever thread it ran; since ISSUE
@@ -234,6 +237,29 @@ def cache_walk(cache):
             if n + "_grid" in cache}
 
 
+# the counters a model's programs leave on the `*_wait` spans (serving/
+# decode.py, "The seam"): a state's traffic and its prefill's chunks, the
+# cached positions read, the experts' load and the kept rows
+COUNTERS = ("state_bytes", "chunks", "live_full", "live_window",
+            "expert_tokens", "expert_load_max", "expert_layers_kept",
+            "expert_layers")
+
+
+def span_counters(waits):
+    """{span name: {"spans": n, counter: mean over the spans that carry
+    it}} of the traced `*_wait` spans."""
+    out = {}
+    for name in sorted({w[0] for w in waits}):
+        mine = [w[3] for w in waits if w[0] == name]
+        row = {"spans": len(mine)}
+        for key in COUNTERS:
+            vals = [a[key] for a in mine if key in a]
+            if vals:
+                row[key] = sum(vals) / len(vals)
+        out[name] = row
+    return out
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", default="gpt2-medium.serve-closed-c64")
@@ -295,6 +321,11 @@ def main(argv=None):
               "lookahead": captured.get("lookahead"),
               "cache_walk": captured.get("walk", {}),
               "summary_device": captured.get("device")}
+    report["span_counters"] = span_counters(waits)
+    for name, row in report["span_counters"].items():
+        print(f"{name}: " + ", ".join(
+            f"{k} {v:.6g}" + (" (mean)" if k != "spans" else "")
+            for k, v in row.items()))
     for name, (tiles, grid) in report["cache_walk"].items():
         print(f"cache walk: {name}_tiles {tiles} of {name}_grid {grid} "
               f"({100 * tiles / max(grid, 1):.2f}%), one layer, all decode "
