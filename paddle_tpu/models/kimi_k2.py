@@ -34,12 +34,33 @@ homogeneous scan, and each layer's weights are arrays of their own, so
 no program slices a layer out of a stack); the cache is carried whole
 from layer to layer and written in place.
 
+What the trees hold is not what `param_shapes` publishes, in two
+places of each layer's attention, laid out once by `K2Params.from_flat`
+so that no program re-lays a weight that never changes:
+
+- `kv_b [rank, heads * (nope + v)]` is stored as its two halves, `w_uk
+  [heads, nope, rank]` and `w_uv [heads, v, rank]`, rank minor: the
+  layout in which the TPU's compiler reads both in the decode step's
+  absorbed products and the prefill's K and V.  Held in the published
+  form, every program copied it whole to that layout twice a layer and
+  sliced the two halves out of it again: 50 MB written a layer.
+- the rotary columns, each head's 64 of `q_b` and the 64 of `kv_a`, are
+  permuted from the published pairs `(2i, 2i + 1)` to the pairs' first
+  lanes, then their second lanes, so that the rotation reads two
+  contiguous halves and not two strided lane sets (each gathered,
+  transposed and copied).  Its output is what the published form
+  gives: the cache's `k_rope` is the same bit for bit.
+
+`param_shapes` stays the published `[in, out]` layout: what a
+checkpoint, the benchmark's reference and `init_params` name.
+
 A chip holds `experts_held` of the `n_routed` experts, from
 `first_expert`: one chip's share of an expert-parallel deployment.  What
 the absent experts would add is left out, and that partial result goes on
 to the next layer; nothing stands in for the other chips.
 """
 
+import functools
 import math
 from typing import NamedTuple
 
@@ -164,15 +185,36 @@ class K2Params(NamedTuple):
     @classmethod
     def from_flat(cls, cfg, flat):
         """{name: array} under `param_shapes`' names -> the program's
-        trees: the same arrays, a dict for each layer."""
+        trees: a dict for each layer, its attention laid out as the
+        module's docstring says (one jitted pass a layer), the other
+        arrays as they came."""
         layers = []
         for i in range(cfg.num_layers):
             pre = f"layers.{i}."
-            layers.append({n[len(pre):]: v for n, v in flat.items()
-                           if n.startswith(pre)})
+            lp = {n[len(pre):]: v for n, v in flat.items()
+                  if n.startswith(pre)}
+            attn = {n: lp.pop(n) for n in ("q_b", "kv_a", "kv_b")}
+            layers.append({**lp, **_lay_out_attention(cfg, **attn)})
         return cls({"embed": flat["embed"], "layers": layers,
                     "final_norm": flat["final_norm"],
                     "lm_head": flat["lm_head"]}, cfg)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _lay_out_attention(cfg, q_b, kv_a, kv_b):
+    """One layer's published `q_b`, `kv_a`, `kv_b` -> the trees' `q_b`,
+    `kv_a` with their rotary columns in halves, `w_uk`, `w_uv`."""
+    heads, nope, rank = cfg.num_heads, cfg.qk_nope_head_dim, \
+        cfg.kv_lora_rank
+    # the pairs' first lanes, then their second lanes
+    rope = np.concatenate([np.arange(0, cfg.qk_rope_head_dim, 2),
+                           np.arange(1, cfg.qk_rope_head_dim, 2)])
+    q_cols = np.concatenate([np.arange(nope), nope + rope])
+    q_b = q_b.reshape(cfg.q_lora_rank, heads, -1)[..., q_cols]
+    kv_a = kv_a[:, np.concatenate([np.arange(rank), rank + rope])]
+    w = kv_b.reshape(rank, heads, -1).transpose(1, 2, 0)
+    return {"q_b": q_b.reshape(cfg.q_lora_rank, -1), "kv_a": kv_a,
+            "w_uk": w[:, :nope], "w_uv": w[:, nope:]}
 
 
 def param_shapes(cfg):
@@ -264,8 +306,9 @@ def yarn_inv_freq(dim, theta, yarn):
 
 def _rope(cfg, x, pos):
     """Rotate x [..., rope] at positions pos (the leading dimensions of
-    x, or broadcastable to them): lanes (2i, 2i+1) are a pair; the result
-    holds the pairs' first halves, then their second halves."""
+    x, or broadcastable to them).  x holds the published pairs (2i,
+    2i+1) as the trees lay them out: the pairs' first lanes, then their
+    second lanes; the result keeps that order."""
     factor, _, _, _, mscale, all_dim = cfg.yarn
     # the published scale of cos and sin: 1 where the two are equal
     m = yarn_mscale(factor, mscale) / yarn_mscale(factor, all_dim)
@@ -273,7 +316,8 @@ def _rope(cfg, x, pos):
         cfg.qk_rope_head_dim, cfg.rope_theta, cfg.yarn)
     cos, sin = jnp.cos(angle) * m, jnp.sin(angle) * m
     x = x.astype(jnp.float32)
-    a, b = x[..., 0::2], x[..., 1::2]
+    half = cfg.qk_rope_head_dim // 2
+    a, b = x[..., :half], x[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
 
 
@@ -301,12 +345,6 @@ def _queries_and_latent(cfg, lp, h, pos):
         [c_kv, k_rope.astype(h.dtype)], axis=-1)
 
 
-def _kv_b(cfg, lp):
-    """W_kvb as (W_uk [rank, heads, nope], W_uv [rank, heads, v])."""
-    w = lp["kv_b"].reshape(cfg.kv_lora_rank, cfg.num_heads, -1)
-    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
-
-
 def _decode(cfg, trees, cache, token, pos):
     """One step of every slot: token [S] at pos [S] -> (cache, final
     hidden [S, H], counters: `expert_counts` int32 [held] and
@@ -319,11 +357,10 @@ def _decode(cfg, trees, cache, token, pos):
     for l, lp in enumerate(trees["layers"]):
         h = rms_norm(cfg, x, lp["attn_norm"])
         q_nope, q_rope, new = _queries_and_latent(cfg, lp, h, pos)
-        w_uk, w_uv = _kv_b(cfg, lp)
-        q_latent = jnp.einsum("shd,chd->shc", q_nope, w_uk)
+        q_latent = jnp.einsum("shd,hdc->shc", q_nope, lp["w_uk"])
         o, latent = resident_mla_attention(
             q_latent, q_rope, new, latent, l, pos, cfg.softmax_scale)
-        o = jnp.einsum("shc,chd->shd", o, w_uv)
+        o = jnp.einsum("shc,hdc->shd", o, lp["w_uv"])
         x = x + o.reshape(o.shape[0], -1) @ lp["o"]
         y, counters = _ffn(cfg, lp, rms_norm(cfg, x, lp["ffn_norm"]),
                            counters)
@@ -366,10 +403,9 @@ def _forward(cfg, trees, ids, valid):
         h = rms_norm(cfg, x, lp["attn_norm"])
         q_nope, q_rope, new = _queries_and_latent(cfg, lp, h, pos)
         latents.append(new)
-        w_uk, w_uv = _kv_b(cfg, lp)
         c_kv = new[:, :cfg.kv_lora_rank]
-        k_nope = jnp.einsum("nc,chd->hnd", c_kv, w_uk)
-        v = jnp.einsum("nc,chd->hnd", c_kv, w_uv)
+        k_nope = jnp.einsum("nc,hdc->hnd", c_kv, lp["w_uk"])
+        v = jnp.einsum("nc,hdc->hnd", c_kv, lp["w_uv"])
         k_rope = jnp.broadcast_to(new[None, :, cfg.kv_lora_rank:],
                                   (cfg.num_heads, n, cfg.qk_rope_head_dim))
         q = jnp.concatenate([q_nope, q_rope], axis=-1).swapaxes(0, 1)

@@ -176,23 +176,32 @@ PASSES_ALONG = {"parameter", "get-tuple-element", "tuple", "bitcast",
 _INSTR = re.compile(r"^\s*(?:ROOT )?%[\w.-]+ = (.*?) ([a-z][\w-]*)\(")
 
 
-def _large_results(text, layer_elems=LAYER_ELEMS):
-    """[(opcode, line)] of every instruction of the compiled program
-    whose result, or an element of whose tuple result, holds a layer's
-    cache or more."""
+def _results(text):
+    """[(opcode, dtype, dims, line)] for every array an instruction of
+    the compiled program yields (each element of a tuple result), those
+    inside fused computations too."""
     out = []
     for line in text.splitlines():
         m = _INSTR.match(line)
         if not m:
             continue
-        sizes = [math.prod(int(n) for n in dims.split(",") if n)
-                 for dims in re.findall(r"[a-z]\w*\[([\d,]*)\]", m.group(1))]
-        if max(sizes, default=0) >= layer_elems:
-            op = m.group(2)
-            if op == "custom-call" and "tpu_custom_call" in line:
-                op = "tpu_custom_call"
-            out.append((op, line.strip()[:160]))
+        op = m.group(2)
+        if op == "custom-call" and "tpu_custom_call" in line:
+            op = "tpu_custom_call"
+        out += [(op, dtype, tuple(int(n) for n in dims.split(",") if n),
+                 line.strip()[:160])
+                for dtype, dims in re.findall(r"([a-z]\w*)\[([\d,]*)\]",
+                                              m.group(1))]
     return out
+
+
+def _large_results(text, layer_elems=LAYER_ELEMS):
+    """[(opcode, line)] of every instruction of the compiled program
+    whose result, or an element of whose tuple result, holds a layer's
+    cache or more."""
+    return list(dict.fromkeys((op, line) for op, _, dims, line
+                              in _results(text)
+                              if math.prod(dims) >= layer_elems))
 
 
 @pytest.fixture(scope="module")
@@ -418,9 +427,13 @@ def k2_programs(v5e):
     def aval(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    trees = kimi_k2.K2Params.from_flat(kcfg, {
-        n: aval(s, F32 if kind == "bias" else BF16)
-        for n, (s, kind) in kimi_k2.param_shapes(kcfg).items()}).trees
+    # the published leaves through the engine's load-time layout
+    flat = {n: aval(s, F32 if kind == "bias" else BF16)
+            for n, (s, kind) in kimi_k2.param_shapes(kcfg).items()}
+    trees = jax.tree.map(
+        lambda a: aval(a.shape, a.dtype),
+        jax.eval_shape(lambda f: kimi_k2.K2Params.from_flat(kcfg, f).trees,
+                       flat))
     i32 = jnp.int32
     state = {"latent": aval((K2_LAYERS, K2_SLOTS, 576, K2_DEPTH), BF16),
              "pos": aval((K2_SLOTS,), i32),
@@ -509,6 +522,34 @@ def test_k2_program_runs_the_kept_rows_where_they_fit(k2_programs, program):
     assert _rows_kept(tokens * 8, 12 / 384) == (
         128 if program == "decode_step" else 1024)
     assert "conditional(" in k2_programs[program].as_text()
+
+
+# what may re-lay an array: copies and transposes, fused or not, slices
+# and gathers
+RELAYOUTS = {"copy", "transpose", "slice", "gather"}
+
+
+@pytest.mark.parametrize("program,tokens", [
+    ("decode_step", K2_SLOTS), ("prefill_b256", 256),
+    ("prefill_b2048", 2048)])
+def test_k2_program_re_lays_no_attention_weight(k2_programs, program,
+                                                tokens):
+    """The trees hold `kv_b` as W_uk and W_uv in the layout the products
+    read, and the rotary columns in halves (`K2Params.from_flat`): no
+    instruction copies, transposes, slices or gathers a bfloat16 array
+    of `kv_b`'s size or of one of its halves along the rank of 512, and
+    none gathers, copies or transposes the rotation's [32, tokens, 64]
+    lanes."""
+    kv_b = 512 * 64 * (128 + 128)
+    results = _results(k2_programs[program].as_text())
+    weights = [line for op, dtype, dims, line in results
+               if op in RELAYOUTS and dtype == "bf16" and 512 in dims
+               and math.prod(dims) in (kv_b, kv_b // 2)]
+    assert not weights, weights
+    lanes = sorted((32, tokens, 64))
+    rotary = [line for op, _, dims, line in results
+              if op in RELAYOUTS - {"slice"} and sorted(dims) == lanes]
+    assert not rotary, rotary
 
 
 def test_k2_decode_step_names_its_calls(k2_programs):

@@ -129,6 +129,83 @@ def test_yarn_frequencies_are_the_references(toy):
         192 ** -0.5 * (0.1 * np.log(64) + 1) ** 2)
 
 
+def test_trees_hold_the_attention_laid_out_beside_the_published_shapes(
+        toy):
+    """`param_shapes` publishes `kv_b [rank, heads * (nope + v)]`; the
+    trees hold its two halves instead, `w_uk [heads, nope, rank]` and
+    `w_uv [heads, v, rank]`, the same numbers and no more of them, and
+    the other leaves under their published names and shapes."""
+    k = toy.kcfg
+    shapes = kimi_k2.param_shapes(k)
+    width = k.qk_nope_head_dim + k.v_head_dim
+    assert shapes["layers.0.kv_b"] == ((k.kv_lora_rank, k.num_heads * width),
+                                       "matrix")
+    for i, lp in enumerate(toy.params.trees["layers"]):
+        assert "kv_b" not in lp
+        pre = f"layers.{i}."
+        assert {n: v.shape for n, v in lp.items()
+                if n not in ("w_uk", "w_uv")} == {
+            n[len(pre):]: s for n, (s, _) in shapes.items()
+            if n.startswith(pre) and n != pre + "kv_b"}
+        w = np.asarray(toy.flat[pre + "kv_b"]).reshape(
+            k.kv_lora_rank, k.num_heads, width).transpose(1, 2, 0)
+        np.testing.assert_array_equal(lp["w_uk"], w[:, :k.qk_nope_head_dim])
+        np.testing.assert_array_equal(lp["w_uv"], w[:, k.qk_nope_head_dim:])
+    assert sum(v.size for v in jax.tree.leaves(toy.params.trees)) == sum(
+        v.size for v in toy.flat.values())
+
+
+def _published_rope(cfg, x, pos):
+    """DeepSeek-V3's rotation of the published lanes, pairs (2i, 2i+1)
+    strided: the pairs' first halves, then their second halves."""
+    factor, _, _, _, mscale, all_dim = cfg.yarn
+    m = kimi_k2.yarn_mscale(factor, mscale) / kimi_k2.yarn_mscale(
+        factor, all_dim)
+    angle = pos.astype(jnp.float32)[..., None] * kimi_k2.yarn_inv_freq(
+        cfg.qk_rope_head_dim, cfg.rope_theta, cfg.yarn)
+    cos, sin = jnp.cos(angle) * m, jnp.sin(angle) * m
+    a, b = x[..., 0::2], x[..., 1::2]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+@pytest.mark.parametrize("rope", [8, 64])
+def test_rotary_on_the_laid_out_trees_is_the_published_bit_for_bit(rope):
+    """`_queries_and_latent` over the trees, whose rotary columns are in
+    halves, against the published rotation of strided pairs over the
+    flat weights: the queries and the latent written to the cache are
+    the same bit for bit, at the toy's rotary width and the published
+    64."""
+    toy = Toy(qk_rope_head_dim=rope) if rope != 8 else Toy()
+    k = toy.kcfg
+    assert k.qk_rope_head_dim == rope
+    h = jnp.asarray(np.random.default_rng(4).standard_normal(
+        (9, k.hidden_size)), jnp.float32)
+    pos = jnp.asarray([0, 1, 2, 5, 17, 30, 31, 47, 63], jnp.int32)
+    lp, pre = toy.params.trees["layers"][1], "layers.1."
+    flat = {n[len(pre):]: v for n, v in toy.flat.items()
+            if n.startswith(pre)}
+
+    @jax.jit
+    def published(h, pos):
+        cq = kimi_k2.rms_norm(k, h @ flat["q_a"], flat["q_a_norm"])
+        q = (cq @ flat["q_b"]).reshape(h.shape[0], k.num_heads, -1)
+        kv = h @ flat["kv_a"]
+        c_kv = kimi_k2.rms_norm(k, kv[:, :k.kv_lora_rank], flat["kv_a_norm"])
+        k_rope = _published_rope(k, kv[:, k.kv_lora_rank:], pos)
+        return (q[..., :k.qk_nope_head_dim],
+                _published_rope(k, q[..., k.qk_nope_head_dim:],
+                                pos[:, None]),
+                jnp.concatenate([c_kv, k_rope], axis=-1))
+
+    got = jax.jit(lambda h, pos: kimi_k2._queries_and_latent(
+        k, lp, h, pos))(h, pos)
+    want = published(h, pos)
+    for name, g, w in zip(("q_nope", "q_rope", "latent"), got, want):
+        assert g.shape == w.shape, name
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=name)
+
+
 def test_absorbed_decode_through_the_latent_cache_equals_the_full_forward(
         toy):
     """Prefill (first form of attention, latent written into the slot),
